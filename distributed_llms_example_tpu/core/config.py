@@ -240,8 +240,6 @@ class TrainConfig:
     # (obs.report --trace).  Host-clock arithmetic only; the single
     # device interaction is one timed block at the log cadence.
     obs_budget: str = "auto"
-    # MFU denominator: peak per-chip FLOP/s in TFLOP/s (v5e bf16 ≈ 197)
-    obs_peak_tflops: float = 197.0
     # per-chip HBM ceiling in GiB for the bucketed memory account
     # (obs/memprof.py): the static account's fit verdict, the report's
     # --max-peak-hbm-frac / --min-hbm-headroom-gib denominators, and the
@@ -491,7 +489,6 @@ def add_tpu_args(p: argparse.ArgumentParser) -> None:
              "also span capture for obs.report --trace).  auto = on "
              "whenever --obs is not off",
     )
-    p.add_argument("--obs-peak-tflops", type=float, default=_D.obs_peak_tflops)
     p.add_argument(
         "--hbm-budget-gib", type=float, default=_D.hbm_budget_gib,
         help="per-chip HBM ceiling in GiB for the bucketed memory account "

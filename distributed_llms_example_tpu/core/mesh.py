@@ -87,19 +87,21 @@ def resolve_mesh_shape(cfg: MeshConfig, n_devices: int) -> MeshSpec:
 def build_mesh(cfg: MeshConfig | MeshSpec | None = None, devices: Sequence[jax.Device] | None = None) -> Mesh:
     """Build the global device mesh.
 
-    ``jax.experimental.mesh_utils.create_device_mesh`` is used when possible
-    so axis order maps onto physical ICI topology (tensor innermost).
+    On TPU ``jax.experimental.mesh_utils.create_device_mesh`` lays the axes
+    onto the physical ICI topology (tensor innermost).
     """
     devices = list(devices if devices is not None else jax.devices())
     if cfg is None:
         cfg = MeshConfig()
     spec = cfg if isinstance(cfg, MeshSpec) else resolve_mesh_shape(cfg, len(devices))
     shape = spec.as_tuple()
-    try:
+    if devices[0].platform == "tpu":
+        # a failure here propagates: enumeration order is not ICI order,
+        # and a mesh laid out against the torus still runs — only slower
         from jax.experimental import mesh_utils
 
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:  # non-TPU platforms (CPU test meshes) lack topology info
+    else:  # CPU test meshes have no topology to honor
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, AXES)
 
@@ -151,12 +153,8 @@ def initialize_distributed(
     # preemption/heartbeat allgathers — dies with "Multiprocess
     # computations aren't implemented on the CPU backend".  Gloo over TCP
     # is jax's supported CPU answer; the flag only affects CPU client
-    # construction (TPU/GPU ignore it), so set it before initialize
-    # whenever this jax version has it.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass  # older/newer jax without the flag: keep its default
+    # construction (TPU/GPU ignore it), so set it before initialize.
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

@@ -368,9 +368,12 @@ class Checkpointer:
         than its peers and desynchronize the per-attempt restore
         agreements."""
         try:
-            return self.manager.item_metadata(step)
+            meta = self.manager.item_metadata(step)
         except FileNotFoundError:
             return None
+        # orbax 0.11 wraps the saved tree in a TreeMetadata; callers
+        # classify the payload by the tree itself (a dict with "state")
+        return None if meta is None else meta.tree
 
     def all_steps(self) -> list[int]:
         return sorted(self.manager.all_steps())

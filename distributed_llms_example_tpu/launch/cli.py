@@ -75,6 +75,7 @@ import json
 import os
 import sys
 
+from distributed_llms_example_tpu.core.compile_cache import place_compile_cache
 from distributed_llms_example_tpu.core.config import (
     add_reference_args,
     add_tpu_args,
@@ -403,6 +404,7 @@ def _write_serve_output(args, lm, tok, prompts, outputs, *, extra=None):
 
 def serve_main(argv: list[str] | None = None) -> int:
     """The ``serve`` subcommand: load → shard → continuous-batching decode."""
+    place_compile_cache()
     args = build_serve_parser().parse_args(argv)
     from distributed_llms_example_tpu.serving.engine import ServingEngine
 
@@ -460,6 +462,7 @@ def build_router_parser() -> argparse.ArgumentParser:
 def serve_router_main(argv: list[str] | None = None) -> int:
     """The ``serve-router`` subcommand: load once, shard once, N engine
     replicas over the one mesh, route to completion."""
+    place_compile_cache()
     args = build_router_parser().parse_args(argv)
     from distributed_llms_example_tpu.obs.chaos import parse_chaos
     from distributed_llms_example_tpu.serving.engine import ServingEngine
@@ -562,6 +565,7 @@ def build_loadgen_parser() -> argparse.ArgumentParser:
 def serve_loadgen_main(argv: list[str] | None = None) -> int:
     """The ``serve-loadgen`` subcommand: load once, shard once, one
     fresh session (or router pool) per offered-QPS grid point."""
+    place_compile_cache()
     args = build_loadgen_parser().parse_args(argv)
     from distributed_llms_example_tpu.serving.engine import ServingEngine
     from distributed_llms_example_tpu.serving.loadgen import (
@@ -659,6 +663,7 @@ def serve_loadgen_main(argv: list[str] | None = None) -> int:
 
 
 def main(argv: list[str] | None = None) -> int:
+    place_compile_cache()
     if argv is None:
         argv = sys.argv[1:]
     if argv and argv[0] == "serve":

@@ -4,7 +4,7 @@ The reference's data layer bottoms out in native code too — `datasets.
 load_dataset('json')` (reference train-torchrun.py:153-159) runs Arrow's
 C++ JSON reader.  Here the equivalent is ``jsonl_loader.cc``: a C++ parser
 for line-delimited JSON records, compiled on demand with the toolchain's
-g++ into ``_jsonl.so`` next to this file, consumed through a zero-copy
+g++ into ``_jsonl.<source hash>.so`` next to this file, consumed through a zero-copy
 ctypes view.  ``data/dataset.py`` routes large JSONL files through it and
 keeps the pure-Python ``json.loads`` path as the always-available fallback
 (``available()`` gates every use).
@@ -17,6 +17,8 @@ parsed by ``json.loads`` only when that field is actually read.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import json
 import os
 import subprocess
@@ -25,11 +27,11 @@ from typing import Iterator, Sequence
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "jsonl_loader.cc")
-_SO = os.path.join(_DIR, "_jsonl.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _build_error: str | None = None
+_compiled_here = False
 
 
 class _DllmJsonl(ctypes.Structure):
@@ -49,16 +51,25 @@ class _DllmJsonl(ctypes.Structure):
     ]
 
 
-def _build() -> str | None:
+def _so_path() -> str:
+    """The library's name carries the hash of the source it was built
+    from, so a library from an older source — or one that arrived with
+    the tree from somewhere else — is never mistaken for this checkout's."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_jsonl.{digest}.so")
+
+
+def _build(so: str) -> str | None:
     """Compile the shared library if needed; returns an error string or None.
 
     Compiles to a per-process temp name and renames into place: the rename
     is atomic, so concurrent builders race harmlessly and an interrupted
-    build can never leave a truncated ``_jsonl.so`` that passes the mtime
-    check forever."""
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
+    build can never leave a truncated library under the final name."""
+    global _compiled_here
+    if os.path.exists(so):
         return None
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-std=c++17", "-O2", "-shared", "-fPIC", _SRC, "-o", tmp]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
@@ -70,7 +81,14 @@ def _build() -> str | None:
         except OSError:
             pass
         return f"g++ failed: {proc.stderr[-500:]}"
-    os.replace(tmp, _SO)
+    os.replace(tmp, so)
+    _compiled_here = True
+    for stale in glob.glob(os.path.join(_DIR, "_jsonl*.so")):
+        if stale != so:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
     return None
 
 
@@ -79,12 +97,13 @@ def _load_lib() -> ctypes.CDLL | None:
     with _lock:
         if _lib is not None or _build_error is not None:
             return _lib
-        err = _build()
+        so = _so_path()
+        err = _build(so)
         if err is not None:
             _build_error = err
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError as e:
             _build_error = str(e)
             return None
@@ -106,6 +125,12 @@ def available() -> bool:
 def build_error() -> str | None:
     """Why ``available()`` is False (None while it's True/untried)."""
     return _build_error
+
+
+def compiled_here() -> bool:
+    """True when THIS process compiled the library from ``jsonl_loader.cc``
+    (False: an earlier run of this checkout left the same build)."""
+    return _compiled_here
 
 
 class JsonlRecords(Sequence):
@@ -136,7 +161,9 @@ class JsonlRecords(Sequence):
             return key, raw.decode("utf-8")
         return key, json.loads(raw)
 
-    def __getitem__(self, i: int) -> dict:
+    def __getitem__(self, i: int | slice) -> dict | list[dict]:
+        if isinstance(i, slice):  # list parity: ``serve --num-prompts`` slices
+            return [self[j] for j in range(*i.indices(self._n))]
         if i < 0:
             i += self._n  # list-parity negative indexing
         if not 0 <= i < self._n:

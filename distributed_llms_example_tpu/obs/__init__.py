@@ -108,9 +108,14 @@ class TrainerObs:
         self.spans = SpanRecorder()
         self.every = max(1, int(cfg.log_every_steps))
         self.flops_per_step: float | None = None
-        self.peak_flops_per_chip = float(
-            getattr(cfg, "obs_peak_tflops", 197.0)
-        ) * 1e12
+        # MFU denominator: looked up by device kind (obs/gauges.py); None
+        # for a device with no published peak — the gauge is then omitted
+        import jax
+
+        from distributed_llms_example_tpu.obs.gauges import PEAK_BF16_FLOPS
+
+        self.device_kind = jax.devices()[0].device_kind
+        self.peak_flops_per_chip = PEAK_BF16_FLOPS.get(self.device_kind)
         hb_every = int(getattr(cfg, "obs_heartbeat_steps", 0) or 0)
         self.heartbeat = Heartbeat(
             every_steps=hb_every,
@@ -174,8 +179,6 @@ class TrainerObs:
         # the cadenced queue-drain probe (budget_probe below)
         self.budget = None
         if budget_enabled(cfg):
-            import jax
-
             self.budget = BudgetAccountant(
                 self.spans,
                 # multi-device CPU dispatch runs the program inline: a
@@ -265,7 +268,11 @@ class TrainerObs:
             sink_mod.emit({"event": "memory_account", **account})
         sink_mod.emit({
             "event": "obs_gauges",
-            "peak_flops_per_chip": self.peak_flops_per_chip,
+            **(
+                {"peak_flops_per_chip": self.peak_flops_per_chip}
+                if self.peak_flops_per_chip
+                else {"mfu_skipped": f"no published peak FLOP/s for device_kind {self.device_kind!r}"}
+            ),
             **report,
         })
 
@@ -499,8 +506,13 @@ class TrainerObs:
     def window_mfu(self, summary: dict) -> float | None:
         """MFU over the just-closed window: compiled-step FLOPs × steps
         over wall seconds and aggregate peak FLOPs.  None until the
-        startup gauge compile has supplied the numerator."""
-        if not self.flops_per_step or not summary.get("window_seconds"):
+        startup gauge compile has supplied the numerator, and on a device
+        whose peak is not in the table (named once, in ``obs_gauges``)."""
+        if (
+            not self.flops_per_step
+            or not self.peak_flops_per_chip
+            or not summary.get("window_seconds")
+        ):
             return None
         import jax
 

@@ -59,6 +59,16 @@ def training_flops_estimate(n_params: int, tokens_per_step: int) -> float:
     return 6.0 * float(n_params) * float(tokens_per_step)
 
 
+# Peak dense bf16 FLOP/s per chip, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud TPU documentation, "TPU v5e" system architecture
+# page (197 TFLOP/s bf16 per chip).  A device that is not listed has no
+# MFU: callers omit the gauge (and a benchmark fails) rather than divide
+# by another chip's peak.
+PEAK_BF16_FLOPS: dict[str, float] = {
+    "TPU v5 lite": 197e12,
+}
+
+
 def mfu(
     flops_per_step: float,
     step_time_s: float,

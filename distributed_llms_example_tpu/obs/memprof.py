@@ -12,7 +12,7 @@ faces of the question "where did the bytes go":
   ``memory_analysis()`` plus the abstract state tree's per-shard byte
   counts (both via ``utils/memory_audit.py``'s shared accounting
   functions — single owner, no forked arithmetic) into ONE bucketed
-  peak composition over the shared taxonomy ``BUCKETS`` (params /
+  peak composition over the shared scheme ``BUCKETS`` (params /
   optimizer_state / grad_accum — the EF carry — / activations+temps /
   kv_cache / other), with donation/aliasing credited (outputs minus
   aliased), the largest-N buffers named, and a fit verdict against an
@@ -58,7 +58,7 @@ from typing import Any, Iterable, Mapping
 from distributed_llms_example_tpu.obs import sink as sink_mod
 from distributed_llms_example_tpu.obs.sink import SCHEMA_VERSION
 
-# The ONE bucket taxonomy both faces (and the serving account) share.
+# The ONE bucket scheme both faces (and the serving account) share.
 # grad_accum covers the in-step fp32 accumulation carry AND the
 # error-feedback tree (TrainState.ef); kv_cache is the serving cache
 # (flat or paged pool); activations is the compiled program's temp
@@ -322,7 +322,7 @@ def serving_account(
     kv_cache_bytes: int,
     hbm_budget_gib: float = 16.0,
 ) -> dict:
-    """The serving tier's bucketed account over the SAME taxonomy: the
+    """The serving tier's bucketed account over the SAME scheme: the
     capacity gauges' cache-bytes arithmetic (serving/engine.py) lands in
     ``kv_cache``, the loaded weights in ``params``.  Shares the fit
     fields with the training account so the report renders both with one

@@ -46,10 +46,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-from distributed_llms_example_tpu.parallel.activation import compat_shard_map
 from distributed_llms_example_tpu.ops.fused_dropout import tile_keep
 
 LANES = 128  # TPU vector lane count: last-dim unit for scratch/statistics
@@ -250,7 +246,7 @@ def _fwd(q, k, v, bias, lbias, *, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -513,7 +509,7 @@ def _bwd_dlbias(q, k, v, bias, lbias, lse, delta, do, *, scale, causal,
         out_specs=pl.BlockSpec((1, 1, block_q, block_k), lb_map),
         out_shape=jax.ShapeDtypeStruct(lbias.shape, lbias.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -571,7 +567,7 @@ def _bwd(q, k, v, bias, lbias, o, lse, do, *, scale, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, 1, block_q, d), q_map),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -629,7 +625,7 @@ def _bwd(q, k, v, bias, lbias, o, lse, do, *, scale, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -1103,7 +1099,7 @@ def flash_decode(
             pltpu.VMEM((q_len, LANES), jnp.float32),
             pltpu.VMEM((q_len, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -1327,7 +1323,7 @@ def flash_decode_paged(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -1407,7 +1403,7 @@ def flash_decode_run(
         )
         args = (*args, bias)
         in_specs = (*in_specs, bias_spec)
-    return compat_shard_map(
+    return jax.shard_map(
         run, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec, check_vma=False
     )(*args)
 
@@ -1501,7 +1497,7 @@ def make_flash_lbias_sharded(
         return o, lse[..., :1]
 
     def run_fwd(args, bias):
-        return compat_shard_map(
+        return jax.shard_map(
             fwd_shard, mesh=mesh, in_specs=fwd_in_specs(bias),
             out_specs=(qkv_spec, lse_spec), check_vma=False,
         )(*args)
@@ -1546,7 +1542,7 @@ def make_flash_lbias_sharded(
         args = tuple(
             x for x in (q, k, v, bias, lbias, o, lse1, do) if x is not None
         ) + ((seed,) if has_dropout else ())
-        dq, dk, dv, dlb = compat_shard_map(
+        dq, dk, dv, dlb = jax.shard_map(
             bwd_shard, mesh=mesh, in_specs=in_specs,
             out_specs=(qkv_spec, qkv_spec, qkv_spec, lb_spec), check_vma=False,
         )(*args)

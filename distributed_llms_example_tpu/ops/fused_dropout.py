@@ -139,6 +139,24 @@ def _hash_bits(seed, tag_a, tag_b, rows, cols):
     return _mix32(x)
 
 
+def hw_seed_words(seed, tag_a, tag_b, row0, col0):
+    """Fold the five values that identify a tile into the TWO int32 words
+    the TPU PRNG accepts (Mosaic refuses ``prng_seed`` with more): two
+    ``_mix32`` chains over the same values from different constants, so a
+    pair collides only where both 32-bit chains do.  A pure function of its
+    arguments — scalar uint32 ops only, the same in a kernel and on the
+    host — which is what keeps the hardware stream's contract: equal
+    (seed, tags, offsets) seed equal bits, and any differing tile or
+    ``_shard_seed`` shard seeds an unrelated stream."""
+    words = []
+    for init in (0x9E3779B9, 0x85EBCA77):
+        h = jnp.uint32(init)
+        for v in (seed, tag_a, tag_b, row0, col0):
+            h = _mix32(h ^ jnp.asarray(v).astype(jnp.uint32))
+        words.append(h.astype(jnp.int32))
+    return tuple(words)
+
+
 def tile_keep(seed, tag_a, tag_b, row0, col0, shape, rate: float,
               hw_rng: bool):
     """Keep-mask for one (rows, cols) tile whose top-left element sits at
@@ -156,7 +174,7 @@ def tile_keep(seed, tag_a, tag_b, row0, col0, shape, rate: float,
         return hash_keep_mask(
             seed, shape, rate, tag_a=tag_a, tag_b=tag_b, row0=row0, col0=col0
         )
-    pltpu.prng_seed(seed, tag_a, tag_b, row0, col0)
+    pltpu.prng_seed(*hw_seed_words(seed, tag_a, tag_b, row0, col0))
     bits = pltpu.prng_random_bits(shape)
     if bits.dtype != jnp.uint32:
         bits = pltpu.bitcast(bits, jnp.uint32)
@@ -388,10 +406,7 @@ def _fused_run(x, seed, rate, residual, mesh):
     back to XLA)."""
     from jax.sharding import PartitionSpec as P
 
-    from distributed_llms_example_tpu.parallel.activation import (
-        BATCH_AXES,
-        compat_shard_map,
-    )
+    from distributed_llms_example_tpu.parallel.activation import BATCH_AXES
 
     if mesh is None or math.prod(mesh.devices.shape) == 1:
         return fused_dropout(x, seed, rate, residual=residual)
@@ -436,7 +451,7 @@ def _fused_run(x, seed, rate, residual, mesh):
     if residual is not None:
         args = (*args, residual)
         in_specs = (*in_specs, spec)
-    return compat_shard_map(
+    return jax.shard_map(
         run, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False
     )(*args)
 

@@ -39,7 +39,7 @@ weight-update-sharding recipe of arXiv:2004.13336, same as the optax
 path.
 
 Sharding: the apply is purely elementwise per leaf, so each leaf runs
-per-shard under ``compat_shard_map`` with the leaf's OWN param
+per-shard under ``jax.shard_map`` with the leaf's OWN param
 PartitionSpec (params, mu, nu and the grad accumulators share it by the
 PR 5 mirror contract — ``analysis/spec_lint.py`` lints both mirrors).
 Health partial sums psum over exactly the leaf's sharded axes.  Leaves
@@ -331,13 +331,12 @@ def _shard_elems(shape: tuple, spec, mesh) -> int:
 def _sharded_leaf(
     p, mu, nu, g, scal, spec, mesh, *, hyper: dict, interpret: bool | None
 ):
-    """Per-shard kernel run under ``compat_shard_map`` with the leaf's
+    """Per-shard kernel run under ``jax.shard_map`` with the leaf's
     own param spec (params/mu/nu/grads share it by the mirror
     contracts); the health partial sums psum over exactly the leaf's
     sharded axes — the second stage of the two-stage reduction."""
     from jax.sharding import PartitionSpec as P
 
-    from distributed_llms_example_tpu.parallel.activation import compat_shard_map
 
     axes = _spec_axes(spec)
 
@@ -349,7 +348,7 @@ def _sharded_leaf(
             stats = jax.lax.psum(stats, axes)
         return p2, mu2, nu2, stats
 
-    return compat_shard_map(
+    return jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), spec, spec, spec, spec),
         out_specs=(spec, spec, spec, P()),

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from distributed_llms_example_tpu.parallel.activation import compat_shard_map
 
 from distributed_llms_example_tpu.ops.attention import (
     NEG_INF,
@@ -742,6 +741,6 @@ def flash_run(
     if has_dropout:
         args = (*args, jnp.asarray(dropout_seed, jnp.int32).reshape(()))
         in_specs = (*in_specs, P())
-    return compat_shard_map(
+    return jax.shard_map(
         run, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec, check_vma=False
     )(*args)

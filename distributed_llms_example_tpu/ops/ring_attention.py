@@ -48,7 +48,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_llms_example_tpu.ops.attention import NEG_INF
-from distributed_llms_example_tpu.parallel.activation import compat_shard_map, pvary_to
+from distributed_llms_example_tpu.parallel.activation import pvary_to
 
 
 def _block_update(carry, q, k, v, bias_blk, q_pos, k_pos, *, scale: float, causal: bool,
@@ -128,11 +128,7 @@ def ring_attention(
     # fresh zeros carry no varying-manual-axes provenance; inside a
     # check_vma region (the stage×sequence pipeline) the running state must
     # match q's vma or the causal lax.cond's branches disagree on types
-    # (pre-vma jax has no typeof/pcast — there pvary_to is the identity)
-    want = (
-        tuple(getattr(jax.typeof(q), "vma", frozenset()))
-        if hasattr(jax, "typeof") else ()
-    )
+    want = tuple(getattr(jax.typeof(q), "vma", frozenset()))
     m, l, acc = pvary_to((m, l, acc), want)
 
     compute_dtype = q.dtype
@@ -216,6 +212,6 @@ def ring_attention_sharded(
             axis_name=seq_axis, axis_size=n, causal=causal, scale=scale, dtype=dtype,
         )
 
-    return compat_shard_map(
+    return jax.shard_map(
         run, mesh=mesh, in_specs=tuple(in_specs), out_specs=qspec, check_vma=False
     )(*args)

@@ -52,24 +52,13 @@ def activation_mesh(mesh: Mesh | None):
         _state.mesh = prev
 
 
-# Does this jax carry the varying-manual-axes (vma) type system?  Newer
-# jax (jax.shard_map, check_vma=) tracks per-axis variance and requires
-# explicit pcasts; 0.4-era jax (jax.experimental.shard_map, check_rep=)
-# has neither — there pvary_to is a no-op and shard_map calls go through
-# ``compat_shard_map`` below with replication checking off.
-_HAS_VMA = hasattr(jax, "typeof") and hasattr(jax.lax, "pcast")
-
-
 def pvary_to(tree, axes):
     """Mark every array in ``tree`` varying over ``axes`` (a name or tuple
     of names) for shard_map's vma checking (check_vma=True), skipping axes
     an array is ALREADY varying over — so values that enter a manual region
     sharded (hence varying) over some axis can be upcast to the full set
     without double-marking.  The single home for this logic: the pipeline
-    body and the ring-attention carry init both need it.  On pre-vma jax
-    this is the identity: there is no variance type to cast."""
-    if not _HAS_VMA:
-        return tree
+    body and the ring-attention carry init both need it."""
     if isinstance(axes, str):
         axes = (axes,)
 
@@ -79,53 +68,6 @@ def pvary_to(tree, axes):
         return jax.lax.pcast(x, missing, to="varying") if missing else x
 
     return jax.tree.map(mark, tree)
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                     check_vma=True):
-    """``jax.shard_map`` across jax generations — the ONE place the two
-    APIs meet, so every manual region (pipelines, flash/ring attention)
-    stays version-portable:
-
-    - new jax: ``jax.shard_map(..., axis_names=..., check_vma=...)``
-      (partial-auto via axis_names; vma-typed).
-    - 0.4-era jax: ``jax.experimental.shard_map.shard_map(..., auto=...)``
-      with ``auto`` = the mesh axes NOT named manual, and
-      ``check_rep=False`` — the old replication checker predates the vma
-      system and rejects these programs; correctness does not depend on
-      it (the bodies do their cross-shard reductions with explicit
-      psums).
-
-    ``axis_names=None`` means fully manual (every mesh axis)."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, **kw,
-        )
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    auto = (
-        frozenset()
-        if axis_names is None
-        else frozenset(mesh.axis_names) - frozenset(axis_names)
-    )
-    # Partial-auto with a REAL (size>1) auto axis is broken on 0.4-era
-    # jax: the partitioner rejects the body's axis_index lowering
-    # ("PartitionId instruction is not supported for SPMD partitioning").
-    # Failing here — before minutes of tracing — names the constraint;
-    # the stage>1 pipelines are blocked on a jax upgrade (ROADMAP).
-    if any(mesh.shape.get(a, 1) > 1 for a in auto):
-        raise NotImplementedError(
-            "this jax version does not support partial-auto shard_map "
-            f"(manual={sorted(axis_names)} with live auto axes "
-            f"{sorted(a for a in auto if mesh.shape.get(a, 1) > 1)}); "
-            "the stage>1 pipeline schedules need a newer jax"
-        )
-    return _legacy_shard_map(
-        f, mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, auto=auto,
-    )
 
 
 def current_kv_cache_dtype() -> str:

@@ -52,7 +52,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_llms_example_tpu.analysis.composition import reason_for
 from distributed_llms_example_tpu.parallel.activation import (
-    compat_shard_map,
     manual_sequence,
     pvary_to,
 )
@@ -517,7 +516,7 @@ def pipeline_apply(
 
     out_specs = (hidden_spec, P()) if with_aux else hidden_spec
 
-    result = compat_shard_map(
+    result = jax.shard_map(
         outer,
         mesh=mesh,
         axis_names=set(axes_all),
@@ -743,7 +742,7 @@ def _pvg_shard_map(body, *, mesh, axis_name, axes_all, seq_axis, n_seq,
         with manual_sequence(seq_axis, n_seq):
             return body(sp, pp, h, ex, lb, rt)
 
-    return compat_shard_map(
+    return jax.shard_map(
         outer,
         mesh=mesh,
         axis_names=set(axes_all),

@@ -66,7 +66,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from distributed_llms_example_tpu.parallel.activation import compat_shard_map
 from distributed_llms_example_tpu.parallel.pipeline import (
     _full_spec,
     _make_run_stage,
@@ -473,7 +472,7 @@ def pipeline_value_and_grad_seq2seq(
     rng_tree = {} if rng is None else {"key": rng}
     repl = lambda t: jax.tree.map(lambda _: P(), t)  # noqa: E731
 
-    return compat_shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         axis_names={axis_name},

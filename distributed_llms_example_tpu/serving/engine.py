@@ -144,7 +144,7 @@ class ServeConfig:
     spec_tokens: int = 0  # speculative decode: drafts per verify round (0 = off)
     spec_draft_model: str = ""  # registry draft model ("" = n-gram self-draft)
     # the bucketed HBM account (obs/memprof.py): the capacity gauges'
-    # cache-bytes arithmetic lands in the shared params/kv_cache taxonomy
+    # cache-bytes arithmetic lands in the shared params/kv_cache scheme
     # and the serve_summary carries its fit verdict against this ceiling
     hbm_budget_gib: float = 16.0
     # where a RESOURCE_EXHAUSTED mid-serve dumps its atomic
@@ -1542,7 +1542,7 @@ class ServeSession:
 
     def _memory_account(self) -> dict:
         """The serving tier's bucketed HBM account over the shared
-        taxonomy: loaded weights in ``params``, the live cache/pool bytes
+        scheme: loaded weights in ``params``, the live cache/pool bytes
         (the capacity gauges' arithmetic) in ``kv_cache``."""
         from distributed_llms_example_tpu.obs import memprof
 
@@ -1985,7 +1985,7 @@ class ServeSession:
         if self.replica is not None:
             summary["replica"] = int(self.replica)
         # the shared bucketed account (params + kv_cache over the one
-        # taxonomy) with its fit verdict — the capacity gauges' bytes,
+        # scheme) with its fit verdict — the capacity gauges' bytes,
         # re-pointed through obs/memprof.py
         acct = self._memory_account()
         summary["memory_account"] = acct

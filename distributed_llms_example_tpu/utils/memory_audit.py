@@ -72,7 +72,7 @@ def compiled_byte_view(ma: Any) -> dict:
     }
 
 
-# TrainState field → shared memory-bucket taxonomy (obs/memprof.py BUCKETS).
+# TrainState field → shared memory-bucket scheme (obs/memprof.py BUCKETS).
 # ``ef`` is the per-worker fp32 error-feedback carry from --grad-compression,
 # i.e. gradient-accumulation state that persists across steps.
 _STATE_FIELD_BUCKETS = {
@@ -84,7 +84,7 @@ _STATE_FIELD_BUCKETS = {
 
 def state_bucket_bytes(a_state: Any, sh: Any) -> dict[str, int]:
     """Per-device shard bytes of the train state, split by top-level
-    TrainState field into the shared bucket taxonomy.  Per-leaf additive,
+    TrainState field into the shared bucket scheme.  Per-leaf additive,
     so ``sum(values)`` EQUALS ``_shard_bytes(a_state, sh)`` — the audit's
     ``analytic_state_bytes`` and memprof's params/optimizer buckets are
     the same numbers from this one function."""

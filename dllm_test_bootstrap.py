@@ -2,9 +2,8 @@
 
 Tests need JAX on an 8-device virtual CPU mesh, which requires
 ``JAX_PLATFORMS=cpu`` and ``--xla_force_host_platform_device_count=8`` to be
-set before the interpreter initializes JAX.  Environments that register a
-TPU PJRT plugin from sitecustomize initialize JAX at interpreter startup, so
-the only reliable fix is to re-exec pytest once with a corrected
+set before the interpreter initializes JAX — whatever environment pytest
+was started from — so pytest re-execs itself once with a corrected
 environment.  This module is imported during pytest's pre-parse phase,
 before output capture starts, so the re-exec'ed process keeps the original
 stdout/stderr.

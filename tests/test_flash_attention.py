@@ -281,8 +281,9 @@ def test_fully_masked_rows_give_finite_zero_grads():
 
 def test_causal_cap_is_head_dim_dependent():
     """Causal tiles cap at 512 for narrow heads (d=64: diagonal masked work
-    dominates wider tiles) but 1024 at d>=128 (7B regime, measured -17%/-24%
-    fwd+bwd at batch 4/8 — BENCH_7B_r05.json attack A)."""
+    dominates wider tiles) but 1024 at d>=128 (7B regime: -17%/-24% fwd+bwd
+    at batch 4/8, measured before this round on other code; not measured on
+    today's)."""
     from distributed_llms_example_tpu.ops.flash_attention import _block_caps
 
     assert _block_caps(True, False, 64) == (512, 512)

@@ -25,6 +25,7 @@ from distributed_llms_example_tpu.ops.fused_dropout import (
     fused_dropout,
     fused_dropout_supported,
     hash_keep_mask,
+    hw_seed_words,
     keep_threshold,
     resolve_impl,
     seed_from_key,
@@ -153,6 +154,51 @@ def test_keep_threshold_is_24bit_exact():
 
 
 # ------------------------------------------------- helper / module layer
+
+
+def test_hw_seed_words_contract():
+    """The hardware-RNG branch runs only compiled on a TPU, so its seeding
+    is pinned here on the host: the fold of (seed, tags, offsets) into the
+    two words the TPU PRNG accepts is a pure function of its inputs, and
+    adjacent tiles, other planes and other ``_shard_seed`` shards all seed
+    differently.  (That Mosaic accepts the two-word seed is
+    tests/test_chip_compile.py's half.)"""
+    from distributed_llms_example_tpu.ops.fused_dropout import _shard_seed
+
+    def words(*ids):
+        w = jax.jit(hw_seed_words)(*[jnp.int32(i) for i in ids])
+        assert len(w) == 2 and all(x.dtype == jnp.int32 and x.shape == () for x in w)
+        return tuple(int(x) for x in w)
+
+    base = (1234, 3, 5, 256, 512)
+    assert words(*base) == words(*base)  # pure: same ids, same words
+    assert words(*base) == tuple(int(x) for x in hw_seed_words(*base))  # traced == eager
+    # every tile of a (batch 16, heads 16, 1024x1024 in 128-blocks) plane
+    # set, every identifying value moved alone, and negative seeds
+    tiles = {
+        words(1234, b, h, r * 128, c * 128)
+        for b in range(4) for h in range(4) for r in range(8) for c in range(8)
+    }
+    assert len(tiles) == 4 * 4 * 8 * 8
+    for i in range(5):
+        moved = list(base)
+        moved[i] += 1 if i < 3 else 128
+        assert words(*moved) != words(*base), f"argument {i} does not reach the seed"
+    assert words(-1, 0, 0, 0, 0) != words(1, 0, 0, 0, 0)
+    # swapped offsets / tags are different tiles, not the same seed
+    assert words(1234, 3, 5, 512, 256) != words(*base)
+    assert words(1234, 5, 3, 256, 512) != words(*base)
+    # each word alone already separates adjacent tiles (no weak half)
+    assert len({t[0] for t in tiles}) == len(tiles) == len({t[1] for t in tiles})
+
+    # shards: _shard_seed folds the mesh position into the seed, and the
+    # fold carries it into both words
+    mesh = jax.make_mesh((4,), ("fsdp",), devices=jax.devices()[:4])
+    per_shard = jax.shard_map(
+        lambda s: jnp.stack(hw_seed_words(_shard_seed(s[0], ("fsdp",)), 0, 0, 0, 0))[None],
+        mesh=mesh, in_specs=jax.sharding.PartitionSpec(), out_specs=jax.sharding.PartitionSpec("fsdp"),
+    )(jnp.full((1,), 1234, jnp.int32))
+    assert len({tuple(map(int, row)) for row in np.asarray(per_shard)}) == 4
 
 
 def test_seed_from_key_deterministic_and_impl_agnostic():

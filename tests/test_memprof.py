@@ -182,7 +182,7 @@ def test_memory_monitor_cpu_skip_is_named_and_once_only(capsys):
 
 
 # ---------------------------------------------------------------------------
-# the serving account: same taxonomy, same fit fields
+# the serving account: same scheme, same fit fields
 # ---------------------------------------------------------------------------
 
 
@@ -254,7 +254,7 @@ def test_static_account_is_additive_and_matches_audit_exactly():
     )
     # the grad_accum bucket (TrainState.ef error-feedback) exists even
     # when EF is absent — 0, not missing (absent beats zero is for
-    # MEASUREMENTS; the taxonomy itself is total)
+    # MEASUREMENTS; the scheme itself is total)
     assert acct["buckets_bytes"]["grad_accum"] == 0  # no EF without int8
 
 

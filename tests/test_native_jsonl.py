@@ -41,6 +41,7 @@ def test_native_matches_python(jsonl_file):
     assert len(recs) == len(RECORDS)
     for got, want in zip(recs, RECORDS):
         assert got == want
+    assert recs[1:3] == RECORDS[1:3] and recs[:-4] == RECORDS[:-4]  # list-parity slices
 
 
 def test_escapes_round_trip(tmp_path):

@@ -105,7 +105,7 @@ class TrainerObs:
             # itself — before its first device_report line — and passes
             # manage_sink=False so the file channel is opened exactly once
             install_sink(build_sink(getattr(cfg, "obs", "stdout"), cfg.output_dir))
-        self.spans = SpanRecorder()
+        self.spans = SpanRecorder(scope="train")
         self.every = max(1, int(cfg.log_every_steps))
         self.flops_per_step: float | None = None
         # MFU denominator: looked up by device kind (obs/gauges.py); None

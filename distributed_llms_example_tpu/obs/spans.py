@@ -1,4 +1,4 @@
-"""Host-side span tracing for the train loop.
+"""Host-side span tracing for the train loop and the serving engine's round.
 
 Monotonic-clock spans (``data_wait``, ``step_dispatch``, ``device_sync``,
 ``eval``, ``checkpoint``, nested freely) plus a per-step ring buffer from
@@ -8,7 +8,15 @@ host — recording a span costs two clock reads and a dict update, and
 NOTHING here touches a device, so instrumented non-logging steps keep the
 zero-sync async-dispatch property MetricLogger already guarantees.
 
-The clock is injectable so tests drive the recorder deterministically.
+Every span, nested ones too, also opens a profiler annotation named
+``<scope>/<name>`` (``jax.profiler.TraceAnnotation``): under a profiler
+session it lands on a ``/host:CPU`` line on the device trace's clock (what
+``Span.set`` is given becomes its stats); with no session it is one inactive
+TraceMe.  The ring keeps the bare names.
+
+The clock and the annotation factory are injectable so tests drive the
+recorder deterministically (and this module imports jax only when a
+recorder is built without a factory).
 """
 
 from __future__ import annotations
@@ -37,6 +45,26 @@ def percentiles(values: Sequence[float], qs: Sequence[float]) -> list[float]:
     return out
 
 
+class Span:
+    """One open span: ``t0`` from its start, ``dur`` / ``end`` once closed
+    (the caller's own arithmetic reads these instead of the clock again);
+    ``set`` hands counters to the span's annotation as its stats."""
+
+    __slots__ = ("t0", "dur", "_annotation")
+
+    def __init__(self, t0: float, annotation):
+        self.t0 = t0
+        self.dur = 0.0
+        self._annotation = annotation
+
+    @property
+    def end(self) -> float:
+        return self.t0 + self.dur
+
+    def set(self, **counters) -> None:
+        self._annotation.set_metadata(**counters)
+
+
 class SpanRecorder:
     """Ring-buffered span/step-time recorder with window summaries.
 
@@ -52,9 +80,16 @@ class SpanRecorder:
         ring_size: int = 512,
         clock: Callable[[], float] = time.perf_counter,
         straggler_factor: float = STRAGGLER_FACTOR,
+        scope: str = "train",
+        annotate: Callable | None = None,
     ):
         self.ring_size = int(ring_size)
         self.clock = clock
+        self.scope = scope
+        if annotate is None:
+            from jax.profiler import TraceAnnotation as annotate
+        # name -> context manager with set_metadata(**counters)
+        self._annotate = annotate
         self.straggler_factor = float(straggler_factor)
         self._ring: list[float] = []  # per-step wall seconds, newest last
         self._depth = 0
@@ -78,25 +113,26 @@ class SpanRecorder:
 
     @contextlib.contextmanager
     def span(self, name: str):
-        self._depth += 1
-        t0 = self.clock()
-        try:
-            yield
-        finally:
-            dt = self.clock() - t0
-            self._depth -= 1
-            agg = self._window_spans.get(name)
-            if agg is None:
-                self._window_spans[name] = [dt, 1, dt]
-            else:
-                agg[0] += dt
-                agg[1] += 1
-                if dt > agg[2]:
-                    agg[2] = dt
-            if self._depth == 0:
-                self._step_spans[name] = self._step_spans.get(name, 0.0) + dt
-                if self.listener is not None:
-                    self.listener.on_span(name, t0, dt)
+        with self._annotate(f"{self.scope}/{name}") as annotation:
+            self._depth += 1
+            sp = Span(self.clock(), annotation)
+            try:
+                yield sp
+            finally:
+                dt = sp.dur = self.clock() - sp.t0
+                self._depth -= 1
+                agg = self._window_spans.get(name)
+                if agg is None:
+                    self._window_spans[name] = [dt, 1, dt]
+                else:
+                    agg[0] += dt
+                    agg[1] += 1
+                    if dt > agg[2]:
+                        agg[2] = dt
+                if self._depth == 0:
+                    self._step_spans[name] = self._step_spans.get(name, 0.0) + dt
+                    if self.listener is not None:
+                        self.listener.on_span(name, sp.t0, dt)
 
     def step_complete(self) -> None:
         """One train-loop iteration finished: record its wall duration

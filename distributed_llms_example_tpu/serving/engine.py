@@ -45,13 +45,18 @@ fields** — useful tokens/sec and the SLO-attainment fraction at the
 configured ``ttft_slo_ms``, the router tier's dispatch inputs) — TTFT
 p95 stops being one opaque aggregate and becomes "the tail waited in
 queue" vs "prefill is slow".
+
+Host spans (obs/spans.py, scope ``serve``): each round is ``serve/round``,
+partitioned into ``admit_prep``, ``prefill_dispatch``, ``decode_dispatch``,
+``token_fetch``, ``emit`` and ``window_log``.  Under a profiler session they
+sit on the device trace's clock; the prefill/decode seconds of the events
+above are these spans' durations.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Any, Sequence
 
 import jax
@@ -68,6 +73,7 @@ from distributed_llms_example_tpu.parallel.activation import (
     constrain_cache,
     kv_cache_context,
 )
+from distributed_llms_example_tpu.obs.spans import SpanRecorder, percentiles
 from distributed_llms_example_tpu.serving import cache_pool
 from distributed_llms_example_tpu.serving import spec as spec_decode
 from distributed_llms_example_tpu.utils.jsonlog import log_json
@@ -207,8 +213,6 @@ class ServeStats:
         return self.decode_tokens / max(self.decode_seconds, 1e-9)
 
     def ttft_percentiles(self) -> tuple[float, float]:
-        from distributed_llms_example_tpu.obs.spans import percentiles
-
         if not self.ttft_s:
             return 0.0, 0.0
         p50, p95 = percentiles(self.ttft_s, (0.50, 0.95))
@@ -218,8 +222,6 @@ class ServeStats:
         """Queue-wait vs prefill share of TTFT over finished requests —
         the serve_summary fields that make a fat TTFT p95 actionable
         (admit more slots vs speed up prefill)."""
-        from distributed_llms_example_tpu.obs.spans import percentiles
-
         q50, q95 = percentiles(self.queue_wait_s, (0.50, 0.95))
         p50, p95 = percentiles(self.prefill_share_s, (0.50, 0.95))
         total = sum(self.ttft_s)
@@ -470,6 +472,8 @@ class ServingEngine:
             self.trace_counts[name] = self.trace_counts.get(name, 0) + 1
             return fn(*args)
 
+        # the program's name on the profiler's XLA Modules line: jit_serve_<name>
+        counted.__name__ = counted.__qualname__ = f"serve_{name}"
         jitted = jax.jit(counted, donate_argnums=donate)
 
         def run(*args):
@@ -882,14 +886,17 @@ class ServingEngine:
         return state
 
     # ---------------------------------------------------------------- loop
-    def open(self, params: Any, *, replica: int | None = None) -> "ServeSession":
+    def open(self, params: Any, *, replica: int | None = None,
+             spans: SpanRecorder | None = None) -> "ServeSession":
         """Open a stepwise serving session over this engine: ``submit``
         requests as they arrive, drive ``step()`` per scheduler round,
         ``finalize()`` at end of life.  ``generate`` below is the batch
         wrapper; the replica router (serving/router.py) drives one open
         session per replica.  ``replica`` stamps the serve events so the
-        router tier's streams stay attributable per engine."""
-        return ServeSession(self, params, replica=replica)
+        router tier's streams stay attributable per engine.  ``spans``
+        replaces the session's span recorder (tests: a virtual clock and
+        a fake annotation factory)."""
+        return ServeSession(self, params, replica=replica, spans=spans)
 
     def generate(
         self,
@@ -947,12 +954,17 @@ class ServeSession:
     ``serve_request`` span stream."""
 
     def __init__(self, engine: ServingEngine, params: Any,
-                 *, replica: int | None = None):
+                 *, replica: int | None = None,
+                 spans: SpanRecorder | None = None):
         import collections
 
         eng = self.eng = engine
         self.params = params
         self.replica = replica
+        # host spans of each round's stages; a ring "step" is one round
+        # period (round end to round end): its outermost span ``round``, the
+        # rest the driver's time between rounds
+        self.spans = spans if spans is not None else SpanRecorder(scope="serve")
         self.n_chips = max(jax.device_count(), 1)
         S = eng.S
         # per-request tables, session-local rid = index (grow on submit)
@@ -1001,7 +1013,7 @@ class ServeSession:
         )
         self.state = eng._init_state(params)
         self.state = eng.warm(params, self.state)
-        self.t_open = time.perf_counter()
+        self.t_open = self.spans.clock()
         self.stats.cache_bytes_resident, self._per_block = (
             eng._state_byte_account(self.state)
         )
@@ -1026,7 +1038,7 @@ class ServeSession:
         ))
         self._bpt_samples: list[float] = []
         self._win_tokens, self._win_occ = 0, 0.0
-        self._win_t0 = time.perf_counter()
+        self._win_t0 = self.t_open
         self._win_prefill, self._win_decode = 0.0, 0.0
         # queueing-telemetry window counters: submissions vs completions
         # inside the window — their imbalance IS the queue growing
@@ -1062,6 +1074,7 @@ class ServeSession:
         if self._finalized:
             raise RuntimeError("session already finalized")
         rid = len(self.requests)
+        now = self.spans.clock()
         self.requests.append(list(tokens))
         self.attn_masks.append(
             list(attention_mask) if attention_mask is not None else None
@@ -1072,7 +1085,6 @@ class ServeSession:
         self.labels.append(rid if label is None else label)
         self.outputs.append([])
         self.ttft.append(None)
-        now = time.perf_counter()
         self.submit_t.append(now)
         self.arrival_t.append(float(arrival) if arrival is not None else now)
         self.first_tok_wall.append(None)
@@ -1192,126 +1204,134 @@ class ServeSession:
             self.slot_chain[slot] = []
             self.slot_bt[slot, :] = self.eng.pool.num_blocks
 
+    def _admission_counters(self, prep, rids: list[int]) -> None:
+        """Stamp an admitting ``admit_prep`` span with how many it admits and
+        how long they queued: this span's start minus each request's arrival."""
+        wait = sum(prep.t0 - self.arrival_t[rid] for rid in rids)
+        prep.set(n=len(rids), queue_wait_us_sum=int(round(wait * 1e6)))
+
     def _admit_now(self, finished: list) -> None:
         eng = self.eng
         if eng.paged and eng.prefix:
             return self._admit_now_prefix(finished)
         S, W, C = eng.S, eng.W, eng.prefill_batch
-        free = [i for i in range(S) if not self.active[i]]
-        n = min(len(free), C, len(self.pending))
-        if n == 0:
-            return
-        plen = lambda rid: min(len(self.requests[rid]), W)  # noqa: E731
-        if eng.paged:
-            # shrink the chunk until the free list funds it: admission
-            # DEFERS on a short pool instead of over-committing — every
-            # eviction frees blocks, so deferred requests admit later
-            while n > 0:
-                needed = sum(
-                    cache_pool.blocks_needed(
-                        plen(self.pending[i]), self.budgets[self.pending[i]],
-                        eng.block_size,
-                    )
-                    for i in range(n)
-                )
-                if eng.pool.can_alloc(needed):
-                    break
-                n -= 1
+        with self.spans.span("admit_prep") as prep:
+            free = [i for i in range(S) if not self.active[i]]
+            n = min(len(free), C, len(self.pending))
             if n == 0:
-                self.stats.admit_deferrals += 1
                 return
-        reqs = [self.pending.popleft() for _ in range(n)]
-        # the smallest compiled admission width covering this chunk —
-        # short prompts stop paying the max_source_length program
-        bucket = next(
-            b for b in eng.buckets if b >= max(plen(rid) for rid in reqs)
-        )
-        ids = np.full((C, bucket), eng.pad, np.int32)
-        mask = np.zeros((C, bucket), np.int32)
-        for r, rid in enumerate(reqs):
-            toks = self.requests[rid][:bucket]
-            ids[r, : len(toks)] = toks
-            mask[r, : len(toks)] = 1
-            if self.attn_masks[rid] is not None:
-                m = self.attn_masks[rid][:bucket]
-                mask[r, : len(m)] = m
-        slot_idx = np.full(C, S, np.int32)  # padding rows drop
-        slot_idx[:n] = free[:n]
-        admit_rows = None
-        if eng.paged:
-            # fund + map each row's blocks BEFORE the program runs: the
-            # flat (chunk × chunk-tiles) assignment carries sentinels for
-            # tiles that must not copy (padding rows, prompt gap)
-            ntc = (bucket + eng.L) // eng.block_size
-            admit_rows = np.full((C, ntc), eng.pool.num_blocks, np.int32)
-            for r, rid in enumerate(reqs):
-                blocks = eng.pool.alloc(
-                    cache_pool.blocks_needed(
-                        plen(rid), self.budgets[rid], eng.block_size
-                    )
-                )
-                assert blocks is not None  # funded above
-                slot = free[r]
-                self.slot_blocks[slot] = blocks
-                row = cache_pool.build_block_row(
-                    eng.n_tiles, blocks,
-                    prompt_len=plen(rid), bucket_width=bucket,
-                    budget=self.budgets[rid], block_size=eng.block_size,
-                    sentinel=eng.pool.num_blocks,
-                )
-                self.slot_bt[slot, :] = row
-                admit_rows[r, :] = row[:ntc]
-        t0 = time.perf_counter()
-        pre = eng._prefill(self.params, jnp.asarray(ids), jnp.asarray(mask))
-        if eng.is_seq2seq:
-            enc, pmask, ckv = pre
-            self.state = eng._admit(
-                self.state, enc, pmask, ckv, jnp.asarray(slot_idx)
-            )
-        else:
-            cache, full_mask, plens, first = pre
+            plen = lambda rid: min(len(self.requests[rid]), W)  # noqa: E731
             if eng.paged:
+                # shrink the chunk until the free list funds it: admission
+                # DEFERS on a short pool instead of over-committing — every
+                # eviction frees blocks, so deferred requests admit later
+                while n > 0:
+                    needed = sum(
+                        cache_pool.blocks_needed(
+                            plen(self.pending[i]), self.budgets[self.pending[i]],
+                            eng.block_size,
+                        )
+                        for i in range(n)
+                    )
+                    if eng.pool.can_alloc(needed):
+                        break
+                    n -= 1
+                if n == 0:
+                    self.stats.admit_deferrals += 1
+                    return
+            reqs = [self.pending.popleft() for _ in range(n)]
+            self._admission_counters(prep, reqs)
+            # the smallest compiled admission width covering this chunk —
+            # short prompts stop paying the max_source_length program
+            bucket = next(
+                b for b in eng.buckets if b >= max(plen(rid) for rid in reqs)
+            )
+            ids = np.full((C, bucket), eng.pad, np.int32)
+            mask = np.zeros((C, bucket), np.int32)
+            for r, rid in enumerate(reqs):
+                toks = self.requests[rid][:bucket]
+                ids[r, : len(toks)] = toks
+                mask[r, : len(toks)] = 1
+                if self.attn_masks[rid] is not None:
+                    m = self.attn_masks[rid][:bucket]
+                    mask[r, : len(m)] = m
+            slot_idx = np.full(C, S, np.int32)  # padding rows drop
+            slot_idx[:n] = free[:n]
+            admit_rows = None
+            if eng.paged:
+                # fund + map each row's blocks BEFORE the program runs: the
+                # flat (chunk × chunk-tiles) assignment carries sentinels for
+                # tiles that must not copy (padding rows, prompt gap)
+                ntc = (bucket + eng.L) // eng.block_size
+                admit_rows = np.full((C, ntc), eng.pool.num_blocks, np.int32)
+                for r, rid in enumerate(reqs):
+                    blocks = eng.pool.alloc(
+                        cache_pool.blocks_needed(
+                            plen(rid), self.budgets[rid], eng.block_size
+                        )
+                    )
+                    assert blocks is not None  # funded above
+                    slot = free[r]
+                    self.slot_blocks[slot] = blocks
+                    row = cache_pool.build_block_row(
+                        eng.n_tiles, blocks,
+                        prompt_len=plen(rid), bucket_width=bucket,
+                        budget=self.budgets[rid], block_size=eng.block_size,
+                        sentinel=eng.pool.num_blocks,
+                    )
+                    self.slot_bt[slot, :] = row
+                    admit_rows[r, :] = row[:ntc]
+        with self.spans.span("prefill_dispatch") as sp:
+            pre = eng._prefill(self.params, jnp.asarray(ids), jnp.asarray(mask))
+            if eng.is_seq2seq:
+                enc, pmask, ckv = pre
                 self.state = eng._admit(
-                    self.state, cache, full_mask, first, jnp.asarray(slot_idx),
-                    jnp.asarray(admit_rows.reshape(-1)),
+                    self.state, enc, pmask, ckv, jnp.asarray(slot_idx)
                 )
             else:
-                self.state = eng._admit(
-                    self.state, cache, full_mask, first, jnp.asarray(slot_idx)
-                )
-            plens_h = np.asarray(jax.device_get(plens))
-            first_h = np.asarray(jax.device_get(first))
-        dt = time.perf_counter() - t0
+                cache, full_mask, plens, first = pre
+                if eng.paged:
+                    self.state = eng._admit(
+                        self.state, cache, full_mask, first, jnp.asarray(slot_idx),
+                        jnp.asarray(admit_rows.reshape(-1)),
+                    )
+                else:
+                    self.state = eng._admit(
+                        self.state, cache, full_mask, first, jnp.asarray(slot_idx)
+                    )
+                plens_h = np.asarray(jax.device_get(plens))
+                first_h = np.asarray(jax.device_get(first))
+        t0, dt, now = sp.t0, sp.dur, sp.end
         self.stats.prefill_seconds += dt
         self._win_prefill += dt
         self.progress += 1
-        now = time.perf_counter()
-        for r, rid in enumerate(reqs):
-            slot = free[r]
-            self.slot_req[slot] = rid
-            self.emitted[slot] = 0
-            self.lengths[slot] = plen(rid)
-            self.base[slot] = bucket
-            self.active[slot] = True
-            self.admit_t[rid] = t0
-            self.prefill_dt[rid] = dt
-            if not eng.is_seq2seq:
-                self.lengths[slot] = int(plens_h[r])
-                # the causal prefill already produced token #1
-                self.outputs[rid].append(int(first_h[r]))
-                self.emitted[slot] = 1
-                self.ttft[rid] = now - self.submit_t[rid]
-                self.first_tok_wall[rid] = now
-                if (
-                    int(first_h[r]) == eng.eos
-                    or self.emitted[slot] >= self.budgets[rid]
-                ):
-                    self._evict_slot(slot)
-                    self._finish_request(rid, slot, now)
-                    finished.append(rid)
-        self.stats.peak_cache_bytes_in_use = max(
-            self.stats.peak_cache_bytes_in_use, self._bytes_in_use()
-        )
+        with self.spans.span("emit"):
+            for r, rid in enumerate(reqs):
+                slot = free[r]
+                self.slot_req[slot] = rid
+                self.emitted[slot] = 0
+                self.lengths[slot] = plen(rid)
+                self.base[slot] = bucket
+                self.active[slot] = True
+                self.admit_t[rid] = t0
+                self.prefill_dt[rid] = dt
+                if not eng.is_seq2seq:
+                    self.lengths[slot] = int(plens_h[r])
+                    # the causal prefill already produced token #1
+                    self.outputs[rid].append(int(first_h[r]))
+                    self.emitted[slot] = 1
+                    self.ttft[rid] = now - self.submit_t[rid]
+                    self.first_tok_wall[rid] = now
+                    if (
+                        int(first_h[r]) == eng.eos
+                        or self.emitted[slot] >= self.budgets[rid]
+                    ):
+                        self._evict_slot(slot)
+                        self._finish_request(rid, slot, now)
+                        finished.append(rid)
+            self.stats.peak_cache_bytes_in_use = max(
+                self.stats.peak_cache_bytes_in_use, self._bytes_in_use()
+            )
 
     def _admit_now_prefix(self, finished: list) -> None:
         """Prefix-cache admission: per-row transactional packing (match
@@ -1330,174 +1350,179 @@ class ServeSession:
         eng = self.eng
         S, W, C = eng.S, eng.W, eng.prefill_batch
         bs, N = eng.block_size, eng.pool.num_blocks
-        free = [i for i in range(S) if not self.active[i]]
-        n = min(len(free), C, len(self.pending))
-        if n == 0:
-            return
-        plen = lambda rid: min(len(self.requests[rid]), W)  # noqa: E731
-        cold: list[tuple[int, int, int, list[str]]] = []  # rid, slot, p, hashes
-        warm: list[dict] = []
-        warm_written: set[int] = set()
-        taken = 0
-        while taken < n:
-            rid = self.pending[0]
-            p = plen(rid)
-            budget = self.budgets[rid]
-            toks = self.requests[rid][:p]
-            # custom-masked prompts have no token-only identity: their KV
-            # depends on the mask too, so they neither match nor register
-            eligible = self.attn_masks[rid] is None
-            hashes = cache_pool.chain_hashes(toks, bs) if eligible else []
-            # keep >= 1 prompt token in the tail — the first output token
-            # is computed from the LAST prompt position's logits, so a
-            # fully-cached prompt still re-prefills its final block
-            chain = (
-                eng.pool.match_chain(hashes[: (p - 1) // bs])
-                if eligible else []
-            )
-            for i, b in enumerate(chain):
-                if b in warm_written:
-                    chain = chain[:i]
-                    break
-            k = len(chain)
-            need = (
-                max(1, math.ceil(p / bs)) - k
-                + math.ceil(max(budget, 1) / bs)
-            )
-            if k:
-                eng.pool.acquire(chain)
-            fresh = eng.pool.alloc(need)
-            if fresh is None:
+        with self.spans.span("admit_prep") as prep:
+            free = [i for i in range(S) if not self.active[i]]
+            n = min(len(free), C, len(self.pending))
+            if n == 0:
+                return
+            plen = lambda rid: min(len(self.requests[rid]), W)  # noqa: E731
+            cold: list[tuple[int, int, int, list[str]]] = []  # rid, slot, p, hashes
+            warm: list[dict] = []
+            warm_written: set[int] = set()
+            taken = 0
+            while taken < n:
+                rid = self.pending[0]
+                p = plen(rid)
+                budget = self.budgets[rid]
+                toks = self.requests[rid][:p]
+                # custom-masked prompts have no token-only identity: their KV
+                # depends on the mask too, so they neither match nor register
+                eligible = self.attn_masks[rid] is None
+                hashes = cache_pool.chain_hashes(toks, bs) if eligible else []
+                # keep >= 1 prompt token in the tail — the first output token
+                # is computed from the LAST prompt position's logits, so a
+                # fully-cached prompt still re-prefills its final block
+                chain = (
+                    eng.pool.match_chain(hashes[: (p - 1) // bs])
+                    if eligible else []
+                )
+                for i, b in enumerate(chain):
+                    if b in warm_written:
+                        chain = chain[:i]
+                        break
+                k = len(chain)
+                need = (
+                    max(1, math.ceil(p / bs)) - k
+                    + math.ceil(max(budget, 1) / bs)
+                )
                 if k:
-                    eng.pool.free(list(reversed(chain)))  # roll back
-                break
-            self.pending.popleft()
-            slot = free[taken]
-            taken += 1
-            blocks = chain + fresh
-            self.slot_blocks[slot] = blocks
-            full_tiles = p // bs
-            if eligible and full_tiles:
-                eng.pool.register(blocks[:full_tiles], hashes[:full_tiles])
-                self.slot_chain[slot] = list(blocks[:full_tiles])
-            else:
-                self.slot_chain[slot] = []
-            if eligible:
-                self.stats.prefix_lookups += 1
-            self.stats.prefill_tokens_total += p
-            if k:
-                self.stats.prefix_hits += 1
-                self.stats.prefill_tokens_saved += k * bs
-                warm_written.update(blocks[k:full_tiles])
-                warm.append({
-                    "rid": rid, "slot": slot, "p": p,
-                    "bucket": next(b for b in eng.buckets if b >= p),
-                    "start": k * bs, "tail": toks[k * bs:],
-                })
-            else:
-                cold.append((rid, slot, p, hashes))
-        if taken == 0:
-            self.stats.admit_deferrals += 1
-            return
-        now = time.perf_counter()
+                    eng.pool.acquire(chain)
+                fresh = eng.pool.alloc(need)
+                if fresh is None:
+                    if k:
+                        eng.pool.free(list(reversed(chain)))  # roll back
+                    break
+                self.pending.popleft()
+                slot = free[taken]
+                taken += 1
+                blocks = chain + fresh
+                self.slot_blocks[slot] = blocks
+                full_tiles = p // bs
+                if eligible and full_tiles:
+                    eng.pool.register(blocks[:full_tiles], hashes[:full_tiles])
+                    self.slot_chain[slot] = list(blocks[:full_tiles])
+                else:
+                    self.slot_chain[slot] = []
+                if eligible:
+                    self.stats.prefix_lookups += 1
+                self.stats.prefill_tokens_total += p
+                if k:
+                    self.stats.prefix_hits += 1
+                    self.stats.prefill_tokens_saved += k * bs
+                    warm_written.update(blocks[k:full_tiles])
+                    warm.append({
+                        "rid": rid, "slot": slot, "p": p,
+                        "bucket": next(b for b in eng.buckets if b >= p),
+                        "start": k * bs, "tail": toks[k * bs:],
+                    })
+                else:
+                    cold.append((rid, slot, p, hashes))
+            if taken == 0:
+                self.stats.admit_deferrals += 1
+                return
+            self._admission_counters(
+                prep, [rid for rid, *_ in cold] + [w["rid"] for w in warm]
+            )
         # ---- cold chunk: the plain prefill+admit path over cold rows
         if cold:
-            bucket = next(
-                b for b in eng.buckets if b >= max(p for _, _, p, _ in cold)
-            )
-            ids = np.full((C, bucket), eng.pad, np.int32)
-            mask = np.zeros((C, bucket), np.int32)
-            slot_idx = np.full(C, S, np.int32)
-            ntc = (bucket + eng.L) // bs
-            admit_rows = np.full((C, ntc), N, np.int32)
-            for r, (rid, slot, p, _h) in enumerate(cold):
-                toks = self.requests[rid][:bucket]
-                ids[r, : len(toks)] = toks
-                mask[r, : len(toks)] = 1
-                if self.attn_masks[rid] is not None:
-                    m = self.attn_masks[rid][:bucket]
-                    mask[r, : len(m)] = m
-                slot_idx[r] = slot
-                row = cache_pool.build_block_row(
-                    eng.n_tiles, self.slot_blocks[slot],
-                    prompt_len=p, bucket_width=bucket,
-                    budget=self.budgets[rid], block_size=bs, sentinel=N,
+            with self.spans.span("admit_prep"):
+                bucket = next(
+                    b for b in eng.buckets if b >= max(p for _, _, p, _ in cold)
                 )
-                self.slot_bt[slot, :] = row
-                admit_rows[r, :] = row[:ntc]
-            t0 = time.perf_counter()
-            cache, full_mask, plens, first = eng._prefill(
-                self.params, jnp.asarray(ids), jnp.asarray(mask)
-            )
-            self.state = eng._admit(
-                self.state, cache, full_mask, first, jnp.asarray(slot_idx),
-                jnp.asarray(admit_rows.reshape(-1)),
-            )
-            plens_h = np.asarray(jax.device_get(plens))
-            first_h = np.asarray(jax.device_get(first))
-            dt = time.perf_counter() - t0
+                ids = np.full((C, bucket), eng.pad, np.int32)
+                mask = np.zeros((C, bucket), np.int32)
+                slot_idx = np.full(C, S, np.int32)
+                ntc = (bucket + eng.L) // bs
+                admit_rows = np.full((C, ntc), N, np.int32)
+                for r, (rid, slot, p, _h) in enumerate(cold):
+                    toks = self.requests[rid][:bucket]
+                    ids[r, : len(toks)] = toks
+                    mask[r, : len(toks)] = 1
+                    if self.attn_masks[rid] is not None:
+                        m = self.attn_masks[rid][:bucket]
+                        mask[r, : len(m)] = m
+                    slot_idx[r] = slot
+                    row = cache_pool.build_block_row(
+                        eng.n_tiles, self.slot_blocks[slot],
+                        prompt_len=p, bucket_width=bucket,
+                        budget=self.budgets[rid], block_size=bs, sentinel=N,
+                    )
+                    self.slot_bt[slot, :] = row
+                    admit_rows[r, :] = row[:ntc]
+            with self.spans.span("prefill_dispatch") as sp:
+                cache, full_mask, plens, first = eng._prefill(
+                    self.params, jnp.asarray(ids), jnp.asarray(mask)
+                )
+                self.state = eng._admit(
+                    self.state, cache, full_mask, first, jnp.asarray(slot_idx),
+                    jnp.asarray(admit_rows.reshape(-1)),
+                )
+                plens_h = np.asarray(jax.device_get(plens))
+                first_h = np.asarray(jax.device_get(first))
+            t0, dt, now = sp.t0, sp.dur, sp.end
             self.stats.prefill_seconds += dt
             self._win_prefill += dt
             self.progress += 1
-            now = time.perf_counter()
-            for r, (rid, slot, p, _h) in enumerate(cold):
-                self._admit_bookkeep(
-                    rid, slot, int(plens_h[r]), bucket, int(first_h[r]),
-                    t0, dt, now, finished,
-                )
+            with self.spans.span("emit"):
+                for r, (rid, slot, p, _h) in enumerate(cold):
+                    self._admit_bookkeep(
+                        rid, slot, int(plens_h[r]), bucket, int(first_h[r]),
+                        t0, dt, now, finished,
+                    )
         # ---- warm chunk: gather matched chains, prefill only the tails
         if warm:
-            width_full = W + eng.L
-            tail_bucket = next(
-                b for b in eng.buckets if b >= max(len(w["tail"]) for w in warm)
-            )
-            ids_t = np.full((C, tail_bucket), eng.pad, np.int32)
-            mask_f = np.zeros((C, width_full), np.int32)
-            start = np.full(C, width_full, np.int32)  # park rows write nowhere
-            tail_last = np.zeros(C, np.int32)
-            slot_idx = np.full(C, S, np.int32)
-            bt = np.full((C, eng.n_tiles), N, np.int32)
-            admit_rows = np.full((C, eng.n_tiles), N, np.int32)
-            for r, wr in enumerate(warm):
-                slot = wr["slot"]
-                tail = wr["tail"]
-                ids_t[r, : len(tail)] = tail
-                mask_f[r, : wr["p"]] = 1
-                start[r] = wr["start"]
-                tail_last[r] = len(tail) - 1
-                slot_idx[r] = slot
-                row = cache_pool.build_block_row(
-                    eng.n_tiles, self.slot_blocks[slot],
-                    prompt_len=wr["p"], bucket_width=wr["bucket"],
-                    budget=self.budgets[wr["rid"]], block_size=bs, sentinel=N,
+            with self.spans.span("admit_prep"):
+                width_full = W + eng.L
+                tail_bucket = next(
+                    b for b in eng.buckets if b >= max(len(w["tail"]) for w in warm)
                 )
-                self.slot_bt[slot, :] = row
-                bt[r, :] = row
-                # scatter ONLY the fresh tail prompt tiles back: the
-                # matched chain is immutable (shared), and decode tiles
-                # keep pool garbage until decode writes them (the
-                # poisoned-pool invariant — masked until valid)
-                k_tiles = wr["start"] // bs
-                full_tiles = max(1, math.ceil(wr["p"] / bs))
-                admit_rows[r, k_tiles:full_tiles] = row[k_tiles:full_tiles]
-            t0 = time.perf_counter()
-            first_w, self.state = eng._warm_admit(
-                self.params, self.state,
-                jnp.asarray(ids_t), jnp.asarray(mask_f), jnp.asarray(start),
-                jnp.asarray(tail_last), jnp.asarray(slot_idx),
-                jnp.asarray(bt), jnp.asarray(admit_rows.reshape(-1)),
-            )
-            first_wh = np.asarray(jax.device_get(first_w))
-            dt = time.perf_counter() - t0
+                ids_t = np.full((C, tail_bucket), eng.pad, np.int32)
+                mask_f = np.zeros((C, width_full), np.int32)
+                start = np.full(C, width_full, np.int32)  # park rows write nowhere
+                tail_last = np.zeros(C, np.int32)
+                slot_idx = np.full(C, S, np.int32)
+                bt = np.full((C, eng.n_tiles), N, np.int32)
+                admit_rows = np.full((C, eng.n_tiles), N, np.int32)
+                for r, wr in enumerate(warm):
+                    slot = wr["slot"]
+                    tail = wr["tail"]
+                    ids_t[r, : len(tail)] = tail
+                    mask_f[r, : wr["p"]] = 1
+                    start[r] = wr["start"]
+                    tail_last[r] = len(tail) - 1
+                    slot_idx[r] = slot
+                    row = cache_pool.build_block_row(
+                        eng.n_tiles, self.slot_blocks[slot],
+                        prompt_len=wr["p"], bucket_width=wr["bucket"],
+                        budget=self.budgets[wr["rid"]], block_size=bs, sentinel=N,
+                    )
+                    self.slot_bt[slot, :] = row
+                    bt[r, :] = row
+                    # scatter ONLY the fresh tail prompt tiles back: the
+                    # matched chain is immutable (shared), and decode tiles
+                    # keep pool garbage until decode writes them (the
+                    # poisoned-pool invariant — masked until valid)
+                    k_tiles = wr["start"] // bs
+                    full_tiles = max(1, math.ceil(wr["p"] / bs))
+                    admit_rows[r, k_tiles:full_tiles] = row[k_tiles:full_tiles]
+            with self.spans.span("prefill_dispatch") as sp:
+                first_w, self.state = eng._warm_admit(
+                    self.params, self.state,
+                    jnp.asarray(ids_t), jnp.asarray(mask_f), jnp.asarray(start),
+                    jnp.asarray(tail_last), jnp.asarray(slot_idx),
+                    jnp.asarray(bt), jnp.asarray(admit_rows.reshape(-1)),
+                )
+                first_wh = np.asarray(jax.device_get(first_w))
+            t0, dt, now = sp.t0, sp.dur, sp.end
             self.stats.prefill_seconds += dt
             self._win_prefill += dt
             self.progress += 1
-            now = time.perf_counter()
-            for r, wr in enumerate(warm):
-                self._admit_bookkeep(
-                    wr["rid"], wr["slot"], wr["p"], wr["bucket"],
-                    int(first_wh[r]), t0, dt, now, finished,
-                )
+            with self.spans.span("emit"):
+                for r, wr in enumerate(warm):
+                    self._admit_bookkeep(
+                        wr["rid"], wr["slot"], wr["p"], wr["bucket"],
+                        int(first_wh[r]), t0, dt, now, finished,
+                    )
         self.stats.peak_cache_bytes_in_use = max(
             self.stats.peak_cache_bytes_in_use, self._bytes_in_use()
         )
@@ -1574,8 +1599,8 @@ class ServeSession:
         n-gram self-drafter or the shrunk draft model; serving/spec.py
         owns BOTH drafters and all acceptance/rollback math (repo_lint
         rule 17) — this method only packs inputs and runs the compiled
-        programs.  Returns host arrays ``(target_tokens (S, k+1),
-        n_emit (S,))``."""
+        programs.  Returns device arrays ``(target_tokens (S, k+1),
+        n_emit (S,))``: the round fetches them."""
         eng = self.eng
         K, S = eng.spec, eng.S
         x = np.full((S, K + 1), eng.pad, np.int32)
@@ -1632,10 +1657,7 @@ class ServeSession:
                 jnp.asarray(rope.astype(np.int32)),
                 jnp.asarray(self.active), jnp.asarray(room),
             )
-        return (
-            np.asarray(jax.device_get(target)),
-            np.asarray(jax.device_get(n_emit)),
-        )
+        return target, n_emit
 
     def _draft_admissions(self) -> None:
         """Bring slots admitted this round into the draft model's cache:
@@ -1723,6 +1745,12 @@ class ServeSession:
     def _step_round(self) -> list[int]:
         if self._finalized:
             raise RuntimeError("session already finalized")
+        with self.spans.span("round"):
+            finished = self._round_stages()
+        self.spans.step_complete()
+        return finished
+
+    def _round_stages(self) -> list[int]:
         eng = self.eng
         finished: list[int] = []
         self._admit_now(finished)
@@ -1731,134 +1759,140 @@ class ServeSession:
         offsets = (
             self.emitted if eng.is_seq2seq else (self.base + self.emitted - 1)
         )
-        t0 = time.perf_counter()
-        if eng.spec:
-            spec_toks, spec_emit = self._spec_dispatch(offsets)
-        elif eng.is_seq2seq:
-            tokens, self.state = eng._step(
-                self.params, self.state,
-                jnp.asarray(offsets.astype(np.int32)),
-                jnp.asarray(self.active),
+        with self.spans.span("decode_dispatch") as dispatch:
+            if eng.spec:
+                target, n_emit = self._spec_dispatch(offsets)
+            elif eng.is_seq2seq:
+                tokens, self.state = eng._step(
+                    self.params, self.state,
+                    jnp.asarray(offsets.astype(np.int32)),
+                    jnp.asarray(self.active),
+                )
+            elif eng.paged:
+                rope = self.lengths + self.emitted - 1
+                tokens, self.state = eng._step(
+                    self.params, self.state,
+                    jnp.asarray(self.slot_bt),
+                    jnp.asarray(offsets.astype(np.int32)),
+                    jnp.asarray(rope.astype(np.int32)),
+                    jnp.asarray(self.active),
+                )
+            else:
+                rope = self.lengths + self.emitted - 1
+                tokens, self.state = eng._step(
+                    self.params, self.state,
+                    jnp.asarray(offsets.astype(np.int32)),
+                    jnp.asarray(rope.astype(np.int32)),
+                    jnp.asarray(self.active),
+                )
+        with self.spans.span("token_fetch") as fetch:  # the host waits for the device here
+            if eng.spec:
+                spec_toks = np.asarray(jax.device_get(target))
+                spec_emit = np.asarray(jax.device_get(n_emit))
+            else:
+                toks = np.asarray(jax.device_get(tokens))
+        dt = fetch.end - dispatch.t0
+        with self.spans.span("emit") as emit:
+            now = emit.t0
+            self.stats.decode_seconds += dt
+            self.stats.decode_steps += 1
+            self.progress += 1
+            self._win_decode += dt
+            n_active = self.active_count
+            self.stats.slot_occupancy += n_active / eng.S
+            self._win_occ += n_active / eng.S
+            self._bpt_samples.append(
+                self._bytes_in_use() / max(self._live_tokens(), 1)
             )
-        elif eng.paged:
-            rope = self.lengths + self.emitted - 1
-            tokens, self.state = eng._step(
-                self.params, self.state,
-                jnp.asarray(self.slot_bt),
-                jnp.asarray(offsets.astype(np.int32)),
-                jnp.asarray(rope.astype(np.int32)),
-                jnp.asarray(self.active),
-            )
-        else:
-            rope = self.lengths + self.emitted - 1
-            tokens, self.state = eng._step(
-                self.params, self.state,
-                jnp.asarray(offsets.astype(np.int32)),
-                jnp.asarray(rope.astype(np.int32)),
-                jnp.asarray(self.active),
-            )
-        if not eng.spec:
-            toks = np.asarray(jax.device_get(tokens))
-        dt = time.perf_counter() - t0
-        self.stats.decode_seconds += dt
-        self.stats.decode_steps += 1
-        self.progress += 1
-        self._win_decode += dt
-        n_active = self.active_count
-        self.stats.slot_occupancy += n_active / eng.S
-        self._win_occ += n_active / eng.S
-        self._bpt_samples.append(
-            self._bytes_in_use() / max(self._live_tokens(), 1)
-        )
-        now = time.perf_counter()
-        if eng.spec:
-            # a verify round appends 1..k+1 tokens per slot — the
-            # accounting counts tokens actually emitted, so tok/s stays
-            # an honest cross-mode comparison
-            appended = self._spec_append(spec_toks, spec_emit, now, finished)
-        else:
-            appended = n_active
-            for slot in np.nonzero(self.active)[0]:
-                rid = int(self.slot_req[slot])
-                tok = int(toks[slot])
-                self.outputs[rid].append(tok)
-                if self.ttft[rid] is None:
-                    self.ttft[rid] = now - self.submit_t[rid]
-                    self.first_tok_wall[rid] = now
-                self.emitted[slot] += 1
-                if tok == eng.eos or self.emitted[slot] >= self.budgets[rid]:
-                    self._evict_slot(slot)  # slot (and its blocks) free NOW
-                    self._finish_request(rid, slot, now)
-                    finished.append(rid)
-        self.stats.decode_tokens += appended
-        self._win_tokens += appended
+            if eng.spec:
+                # a verify round appends 1..k+1 tokens per slot — the
+                # accounting counts tokens actually emitted, so tok/s stays
+                # an honest cross-mode comparison
+                appended = self._spec_append(spec_toks, spec_emit, now, finished)
+            else:
+                appended = n_active
+                for slot in np.nonzero(self.active)[0]:
+                    rid = int(self.slot_req[slot])
+                    tok = int(toks[slot])
+                    self.outputs[rid].append(tok)
+                    if self.ttft[rid] is None:
+                        self.ttft[rid] = now - self.submit_t[rid]
+                        self.first_tok_wall[rid] = now
+                    self.emitted[slot] += 1
+                    if tok == eng.eos or self.emitted[slot] >= self.budgets[rid]:
+                        self._evict_slot(slot)  # slot (and its blocks) free NOW
+                        self._finish_request(rid, slot, now)
+                        finished.append(rid)
+            self.stats.decode_tokens += appended
+            self._win_tokens += appended
         every = eng.serve.log_every_steps
         if every and self.stats.decode_steps % every == 0:
-            w_dt = max(now - self._win_t0, 1e-9)
-            window = {
-                "event": "serve_window",
-                "step": self.stats.decode_steps,
-                "decode_tokens_per_sec": round(self._win_tokens / w_dt, 1),
-                "decode_tokens_per_sec_chip": round(
-                    self._win_tokens / w_dt / self.n_chips, 1
-                ),
-                "slot_occupancy": round(self._win_occ / every, 4),
-                "queue_depth": len(self.pending),
-                # queueing telemetry: the window's offered vs served rate
-                # and their imbalance — a sustained positive queue_growth
-                # is the open-loop collapse signal (arrivals outpacing
-                # service), visible live instead of post-hoc
-                "arrival_rate_per_sec": round(self._win_arrivals / w_dt, 2),
-                "service_rate_per_sec": round(self._win_done / w_dt, 2),
-                "queue_growth": int(self._win_arrivals - self._win_done),
-                # the window's wall split: admission prefill vs decode
-                # steps — a window whose prefill share balloons is paying
-                # admission on the decode critical path
-                "prefill_ms": round(self._win_prefill * 1e3, 1),
-                "decode_ms": round(self._win_decode * 1e3, 1),
-                # capacity gauges: what the cache state holds RIGHT NOW
-                # per live token — the number the paged pool shrinks
-                "cache_bytes_in_use": self._bytes_in_use(),
-                "cache_bytes_per_token": round(
-                    self._bytes_in_use() / max(self._live_tokens(), 1), 1
-                ),
-            }
-            if eng.paged:
-                window["pool_blocks_in_use"] = eng.pool.blocks_in_use
-                window["pool_blocks_free"] = eng.pool.blocks_free
-                if eng.prefix:
-                    # cumulative-to-date prefix-cache gauges: hit rate over
-                    # eligible admissions, prefill tokens served from the
-                    # pool instead of recomputed, and the warm set's bytes
-                    window["prefix_hit_rate"] = round(
-                        self.stats.prefix_hits
-                        / max(self.stats.prefix_lookups, 1), 4
+            with self.spans.span("window_log"):
+                w_dt = max(now - self._win_t0, 1e-9)
+                window = {
+                    "event": "serve_window",
+                    "step": self.stats.decode_steps,
+                    "decode_tokens_per_sec": round(self._win_tokens / w_dt, 1),
+                    "decode_tokens_per_sec_chip": round(
+                        self._win_tokens / w_dt / self.n_chips, 1
+                    ),
+                    "slot_occupancy": round(self._win_occ / every, 4),
+                    "queue_depth": len(self.pending),
+                    # queueing telemetry: the window's offered vs served rate
+                    # and their imbalance — a sustained positive queue_growth
+                    # is the open-loop collapse signal (arrivals outpacing
+                    # service), visible live instead of post-hoc
+                    "arrival_rate_per_sec": round(self._win_arrivals / w_dt, 2),
+                    "service_rate_per_sec": round(self._win_done / w_dt, 2),
+                    "queue_growth": int(self._win_arrivals - self._win_done),
+                    # the window's wall split: admission prefill vs decode
+                    # steps — a window whose prefill share balloons is paying
+                    # admission on the decode critical path
+                    "prefill_ms": round(self._win_prefill * 1e3, 1),
+                    "decode_ms": round(self._win_decode * 1e3, 1),
+                    # capacity gauges: what the cache state holds RIGHT NOW
+                    # per live token — the number the paged pool shrinks
+                    "cache_bytes_in_use": self._bytes_in_use(),
+                    "cache_bytes_per_token": round(
+                        self._bytes_in_use() / max(self._live_tokens(), 1), 1
+                    ),
+                }
+                if eng.paged:
+                    window["pool_blocks_in_use"] = eng.pool.blocks_in_use
+                    window["pool_blocks_free"] = eng.pool.blocks_free
+                    if eng.prefix:
+                        # cumulative-to-date prefix-cache gauges: hit rate over
+                        # eligible admissions, prefill tokens served from the
+                        # pool instead of recomputed, and the warm set's bytes
+                        window["prefix_hit_rate"] = round(
+                            self.stats.prefix_hits
+                            / max(self.stats.prefix_lookups, 1), 4
+                        )
+                        window["prefill_tokens_saved_frac"] = round(
+                            self.stats.prefill_tokens_saved
+                            / max(self.stats.prefill_tokens_total, 1), 4
+                        )
+                        window["pool_blocks_warm"] = eng.pool.blocks_warm
+                        window["warm_bytes"] = (
+                            eng.pool.blocks_warm * self._per_block
+                        )
+                if eng.spec:
+                    # the speculative ledger live: window-local multi-token
+                    # yield + the cumulative draft acceptance rate
+                    window["accepted_tokens_per_step"] = round(
+                        self._win_spec_emitted / max(self._win_spec_steps, 1), 4
                     )
-                    window["prefill_tokens_saved_frac"] = round(
-                        self.stats.prefill_tokens_saved
-                        / max(self.stats.prefill_tokens_total, 1), 4
+                    window["acceptance_rate"] = round(
+                        self.stats.spec_accepted
+                        / max(self.stats.spec_drafted, 1), 4
                     )
-                    window["pool_blocks_warm"] = eng.pool.blocks_warm
-                    window["warm_bytes"] = (
-                        eng.pool.blocks_warm * self._per_block
-                    )
-            if eng.spec:
-                # the speculative ledger live: window-local multi-token
-                # yield + the cumulative draft acceptance rate
-                window["accepted_tokens_per_step"] = round(
-                    self._win_spec_emitted / max(self._win_spec_steps, 1), 4
-                )
-                window["acceptance_rate"] = round(
-                    self.stats.spec_accepted
-                    / max(self.stats.spec_drafted, 1), 4
-                )
-            if self.replica is not None:
-                window["replica"] = int(self.replica)
-            log_json(window)
-            self._win_tokens, self._win_t0, self._win_occ = 0, now, 0.0
-            self._win_prefill, self._win_decode = 0.0, 0.0
-            self._win_arrivals, self._win_done = 0, 0
-            self._win_spec_steps, self._win_spec_emitted = 0, 0
+                if self.replica is not None:
+                    window["replica"] = int(self.replica)
+                log_json(window)
+                self._win_tokens, self._win_t0, self._win_occ = 0, now, 0.0
+                self._win_prefill, self._win_decode = 0.0, 0.0
+                self._win_arrivals, self._win_done = 0, 0
+                self._win_spec_steps, self._win_spec_emitted = 0, 0
         return finished
 
     # ------------------------------------------------------------ closing
@@ -1889,7 +1923,7 @@ class ServeSession:
         stats.goodput = compute_goodput(
             self.ttft,
             [len(o) for o in self.outputs],
-            wall_s=time.perf_counter() - self.t_open,
+            wall_s=self.spans.clock() - self.t_open,
             ttft_slo_ms=eng.serve.ttft_slo_ms,
             n_chips=self.n_chips,
         )
@@ -1901,8 +1935,6 @@ class ServeSession:
         p50, p95 = stats.ttft_percentiles()
         # arrival→submit delay percentiles over every request (0s under
         # closed-loop driving; the open-loop driver's queueing signature)
-        from distributed_llms_example_tpu.obs.spans import percentiles
-
         qd50, qd95, qd99 = percentiles(
             [s - a for s, a in zip(self.submit_t, self.arrival_t)],
             (0.50, 0.95, 0.99),
@@ -1935,6 +1967,9 @@ class ServeSession:
             "cache_bytes_resident": stats.cache_bytes_resident,
             "peak_cache_bytes_in_use": stats.peak_cache_bytes_in_use,
             "cache_bytes_per_token": round(stats.bytes_per_live_token, 1),
+            # the host spans since the session opened (obs/spans.py summary):
+            # round-period percentiles over the ring, per-span total/count/max
+            "host_spans": self.spans.summary(),
         }
         if eng.paged:
             summary["pool_blocks"] = eng.pool.num_blocks

@@ -146,6 +146,177 @@ def test_span_recording_time_budget():
 
 
 # ---------------------------------------------------------------------------
+# one primitive, two clocks: every span also opens a profiler annotation
+# ---------------------------------------------------------------------------
+
+class FakeAnnotations:
+    """Annotation factory double: records each annotation's name, stats and
+    extent on the recorder's (fake) clock, in opening order."""
+
+    def __init__(self, clock):
+        self.clock = clock
+        self.events: list[dict] = []
+
+    def __call__(self, name, **stats):
+        return _FakeAnnotation(self, name, stats)
+
+    def named(self, name):
+        return [e for e in self.events if e["name"] == name]
+
+
+class _FakeAnnotation:
+    def __init__(self, owner, name, stats):
+        self.owner = owner
+        self.event = {"name": name, "stats": dict(stats)}
+
+    def __enter__(self):
+        self.event["start"] = self.owner.clock()
+        self.owner.events.append(self.event)
+        return self
+
+    def __exit__(self, *exc):
+        self.event["end"] = self.owner.clock()
+        return False
+
+    def set_metadata(self, **stats):
+        self.event["stats"].update(stats)
+
+
+@pytest.mark.parametrize("scope", ["train", "serve"])
+def test_every_span_opens_an_annotation_named_by_scope(scope):
+    clock = FakeClock()
+    notes = FakeAnnotations(clock)
+    rec = SpanRecorder(clock=clock, scope=scope, annotate=notes)
+    with rec.span("step_dispatch"):
+        clock.advance(0.25)
+        with rec.span("data_wait") as inner:  # nested spans annotate too
+            clock.advance(0.5)
+            inner.set(rows=8, batch=3)  # counters go straight to the annotation
+    assert [e["name"] for e in notes.events] == [f"{scope}/step_dispatch", f"{scope}/data_wait"]
+    outer, nested = notes.events
+    assert (outer["start"], outer["end"]) == (0.0, 0.75) and outer["stats"] == {}
+    assert (nested["start"], nested["end"]) == (0.25, 0.75)
+    assert nested["stats"] == {"batch": 3, "rows": 8}
+    # the handle gives the caller the span's own clock reads
+    assert (inner.t0, inner.dur, inner.end) == (0.25, 0.5, 0.75)
+
+
+def test_ring_keeps_bare_names_and_the_outermost_partition():
+    """The annotation changes nothing the budget reads: the step's record
+    holds the OUTERMOST spans under their bare names and nothing else
+    (counters live on the annotation alone)."""
+    clock = FakeClock()
+    notes = FakeAnnotations(clock)
+    rec = SpanRecorder(clock=clock, scope="serve", annotate=notes)
+    with rec.span("round"):
+        with rec.span("admit_prep") as prep:
+            clock.advance(0.01)
+            prep.set(n=1, queue_wait_us_sum=7)
+        with rec.span("emit"):
+            clock.advance(0.02)
+    rec.step_complete()
+    with rec.span("round"):
+        clock.advance(0.03)
+    rec.step_complete()
+    first, second = rec.window_step_records()
+    assert first == second == {"dur": pytest.approx(0.03), "spans": {"round": pytest.approx(0.03)}}
+    assert notes.named("serve/admit_prep")[0]["stats"] == {"n": 1, "queue_wait_us_sum": 7}
+    s = rec.summary()
+    assert set(s["spans"]) == {"round", "admit_prep", "emit"}  # bare names in the window aggregates too
+
+
+def test_span_closes_its_annotation_and_depth_when_the_body_raises():
+    clock = FakeClock()
+    notes = FakeAnnotations(clock)
+    rec = SpanRecorder(clock=clock, annotate=notes)
+    with pytest.raises(ValueError):
+        with rec.span("step_dispatch"):
+            clock.advance(0.1)
+            raise ValueError("boom")
+    assert notes.events[0]["end"] == pytest.approx(0.1)
+    with rec.span("data_wait"):
+        clock.advance(0.2)
+    rec.step_complete()
+    # both are outermost: the failed span did not leave the depth raised
+    assert rec.window_step_records()[0]["spans"] == {
+        "step_dispatch": pytest.approx(0.1), "data_wait": pytest.approx(0.2)}
+
+
+@pytest.mark.parametrize("annotate", ["fake", "profiler"])
+def test_step_budget_numbers_do_not_depend_on_the_annotation(annotate):
+    """The trainer's account on a hand-driven window reads the same numbers
+    with the fake factory and with jax's own (inactive) TraceAnnotation."""
+    from distributed_llms_example_tpu.obs.budget import BudgetAccountant
+
+    clock = FakeClock()
+    rec = SpanRecorder(clock=clock, scope="train",
+                       annotate=FakeAnnotations(clock) if annotate == "fake" else None)
+    bud = BudgetAccountant(rec)
+    for last in (False, False, True):
+        with rec.span("data_wait"):
+            clock.advance(0.02)
+        with rec.span("host_overhead"):
+            clock.advance(0.01)
+            with rec.span("obs_gauge_compile"):  # nested: annotated, not in the partition
+                clock.advance(0.002)
+        with rec.span("step_dispatch"):
+            clock.advance(0.005)
+        if last:
+            with rec.span("device_busy"):
+                clock.advance(0.05)
+            with rec.span("device_sync"):
+                clock.advance(0.01)
+        clock.advance(0.004)
+        rec.step_complete()
+    acct = bud.close_window(step=3, epoch=0, emit=False)
+    assert acct["window_steps"] == 3
+    assert acct["data_wait_ms"] == pytest.approx(60.0)
+    assert acct["host_overhead_ms"] == pytest.approx(36.0)
+    assert acct["dispatch_ms"] == pytest.approx(15.0)
+    assert acct["device_busy_ms"] == pytest.approx(50.0)
+    assert acct["sync_block_ms"] == pytest.approx(10.0)
+    assert acct["unattributed_ms"] == pytest.approx(12.0)
+    assert acct["wall_ms"] == pytest.approx(183.0)
+
+
+def test_ring_is_bounded():
+    clock = FakeClock()
+    rec = SpanRecorder(ring_size=8, clock=clock, scope="serve", annotate=FakeAnnotations(clock))
+    for i in range(50):
+        with rec.span("round") as rd:
+            clock.advance(0.01)
+            rd.set(n=i)  # a counter leaves nothing behind in the recorder
+        rec.step_complete()
+    assert len(rec._ring) == 8 and len(rec._step_records) == 8
+    assert rec._step_records[-1] == {"dur": pytest.approx(0.01), "spans": {"round": pytest.approx(0.01)}}
+    assert rec.summary()["window_steps"] == 50
+
+
+def test_trainer_obs_spans_are_train_scoped(tmp_path):
+    """TrainerObs adds no ring span: its existing spans become visible as
+    ``train/<name>`` and the ring keeps the names the budget reads."""
+    from distributed_llms_example_tpu.core.config import TrainConfig
+    from distributed_llms_example_tpu.obs import TrainerObs
+
+    obs = TrainerObs(TrainConfig(output_dir=str(tmp_path), obs="off", health="off"), start_step=0)
+    assert obs.spans.scope == "train"
+    notes = FakeAnnotations(obs.spans.clock)
+    obs.spans._annotate = notes
+    list(obs.wrap_batches([{"x": 1}]))
+    with obs.step_span():
+        pass
+    with obs.host_span():
+        pass
+    with obs.sync_span():
+        pass
+    assert [e["name"] for e in notes.events] == [
+        "train/data_wait", "train/data_wait", "train/step_dispatch", "train/host_overhead", "train/device_sync"]
+    obs.spans.step_complete()
+    assert set(obs.spans.window_step_records()[0]["spans"]) == {
+        "data_wait", "step_dispatch", "host_overhead", "device_sync"}
+
+
+# ---------------------------------------------------------------------------
 # gauges: MFU math, HBM gating, collective accounting on a known FSDP HLO
 # ---------------------------------------------------------------------------
 

@@ -424,6 +424,233 @@ def test_engine_matches_static_batching_seq2seq(mesh8, capsys):
         assert len(g) <= budget
 
 
+# ------------------------------------- host spans inside the engine's round
+
+
+class _Annotations:
+    """Annotation factory double (obs/spans.py's ``annotate``): every
+    annotation's name, stats and extent on the given clock."""
+
+    def __init__(self, clock):
+        self.clock, self.events = clock, []
+
+    def __call__(self, name, **stats):
+        owner, event = self, {"name": name, "stats": dict(stats)}
+
+        class _One:
+            def __enter__(self):
+                event["start"] = owner.clock()
+                owner.events.append(event)
+                return self
+
+            def __exit__(self, *exc):
+                event["end"] = owner.clock()
+                return False
+
+            def set_metadata(self, **more):
+                event["stats"].update(more)
+
+        return _One()
+
+    def children(self, parent):
+        return [e for e in self.events if e is not parent
+                and parent["start"] <= e["start"] and e["end"] <= parent["end"]]
+
+
+class _Clock:
+    t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+@pytest.fixture(scope="module")
+def serve_rig(mesh8):
+    """A small seq2seq engine shared by the span tests: 8 slots, waves of 4."""
+    from distributed_llms_example_tpu.parallel.sharding import shard_params
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine
+
+    lm = load_model("bart-test")
+    params = shard_params(lm.init_params(0), mesh8)
+    eng = ServingEngine(
+        lm.module, lm.config, mesh8,
+        ServeConfig(max_slots=8, prefill_batch=4, max_new_tokens=8,
+                    max_source_length=32, log_every_steps=3),
+        is_seq2seq=True,
+    )
+    return eng, params
+
+
+def _traced_session(rig, clock, **recorder):
+    from distributed_llms_example_tpu.obs.spans import SpanRecorder
+
+    eng, params = rig
+    notes = _Annotations(clock)
+    return eng.open(params, spans=SpanRecorder(clock=clock, scope="serve", annotate=notes, **recorder)), notes
+
+
+def test_round_spans_partition_the_round(serve_rig, capsys):
+    """On the real clock: a plain round is admit_prep + decode_dispatch +
+    token_fetch + emit (+ window_log at its cadence) with under 5 % of self
+    time; a round that admits also has prefill_dispatch and its own emit."""
+    import time
+
+    sess, notes = _traced_session(serve_rig, time.perf_counter)
+    rng = np.random.RandomState(3)
+    for r in _requests(rng, 10):
+        sess.submit(r, max_new=8)
+    while sess.has_work():
+        sess.step()
+    sess.finalize()
+    rounds = [e for e in notes.events if e["name"] == "serve/round"]
+    assert len(rounds) == sess.stats.decode_steps and len(rounds) >= 8
+    plain_self, kinds = [], set()
+    for rd in rounds:
+        kids = notes.children(rd)
+        names = [k["name"] for k in kids]
+        for a, b in zip(kids, kids[1:]):
+            assert a["end"] <= b["start"]  # siblings, in order, none nested in another
+        wave = "serve/prefill_dispatch" in names
+        kinds.add(wave)
+        tail = ["serve/decode_dispatch", "serve/token_fetch", "serve/emit"]
+        logs = ["serve/window_log"] if names[-1] == "serve/window_log" else []
+        if wave:
+            assert names == ["serve/admit_prep", "serve/prefill_dispatch", "serve/emit"] + tail + logs
+            assert set(kids[0]["stats"]) == {"n", "queue_wait_us_sum"}
+        else:
+            assert names == ["serve/admit_prep"] + tail + logs
+            assert kids[0]["stats"] == {}
+            covered = sum(k["end"] - k["start"] for k in kids)
+            plain_self.append(1.0 - covered / (rd["end"] - rd["start"]))
+        assert all(e["stats"] == {} for e in [rd] + kids[1:])  # the admitting admit_prep alone carries counters
+    assert kinds == {True, False}
+    assert sorted(plain_self)[len(plain_self) // 2] < 0.05
+    logged = sum("serve/window_log" in [k["name"] for k in notes.children(rd)] for rd in rounds)
+    assert logged == len(rounds) // 3  # log_every_steps=3
+    capsys.readouterr()
+
+
+def test_queue_wait_counters_on_a_virtual_clock(serve_rig, capsys):
+    clock = _Clock()
+    sess, notes = _traced_session(serve_rig, clock)
+    rng = np.random.RandomState(4)
+    reqs = _requests(rng, 5)
+    clock.t = 10.0
+    rids = [sess.submit(reqs[0], max_new=4, arrival=9.25)] + [sess.submit(r, max_new=4) for r in reqs[1:4]]
+    clock.t = 10.5
+    rids.append(sess.submit(reqs[4], max_new=4, arrival=10.125))
+    assert rids == [0, 1, 2, 3, 4] and notes.events == []  # submit opens no span: the driver has its own
+    clock.t = 11.0
+    sess.step()  # admits the first wave of 4 at t = 11
+    clock.t = 12.0
+    sess.step()  # and the fifth request at t = 12
+    preps = [e for e in notes.events if e["name"] == "serve/admit_prep" and e["stats"]]
+    # queue wait = the admit_prep span's start minus the request's arrival (submit instant when none was given)
+    assert [e["stats"] for e in preps] == [
+        {"n": 4, "queue_wait_us_sum": 1_750_000 + 3 * 1_000_000}, {"n": 1, "queue_wait_us_sum": 1_875_000}]
+    # the ring keeps the round under its bare name, and no counter
+    assert sess.spans.window_step_records() == [{"dur": 11.0, "spans": {"round": 0.0}}, {"dur": 1.0, "spans": {"round": 0.0}}]
+    # the engine's own stamps are the spans' clock reads
+    assert sess.submit_t == [10.0] * 4 + [10.5] and sess.admit_t == [11.0] * 4 + [12.0]
+    while sess.has_work():
+        sess.step()
+    sess.finalize()
+    capsys.readouterr()
+
+
+def test_serve_span_ring_is_bounded(serve_rig, capsys):
+    import time
+
+    sess, notes = _traced_session(serve_rig, time.perf_counter, ring_size=4)
+    rng = np.random.RandomState(5)
+    for r in _requests(rng, 10):
+        sess.submit(r, max_new=8)
+    while sess.has_work():
+        sess.step()
+    assert sess.stats.decode_steps > 4
+    assert len(sess.spans._ring) == len(sess.spans._step_records) == 4
+    sess.finalize()
+    capsys.readouterr()
+
+
+def test_round_syncs_and_event_fields_are_what_they_were(serve_rig, monkeypatch, capsys):
+    """The counting pin (PR 3's technique): a seq2seq round fetches exactly
+    once (the token vector), wave or not, and never blocks another way; the
+    serve events keep every field they had, serve_summary gains host_spans."""
+    import inspect
+    import json as _json
+
+    from distributed_llms_example_tpu.serving import engine as engine_mod
+
+    calls = {"device_get": 0, "block_until_ready": 0}
+    real_get, real_block = jax.device_get, jax.block_until_ready
+    monkeypatch.setattr(jax, "device_get", lambda x: (calls.__setitem__("device_get", calls["device_get"] + 1), real_get(x))[1])
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: (calls.__setitem__("block_until_ready", calls["block_until_ready"] + 1), real_block(x))[1])
+    assert ".block_until_ready(" not in inspect.getsource(engine_mod.ServeSession)
+    eng, params = serve_rig
+    sess = eng.open(params)
+    rng = np.random.RandomState(6)
+    for r in _requests(rng, 10):
+        sess.submit(r, max_new=8)
+    capsys.readouterr()
+    rounds = 0
+    while sess.has_work():
+        before = calls["device_get"]
+        sess.step()
+        rounds += 1
+        assert calls["device_get"] - before == 1
+    assert calls == {"device_get": rounds, "block_until_ready": 0}
+    sess.finalize()
+    events = [_json.loads(x) for x in capsys.readouterr().out.splitlines() if x.startswith("{")]
+    window = next(e for e in events if e.get("event") == "serve_window")
+    assert set(window) == {
+        "event", "step", "decode_tokens_per_sec", "decode_tokens_per_sec_chip", "slot_occupancy", "queue_depth",
+        "arrival_rate_per_sec", "service_rate_per_sec", "queue_growth", "prefill_ms", "decode_ms",
+        "cache_bytes_in_use", "cache_bytes_per_token"}
+    assert window["decode_ms"] > 0 and window["prefill_ms"] > 0
+    summary = next(e for e in events if e.get("event") == "serve_summary")
+    was = {
+        "event", "sequences", "decode_steps", "decode_tokens", "decode_tokens_per_sec", "decode_tokens_per_sec_chip",
+        "ttft_p50_ms", "ttft_p95_ms", "queue_delay_p50_ms", "queue_delay_p95_ms", "queue_delay_p99_ms",
+        "ttft_queue_p50_ms", "ttft_queue_p95_ms", "ttft_prefill_p50_ms", "ttft_prefill_p95_ms", "ttft_queue_share",
+        "ttft_prefill_share", "goodput_tokens_per_sec", "goodput_tokens_per_sec_chip", "slot_occupancy",
+        "prefill_seconds", "slots", "chips", "kv_cache_dtype", "paged_kv", "prefill_buckets", "cache_bytes_resident",
+        "peak_cache_bytes_in_use", "cache_bytes_per_token", "memory_account", "hbm_headroom_gib"}
+    assert set(summary) - {"peak_hbm_bytes"} == was | {"host_spans"}
+    host = summary["host_spans"]
+    assert host["window_steps"] == rounds and host["spans"]["round"]["count"] == rounds
+    assert set(host["spans"]) == {"round", "admit_prep", "prefill_dispatch", "decode_dispatch",
+                                  "token_fetch", "emit", "window_log"}
+    # prefill_seconds and the decode seconds ARE the spans' durations
+    assert summary["prefill_seconds"] == pytest.approx(host["spans"]["prefill_dispatch"]["total_ms"] / 1e3, abs=2e-3)
+    decode_ms = host["spans"]["decode_dispatch"]["total_ms"] + host["spans"]["token_fetch"]["total_ms"]
+    assert sess.stats.decode_seconds * 1e3 == pytest.approx(decode_ms, rel=0.02)
+
+
+def test_serving_programs_are_named(mesh8, caplog, capsys):
+    """``_wrap`` gives each jitted program its own name (``jit_serve_<name>`` on
+    the profiler's XLA Modules line), and ``trace_counts`` keeps its keys."""
+    import logging
+
+    from distributed_llms_example_tpu.parallel.sharding import shard_params
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine
+
+    lm = load_model("bart-test")
+    eng = ServingEngine(
+        lm.module, lm.config, mesh8,
+        ServeConfig(max_slots=4, prefill_batch=4, max_new_tokens=4, max_source_length=16, log_every_steps=0),
+        is_seq2seq=True,
+    )
+    with caplog.at_level(logging.DEBUG, logger="jax._src.dispatch"):
+        eng.open(shard_params(lm.init_params(0), mesh8)).finalize()
+    compiled = {m.split("compilation of ")[1].split(" in ")[0]
+                for m in (r.getMessage() for r in caplog.records) if "Finished XLA compilation of " in m}
+    assert {"jit(serve_prefill)", "jit(serve_admit)", "jit(serve_decode_step)"} <= compiled
+    assert not any("counted" in name for name in compiled)
+    assert set(eng.trace_counts) == {"prefill", "admit", "decode_step"}
+    capsys.readouterr()
+
+
 def test_compute_goodput_slo_arithmetic():
     """The goodput fields pinned on hand numbers: useful tokens are the
     tokens of requests whose TTFT met the SLO; attainment counts finished

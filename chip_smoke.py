@@ -233,7 +233,7 @@ def kernels_phase(out: dict, sz: dict, seed: int, interpret: bool) -> None:
     import jax.numpy as jnp
 
     from distributed_llms_example_tpu.ops.attention import dot_product_attention
-    from distributed_llms_example_tpu.ops.flash_attention import flash_attention, flash_decode
+    from distributed_llms_example_tpu.ops.flash_attention import auto_block, decode_step_heads, flash_attention, flash_decode
     from distributed_llms_example_tpu.ops.fused_dropout import fused_dropout
     from distributed_llms_example_tpu.ops.fused_optim import SCALARS, adamw_leaf_reference, fused_adamw_leaf
     from distributed_llms_example_tpu.ops.mha import decode_step_bias
@@ -286,15 +286,23 @@ def kernels_phase(out: dict, sz: dict, seed: int, interpret: bool) -> None:
         flash[name] = {k_: round(e, 5) for k_, e in errs.items()}
     out["flash_rel_err"] = flash
 
-    decode = {}
+    # one grid step of flash_decode streams a kv tile of every head of a slot
+    # (decode_step_heads, from the shapes); the q block is one row for a plain
+    # step and up to 8 for a speculative verify
+    decode, tiling = {}, {}
     for cache in sz["decode_caches"]:
-        q, k, v = rnd(11, (B, H, 1, D)), rnd(12, (B, H, cache, D)), rnd(13, (B, H, cache, D))
-        offsets = jax.random.randint(jax.random.fold_in(key, 14), (B,), 0, cache).astype(jnp.int32)
-        got = jax.jit(lambda q, k, v, o: flash_decode(q, k, v, offsets=o, interpret=interpret))(q, k, v, offsets)
-        want = ref_attention(q, k, v, decode_step_bias(offsets, 1, cache), False)
-        decode[str(cache)] = round(rel_err(got, want), 5)
-        check(decode[str(cache)] <= TOL_FWD, f"flash_decode cache {cache} off by {decode[str(cache)]}")
+        for q_len in (1, 8):
+            q, k, v = rnd(11, (B, H, q_len, D)), rnd(12, (B, H, cache, D)), rnd(13, (B, H, cache, D))
+            offsets = jax.random.randint(jax.random.fold_in(key, 14), (B,), 0, cache - q_len + 1).astype(jnp.int32)
+            got = jax.jit(lambda q, k, v, o: flash_decode(q, k, v, offsets=o, interpret=interpret))(q, k, v, offsets)
+            want = ref_attention(q, k, v, decode_step_bias(offsets, q_len, cache), False)
+            name = f"{cache}" if q_len == 1 else f"{cache}x{q_len}rows"
+            decode[name] = round(rel_err(got, want), 5)
+            check(decode[name] <= TOL_FWD, f"flash_decode cache {cache}, {q_len} q rows, off by {decode[name]}")
+        tiling[str(cache)] = decode_step_heads(H, auto_block(cache), D, 2)
+        check(tiling[str(cache)] == H, f"flash_decode cache {cache}: a step holds {tiling[str(cache)]} of {H} heads")
     out["flash_decode_rel_err"] = decode
+    out["flash_decode_heads_per_step"] = tiling
 
     # fused dropout(+residual): statistics, determinism, fwd mask == bwd mask
     p = 0.1

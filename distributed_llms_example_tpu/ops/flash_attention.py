@@ -924,6 +924,117 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
     return q.astype(jnp.float32) * scale[..., None]
 
 
+# K and V blocks of one grid step, double-buffered by the pipeline: half of
+# the 16 MB a kernel may hold in VMEM by default; the rest is q, bias,
+# output, scratch and the step's own temporaries
+DECODE_STEP_VMEM_BUDGET = 8 * 2**20
+
+
+def _vmem_tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """Bytes a (rows, cols) block takes in VMEM: lanes pad to 128, sublanes
+    to 8 words of 32 bits (16 bf16 rows, 32 int8 rows)."""
+    sub = 8 * (4 // itemsize)
+    return -(-rows // sub) * sub * -(-cols // LANES) * LANES * itemsize
+
+
+def decode_step_heads(
+    heads: int, block_k: int, head_dim: int, itemsize: int,
+    *, int8_scales: bool = False,
+) -> int:
+    """Heads of a cache slot that one grid step of ``flash_decode`` covers,
+    from what the call's shapes say.
+
+    All of them when their K and V tiles fit ``DECODE_STEP_VMEM_BUDGET``
+    double-buffered, else the largest divisor of ``heads`` that does — down
+    to one head a step, the tiling before PR 26, when nothing larger fits.
+    Under ``int8_scales`` the tiles count as the f32 the step dequantizes
+    them to, and a split keeps 8 heads together: the (B, H, L) scales'
+    block has the head axis second to last, where the chip wants 8 rows or
+    the whole axis.  No such group fitting is an error here, not a Mosaic
+    failure far from its cause."""
+    head_bytes = 2 * _vmem_tile_bytes(block_k, head_dim, 4 if int8_scales else itemsize)
+    head_multiple = 8 if int8_scales else 1
+    fits = [
+        h for h in range(1, heads + 1)
+        if heads % h == 0 and 2 * h * head_bytes <= DECODE_STEP_VMEM_BUDGET
+        and (h % head_multiple == 0 or h == heads)
+    ]
+    if fits:
+        return max(fits)
+    if int8_scales:
+        raise ValueError(
+            f"flash_decode: int8 K/V with {heads} heads, kv tile {block_k} x "
+            f"{head_dim}: neither all heads nor a group of 8 fits the step's "
+            f"VMEM budget ({DECODE_STEP_VMEM_BUDGET} bytes, tiles counted as "
+            "f32); pass a smaller block_k or take the XLA path"
+        )
+    return 1
+
+
+def _decode_tile(q, k, v, k_scale, v_scale, bias, valid, m_prev, l_prev, acc,
+                 *, scale: float):
+    """One kv tile's online-softmax update for a group of heads — the
+    arithmetic ``_decode_kernel`` and ``_decode_paged_kernel`` share, which
+    is what keeps them bit-identical at equal tiling.
+
+    ``q``: (h, q_len, d); ``k``/``v``: (h, block_k, d), s8 with
+    ``k_scale``/``v_scale`` (h, block_k) under int8 KV; ``bias``: None or
+    broadcastable to (h, q_len, block_k); ``valid``: (q_len, block_k) bool,
+    the per-row length mask; ``m_prev``/``l_prev``: (h, q_len, 1) running
+    max and denominator, ``acc``: (h, q_len, d).  The heads are one batched
+    contraction: sixteen one-row products a slot are latency, not work, and
+    batched they overlap (v5e, PR 26: 0.90 ms for twelve calls at 64 slots
+    x 16 heads x cache 128, against 1.91 ms as an unrolled loop of 2-D
+    products).  Returns the new (m, l, acc)."""
+    if k_scale is not None:
+        # dequantize the tile in VMEM: HBM moved 1 byte/elem, the MXU sees
+        # f32 — the XLA fallback's own expression
+        k, v = dequantize_kv(k, k_scale), dequantize_kv(v, v_scale)
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+    )
+    s *= scale
+    if bias is not None:
+        s += bias.astype(jnp.float32)
+    s = jnp.where(valid[None], s, -jnp.inf)
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_next = jnp.maximum(m_prev, m_cur)
+    safe_m = jnp.where(m_next == -jnp.inf, 0.0, m_next)
+    alpha = jnp.exp(m_prev - safe_m)
+    p = jnp.exp(s - safe_m)
+    l_next = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )
+    return m_next, l_next, acc * alpha + pv
+
+
+def _decode_valid(offset, ki, q_len: int, block_k: int):
+    """(q_len, block_k) bottom-right aligned length mask of kv tile ``ki``:
+    q row r sits at absolute position offset + r and may attend cache
+    slots <= its own."""
+    q_pos = offset + jax.lax.broadcasted_iota(jnp.int32, (q_len, block_k), 0)
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (q_len, block_k), 1)
+    return q_pos >= k_pos
+
+
+def _decode_bias_spec(bias_shape, hb: int, q_len: int, block_k: int):
+    """BlockSpec of a decode step's additive bias, every dim 1 or full: the
+    step's slot x heads x q rows x kv tile where the bias has them.  The
+    grid is (slot, head group, kv tile), whatever scalar-prefetch refs
+    follow."""
+    b1, h1, q1, k1 = (n == 1 for n in bias_shape)
+
+    def index_map(b, h, ki, *_):
+        return (0 if b1 else b, 0 if h1 else h, 0, 0 if k1 else ki)
+
+    return pl.BlockSpec(
+        (1, 1 if h1 else hb, 1 if q1 else q_len, 1 if k1 else block_k),
+        index_map,
+    )
+
+
 def _decode_kernel(
     *refs, scale: float, block_k: int, nk: int, has_bias: bool,
     has_scales: bool = False,
@@ -935,8 +1046,9 @@ def _decode_kernel(
     vs_ref = next(it) if has_scales else None
     bias_ref = next(it) if has_bias else None
     o_ref, m_scr, l_scr, acc_scr = it
-    bi = pl.program_id(0)
     ki = pl.program_id(2)
+    q_len = q_ref.shape[2]  # the step's blocks: one slot x a group of heads
+    offset = off_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
@@ -944,58 +1056,28 @@ def _decode_kernel(
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    offset = off_ref[bi]
-    q_len = q_ref.shape[2]
-    # every live position of this row's tile is <= offset + q_len - 1:
+    # every live position of this slot's tile is <= offset + q_len - 1:
     # tiles past that contribute nothing — skip their DMA'd compute
-    live = ki * block_k <= offset + q_len - 1
-
-    @pl.when(live)
+    @pl.when(ki * block_k <= offset + q_len - 1)
     def _compute():
-        q = q_ref[0, 0]  # (q_len, d)
-        k = k_ref[0, 0]  # (block_k, d) — s8 under int8 KV
-        if ks_ref is not None:
-            # dequantize the tile in VMEM: HBM moved 1 byte/elem, the MXU
-            # sees f32 — same expression as dequantize_kv
-            k = k.astype(jnp.float32) * ks_ref[0, 0][:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        m, l, acc = _decode_tile(
+            q_ref[0], k_ref[0], v_ref[0],
+            None if ks_ref is None else ks_ref[0],
+            None if vs_ref is None else vs_ref[0],
+            None if bias_ref is None else bias_ref[0],
+            _decode_valid(offset, ki, q_len, block_k),
+            m_scr[:, :, :1], l_scr[:, :, :1], acc_scr[:],
+            scale=scale,
         )
-        s *= scale
-        if bias_ref is not None:
-            s += bias_ref[0, 0].astype(jnp.float32)
-        # bottom-right aligned length mask: q row r sits at absolute
-        # position offset + r and may attend cache slots <= its own
-        q_pos = offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_cur)
-        safe_m = jnp.where(m_next == -jnp.inf, 0.0, m_next)
-        alpha = jnp.exp(m_prev - safe_m)
-        p = jnp.exp(s - safe_m)
-        l_scr[:] = jax.lax.broadcast_in_dim(
-            (alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True))[:, 0],
-            l_scr.shape, (0,),
-        )
-        m_scr[:] = jax.lax.broadcast_in_dim(m_next[:, 0], m_scr.shape, (0,))
-        v = v_ref[0, 0]
-        if vs_ref is not None:
-            v = v.astype(jnp.float32) * vs_ref[0, 0][:, None]
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[:] = acc_scr[:] * alpha + pv
+        m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l, l_scr.shape)
+        acc_scr[:] = acc
 
     @pl.when(ki == nk - 1)
     def _finish():
-        l = l_scr[:, :1]
+        l = l_scr[:, :, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
 def flash_decode(
@@ -1028,6 +1110,15 @@ def flash_decode(
     vs f32 buffers.  Inference only (no vjp); numerically identical to
     masked ``dot_product_attention`` on the same (dequantized) inputs
     (the parity tests pin greedy and beam decode against it).
+
+    One grid step streams one kv tile of ALL heads of a cache slot (of
+    fewer when they do not fit VMEM: ``decode_step_heads``, from the shapes
+    alone), so the grid is (B, H / heads, L / block_k) — (64, 1, 1) at
+    bart-large-cnn's serving shape, 64 slots x 16 heads x cache 128 x d
+    64, where a step per (slot, head) had spent 0.51 us on 2 x 16 KB
+    (v5e, PR 26: twelve calls 6.96 -> 0.90 ms).  Per head the online
+    softmax, its order over kv tiles, the per-row mask, the dead-tile skip
+    and the fp32 accumulation are what they were.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -1056,7 +1147,10 @@ def flash_decode(
         interpret = _default_interpret()
     offsets = jnp.asarray(offsets, jnp.int32).reshape(batch)
     nk = kv_len // block_k
-    grid = (batch, heads, nk)
+    hb = decode_step_heads(
+        heads, block_k, d, k.dtype.itemsize, int8_scales=has_scales
+    )
+    grid = (batch, heads // hb, nk)
 
     def q_map(b, h, ki):
         return (b, h, 0, 0)
@@ -1069,22 +1163,17 @@ def flash_decode(
 
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),  # offsets, whole array
-        pl.BlockSpec((1, 1, q_len, d), q_map),
-        pl.BlockSpec((1, 1, block_k, d), kv_map),
-        pl.BlockSpec((1, 1, block_k, d), kv_map),
+        pl.BlockSpec((1, hb, q_len, d), q_map),
+        pl.BlockSpec((1, hb, block_k, d), kv_map),
+        pl.BlockSpec((1, hb, block_k, d), kv_map),
     ]
     if has_scales:
         in_specs += [
-            pl.BlockSpec((1, 1, block_k), scale_map),
-            pl.BlockSpec((1, 1, block_k), scale_map),
+            pl.BlockSpec((1, hb, block_k), scale_map),
+            pl.BlockSpec((1, hb, block_k), scale_map),
         ]
     if bias is not None:
-        inner = _bias_spec(bias.shape, q_len, block_k)
-
-        def bias_map(b, h, ki):
-            return inner.index_map(b, h, 0, ki)
-
-        in_specs.append(pl.BlockSpec(inner.block_shape, bias_map))
+        in_specs.append(_decode_bias_spec(bias.shape, hb, q_len, block_k))
     out = pl.pallas_call(
         functools.partial(
             _decode_kernel, scale=float(scale), block_k=block_k, nk=nk,
@@ -1092,12 +1181,12 @@ def flash_decode(
         ),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, q_len, d), q_map),
+        out_specs=pl.BlockSpec((1, hb, q_len, d), q_map),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((q_len, LANES), jnp.float32),
-            pltpu.VMEM((q_len, LANES), jnp.float32),
-            pltpu.VMEM((q_len, d), jnp.float32),
+            pltpu.VMEM((hb, q_len, LANES), jnp.float32),
+            pltpu.VMEM((hb, q_len, LANES), jnp.float32),
+            pltpu.VMEM((hb, q_len, d), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -1177,46 +1266,26 @@ def _decode_paged_kernel(
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]  # one pool block's head slice: (block_k, d)
-        if ks_ref is not None:
-            k = k.astype(jnp.float32) * ks_ref[0, 0][:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        # one pool block's heads, through the flat kernel's own tile
+        # arithmetic on the same group of heads: bit-identity rests on it
+        m, l, acc = _decode_tile(
+            q_ref[0], k_ref[0], v_ref[0],
+            None if ks_ref is None else ks_ref[0],
+            None if vs_ref is None else vs_ref[0],
+            None if bias_ref is None else bias_ref[0],
+            _decode_valid(offset, ki, q_len, block_k),
+            m_scr[:, :, :1], l_scr[:, :, :1], acc_scr[:],
+            scale=scale,
         )
-        s *= scale
-        if bias_ref is not None:
-            s += bias_ref[0, 0].astype(jnp.float32)
-        q_pos = offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_cur)
-        safe_m = jnp.where(m_next == -jnp.inf, 0.0, m_next)
-        alpha = jnp.exp(m_prev - safe_m)
-        p = jnp.exp(s - safe_m)
-        l_scr[:] = jax.lax.broadcast_in_dim(
-            (alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True))[:, 0],
-            l_scr.shape, (0,),
-        )
-        m_scr[:] = jax.lax.broadcast_in_dim(m_next[:, 0], m_scr.shape, (0,))
-        v = v_ref[0, 0]
-        if vs_ref is not None:
-            v = v.astype(jnp.float32) * vs_ref[0, 0][:, None]
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[:] = acc_scr[:] * alpha + pv
+        m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l, l_scr.shape)
+        acc_scr[:] = acc
 
     @pl.when(ki == nk - 1)
     def _finish():
-        l = l_scr[:, :1]
+        l = l_scr[:, :, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
 def flash_decode_paged(
@@ -1271,7 +1340,12 @@ def flash_decode_paged(
         interpret = _default_interpret()
     block_tables = jnp.asarray(block_tables, jnp.int32).reshape(batch, n_tiles)
     offsets = jnp.asarray(offsets, jnp.int32).reshape(batch)
-    grid = (batch, heads, n_tiles)
+    # the flat kernel's head group (a pool block is one slot's tile, so a
+    # step holds one slot): same blocks, same arithmetic, same bits
+    hb = decode_step_heads(
+        heads, block_k, d, k_pool.dtype.itemsize, int8_scales=has_scales
+    )
+    grid = (batch, heads // hb, n_tiles)
     clamp = num_blocks - 1
 
     def q_map(b, h, ki, bt_ref, off_ref):
@@ -1286,33 +1360,26 @@ def flash_decode_paged(
         return (jnp.minimum(bt_ref[b, ki], clamp), h, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, q_len, d), q_map),
-        pl.BlockSpec((1, 1, block_k, d), pool_map),
-        pl.BlockSpec((1, 1, block_k, d), pool_map),
+        pl.BlockSpec((1, hb, q_len, d), q_map),
+        pl.BlockSpec((1, hb, block_k, d), pool_map),
+        pl.BlockSpec((1, hb, block_k, d), pool_map),
     ]
     if has_scales:
         in_specs += [
-            pl.BlockSpec((1, 1, block_k), pool_scale_map),
-            pl.BlockSpec((1, 1, block_k), pool_scale_map),
+            pl.BlockSpec((1, hb, block_k), pool_scale_map),
+            pl.BlockSpec((1, hb, block_k), pool_scale_map),
         ]
     if bias is not None:
-        inner = _bias_spec(bias.shape, q_len, block_k)
-
-        def bias_map(b, h, ki, bt_ref, off_ref):
-            return inner.index_map(b, h, 0, ki)
-
-        in_specs.append(pl.BlockSpec(inner.block_shape, bias_map))
+        in_specs.append(_decode_bias_spec(bias.shape, hb, q_len, block_k))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, q_len, d), lambda b, h, ki, bt_ref, off_ref: (b, h, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, hb, q_len, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((q_len, LANES), jnp.float32),
-            pltpu.VMEM((q_len, LANES), jnp.float32),
-            pltpu.VMEM((q_len, d), jnp.float32),
+            pltpu.VMEM((hb, q_len, LANES), jnp.float32),
+            pltpu.VMEM((hb, q_len, LANES), jnp.float32),
+            pltpu.VMEM((hb, q_len, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(

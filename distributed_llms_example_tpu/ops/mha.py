@@ -163,12 +163,31 @@ def select_decode_impl(
     ``auto`` picks the Pallas **decode kernel** (``flash_decode``: one
     short q block — a single decode row, or the speculative verify's
     k+1 rows — against the cached K/V buffer, per-row length mask,
-    dead-tile skip) on TPU when the cache length tiles and — under a
-    multi-device mesh — batch/heads split evenly over (data×fsdp) and
-    ``tensor`` (the kernel runs per-shard under ``shard_map``, like
-    training flash).  ``flash`` forces the kernel wherever eligible; XLA
-    attention (per-row masked ``dot_product_attention``) otherwise.
-    ``ring`` has no KV-cache path and falls back to XLA."""
+    dead-tile skip; one grid step streams a kv tile of every head of a
+    cache slot, ``decode_step_heads``) on TPU when the cache length
+    tiles and — under a multi-device mesh — batch/heads split evenly
+    over (data×fsdp) and ``tensor`` (the kernel runs per-shard under
+    ``shard_map``, like training flash; the step then holds the shard's
+    heads).  ``flash`` forces the kernel wherever eligible; XLA attention
+    (per-row masked ``dot_product_attention``) otherwise.  ``ring`` has no
+    KV-cache path and falls back to XLA.
+
+    Where the line is and why it did not move in PR 26: a cache shorter
+    than 128 takes XLA.  At 128 (bart-large-cnn's serving shape, 64 slots
+    x 16 heads x d 64, bf16, one row) the kernel's twelve calls of a round
+    take 0.60 ms inside the serving program on v5e (0.90 alone), under the
+    2 ms that would have moved the line.  The same cell with this shape
+    sent to XLA instead runs a round of the same length (14.5 ms either
+    way, ``gap_p95_ms`` 48.4 against 48.6): XLA's one-row fusions take
+    1.4 ms there, not the 0.56 they take alone, and the cache is relaid on
+    both paths, because it rests with its length on the lanes
+    (``{2,3,1,0}``: d = 64 would waste half of them) where neither the
+    custom call (row-major) nor XLA's own scatter and products
+    (``{3,1,2,0}``) read it: ``copy bf16[64,16,128,64]``, 4.4 ms a round
+    in front of the kernel, 3.2 around XLA's path.  That relayout, not the
+    kernel, is what the next change to this rule, to the kernel's operand
+    layout or to how the cache rests is judged by (PERF.md, Findings
+    PR 26)."""
     if attention_impl not in ("auto", "flash", "ring", "xla"):
         raise ValueError(
             f"attention_impl={attention_impl!r}: must be 'auto', 'flash', 'ring', or 'xla'"

@@ -137,14 +137,32 @@ def test_flash_llama7b_shape_compiles(one_chip, compiled_kernels):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *shapes)
 
 
-@pytest.mark.parametrize("cache_len", [128, 1024])
-def test_flash_decode_compiles(cache_len, one_chip, compiled_kernels):
+DECODE_CASES = {
+    # name: (slots, q rows, cache length, int8 K/V with (B, H, L) scales)
+    "train-batch-cache128": (B, 1, TGT, False),
+    "train-batch-cache1024": (B, 1, SRC, False),
+    # bart-large-cnn.serve-steady's decode step, exactly: 64 slots, one row, cache 128
+    "serve-cell": (64, 1, TGT, False),
+    "serve-cell-verify-8-rows": (64, 8, TGT, False),
+    "serve-cell-cache1024": (64, 1, SRC, False),
+    "serve-cell-int8": (64, 1, TGT, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_flash_decode_compiles(case, one_chip, compiled_kernels):
     from distributed_llms_example_tpu.ops.flash_attention import flash_decode
 
-    def step(q, k, v, offsets):
-        return flash_decode(q, k, v, offsets=offsets)
+    slots, q_len, cache_len, int8 = DECODE_CASES[case]
+    kv = ((slots, H, cache_len, D), jnp.int8 if int8 else BF16)
+    shapes = [((slots, H, q_len, D), BF16), kv, kv, ((slots,), jnp.int32)]
+    if int8:
+        shapes += [((slots, H, cache_len), F32)] * 2
 
-    _compile(step, one_chip, *_qkv(1, cache_len), ((B,), jnp.int32))
+    def step(q, k, v, offsets, k_scale=None, v_scale=None):
+        return flash_decode(q, k, v, offsets=offsets, k_scale=k_scale, v_scale=v_scale)
+
+    _compile(step, one_chip, *shapes)
 
 
 @pytest.mark.parametrize("with_residual", [False, True], ids=["plain", "residual"])

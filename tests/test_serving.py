@@ -31,21 +31,103 @@ def _dense_decode_ref(q, k, v, bias, offsets, scale=None):
     return dot_product_attention(q, k, v, step if bias is None else bias + step, scale=scale)
 
 
-@pytest.mark.parametrize("q_len", [1, 4])
-def test_flash_decode_matches_dense(q_len):
+# name: (B, H, L, d, q_len, bias, int8 K/V, block_k, K+V VMEM budget) — bias
+# is None, "pad" (B, 1, 1, L) or "full" (B, H, q, L: T5's decode-step bias)
+DECODE_CASES = {
+    "tiny-q1": (3, 4, 64, 16, 1, "pad", False, None, None),
+    "tiny-q4": (3, 4, 64, 16, 4, "pad", False, None, None),
+    # the serve cell's head shape (bart-large-cnn: 16 heads x 64, cache 128):
+    # a grid step holds every head of a slot
+    "cell-q1": (4, 16, 128, 64, 1, None, False, None, None),
+    "cell-q8": (4, 16, 128, 64, 8, None, False, None, None),
+    "cell-q1-pad-bias": (4, 16, 128, 64, 1, "pad", False, None, None),
+    "cell-q8-pad-bias": (4, 16, 128, 64, 8, "pad", False, None, None),
+    "cell-q1-full-bias": (4, 16, 128, 64, 1, "full", False, None, None),
+    "cell-q8-full-bias": (4, 16, 128, 64, 8, "full", False, None, None),
+    "cell-q1-int8": (4, 16, 128, 64, 1, None, True, None, None),
+    "cell-q8-int8-full-bias": (4, 16, 128, 64, 8, "full", True, None, None),
+    # four kv tiles, offsets in the first, a middle and the last one
+    "tiles-q1": (4, 4, 256, 16, 1, "pad", False, 64, None),
+    "tiles-q4-full-bias": (4, 4, 256, 16, 4, "full", False, 64, None),
+    # a budget that holds two of eight heads: the head axis splits
+    "split-heads-q1": (3, 8, 64, 16, 1, "full", False, None, 4 * 2 * 64 * 128 * 4),
+    "split-heads-q4-int8": (3, 16, 64, 16, 4, "pad", True, None, 16 * 2 * 64 * 128 * 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_flash_decode_matches_dense(case, monkeypatch):
+    from distributed_llms_example_tpu.ops import flash_attention as fa
+
+    B, H, L, d, q_len, bias_kind, int8, block_k, budget = DECODE_CASES[case]
+    if budget is not None:
+        monkeypatch.setattr(fa, "DECODE_STEP_VMEM_BUDGET", budget)
+        hb = fa.decode_step_heads(
+            H, block_k or L, d, 1 if int8 else 4, int8_scales=int8
+        )
+        assert 1 < hb < H  # the case is what its name says
     rng = np.random.RandomState(0)
-    B, H, L, d = 3, 4, 64, 16
     q = jnp.asarray(rng.randn(B, H, q_len, d).astype(np.float32))
     k = jnp.asarray(rng.randn(B, H, L, d).astype(np.float32))
     v = jnp.asarray(rng.randn(B, H, L, d).astype(np.float32))
-    bias = jnp.asarray(
-        np.where(rng.rand(B, 1, 1, L) > 0.2, 0.0, NEG_INF).astype(np.float32)
-    )
+    bias = None
+    if bias_kind == "pad":
+        bias = np.where(rng.rand(B, 1, 1, L) > 0.2, 0.0, NEG_INF)
+        if not case.startswith("tiny"):  # the two tiny cases keep the draw they always had
+            # key 0 stays live: it is all the fresh slot's first row sees, and
+            # the dense reference spreads a row with no live key over masked ones
+            bias[..., 0] = 0.0
+    elif bias_kind == "full":
+        bias = rng.randn(B, H, q_len, L)
+    if bias is not None:
+        bias = jnp.asarray(bias.astype(np.float32))
     # ragged per-row offsets: fresh slot (0), mid-decode, cache-full
-    offsets = jnp.array([0, 17, L - q_len], jnp.int32)
-    out = flash_decode(q, k, v, bias, offsets=offsets)
+    offsets = jnp.array([0, 17, L - q_len, L // 2 + 3][:B], jnp.int32)
+    if int8:
+        qk, ks = fa.quantize_kv(k)
+        qv, vs = fa.quantize_kv(v)
+        out = flash_decode(
+            q, qk, qv, bias, offsets=offsets, k_scale=ks, v_scale=vs, block_k=block_k
+        )
+        k, v = fa.dequantize_kv(qk, ks), fa.dequantize_kv(qv, vs)
+    else:
+        out = flash_decode(q, k, v, bias, offsets=offsets, block_k=block_k)
     ref = _dense_decode_ref(q, k, v, bias, offsets)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
+
+
+def test_flash_decode_grid_is_slots_by_tiles():
+    """At the serve cell's shape one call takes at most batch x kv-tiles grid
+    steps: a step per (slot, head) cost 0.51 us for 2 x 16 KB on the chip
+    (PERF.md, PR 26), and no CPU test would notice its return."""
+    B, H, L, d = 64, 16, 128, 64
+    q = jax.ShapeDtypeStruct((B, H, 1, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, H, L, d), jnp.bfloat16)
+    off = jax.ShapeDtypeStruct((B,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, o: flash_decode(q, k, v, offsets=o))(q, kv, kv, off)
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    grid = calls[0].params["grid_mapping"].grid
+    assert int(np.prod(grid)) <= B * (L // 128), grid
+
+
+def test_decode_step_heads_rule():
+    from distributed_llms_example_tpu.ops.flash_attention import decode_step_heads
+
+    # bart-large-cnn's serve cell: all 16 heads of a slot, 1 MB of VMEM a step
+    assert decode_step_heads(16, 128, 64, 2) == 16
+    # a 7B cache tile (512 x 128 bf16) is 256 KB a head for K and V: 16 of 32 heads
+    assert decode_step_heads(32, 512, 128, 2) == 16
+    # five heads of 2 MB do not fit and 5 has no smaller group: one head a
+    # step, the tiling before PR 26
+    assert decode_step_heads(5, 512, 1024, 2) == 1
+    # int8 K/V: the scales' block wants 8 heads or all of them
+    assert decode_step_heads(16, 128, 64, 1, int8_scales=True) == 16
+    assert decode_step_heads(32, 512, 128, 1, int8_scales=True) == 8
+    # 12 heads x a 512-row tile as f32 do not fit and no group of 8 divides
+    # them: named here, not a Mosaic failure on a (1, 1, block_k) scale block
+    with pytest.raises(ValueError, match="int8 K/V with 12 heads"):
+        decode_step_heads(12, 512, 64, 1, int8_scales=True)
 
 
 def test_flash_decode_stale_cache_unreachable():

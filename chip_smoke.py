@@ -47,6 +47,9 @@ FULL = dict(
     new_tokens=128, decode_caches=(128, 1024), pad_to=128,
     train_extra=("--grad-accum-steps", "2"),
 )
+# the sparse decoder's operators at LFM2-8B-A1B's published widths (lfm2_phase)
+FULL_LFM2 = dict(d=2048, taps=3, experts=32, top_k=4, expert_ff=1792, batch=4, seq=1024)
+TINY_LFM2 = dict(d=64, taps=3, experts=8, top_k=4, expert_ff=32, batch=2, seq=32)
 TINY = dict(
     model="bart-test", batch=4, src=64, tgt=32, heads=4, head_dim=16,
     d_model=1024, ffn=256, vocab=264, train_rows=16, val_rows=4, prompts=4,
@@ -373,6 +376,75 @@ def kernels_phase(out: dict, sz: dict, seed: int, interpret: bool) -> None:
     out["tolerances"] = {"fwd": TOL_FWD, "grad": TOL_GRAD, "adamw": TOL_ADAMW, "keep_band": KEEP_BAND}
 
 
+# ---------------------------------------------- LFM2's operators, at width
+
+
+def lfm2_phase(out: dict, sz: dict, seed: int) -> None:
+    """The gated short convolution (a prefill, then cached steps that carry its
+    state) and the no-drop expert layer (sorted assignments, grouped products)
+    in bfloat16 at LFM2-8B-A1B's widths, against the plain float32 reference
+    (``benchmarks/reference/lfm2_moe.py``), in this process."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import precision, spec as spec_mod
+    from distributed_llms_example_tpu.models.lfm2 import Lfm2Config, ShortConv
+    from distributed_llms_example_tpu.ops.moe import MoEMLP
+
+    ref = spec_mod.load_module("reference", "lfm2_moe")
+    dot = precision.make_dot("fp32")
+    d, taps, E, K, ff, B, T = (sz[k] for k in ("d", "taps", "experts", "top_k", "expert_ff", "batch", "seq"))
+    key = jax.random.PRNGKey(seed)
+    rnd = lambda i, shape, scale: jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32) * scale  # noqa: E731
+    std = 0.9 / d ** 0.5  # projections write ~0.9 a channel from a unit-rms input, as the cell's weights do
+    x = rnd(0, (B, T, d), 1.0)
+
+    # -- conv operator: the whole sequence at once, then prefill T-8 and 8 cached steps
+    cfg = Lfm2Config(hidden_size=d, conv_L_cache=taps, num_hidden_layers=1, layer_types=("conv",))
+    conv = ShortConv(cfg, dtype=jnp.bfloat16)
+    p = {"in_proj": {"kernel": rnd(1, (d, 3 * d), std)}, "conv_weight": rnd(2, (d, taps), 0.33),
+         "out_proj": {"kernel": rnd(3, (d, d), std)}}
+    names = {"L.conv.in_proj.weight": p["in_proj"]["kernel"], "L.conv.conv.weight": p["conv_weight"],
+             "L.conv.out_proj.weight": p["out_proj"]["kernel"]}
+    want = jax.jit(jax.vmap(lambda row: ref.conv_operator(dot, names, "L", row)))(x)
+    got = jax.jit(lambda v: conv.apply({"params": p}, v))(x.astype(jnp.bfloat16))
+    err = rel_err(got, want)
+    check(err <= TOL_FWD, f"short conv, whole sequence: off by {err}")
+    cache = jax.tree.map(jnp.zeros_like, conv.init(key, x.astype(jnp.bfloat16), use_cache=True)["cache"])
+    step = jax.jit(lambda c, v: conv.apply({"params": p, "cache": c}, v, use_cache=True, mutable=["cache"]))
+    head, mut = step(cache, x[:, : T - 8].astype(jnp.bfloat16))
+    pieces = [head]
+    for t in range(T - 8, T):
+        y, mut = step(mut["cache"], x[:, t : t + 1].astype(jnp.bfloat16))
+        pieces.append(y)
+    err_cached = rel_err(jnp.concatenate(pieces, axis=1), want)
+    check(err_cached <= TOL_FWD, f"short conv, prefill then cached steps: off by {err_cached}")
+    out["short_conv_rel_err"] = {"whole": float(f"{err:.3g}"), "prefill_then_steps": float(f"{err_cached:.3g}")}
+
+    # -- expert layer: tokens whose 4th and 5th scores nearly tie may route
+    # otherwise in bfloat16 than in float32; they are counted, not compared
+    moe = MoEMLP(num_experts=E, intermediate_size=ff, top_k=K, capacity_factor=-1.0, dtype=jnp.bfloat16,
+                 scorer="sigmoid", use_expert_bias=True, aux_loss=False)
+    mp = {"router": {"kernel": rnd(4, (d, E), std)}, "expert_bias": rnd(5, (E,), 0.05),
+          "gate_proj": rnd(6, (E, d, ff), std), "up_proj": rnd(7, (E, d, ff), std), "down_proj": rnd(8, (E, ff, d), 0.9 / ff ** 0.5)}
+    mnames = {"L.feed_forward.gate.weight": mp["router"]["kernel"], "L.feed_forward.expert_bias": mp["expert_bias"],
+              "L.feed_forward.experts.w1.weight": mp["gate_proj"], "L.feed_forward.experts.w3.weight": mp["up_proj"],
+              "L.feed_forward.experts.w2.weight": mp["down_proj"]}
+    rcfg = {"num_experts_per_tok": K, "use_expert_bias": True, "norm_topk_prob": True, "routed_scaling_factor": 1.0}
+    xb = x.astype(jnp.bfloat16)  # both sides start from the same rounded input
+    want, margin = jax.jit(lambda v: ref.expert_layer(dot, mnames, "L", v, rcfg))(xb.astype(jnp.float32).reshape(B * T, d))
+    got, stats = jax.jit(lambda v: moe.apply({"params": mp}, v, mutable=["moe_stats"]))(xb)
+    load = jax.tree.leaves(stats["moe_stats"])[0]
+    check(int(load.sum()) == B * T * K, f"expert layer: {int(load.sum())} assignments for {B * T * K} routed")
+    clear = margin >= ref.NEAR_TIE
+    token_err = jnp.max(jnp.abs(got.reshape(B * T, d).astype(jnp.float32) - want), axis=-1) / jnp.max(jnp.abs(want))
+    err = float(jnp.max(jnp.where(clear, token_err, 0.0)))
+    check(err <= TOL_FWD, f"no-drop expert layer: off by {err} on tokens with a clear routing margin")
+    out["expert_layer"] = {"rel_err_clear_margin": float(f"{err:.3g}"), "near_tie_share": float(1.0 - jnp.mean(clear)),
+                           "rel_err_near_tie_max": float(f"{float(jnp.max(jnp.where(clear, 0.0, token_err))):.3g}"),
+                           "experts_hit": int((load > 0).sum()), "max_load_over_mean": float(load.max() / load.mean())}
+
+
 # ------------------------------------------------------------------- train
 
 
@@ -630,6 +702,8 @@ def main(argv: list[str] | None = None) -> int:
         else:
             with phase("kernels", results) as out:
                 kernels_phase(out, sz, args.seed, interpret=not on_tpu)
+            with phase("lfm2", results) as out:
+                lfm2_phase(out, TINY_LFM2 if args.rehearse else FULL_LFM2, args.seed)
             with phase("train", results) as out:
                 train_phase(out, sz, args.seed, on_tpu, results["device"]["compile_cache_dir"])
             with phase("serve", results) as out:

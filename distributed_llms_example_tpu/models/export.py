@@ -218,7 +218,62 @@ def export_llama_state_dict(params: Mapping[str, Any]) -> dict[str, np.ndarray]:
     return out
 
 
+_LFM2_DENSE = {"gate_proj": "w1", "up_proj": "w3", "down_proj": "w2"}
+_LFM2_LEAVES = {
+    "operator_norm/scale": ("operator_norm.weight", False),
+    "ffn_norm/scale": ("ffn_norm.weight", False),
+    "conv/in_proj/kernel": ("conv.in_proj.weight", True),
+    "conv/out_proj/kernel": ("conv.out_proj.weight", True),
+    "self_attn/q_proj/kernel": ("self_attn.q_proj.weight", True),
+    "self_attn/k_proj/kernel": ("self_attn.k_proj.weight", True),
+    "self_attn/v_proj/kernel": ("self_attn.v_proj.weight", True),
+    "self_attn/o_proj/kernel": ("self_attn.out_proj.weight", True),
+    "self_attn/q_norm/scale": ("self_attn.q_layernorm.weight", False),
+    "self_attn/k_norm/scale": ("self_attn.k_layernorm.weight", False),
+    "mlp/router/kernel": ("feed_forward.gate.weight", True),
+    "mlp/expert_bias": ("feed_forward.expert_bias", False),
+}
+
+
+def export_lfm2_state_dict(params: Mapping[str, Any]) -> dict[str, np.ndarray]:
+    """Our LFM2-MoE tree (models/lfm2.py) → HF ``Lfm2MoeForCausalLM`` names:
+    projections transposed to (out, in), the depthwise taps as a Conv1d
+    weight (channels, 1, taps), stacked experts unstacked into
+    ``feed_forward.experts.{j}.w{1,2,3}``; the head is tied, so there is no
+    ``lm_head``."""
+    out: dict[str, np.ndarray] = {}
+    for path, arr in _flat(params).items():
+        if path == "embed_tokens/embedding":
+            out["model.embed_tokens.weight"] = arr
+            continue
+        if path == "final_norm/scale":
+            out["model.embedding_norm.weight"] = arr
+            continue
+        m = re.fullmatch(r"block_(\d+)/(.+)", path)
+        if not m:
+            raise ValueError(f"unrecognized LFM2 parameter path: {path}")
+        base, rest = f"model.layers.{m.group(1)}", m.group(2)
+        if rest in _LFM2_LEAVES:
+            name, transpose = _LFM2_LEAVES[rest]
+            out[f"{base}.{name}"] = _t(arr) if transpose else arr
+            continue
+        if rest == "conv/conv_weight":
+            out[f"{base}.conv.conv.weight"] = np.ascontiguousarray(arr[:, None, :])
+            continue
+        m = re.fullmatch(r"mlp/(gate_proj|up_proj|down_proj)(/kernel)?", rest)
+        if not m:
+            raise ValueError(f"unrecognized LFM2 parameter path: {path}")
+        name = _LFM2_DENSE[m.group(1)]
+        if m.group(2) is not None:  # a leading dense layer
+            out[f"{base}.feed_forward.{name}.weight"] = _t(arr)
+        else:  # stacked experts: (E, d_in, d_out)
+            for j in range(arr.shape[0]):
+                out[f"{base}.feed_forward.experts.{j}.{name}.weight"] = _t(arr[j])
+    return out
+
+
 EXPORTERS = {
+    "lfm2": export_lfm2_state_dict,
     "t5": export_t5_state_dict,
     "bart": export_bart_state_dict,
     "llama": export_llama_state_dict,
@@ -304,6 +359,21 @@ def hf_config_dict(family: str, cfg: Any) -> dict:
             out["num_experts_per_tok"] = cfg.num_experts_per_tok
             out["router_aux_loss_coef"] = cfg.moe_aux_weight
         return out
+    if family == "lfm2":
+        return {
+            "model_type": "lfm2_moe",
+            "architectures": ["Lfm2MoeForCausalLM"],
+            **{k: getattr(cfg, k) for k in (
+                "vocab_size", "hidden_size", "intermediate_size", "moe_intermediate_size",
+                "num_hidden_layers", "num_dense_layers", "num_attention_heads", "num_key_value_heads",
+                "conv_L_cache", "num_experts", "num_experts_per_tok", "norm_topk_prob", "use_expert_bias",
+                "routed_scaling_factor", "norm_eps", "rope_theta", "max_position_embeddings",
+                "pad_token_id", "bos_token_id", "eos_token_id",
+            )},
+            "layer_types": list(cfg.layer_types),
+            "conv_bias": False,
+            "tie_word_embeddings": True,
+        }
     raise ValueError(f"no HF config export for family {family!r}")
 
 

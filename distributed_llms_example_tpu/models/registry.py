@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from distributed_llms_example_tpu.models import t5 as t5_mod
 from distributed_llms_example_tpu.models.bart import BartConfig, BartForConditionalGeneration
 from distributed_llms_example_tpu.models.convert import convert_state_dict
+from distributed_llms_example_tpu.models.lfm2 import Lfm2Config, Lfm2ForCausalLM
 from distributed_llms_example_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from distributed_llms_example_tpu.models.t5 import T5Config, T5ForConditionalGeneration
 
@@ -104,6 +105,24 @@ LLAMA_CONFIGS: dict[str, LlamaConfig] = {
         max_position_embeddings=32768, rope_theta=1e6,
         num_experts=8, num_experts_per_tok=2, moe_aux_weight=0.02,
     ),
+}
+
+
+# LFM2-MoE (models/lfm2.py): gated short convolutions and GQA by layer, a
+# sigmoid-routed expert layer after the leading dense ones.  Sizes from
+# LiquidAI/LFM2-8B-A1B's config.json; pad/eos are the byte tokenizer's (no
+# tokenizer files offline).
+LFM2_CONFIGS: dict[str, Lfm2Config] = {
+    # every layer kind in five layers: conv+dense, attention+experts, conv+experts
+    "lfm2-moe-test": Lfm2Config(
+        vocab_size=256, hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+        num_hidden_layers=5,
+        layer_types=("conv", "full_attention", "conv", "conv", "full_attention"),
+        num_dense_layers=1, num_attention_heads=4, num_key_value_heads=2,
+        num_experts=8, num_experts_per_tok=4, max_position_embeddings=256,
+        param_dtype=None,
+    ),
+    "lfm2-8b-a1b": Lfm2Config(),
 }
 
 
@@ -241,6 +260,9 @@ def _build(family: str, cfg: Any, dtype: jnp.dtype, remat: bool, params: Any = N
     if family in ("llama", "mixtral"):  # mixtral = llama blocks + MoE MLP
         module = LlamaForCausalLM(cfg, dtype=dtype, remat=remat, remat_policy=remat_policy)
         return LoadedModel("llama", cfg, module, params, is_seq2seq=False)
+    if family == "lfm2":
+        module = Lfm2ForCausalLM(cfg, dtype=dtype, remat=remat, remat_policy=remat_policy)
+        return LoadedModel("lfm2", cfg, module, params, is_seq2seq=False)
     raise ValueError(f"unsupported model family {family!r}")
 
 
@@ -306,6 +328,7 @@ def load_model(
         if (
             moe_capacity_factor is not None
             and getattr(cfg, "num_experts", 0) > 0
+            and hasattr(cfg, "moe_capacity_factor")  # LFM2's experts never drop
         ):
             cfg = dataclasses.replace(cfg, moe_capacity_factor=moe_capacity_factor)
         if fused_ce is not None and hasattr(cfg, "fused_ce"):
@@ -334,7 +357,9 @@ def load_model(
         return _build("bart", _apply_impl(BART_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
     if short in LLAMA_CONFIGS:
         return _build("llama", _apply_impl(LLAMA_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
-    known = sorted(T5_CONFIGS) + sorted(BART_CONFIGS) + sorted(LLAMA_CONFIGS)
+    if short in LFM2_CONFIGS:
+        return _build("lfm2", _apply_impl(LFM2_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
+    known = sorted(T5_CONFIGS) + sorted(BART_CONFIGS) + sorted(LLAMA_CONFIGS) + sorted(LFM2_CONFIGS)
     raise ValueError(
         f"unknown model {name_or_path!r}: not a local checkpoint dir and not one of {known}"
     )
@@ -346,5 +371,6 @@ __all__ = [
     "T5_CONFIGS",
     "BART_CONFIGS",
     "LLAMA_CONFIGS",
+    "LFM2_CONFIGS",
     "t5_mod",
 ]

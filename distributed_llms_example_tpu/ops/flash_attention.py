@@ -747,6 +747,19 @@ def auto_block(seq_len: int, cap: int = MAX_BLOCK) -> int:
     return 0
 
 
+def decode_block(kv_len: int) -> int:
+    """The kv tile of a cached decode step.  A bias or length-mask block ends
+    on the tile (its lane axis), and Mosaic takes a lane block only in
+    multiples of 128 or whole: so the largest 128-aligned tile dividing the
+    cache where there is one (1280 = 1024 + 256 gives 256, where
+    ``auto_block``'s 16-aligned 320 does not compile), else ``auto_block``'s.
+    Every power-of-two cache gets the tile it had."""
+    for b in range(min(MAX_BLOCK, kv_len) // 128 * 128, 127, -128):
+        if kv_len % b == 0:
+            return b
+    return auto_block(kv_len)
+
+
 def flash_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -1010,11 +1023,13 @@ def _decode_tile(q, k, v, k_scale, v_scale, bias, valid, m_prev, l_prev, acc,
     return m_next, l_next, acc * alpha + pv
 
 
-def _decode_valid(offset, ki, q_len: int, block_k: int):
+def _decode_valid(offset, ki, q_len: int, block_k: int, q_group: int = 1):
     """(q_len, block_k) bottom-right aligned length mask of kv tile ``ki``:
-    q row r sits at absolute position offset + r and may attend cache
-    slots <= its own."""
-    q_pos = offset + jax.lax.broadcasted_iota(jnp.int32, (q_len, block_k), 0)
+    q row r sits at absolute position offset + r // q_group (``q_group``
+    rows a position: the query heads that share a KV head) and may attend
+    cache slots <= its own."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (q_len, block_k), 0)
+    q_pos = offset + (row if q_group == 1 else row // q_group)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (q_len, block_k), 1)
     return q_pos >= k_pos
 
@@ -1037,7 +1052,7 @@ def _decode_bias_spec(bias_shape, hb: int, q_len: int, block_k: int):
 
 def _decode_kernel(
     *refs, scale: float, block_k: int, nk: int, has_bias: bool,
-    has_scales: bool = False,
+    has_scales: bool = False, q_group: int = 1,
 ):
     it = iter(refs)
     off_ref = next(it)  # SMEM (batch,) int32: absolute position of q row 0
@@ -1065,7 +1080,7 @@ def _decode_kernel(
             None if ks_ref is None else ks_ref[0],
             None if vs_ref is None else vs_ref[0],
             None if bias_ref is None else bias_ref[0],
-            _decode_valid(offset, ki, q_len, block_k),
+            _decode_valid(offset, ki, q_len, block_k, q_group),
             m_scr[:, :, :1], l_scr[:, :, :1], acc_scr[:],
             scale=scale,
         )
@@ -1093,6 +1108,7 @@ def flash_decode(
     block_k: int | None = None,
     interpret: bool | None = None,
     dtype: jnp.dtype | None = None,
+    q_group: int = 1,
 ) -> jnp.ndarray:
     """Decode-step attention: a short q block against a cached K/V buffer.
 
@@ -1111,6 +1127,12 @@ def flash_decode(
     masked ``dot_product_attention`` on the same (dequantized) inputs
     (the parity tests pin greedy and beam decode against it).
 
+    ``q_group`` > 1 is grouped-query attention without a repeated cache:
+    H counts the KV heads, and the q block holds, position by position, the
+    ``q_group`` query heads that read one KV head (row r is position
+    ``offsets[b] + r // q_group``), so each K/V tile is streamed once for
+    all of them (``ops/mha.py`` folds the heads in and out).
+
     One grid step streams one kv tile of ALL heads of a cache slot (of
     fewer when they do not fit VMEM: ``decode_step_heads``, from the shapes
     alone), so the grid is (B, H / heads, L / block_k) — (64, 1, 1) at
@@ -1124,7 +1146,7 @@ def flash_decode(
         scale = q.shape[-1] ** -0.5
     batch, heads, q_len, d = q.shape
     kv_len = k.shape[2]
-    block_k = auto_block(kv_len) if block_k is None else min(block_k, kv_len)
+    block_k = decode_block(kv_len) if block_k is None else min(block_k, kv_len)
     if not block_k or kv_len % block_k or block_k % 8:
         raise ValueError(
             f"kv_len {kv_len} not divisible into 8-aligned blocks ({block_k})"
@@ -1177,7 +1199,7 @@ def flash_decode(
     out = pl.pallas_call(
         functools.partial(
             _decode_kernel, scale=float(scale), block_k=block_k, nk=nk,
-            has_bias=bias is not None, has_scales=has_scales,
+            has_bias=bias is not None, has_scales=has_scales, q_group=q_group,
         ),
         grid=grid,
         in_specs=in_specs,
@@ -1212,7 +1234,7 @@ def flash_decode_supported(
     block is small enough to live in scratch (plain decode steps are 1
     row, speculative verify up to ``MAX_DECODE_Q_ROWS`` — the cap keeps
     prefill-sized calls out)."""
-    bk = auto_block(kv_len) if block_k is None else min(block_k, kv_len)
+    bk = decode_block(kv_len) if block_k is None else min(block_k, kv_len)
     return (
         0 < q_len <= MAX_DECODE_Q_ROWS
         and bk > 0
@@ -1418,6 +1440,7 @@ def flash_decode_run(
     scale: float | None = None,
     dtype: jnp.dtype | None = None,
     interpret: bool | None = None,
+    q_group: int = 1,
 ) -> jnp.ndarray:
     """Run the decode kernel — directly on one device, per-shard under
     ``shard_map`` on a mesh (batch over data×fsdp×expert, heads over
@@ -1436,7 +1459,7 @@ def flash_decode_run(
     if mesh is None or _math.prod(mesh.devices.shape) == 1:
         return flash_decode(
             q, k, v, bias, offsets=offsets, k_scale=k_scale, v_scale=v_scale,
-            scale=scale, dtype=dtype, interpret=interpret,
+            scale=scale, dtype=dtype, interpret=interpret, q_group=q_group,
         )
     batch_axes = tuple(a for a in BATCH_AXES if a in mesh.shape)
     head_axis = "tensor" if "tensor" in mesh.shape else None
@@ -1453,7 +1476,7 @@ def flash_decode_run(
         return flash_decode(
             q, k, v, rest[0] if rest else None, offsets=off,
             k_scale=ks, v_scale=vs, scale=scale,
-            dtype=dtype, interpret=interpret,
+            dtype=dtype, interpret=interpret, q_group=q_group,
         )
 
     args = (q, k, v, jnp.asarray(offsets, jnp.int32).reshape(q.shape[0]))

@@ -24,11 +24,13 @@ from distributed_llms_example_tpu.ops.attention import (
     make_causal_bias,
 )
 from distributed_llms_example_tpu.ops.flash_attention import (
+    MAX_DECODE_Q_ROWS,
     flash_attention,
     flash_decode_run,
     flash_decode_supported,
     flash_supported,
 )
+from distributed_llms_example_tpu.ops.norms import RMSNorm
 from distributed_llms_example_tpu.ops.ring_attention import ring_attention, ring_attention_sharded
 from distributed_llms_example_tpu.parallel.activation import (
     BATCH_AXES,
@@ -292,6 +294,9 @@ class MultiHeadAttention(nn.Module):
     # mask never materializes in HBM (ops/flash_attention.py); the XLA
     # path applies the reference bernoulli mask to the probs.
     probs_dropout_rate: float = 0.0
+    # RMSNorm over head_dim of every q and k head, before RoPE (LFM2,
+    # Qwen3-class ``q_layernorm``/``k_layernorm``); None = the model has none
+    qk_norm_eps: float | None = None
 
     @property
     def kv_heads(self) -> int:
@@ -305,6 +310,9 @@ class MultiHeadAttention(nn.Module):
         self.k_proj = mk(inner_kv, "k_proj")
         self.v_proj = mk(inner_kv, "v_proj")
         self.o_proj = mk(self.model_dim, "o_proj")
+        if self.qk_norm_eps is not None:
+            self.q_norm = RMSNorm(self.qk_norm_eps, self.dtype, name="q_norm")
+            self.k_norm = RMSNorm(self.qk_norm_eps, self.dtype, name="k_norm")
 
     def _split(self, x: jnp.ndarray, heads: int) -> jnp.ndarray:
         b, s, _ = x.shape
@@ -472,6 +480,8 @@ class MultiHeadAttention(nn.Module):
             kv_src = hidden if kv_hidden is None else kv_hidden
             k = self._split(self.k_proj(kv_src), self.kv_heads)
             v = self._split(self.v_proj(kv_src), self.kv_heads)
+            if self.qk_norm_eps is not None:
+                q, k = self.q_norm(q), self.k_norm(k)
 
         offset = 0
         decode_offsets = None  # (B,) absolute position of q row 0, cached decode
@@ -518,8 +528,25 @@ class MultiHeadAttention(nn.Module):
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
 
-        if self.kv_heads != self.num_heads:
-            rep = self.num_heads // self.kv_heads
+        # grouped-query attention: the cached decode kernel reads each KV head
+        # once for the ``rep`` query heads that share it (folded into its q
+        # rows below); every other path gets K and V repeated to the q heads
+        rep = self.num_heads // self.kv_heads
+        mesh = current_mesh()
+        fold_gqa = (
+            rep > 1
+            and decode_offsets is not None
+            and q.shape[2] * rep <= MAX_DECODE_Q_ROWS
+            and (bias is None or bias.shape[1] == bias.shape[2] == 1)
+            and (mesh is None or self.kv_heads % mesh.shape.get("tensor", 1) == 0)
+            and (deterministic or not self.probs_dropout_rate)
+            and select_decode_impl(
+                self.attention_impl, batch=q.shape[0], heads=self.kv_heads,
+                head_dim=self.head_dim, q_len=q.shape[2] * rep, kv_len=k.shape[2],
+                mesh=mesh, backend=jax.default_backend(), device_count=jax.device_count(),
+            )[0] == "flash_decode"
+        )
+        if rep > 1 and not fold_gqa:
             k = jnp.repeat(k, rep, axis=1)
             v = jnp.repeat(v, rep, axis=1)
             if k_scale is not None:
@@ -574,7 +601,16 @@ class MultiHeadAttention(nn.Module):
             )
             b, h, s, d = out.shape
             return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b, s, h * d))
-        mesh = current_mesh()
+        if fold_gqa:
+            _log_impl_once("flash_decode", f"grouped: {rep} query heads a KV head as q rows")
+            b, _, t, d = q.shape
+            rows = q.reshape(b, self.kv_heads, rep, t, d).swapaxes(2, 3).reshape(b, self.kv_heads, t * rep, d)
+            out = flash_decode_run(
+                rows, k, v, bias, offsets=decode_offsets, mesh=mesh,
+                k_scale=k_scale, v_scale=v_scale, dtype=self.dtype, q_group=rep,
+            )
+            out = out.reshape(b, self.kv_heads, t, rep, d).swapaxes(2, 3).reshape(b, self.num_heads, t, d)
+            return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b, t, self.num_heads * d))
         if decode_offsets is not None:
             decode_dropout = (
                 float(self.probs_dropout_rate) if not deterministic else 0.0

@@ -188,9 +188,22 @@ def constrain_kv_scale(x: jax.Array) -> jax.Array:
     return constrain(x, kv_scale_spec(x.shape, dict(mesh.shape)))
 
 
+def constrain_conv_state(x: jax.Array) -> jax.Array:
+    """(batch, channels, taps-1) conv-layer decode state: batch rows over the
+    batch axes, channels over ``tensor`` (``sharding.conv_state_spec``)."""
+    mesh = current_mesh()
+    if mesh is None or x.ndim != 3:
+        return x
+    from distributed_llms_example_tpu.parallel.sharding import conv_state_spec
+
+    return constrain(x, conv_state_spec(x.shape, dict(mesh.shape)))
+
+
 def constrain_cache(tree):
     """Pin a whole flax "cache" collection (or cross-KV tuple tree) to the
-    serving layout: every 4-D leaf via ``constrain_kv``, 3-D ``*_scale``
+    serving layout, leaf by leaf: a ``conv_state`` via
+    ``constrain_conv_state``, K/V buffers (and the unnamed 4-D leaves of a
+    cross-KV tuple) via ``constrain_kv``, 3-D ``*_scale``
     leaves (the int8 KV cache's per-head per-position scales) via
     ``constrain_kv_scale``, scalars (the ``cache_index`` counters)
     replicated by GSPMD default.  No-op without an ambient mesh — the
@@ -203,6 +216,8 @@ def constrain_cache(tree):
 
     def pin(path, x):
         nd = getattr(x, "ndim", 0)
+        if leaf_key(path) == "conv_state":
+            return constrain_conv_state(x)
         if nd == 4:
             return constrain_kv(x)
         if nd == 3 and leaf_key(path).endswith("_scale"):

@@ -108,6 +108,10 @@ DEFAULT_RULES: list[tuple[str, P]] = [
     # o is row-parallel (heads*head_dim, d_model)
     (r"(self_attn|cross_attn|attention)/(q|k|v)_proj/kernel", P("fsdp", "tensor")),
     (r"(self_attn|cross_attn|attention)/o_proj/kernel", P("tensor", "fsdp")),
+    # gated short convolution (LFM2): in column-parallel (d, 3d), out
+    # row-parallel; the (d, taps) depthwise weight is small and replicated
+    (r"conv/in_proj/kernel", P("fsdp", "tensor")),
+    (r"conv/out_proj/kernel", P("tensor", "fsdp")),
     # MoE: stacked expert weights — experts over the dedicated ``expert``
     # axis (GSPMD lowers the dispatch/combine einsums to the expert
     # all-to-all), megatron column/row splits over ``tensor`` WITHIN each
@@ -146,8 +150,21 @@ CACHE_RULES: list[tuple[str, P]] = [
     # scales, (batch, heads, len) — the K/V layout minus head_dim, so the
     # scales always live next to the buffers they dequantize
     (r"(key_scale|value_scale)$", P(("data", "fsdp", "expert"), "tensor", None)),
+    # a conv layer's decode state (models/lfm2.py): (batch, channels, taps-1),
+    # the channels over ``tensor`` like the in-projection that produces them
+    (r"conv_state$", P(("data", "fsdp", "expert"), "tensor", None)),
     (r"cache_index$", P()),
 ]
+
+# The cache leaves that grow with the cache length, and the axis it lies on:
+# what widens when a bucket-width prefill lands in a full-width slot.  Any
+# other leaf (a conv state, a counter) has the same shape at every width.
+CACHE_LENGTH_AXIS = {"cached_key": 2, "cached_value": 2, "key_scale": 2, "value_scale": 2}
+
+
+def cache_leaf_name(path: tuple) -> str:
+    """The last key of a cache leaf's tree path: what the cache rules match."""
+    return _path_str(path).rsplit("/", 1)[-1]
 
 
 def cache_rules() -> ShardingRules:
@@ -200,6 +217,13 @@ def kv_scale_spec(shape: tuple, mesh_axes: Any) -> P:
     the same way.  THE single definition of the scale layout:
     ``activation.constrain_kv_scale`` and the engine's host placement
     both derive from it."""
+    full = kv_leaf_spec((*shape, 1), mesh_axes)
+    return P(full[0], full[1], None)
+
+
+def conv_state_spec(shape: tuple, mesh_axes: Any) -> P:
+    """The CACHE_RULES layout for one (batch, channels, taps-1) conv-state
+    leaf, divisibility-guarded like ``kv_leaf_spec``."""
     full = kv_leaf_spec((*shape, 1), mesh_axes)
     return P(full[0], full[1], None)
 

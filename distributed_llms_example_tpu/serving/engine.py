@@ -74,6 +74,7 @@ from distributed_llms_example_tpu.parallel.activation import (
     kv_cache_context,
 )
 from distributed_llms_example_tpu.obs.spans import SpanRecorder, percentiles
+from distributed_llms_example_tpu.parallel.sharding import CACHE_LENGTH_AXIS, _path_str, cache_leaf_name
 from distributed_llms_example_tpu.serving import cache_pool
 from distributed_llms_example_tpu.serving import spec as spec_decode
 from distributed_llms_example_tpu.utils.jsonlog import log_json
@@ -289,6 +290,17 @@ def device_peak_bytes() -> int | None:
     return max(s["peak_bytes_in_use"] for s in stats)
 
 
+# what a decode round of a model with experts appends to its token vector,
+# summed (hit, assignments) or maximised (max_load) over the expert layers:
+# experts that received a row, the largest expert's rows, all rows routed.
+# Every slot of the round is routed, idle ones too: the program computes them.
+MOE_COUNTERS = ("moe_experts_hit", "moe_max_load", "moe_assignments")
+
+
+class UnsupportedServeMode(ValueError):
+    """A ``ServeConfig`` mode this model's cache state cannot run under."""
+
+
 class ServingEngine:
     """Greedy continuous-batching decode over a fixed slot set.
 
@@ -326,6 +338,23 @@ class ServingEngine:
         self.buckets = tuple(
             sorted({int(b) for b in self.serve.prefill_buckets if 0 < int(b) < self.W})
         ) + (self.W,)
+        # a model whose cache holds state other than K/V (LFM2's conv state)
+        # serves on the flat cache only: the block pool pages K/V by cache
+        # position, a state leaf has none; the speculative verify and the
+        # warm prefix admission would have to roll a state back
+        if getattr(config, "has_recurrent_state", False):
+            for mode, on in (("paged_kv", self.serve.paged_kv),
+                             ("prefix_cache", self.serve.prefix_cache),
+                             ("spec_tokens", self.serve.spec_tokens)):
+                if on:
+                    raise UnsupportedServeMode(
+                        f"{mode} is not supported for {type(config).__name__}: its "
+                        "cache holds a convolution state beside K/V, which the "
+                        "block pool cannot page and a rejected draft cannot roll "
+                        "back; serve it on the flat cache (the default)"
+                    )
+        # experts: the decode round reports their load (moe_* counters)
+        self.moe = getattr(config, "num_experts", 0) > 0
         self.paged = bool(self.serve.paged_kv)
         self.pool: cache_pool.CachePool | None = None
         if self.paged:
@@ -476,12 +505,19 @@ class ServingEngine:
         counted.__name__ = counted.__qualname__ = f"serve_{name}"
         jitted = jax.jit(counted, donate_argnums=donate)
 
-        def run(*args):
-            with activation_mesh(self.mesh), kv_cache_context(
-                self.serve.kv_cache_dtype
-            ):
-                return jitted(*args)
+        def under_contexts(call):
+            def run(*args):
+                with activation_mesh(self.mesh), kv_cache_context(
+                    self.serve.kv_cache_dtype
+                ):
+                    return call(*args)
 
+            return run
+
+        run = under_contexts(jitted)
+        # the same program lowered for abstract arguments, not run: what a
+        # compile for a described chip reads (tests/test_chip_compile.py)
+        run.lower = under_contexts(jitted.lower)
         return run
 
     @staticmethod
@@ -564,14 +600,14 @@ class ServingEngine:
             width_full = self.W + L
 
             def _pad_cache_tree(cache):
-                # bucket-width chunk cache → slot width; K/V on axis 2,
-                # int8 scale leaves on axis 2 too, scalars untouched
-                def pad(x):
-                    if x.ndim >= 3:
-                        return self._pad_axis(x, 2, width_full)
-                    return x
+                # bucket-width chunk cache → slot width, by leaf: K/V and the
+                # int8 scale leaves grow along their length axis; a conv state
+                # or a counter has one shape at every width
+                def pad(path, x):
+                    axis = CACHE_LENGTH_AXIS.get(cache_leaf_name(path))
+                    return x if axis is None else self._pad_axis(x, axis, width_full)
 
-                return jax.tree.map(pad, cache)
+                return jax.tree_util.tree_map_with_path(pad, cache)
 
             if self.paged:
                 n_blocks, bs = self.pool.num_blocks, self.block_size
@@ -688,16 +724,24 @@ class ServingEngine:
                         use_cache=True,
                         positions=rope_pos[:, None],
                         cache_positions=offs,
-                        mutable=["cache"],
+                        mutable=["cache", "moe_stats"] if self.moe else ["cache"],
                     )
                     nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                     nxt = jnp.where(active, nxt, self.pad)
-                    return nxt, {
+                    state = {
                         **state,
                         "cache": constrain_cache(mut["cache"]),
                         "mask": mask,
                         "last": nxt,
                     }
+                    if self.moe:
+                        # the round's expert load rides the token vector's tail,
+                        # so that one fetch brings both (MOE_COUNTERS' order)
+                        load = jnp.stack(jax.tree.leaves(mut["moe_stats"]))  # (layers, E)
+                        nxt = jnp.concatenate([nxt, jnp.stack(
+                            [jnp.sum(load > 0), jnp.max(load), jnp.sum(load)]
+                        ).astype(jnp.int32)])
+                    return nxt, state
 
         self._prefill_core = prefill
         self._prefill = self._wrap(prefill, name="prefill")
@@ -721,6 +765,7 @@ class ServingEngine:
         from jax.sharding import PartitionSpec as P
 
         from distributed_llms_example_tpu.parallel.sharding import (
+            conv_state_spec,
             kv_leaf_spec,
             kv_scale_spec,
             pool_rules,
@@ -738,6 +783,8 @@ class ServingEngine:
             # block dim never shards over the batch axes — POOL_RULES
             leaf = path.rsplit("/", 1)[-1]
             return pool_rules().spec_for(leaf, nd)
+        if path.endswith("conv_state"):
+            return conv_state_spec(x.shape, mesh_axes)
         if nd == 4:  # cached/cross K/V: the ONE shared layout definition
             return kv_leaf_spec(x.shape, mesh_axes)
         if nd == 3 and path.endswith("_scale"):  # int8 KV scales
@@ -750,8 +797,6 @@ class ServingEngine:
             return tree
         import jax.tree_util as jtu
         from jax.sharding import NamedSharding
-
-        from distributed_llms_example_tpu.parallel.sharding import _path_str
 
         return jtu.tree_map_with_path(
             lambda p, x: jax.device_put(
@@ -895,7 +940,19 @@ class ServingEngine:
         session per replica.  ``replica`` stamps the serve events so the
         router tier's streams stay attributable per engine.  ``spans``
         replaces the session's span recorder (tests: a virtual clock and
-        a fake annotation factory)."""
+        a fake annotation factory).
+
+        Where the model's config states the dtype its weights are stored in
+        (``param_dtype``), the session keeps the floating-point weights
+        resident in it: a model published in bfloat16 is not served from a
+        float32 copy that every use casts again.  A config that states none
+        (BART, T5, LLaMA here) is served from ``params`` as handed in."""
+        stated = getattr(self.config, "param_dtype", None)
+        if stated is not None:
+            dtype = jnp.dtype(stated)
+            params = jax.jit(lambda p: jax.tree.map(
+                lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x, p
+            ))(params)
         return ServeSession(self, params, replica=replica, spans=spans)
 
     def generate(
@@ -1017,6 +1074,15 @@ class ServeSession:
         self.stats.cache_bytes_resident, self._per_block = (
             eng._state_byte_account(self.state)
         )
+        # the flat cache's static bytes by kind of leaf (K/V with their int8
+        # scales, conv state): metadata arithmetic, no device fetch
+        by_kind = {"kv_bytes": 0, "conv_state_bytes": 0}
+        for path, x in jax.tree_util.tree_leaves_with_path(self.state.get("cache", self.state.get("pool", {}))):
+            leaf = cache_leaf_name(path)
+            if leaf != "cache_index":  # a counter, not state
+                kind = "conv_state_bytes" if leaf == "conv_state" else "kv_bytes"
+                by_kind[kind] += int(np.prod(x.shape)) * x.dtype.itemsize
+        self._cache_bytes_by_kind = by_kind
         if eng.paged and eng.prefix:
             # the device pool tensor was just re-zeroed (_init_state), so
             # any warm chains a PREVIOUS session retained now index
@@ -1791,6 +1857,8 @@ class ServeSession:
                 spec_emit = np.asarray(jax.device_get(n_emit))
             else:
                 toks = np.asarray(jax.device_get(tokens))
+                if len(toks) > eng.S:  # a flat round of a model with experts
+                    fetch.set(**{k: int(v) for k, v in zip(MOE_COUNTERS, toks[eng.S:])})
         dt = fetch.end - dispatch.t0
         with self.spans.span("emit") as emit:
             now = emit.t0
@@ -1965,6 +2033,7 @@ class ServeSession:
             "paged_kv": eng.paged,
             "prefill_buckets": list(eng.buckets),
             "cache_bytes_resident": stats.cache_bytes_resident,
+            **self._cache_bytes_by_kind,
             "peak_cache_bytes_in_use": stats.peak_cache_bytes_in_use,
             "cache_bytes_per_token": round(stats.bytes_per_live_token, 1),
             # the host spans since the session opened (obs/spans.py summary):

@@ -15,6 +15,7 @@ runs in the test's own process, and all of it lives in this one file.
 """
 
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -215,3 +216,110 @@ def test_flash_run_on_fsdp4_mesh_compiles(fsdp4_mesh, compiled_kernels):
     )
     assert text.count("tpu_custom_call") >= 3
     assert "all-gather" not in text
+
+
+# ---- lfm2-8b-a1b.serve-steady: the cell's three serving programs, whole ----
+
+LFM2_SLOTS, LFM2_WAVE, LFM2_PROMPT, LFM2_NEW = 128, 4, 1024, 256
+
+
+def test_flash_decode_grouped_query_cell_shape_compiles(one_chip, compiled_kernels):
+    """The new cell's decode attention: 8 KV heads, each streamed once for its 4
+    query heads (folded into the q rows), a 1280-row cache (kv tile 256: the
+    mask's lane block must be a multiple of 128) and the padding mask."""
+    from distributed_llms_example_tpu.ops.flash_attention import decode_block, flash_decode
+
+    cache = LFM2_PROMPT + LFM2_NEW
+    assert decode_block(cache) == 256 and decode_block(128) == 128 and decode_block(1024) == 512
+    kv = ((LFM2_SLOTS, 8, cache, D), BF16)
+    _compile(
+        lambda q, k, v, bias, offsets: flash_decode(q, k, v, bias, offsets=offsets, q_group=4),
+        one_chip, ((LFM2_SLOTS, 8, 4, D), BF16), kv, kv, ((LFM2_SLOTS, 1, 1, cache), F32), ((LFM2_SLOTS,), jnp.int32),
+    )
+
+
+@pytest.fixture
+def lfm2_cell_engine(topo, compiled_kernels, monkeypatch):
+    """A ``ServingEngine`` at the cell's sizes on a described chip: the model the
+    benchmark registers from its configuration file, bfloat16 weights."""
+    from benchmarks.harness import program, spec as spec_mod
+    from distributed_llms_example_tpu.core.config import MeshConfig
+    from distributed_llms_example_tpu.core.mesh import build_mesh
+    from distributed_llms_example_tpu.models import registry
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels' auto rules and donation ask it
+    cfg = spec_mod.load_json(os.path.join(spec_mod.BENCH_DIR, "configs", "lfm2-8b-a1b.json"))
+    lm = registry.load_model(
+        program.register_bench_model(cfg, spec_mod.load_module("adapters", cfg["family"])), dtype=BF16)
+    serve = ServeConfig(max_slots=LFM2_SLOTS, prefill_batch=LFM2_WAVE, max_new_tokens=LFM2_NEW,
+                        max_source_length=LFM2_PROMPT)
+    mesh = build_mesh(MeshConfig(data=-1), devices=topo.devices[:1])
+    return lm, ServingEngine(lm.module, lm.config, mesh, serve, is_seq2seq=False)
+
+
+def test_lfm2_cell_serving_programs_compile_and_fit(lfm2_cell_engine, one_chip):
+    """Decode step, prefill wave and admit at 128 slots, 4 x 1024, cache 1280,
+    32 query / 8 KV heads of 64, every expert: what the chip's compiler says of
+    their memory, and that the decode step holds no K/V repeated to the query
+    heads (3.0 GB of temporaries before the heads were grouped, 1.35 GB after)."""
+    lm, eng = lfm2_cell_engine
+
+    def abstract(tree, dtype=None):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype if dtype is not None and jnp.issubdtype(x.dtype, jnp.floating) else x.dtype,
+            sharding=one_chip), tree)
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    params = abstract(jax.eval_shape(lambda: lm.init_params(0)), BF16)
+    zeros = lambda n: (jnp.zeros((n, LFM2_PROMPT), jnp.int32),) * 2  # noqa: E731
+    slots_cache, slots_mask, _, _ = jax.eval_shape(lambda p: eng._prefill_core(p, *zeros(LFM2_SLOTS)), params)
+    wave_cache, wave_mask, _, wave_first = jax.eval_shape(lambda p: eng._prefill_core(p, *zeros(LFM2_WAVE)), params)
+    shapes = {jax.tree_util.keystr(p): x.shape for p, x in jax.tree_util.tree_leaves_with_path(slots_cache)}
+    assert sorted(set(shapes.values())) == [(), (128, 8, 1280, 64), (128, 2048, 2)], shapes
+    state = {"cache": abstract(slots_cache), "mask": abstract(slots_mask), "last": i32(LFM2_SLOTS)}
+    active = jax.ShapeDtypeStruct((LFM2_SLOTS,), jnp.bool_, sharding=one_chip)
+
+    step = eng._step.lower(params, state, i32(LFM2_SLOTS), i32(LFM2_SLOTS), active).compile()
+    text, mem = step.as_text(), step.memory_analysis()
+    assert "tpu_custom_call" in text and "%gmm" in text and "ragged-dot" not in text  # the Pallas grouped product
+    assert "bf16[128,32,1280,64]" not in text  # K/V are read at their 8 heads
+    assert mem.argument_size_in_bytes < 3.8e9 and mem.temp_size_in_bytes < 1.6e9, mem
+    assert mem.alias_size_in_bytes > 0.33e9  # the cache is updated in place
+
+    wave = eng._prefill.lower(params, i32(LFM2_WAVE, LFM2_PROMPT), i32(LFM2_WAVE, LFM2_PROMPT)).compile()
+    assert "%gmm" in wave.as_text() and wave.memory_analysis().temp_size_in_bytes < 1.5e9
+    admit = eng._admit.lower(state, abstract(wave_cache), abstract(wave_mask), abstract(wave_first), i32(LFM2_WAVE)).compile()
+    assert admit.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_lfm2_seeded_weights_are_made_in_one_copy(one_chip):
+    """The one jitted call that makes the program's float32 tree from the seed
+    holds the tree and next to nothing beside it: 6.66 GB of output, no
+    stacked or transposed second copy (``reference/lfm2_moe.py`` names its
+    tensors per layer in the program's layout for this)."""
+    from benchmarks.harness import program, spec as spec_mod, weights
+
+    cfg = spec_mod.load_json(os.path.join(spec_mod.BENCH_DIR, "configs", "lfm2-8b-a1b.json"))
+    ref, adapter = (spec_mod.load_module(kind, cfg["family"]) for kind in ("reference", "adapters"))
+    spec, to_tree = ref.param_spec(cfg), program.to_program_tree(adapter.leaf_map(cfg))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    mem = jax.jit(lambda k: to_tree(weights._generate(spec, k))).lower(key).compile().memory_analysis()
+    assert 6.6e9 < mem.output_size_in_bytes < 6.7e9 and mem.temp_size_in_bytes < 0.1e9, mem
+
+
+@pytest.mark.parametrize("rows,k,n", [(512, 2048, 1792), (512, 1792, 2048), (16384, 2048, 1792), (16384, 1792, 2048)],
+                         ids=["decode-gate", "decode-down", "wave-gate", "wave-down"])
+def test_grouped_expert_product_compiles_at_the_cell_shapes(rows, k, n, one_chip, compiled_kernels):
+    """``ops/moe.py`` ``grouped_dot``'s Pallas path (megablox ``gmm`` under
+    ``gmm_tiling``) at the new cell's decode round (128 slots x top-4) and
+    prefill wave (4 x 1024 x top-4): 32 experts of 2048 x 1792, bfloat16."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from distributed_llms_example_tpu.ops.moe import gmm_tiling
+
+    tiling = gmm_tiling(rows, k, n, 2)
+    assert tiling == ((128 if rows == 512 else 256), k, n // 2)
+    text = _compile(lambda x, w, load: gmm(x, w, load, BF16, tiling), one_chip,
+                    ((rows, k), BF16), ((32, k, n), BF16), ((32,), jnp.int32))
+    assert "ragged-dot" not in text

@@ -658,7 +658,8 @@ def test_serve_span_ring_is_bounded(serve_rig, capsys):
 def test_round_syncs_and_event_fields_are_what_they_were(serve_rig, monkeypatch, capsys):
     """The counting pin (PR 3's technique): a seq2seq round fetches exactly
     once (the token vector), wave or not, and never blocks another way; the
-    serve events keep every field they had, serve_summary gains host_spans."""
+    serve events keep every field they had, serve_summary gains host_spans
+    (PR 25) and the cache's bytes by kind of leaf (PR 28)."""
     import inspect
     import json as _json
 
@@ -698,7 +699,9 @@ def test_round_syncs_and_event_fields_are_what_they_were(serve_rig, monkeypatch,
         "ttft_prefill_share", "goodput_tokens_per_sec", "goodput_tokens_per_sec_chip", "slot_occupancy",
         "prefill_seconds", "slots", "chips", "kv_cache_dtype", "paged_kv", "prefill_buckets", "cache_bytes_resident",
         "peak_cache_bytes_in_use", "cache_bytes_per_token", "memory_account", "hbm_headroom_gib"}
-    assert set(summary) - {"peak_hbm_bytes"} == was | {"host_spans"}
+    # PR 28 adds the static cache bytes by kind of leaf, beside cache_bytes_resident
+    assert set(summary) - {"peak_hbm_bytes"} == was | {"host_spans", "kv_bytes", "conv_state_bytes"}
+    assert summary["kv_bytes"] > 0 and summary["conv_state_bytes"] == 0
     host = summary["host_spans"]
     assert host["window_steps"] == rounds and host["spans"]["round"]["count"] == rounds
     assert set(host["spans"]) == {"round", "admit_prep", "prefill_dispatch", "decode_dispatch",

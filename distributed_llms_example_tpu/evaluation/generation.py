@@ -252,27 +252,35 @@ def make_beam_search(
 # ----------------------------------------------------------- decoder-only
 
 
+def causal_cache_shapes(model: Any, params: Any, batch: int, width: int):
+    """Abstract decode cache of a decoder-only model for ``batch`` rows of
+    ``width`` positions: one abstract trace of ``model.init``."""
+    return jax.eval_shape(
+        lambda p: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((batch, width), jnp.int32), use_cache=True
+        ),
+        params,
+    )["cache"]
+
+
 def _causal_prefill(
-    model: Any, params: Any, input_ids: jnp.ndarray, attention_mask: jnp.ndarray, new_tokens: int
+    model: Any, params: Any, input_ids: jnp.ndarray, attention_mask: jnp.ndarray, new_tokens: int,
+    cache_shapes: Any = None,
 ):
     """One-pass prompt prefill for decoder-only decode.
 
-    Allocates cache buffers for prompt + generation, runs the prompt
-    through once, and returns ``(cache, full_mask, lengths, first_logits)``
-    where ``first_logits`` is each row's logits at its last *valid* prompt
-    position.  Right-padded prompts are supported: RoPE positions follow
-    the true sequence (cumsum over the mask), not the cache slot, and pad
-    slots stay masked out of attention."""
+    Allocates cache buffers for prompt + generation (``cache_shapes`` where
+    the caller knows them already, else ``causal_cache_shapes``), runs the
+    prompt through once, and returns ``(cache, full_mask, lengths,
+    first_logits)`` where ``first_logits`` is each row's logits at its last
+    *valid* prompt position.  Right-padded prompts are supported: RoPE
+    positions follow the true sequence (cumsum over the mask), not the cache
+    slot, and pad slots stay masked out of attention."""
     B, P = input_ids.shape
-    width = P + new_tokens
-    shapes = jax.eval_shape(
-        lambda p: model.init(
-            jax.random.PRNGKey(0), jnp.zeros((B, width), jnp.int32), use_cache=True
-        ),
-        params,
-    )
+    if cache_shapes is None:
+        cache_shapes = causal_cache_shapes(model, params, B, P + new_tokens)
     cache = constrain_cache(
-        jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes["cache"])
+        jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), cache_shapes)
     )
     full_mask = jnp.concatenate([attention_mask, jnp.zeros((B, new_tokens), jnp.int32)], axis=1)
     lengths = jnp.sum(attention_mask, axis=1).astype(jnp.int32)

@@ -140,7 +140,9 @@ def build_serve_parser() -> argparse.ArgumentParser:
     p.add_argument("--max-slots", type=int, default=8,
                    help="concurrent decode slots (the fixed serving batch)")
     p.add_argument("--prefill-batch", type=int, default=0,
-                   help="sequences prefilled per admission chunk "
+                   help="most sequences one admission wave prefills; a "
+                        "wave that fits the mesh's batch shards runs a "
+                        "program of that many rows instead "
                         "(0 = max-slots, which always shards when the "
                         "slot count does)")
     p.add_argument("--max-new-tokens", type=int, default=128)

@@ -56,6 +56,7 @@ above are these spans' durations.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from typing import Any, Sequence
 
@@ -66,6 +67,7 @@ import numpy as np
 from distributed_llms_example_tpu.evaluation.generation import (
     _causal_prefill,
     _init_cache,
+    causal_cache_shapes,
 )
 from distributed_llms_example_tpu.parallel.activation import (
     BATCH_AXES,
@@ -85,11 +87,13 @@ class ServeConfig:
     """Engine shape/behavior knobs (all compiled shapes derive from these).
 
     ``max_slots``: concurrent in-flight sequences — the decode batch.
-    ``prefill_batch``: sequences prefilled per admission chunk (one compile
-    at this batch; fewer pending sequences ride the same program with
-    dropped padding rows); 0 = auto (``max_slots`` — always divides the
-    mesh's batch shards when the slot count does, so the defaults work on
-    any mesh).  ``max_source_length``: fixed prompt width (prompts are
+    ``prefill_batch``: the most sequences one admission wave prefills — a
+    cap, not the size every wave computes: the programs are compiled at two
+    row counts, this one and the mesh's batch shards (1 on one chip), and a
+    wave no larger than the shards runs the small one
+    (``ServingEngine.wave_rows``); 0 = auto (``max_slots`` — always divides
+    the mesh's batch shards when the slot count does, so the defaults work
+    on any mesh).  ``max_source_length``: fixed prompt width (prompts are
     padded to it; the serving twin of the trainer's bucketed max).
     ``max_new_tokens``: decode budget per sequence = the KV-cache length
     (seq2seq) or its decode tail (causal).  ``request_spans``: emit one
@@ -453,11 +457,17 @@ class ServingEngine:
                     f"{batch_shards} batch shards (data×fsdp×expert) — "
                     "uneven slot rows cannot shard"
                 )
+        # the row counts an admission wave is compiled at: the fewest rows
+        # the mesh can shard, and the cap.  Below the knee nearly every
+        # wave holds one request, and a wave of prefill_batch rows would
+        # compute rows nobody sent while every live slot waits for it
+        self.wave_sizes = tuple(sorted({batch_shards, self.prefill_batch}))
         # per-program Python trace counts: a retrace IS a recompile, so the
         # zero-recompile contract (AOT-warmed buckets, fixed-shape churn)
         # is pinnable by comparing these before/after serving traffic
         self.trace_counts: dict[str, int] = {}
         self._warmed = False
+        self._slot_cache = None  # _slot_cache_shapes' memo
         if self.spec and self.serve.spec_draft_model:
             from distributed_llms_example_tpu.models.registry import load_model
 
@@ -478,7 +488,7 @@ class ServingEngine:
                 )
             self.drafter = spec_decode.DraftRunner(
                 dm, slots=self.S, src_width=self.W, max_new=self.L,
-                buckets=self.buckets, prefill_batch=self.prefill_batch,
+                buckets=self.buckets, wave_sizes=self.wave_sizes,
                 k=self.spec, pad=self.pad,
                 kv_cache_dtype=self.serve.kv_cache_dtype, wrap=self._wrap,
             )
@@ -518,7 +528,16 @@ class ServingEngine:
         # the same program lowered for abstract arguments, not run: what a
         # compile for a described chip reads (tests/test_chip_compile.py)
         run.lower = under_contexts(jitted.lower)
+        # its output shapes, from the trace a later call with such arguments reuses
+        run.eval_shape = under_contexts(jitted.eval_shape)
         return run
+
+    def wave_rows(self, n: int) -> int:
+        """Rows of the program that admits a chunk of ``n`` requests: the
+        small compiled size where they fit it, else ``prefill_batch``.  A
+        row past ``n`` parks at slot ``S`` (its writes drop) and was
+        mask-invisible to the others, so the size changes no served token."""
+        return self.wave_sizes[0] if n <= self.wave_sizes[0] else self.prefill_batch
 
     @staticmethod
     def _pad_axis(x, axis: int, width: int):
@@ -592,8 +611,22 @@ class ServingEngine:
                 }
         else:
             def prefill(params, ids, mask):
+                # the chunk's cache, by leaf, from the slots' own shapes: a
+                # row a request, K/V as long as this bucket's prompt + tail
+                rows, width = ids.shape[0], ids.shape[1] + L
+
+                def chunk(path, a):
+                    shape = list(a.shape)
+                    if shape:
+                        shape[0] = rows
+                    axis = CACHE_LENGTH_AXIS.get(cache_leaf_name(path))
+                    if axis is not None:
+                        shape[axis] = width
+                    return jax.ShapeDtypeStruct(tuple(shape), a.dtype)
+
                 cache, full_mask, lengths, first = _causal_prefill(
-                    model, params, ids, mask, L
+                    model, params, ids, mask, L,
+                    jax.tree_util.tree_map_with_path(chunk, self._slot_cache_shapes(params)),
                 )
                 return cache, full_mask, lengths, jnp.argmax(first, axis=-1).astype(jnp.int32)
 
@@ -805,46 +838,52 @@ class ServingEngine:
             tree,
         )
 
+    def _slot_cache_shapes(self, params):
+        """Abstract cache of the ``S`` slots of a causal model at full width:
+        one abstract trace of ``model.init``, made once an engine.  The
+        slot state is zeros of it, and every prefill program sizes its
+        chunk's cache from it instead of tracing ``model.init`` again."""
+        if self._slot_cache is None:
+            with kv_cache_context(self.serve.kv_cache_dtype):
+                self._slot_cache = causal_cache_shapes(
+                    self.model, params, self.S, self.W + self.L
+                )
+        return self._slot_cache
+
     def _init_state(self, params) -> dict:
         S, W, L = self.S, self.W, self.L
-        zeros = lambda s: jax.tree.map(  # noqa: E731
-            lambda a: jnp.zeros(a.shape, a.dtype), s
-        )
-        with kv_cache_context(self.serve.kv_cache_dtype):
-            if self.is_seq2seq:
-                ids = jnp.zeros((S, W), jnp.int32)
-                mask = jnp.zeros((S, W), jnp.int32)
-                a_enc, _, a_ckv = jax.eval_shape(
-                    lambda p: self._prefill_core(p, ids, mask), params
+        if self.is_seq2seq:
+            # what a slot holds of the encoder is what a prefill row returns:
+            # read from the small wave's own trace, which ``warm`` then runs
+            ids = jnp.zeros((self.wave_sizes[0], W), jnp.int32)
+            slots = lambda tree: jax.tree.map(  # noqa: E731 — a wave's rows -> S slots
+                lambda a: jnp.zeros((S, *a.shape[1:]), a.dtype), tree
+            )
+            a_enc, a_mask, a_ckv = self._prefill.eval_shape(params, ids, ids)
+            enc0, mask = slots(a_enc), slots(a_mask)
+            with kv_cache_context(self.serve.kv_cache_dtype):
+                cache = _init_cache(self.model, params, S, L, enc0, mask)
+            state = {
+                "cache": cache,
+                "enc": enc0,
+                "enc_mask": mask,
+                "ckv": slots(a_ckv),
+                "last": jnp.full((S, 1), self.pad, jnp.int32),
+            }
+        else:
+            a_cache = self._slot_cache_shapes(params)
+            state = {
+                "mask": jnp.zeros((S, W + L), jnp.int32),
+                "last": jnp.full((S,), self.pad, jnp.int32),
+            }
+            if self.paged:
+                state["pool"] = cache_pool.pool_cache_tree(
+                    a_cache, self.pool.num_blocks, self.block_size
                 )
-                enc0 = zeros(a_enc)
-                state = {
-                    "cache": _init_cache(self.model, params, S, L, enc0, mask),
-                    "enc": enc0,
-                    "enc_mask": mask,
-                    "ckv": zeros(a_ckv),
-                    "last": jnp.full((S, 1), self.pad, jnp.int32),
-                }
             else:
-                ids = jnp.zeros((S, W), jnp.int32)
-                mask = jnp.zeros((S, W), jnp.int32)
-                a_cache, a_mask, _, _ = jax.eval_shape(
-                    lambda p: self._prefill_core(p, ids, mask), params
+                state["cache"] = jax.tree.map(
+                    lambda a: jnp.zeros(a.shape, a.dtype), a_cache
                 )
-                if self.paged:
-                    state = {
-                        "pool": cache_pool.pool_cache_tree(
-                            a_cache, self.pool.num_blocks, self.block_size
-                        ),
-                        "mask": zeros(a_mask),
-                        "last": jnp.full((S,), self.pad, jnp.int32),
-                    }
-                else:
-                    state = {
-                        "cache": zeros(a_cache),
-                        "mask": zeros(a_mask),
-                        "last": jnp.full((S,), self.pad, jnp.int32),
-                    }
         return self._place(state)
 
     # ------------------------------------------------------------ capacity
@@ -863,44 +902,42 @@ class ServingEngine:
 
     def warm(self, params, state) -> Any:
         """AOT-warm every compiled program before the first real request:
-        one prefill+admit trace per bucket (zeros, all writes dropped via
-        out-of-range slot indices) and one all-slots-idle decode step —
-        so no request ever pays a compile, and the trace counts are
+        one prefill+admit trace per bucket and wave size (zeros, all writes
+        dropped via out-of-range slot indices) and one all-slots-idle decode
+        step — so no request ever pays a compile, and the trace counts are
         pinned BEFORE traffic (``trace_counts``).  Returns the (possibly
         donated-and-rebound) state."""
         if self._warmed:
             return state
-        C, S = self.prefill_batch, self.S
-        park = jnp.full((C,), S, jnp.int32)  # out of range: every write drops
-        for bucket in self.buckets:
-            ids = jnp.zeros((C, bucket), jnp.int32)
-            mask = jnp.zeros((C, bucket), jnp.int32)
-            pre = self._prefill(params, ids, mask)
+        S = self.S
+        width_full = self.W + self.L
+        for rows, bucket in itertools.product(self.wave_sizes, self.buckets):
+            park = jnp.full((rows,), S, jnp.int32)  # out of range: every write drops
+            ids = jnp.zeros((rows, bucket), jnp.int32)
+            pre = self._prefill(params, ids, ids)
             if self.is_seq2seq:
                 enc, pmask, ckv = pre
                 state = self._admit(state, enc, pmask, ckv, park)
             elif self.paged:
                 cache, full_mask, _, first = pre
                 ntc = (bucket + self.L) // self.block_size
-                sentinel = jnp.full((C * ntc,), self.pool.num_blocks, jnp.int32)
+                sentinel = jnp.full((rows * ntc,), self.pool.num_blocks, jnp.int32)
                 state = self._admit(state, cache, full_mask, first, park, sentinel)
             else:
                 cache, full_mask, _, first = pre
                 state = self._admit(state, cache, full_mask, first, park)
-        if self.paged and self.prefix:
-            # one warm-admission trace per tail bucket, all writes dropped
-            # (park slots, sentinel block tables, out-of-range starts)
-            width_full = self.W + self.L
-            for bucket in self.buckets:
+            if self.paged and self.prefix:
+                # the warm admission of a tail of this width, all writes dropped
+                # (park slots, sentinel block tables, out-of-range starts)
                 _, state = self._warm_admit(
                     params, state,
-                    jnp.zeros((C, bucket), jnp.int32),
-                    jnp.zeros((C, width_full), jnp.int32),
-                    jnp.full((C,), width_full, jnp.int32),
-                    jnp.zeros((C,), jnp.int32),
+                    ids,
+                    jnp.zeros((rows, width_full), jnp.int32),
+                    jnp.full((rows,), width_full, jnp.int32),
+                    jnp.zeros((rows,), jnp.int32),
                     park,
-                    jnp.full((C, self.n_tiles), self.pool.num_blocks, jnp.int32),
-                    jnp.full((C * self.n_tiles,), self.pool.num_blocks, jnp.int32),
+                    jnp.full((rows, self.n_tiles), self.pool.num_blocks, jnp.int32),
+                    jnp.full((rows * self.n_tiles,), self.pool.num_blocks, jnp.int32),
                 )
         idle = jnp.zeros((S,), bool)
         pos = jnp.zeros((S,), jnp.int32)
@@ -1118,6 +1155,8 @@ class ServeSession:
             self.draft_state = eng.drafter.init_state()
             self.draft_state = eng.drafter.warm(self.draft_state)
         self._win_spec_steps, self._win_spec_emitted = 0, 0
+        # prefill waves by the rows their programs computed (the summary's)
+        self._waves_by_rows: dict[int, int] = {}
         self._finalized = False
 
     # ------------------------------------------------------------- intake
@@ -1276,6 +1315,28 @@ class ServeSession:
         wait = sum(prep.t0 - self.arrival_t[rid] for rid in rids)
         prep.set(n=len(rids), queue_wait_us_sum=int(round(wait * 1e6)))
 
+    def _prompt_rows(self, rids: Sequence[int], bucket: int):
+        """``(ids, mask)`` of an admission chunk at ``bucket`` width: a row a
+        request, at the compiled row count that holds them
+        (``ServingEngine.wave_rows``); rows past the last are padding."""
+        rows = self.eng.wave_rows(len(rids))
+        ids = np.full((rows, bucket), self.eng.pad, np.int32)
+        mask = np.zeros((rows, bucket), np.int32)
+        for r, rid in enumerate(rids):
+            toks = self.requests[rid][:bucket]
+            ids[r, : len(toks)] = toks
+            mask[r, : len(toks)] = 1
+            if self.attn_masks[rid] is not None:
+                m = self.attn_masks[rid][:bucket]
+                mask[r, : len(m)] = m
+        return ids, mask
+
+    def _count_wave(self, dispatch, admitted: int, rows: int) -> None:
+        """Stamp a ``prefill_dispatch`` span with the requests its wave admits
+        and the rows its programs compute, and keep the summary's tally."""
+        dispatch.set(rows=admitted, rows_computed=rows)
+        self._waves_by_rows[rows] = self._waves_by_rows.get(rows, 0) + 1
+
     def _admit_now(self, finished: list) -> None:
         eng = self.eng
         if eng.paged and eng.prefix:
@@ -1312,16 +1373,9 @@ class ServeSession:
             bucket = next(
                 b for b in eng.buckets if b >= max(plen(rid) for rid in reqs)
             )
-            ids = np.full((C, bucket), eng.pad, np.int32)
-            mask = np.zeros((C, bucket), np.int32)
-            for r, rid in enumerate(reqs):
-                toks = self.requests[rid][:bucket]
-                ids[r, : len(toks)] = toks
-                mask[r, : len(toks)] = 1
-                if self.attn_masks[rid] is not None:
-                    m = self.attn_masks[rid][:bucket]
-                    mask[r, : len(m)] = m
-            slot_idx = np.full(C, S, np.int32)  # padding rows drop
+            ids, mask = self._prompt_rows(reqs, bucket)
+            rows = len(ids)
+            slot_idx = np.full(rows, S, np.int32)  # padding rows drop
             slot_idx[:n] = free[:n]
             admit_rows = None
             if eng.paged:
@@ -1329,7 +1383,7 @@ class ServeSession:
                 # flat (chunk × chunk-tiles) assignment carries sentinels for
                 # tiles that must not copy (padding rows, prompt gap)
                 ntc = (bucket + eng.L) // eng.block_size
-                admit_rows = np.full((C, ntc), eng.pool.num_blocks, np.int32)
+                admit_rows = np.full((rows, ntc), eng.pool.num_blocks, np.int32)
                 for r, rid in enumerate(reqs):
                     blocks = eng.pool.alloc(
                         cache_pool.blocks_needed(
@@ -1348,6 +1402,7 @@ class ServeSession:
                     self.slot_bt[slot, :] = row
                     admit_rows[r, :] = row[:ntc]
         with self.spans.span("prefill_dispatch") as sp:
+            self._count_wave(sp, n, rows)
             pre = eng._prefill(self.params, jnp.asarray(ids), jnp.asarray(mask))
             if eng.is_seq2seq:
                 enc, pmask, ckv = pre
@@ -1495,18 +1550,12 @@ class ServeSession:
                 bucket = next(
                     b for b in eng.buckets if b >= max(p for _, _, p, _ in cold)
                 )
-                ids = np.full((C, bucket), eng.pad, np.int32)
-                mask = np.zeros((C, bucket), np.int32)
-                slot_idx = np.full(C, S, np.int32)
+                ids, mask = self._prompt_rows([rid for rid, *_ in cold], bucket)
+                rows = len(ids)
+                slot_idx = np.full(rows, S, np.int32)
                 ntc = (bucket + eng.L) // bs
-                admit_rows = np.full((C, ntc), N, np.int32)
+                admit_rows = np.full((rows, ntc), N, np.int32)
                 for r, (rid, slot, p, _h) in enumerate(cold):
-                    toks = self.requests[rid][:bucket]
-                    ids[r, : len(toks)] = toks
-                    mask[r, : len(toks)] = 1
-                    if self.attn_masks[rid] is not None:
-                        m = self.attn_masks[rid][:bucket]
-                        mask[r, : len(m)] = m
                     slot_idx[r] = slot
                     row = cache_pool.build_block_row(
                         eng.n_tiles, self.slot_blocks[slot],
@@ -1516,6 +1565,7 @@ class ServeSession:
                     self.slot_bt[slot, :] = row
                     admit_rows[r, :] = row[:ntc]
             with self.spans.span("prefill_dispatch") as sp:
+                self._count_wave(sp, len(cold), rows)
                 cache, full_mask, plens, first = eng._prefill(
                     self.params, jnp.asarray(ids), jnp.asarray(mask)
                 )
@@ -1542,13 +1592,14 @@ class ServeSession:
                 tail_bucket = next(
                     b for b in eng.buckets if b >= max(len(w["tail"]) for w in warm)
                 )
-                ids_t = np.full((C, tail_bucket), eng.pad, np.int32)
-                mask_f = np.zeros((C, width_full), np.int32)
-                start = np.full(C, width_full, np.int32)  # park rows write nowhere
-                tail_last = np.zeros(C, np.int32)
-                slot_idx = np.full(C, S, np.int32)
-                bt = np.full((C, eng.n_tiles), N, np.int32)
-                admit_rows = np.full((C, eng.n_tiles), N, np.int32)
+                rows = eng.wave_rows(len(warm))
+                ids_t = np.full((rows, tail_bucket), eng.pad, np.int32)
+                mask_f = np.zeros((rows, width_full), np.int32)
+                start = np.full(rows, width_full, np.int32)  # park rows write nowhere
+                tail_last = np.zeros(rows, np.int32)
+                slot_idx = np.full(rows, S, np.int32)
+                bt = np.full((rows, eng.n_tiles), N, np.int32)
+                admit_rows = np.full((rows, eng.n_tiles), N, np.int32)
                 for r, wr in enumerate(warm):
                     slot = wr["slot"]
                     tail = wr["tail"]
@@ -1572,6 +1623,7 @@ class ServeSession:
                     full_tiles = max(1, math.ceil(wr["p"] / bs))
                     admit_rows[r, k_tiles:full_tiles] = row[k_tiles:full_tiles]
             with self.spans.span("prefill_dispatch") as sp:
+                self._count_wave(sp, len(warm), rows)
                 first_w, self.state = eng._warm_admit(
                     self.params, self.state,
                     jnp.asarray(ids_t), jnp.asarray(mask_f), jnp.asarray(start),
@@ -1749,18 +1801,11 @@ class ServeSession:
         for bucket, slots_ in sorted(by_bucket.items()):
             for i in range(0, len(slots_), C):
                 chunk = slots_[i : i + C]
-                ids = np.full((C, bucket), eng.pad, np.int32)
-                mask = np.zeros((C, bucket), np.int32)
-                slot_idx = np.full((C,), eng.S, np.int32)
-                for r, s in enumerate(chunk):
-                    rid = int(self.slot_req[s])
-                    toks = self.requests[rid][:bucket]
-                    ids[r, : len(toks)] = toks
-                    mask[r, : len(toks)] = 1
-                    if self.attn_masks[rid] is not None:
-                        m = list(self.attn_masks[rid][:bucket])
-                        mask[r, : len(m)] = m
-                    slot_idx[r] = s
+                ids, mask = self._prompt_rows(
+                    [int(self.slot_req[s]) for s in chunk], bucket
+                )
+                slot_idx = np.full(len(ids), eng.S, np.int32)
+                slot_idx[: len(chunk)] = chunk
                 self.draft_state = eng.drafter.admit_prompt(
                     self.draft_state, jnp.asarray(ids), jnp.asarray(mask),
                     jnp.asarray(slot_idx),
@@ -2025,6 +2070,10 @@ class ServeSession:
             **stats.goodput,
             "slot_occupancy": round(stats.slot_occupancy, 4),
             "prefill_seconds": round(stats.prefill_seconds, 3),
+            # waves by the row count of the programs that ran them
+            "prefill_waves_by_rows": {
+                str(r): n for r, n in sorted(self._waves_by_rows.items())
+            },
             "slots": eng.S,
             "chips": self.n_chips,
             # capacity block: config knobs + the measured static account —

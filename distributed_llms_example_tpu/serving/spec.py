@@ -238,7 +238,7 @@ class DraftRunner:
     and the next round's catch-up span overwrites them before any read."""
 
     def __init__(self, loaded: Any, *, slots: int, src_width: int,
-                 max_new: int, buckets: Sequence[int], prefill_batch: int,
+                 max_new: int, buckets: Sequence[int], wave_sizes: Sequence[int],
                  k: int, pad: int, kv_cache_dtype: str, wrap: Any):
         self.model = loaded.module
         self.config = loaded.config
@@ -247,7 +247,7 @@ class DraftRunner:
             params = jax.device_get(loaded.init_params(0))
         self.params = params
         self.S, self.W, self.L, self.K = slots, src_width, max_new, k
-        self.C = prefill_batch
+        self.wave_sizes = tuple(wave_sizes)  # the engine's: admissions share its rows
         self.pad = pad
         self.width = src_width + max_new
         self.buckets = tuple(buckets)
@@ -363,19 +363,18 @@ class DraftRunner:
         return {"cache": zeros(a_cache), "mask": zeros(a_mask)}
 
     def warm(self, state) -> Any:
-        """One prefill+admit trace per bucket (parked writes) plus one
-        all-idle round — the draft programs join the engine's
+        """One prefill+admit trace per bucket and wave size (parked writes)
+        plus one all-idle round — the draft programs join the engine's
         zero-recompile contract."""
         if self._warmed:
             return state
-        C, S, K = self.C, self.S, self.K
-        park = jnp.full((C,), S, jnp.int32)
-        for bucket in self.buckets:
-            cache, fm = self._prefill(
-                self.params, jnp.zeros((C, bucket), jnp.int32),
-                jnp.zeros((C, bucket), jnp.int32),
-            )
-            state = self._admit(state, cache, fm, park)
+        S, K = self.S, self.K
+        for rows in self.wave_sizes:
+            park = jnp.full((rows,), S, jnp.int32)
+            for bucket in self.buckets:
+                ids = jnp.zeros((rows, bucket), jnp.int32)
+                cache, fm = self._prefill(self.params, ids, ids)
+                state = self._admit(state, cache, fm, park)
         idle = jnp.zeros((S,), bool)
         z = jnp.zeros((S,), jnp.int32)
         _, state = self._round(
@@ -387,8 +386,8 @@ class DraftRunner:
 
     def admit_prompt(self, state, ids, mask, slot_idx) -> Any:
         """Prefill + admit one bucket-width chunk of prompts into the
-        draft cache (host passes rows padded to ``prefill_batch``, parked
-        rows at slot index S)."""
+        draft cache (host passes rows padded to one of ``wave_sizes``,
+        parked rows at slot index S)."""
         cache, fm = self._prefill(self.params, ids, mask)
         return self._admit(state, cache, fm, slot_idx)
 

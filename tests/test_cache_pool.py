@@ -403,11 +403,11 @@ def test_engine_int8_all_flags_vs_static(llama_runs):
     outs = eng.generate(params, reqs)
     for got, want in zip(outs, static8):
         assert trim_eos(got, eos, pad) == trim_eos(want, eos, pad)
-    # one trace per bucket for prefill/admit, ONE decode step — and no
-    # retrace on a second serve over the same engine
-    assert eng.trace_counts == {"prefill": 2, "admit": 2, "decode_step": 1}
+    # one trace per bucket and wave size (1 row, 2 rows) for prefill/admit,
+    # ONE decode step — and no retrace on a second serve over the same engine
+    assert eng.trace_counts == {"prefill": 4, "admit": 4, "decode_step": 1}
     eng.generate(params, reqs)
-    assert eng.trace_counts == {"prefill": 2, "admit": 2, "decode_step": 1}
+    assert eng.trace_counts == {"prefill": 4, "admit": 4, "decode_step": 1}
 
 
 def test_engine_int8_token_match_rates(llama_runs):
@@ -503,8 +503,8 @@ def test_engine_pool_blocks_all_returned_random_churn(llama_runs):
 
 def test_engine_seq2seq_buckets_identical_and_warm():
     """Bucketed admission on the seq2seq engine: identical tokens to the
-    single-width engine, one compiled prefill/admit per bucket (all
-    AOT-warmed at first generate), capacity gauges in the summary."""
+    single-width engine, one compiled prefill/admit per bucket and wave
+    size (all AOT-warmed at first generate), capacity gauges in the summary."""
     lm = load_model("t5-test")
     params = lm.init_params(0)
     rng = np.random.RandomState(13)
@@ -513,7 +513,7 @@ def test_engine_seq2seq_buckets_identical_and_warm():
     eng = _engine(lm, is_seq2seq=True, W=32, L=8, prefill_buckets=(8, 16))
     outs = eng.generate(params, reqs)
     assert outs == flat
-    assert eng.trace_counts == {"prefill": 3, "admit": 3, "decode_step": 1}
+    assert eng.trace_counts == {"prefill": 6, "admit": 6, "decode_step": 1}
     assert eng.last_stats.cache_bytes_resident > 0
     assert eng.last_stats.bytes_per_live_token > 0
 
@@ -786,15 +786,16 @@ def test_engine_prefix_warm_vs_cold_bit_identical(llama_runs):
     # all requests share ONE full block (the system prompt): first writer
     # wins, so exactly one block is registered and retained warm
     assert eng.pool.blocks_warm == 1
-    # compiled-program budget: one warm_admit per bucket, nothing retraced
+    # compiled-program budget: one warm_admit per bucket and wave size
+    # (1 row, 2 rows), nothing retraced
     assert eng.trace_counts == {
-        "prefill": 1, "admit": 1, "warm_admit": 1, "decode_step": 1,
+        "prefill": 2, "admit": 2, "warm_admit": 2, "decode_step": 1,
     }
     outs2 = eng.generate(params, reqs)
     assert outs2 == flat
     assert eng.last_stats.prefix_hits == len(reqs) - 1
     assert eng.trace_counts == {
-        "prefill": 1, "admit": 1, "warm_admit": 1, "decode_step": 1,
+        "prefill": 2, "admit": 2, "warm_admit": 2, "decode_step": 1,
     }
 
 

@@ -259,7 +259,7 @@ def lfm2_cell_engine(topo, compiled_kernels, monkeypatch):
 
 
 def test_lfm2_cell_serving_programs_compile_and_fit(lfm2_cell_engine, one_chip):
-    """Decode step, prefill wave and admit at 128 slots, 4 x 1024, cache 1280,
+    """Decode step, prefill wave and admit at 128 slots, 1 x and 4 x 1024, cache 1280,
     32 query / 8 KV heads of 64, every expert: what the chip's compiler says of
     their memory, and that the decode step holds no K/V repeated to the query
     heads (3.0 GB of temporaries before the heads were grouped, 1.35 GB after)."""
@@ -274,7 +274,6 @@ def test_lfm2_cell_serving_programs_compile_and_fit(lfm2_cell_engine, one_chip):
     params = abstract(jax.eval_shape(lambda: lm.init_params(0)), BF16)
     zeros = lambda n: (jnp.zeros((n, LFM2_PROMPT), jnp.int32),) * 2  # noqa: E731
     slots_cache, slots_mask, _, _ = jax.eval_shape(lambda p: eng._prefill_core(p, *zeros(LFM2_SLOTS)), params)
-    wave_cache, wave_mask, _, wave_first = jax.eval_shape(lambda p: eng._prefill_core(p, *zeros(LFM2_WAVE)), params)
     shapes = {jax.tree_util.keystr(p): x.shape for p, x in jax.tree_util.tree_leaves_with_path(slots_cache)}
     assert sorted(set(shapes.values())) == [(), (128, 8, 1280, 64), (128, 2048, 2)], shapes
     state = {"cache": abstract(slots_cache), "mask": abstract(slots_mask), "last": i32(LFM2_SLOTS)}
@@ -287,10 +286,13 @@ def test_lfm2_cell_serving_programs_compile_and_fit(lfm2_cell_engine, one_chip):
     assert mem.argument_size_in_bytes < 3.8e9 and mem.temp_size_in_bytes < 1.6e9, mem
     assert mem.alias_size_in_bytes > 0.33e9  # the cache is updated in place
 
-    wave = eng._prefill.lower(params, i32(LFM2_WAVE, LFM2_PROMPT), i32(LFM2_WAVE, LFM2_PROMPT)).compile()
-    assert "%gmm" in wave.as_text() and wave.memory_analysis().temp_size_in_bytes < 1.5e9
-    admit = eng._admit.lower(state, abstract(wave_cache), abstract(wave_mask), abstract(wave_first), i32(LFM2_WAVE)).compile()
-    assert admit.memory_analysis().temp_size_in_bytes < 0.1e9
+    assert eng.wave_sizes == (1, LFM2_WAVE)  # a wave of one request runs a one-row program (PR 30)
+    for rows in eng.wave_sizes:
+        wave_cache, wave_mask, _, wave_first = jax.eval_shape(lambda p: eng._prefill_core(p, *zeros(rows)), params)
+        wave = eng._prefill.lower(params, i32(rows, LFM2_PROMPT), i32(rows, LFM2_PROMPT)).compile()
+        assert "%gmm" in wave.as_text() and wave.memory_analysis().temp_size_in_bytes < 1.5e9
+        admit = eng._admit.lower(state, abstract(wave_cache), abstract(wave_mask), abstract(wave_first), i32(rows)).compile()
+        assert admit.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
 def test_lfm2_seeded_weights_are_made_in_one_copy(one_chip):

@@ -599,12 +599,15 @@ def test_round_spans_partition_the_round(serve_rig, capsys):
         if wave:
             assert names == ["serve/admit_prep", "serve/prefill_dispatch", "serve/emit"] + tail + logs
             assert set(kids[0]["stats"]) == {"n", "queue_wait_us_sum"}
+            # the wave's dispatch says what it admitted and what its programs computed
+            assert kids[1]["stats"] == {"rows": kids[0]["stats"]["n"], "rows_computed": 4}
         else:
             assert names == ["serve/admit_prep"] + tail + logs
             assert kids[0]["stats"] == {}
             covered = sum(k["end"] - k["start"] for k in kids)
             plain_self.append(1.0 - covered / (rd["end"] - rd["start"]))
-        assert all(e["stats"] == {} for e in [rd] + kids[1:])  # the admitting admit_prep alone carries counters
+        # the admitting admit_prep and its prefill_dispatch alone carry counters
+        assert all(e["stats"] == {} for e in [rd] + kids[2 if wave else 1:])
     assert kinds == {True, False}
     assert sorted(plain_self)[len(plain_self) // 2] < 0.05
     logged = sum("serve/window_log" in [k["name"] for k in notes.children(rd)] for rd in rounds)
@@ -659,7 +662,8 @@ def test_round_syncs_and_event_fields_are_what_they_were(serve_rig, monkeypatch,
     """The counting pin (PR 3's technique): a seq2seq round fetches exactly
     once (the token vector), wave or not, and never blocks another way; the
     serve events keep every field they had, serve_summary gains host_spans
-    (PR 25) and the cache's bytes by kind of leaf (PR 28)."""
+    (PR 25), the cache's bytes by kind of leaf (PR 28) and the waves by the
+    rows their programs computed (PR 30)."""
     import inspect
     import json as _json
 
@@ -700,7 +704,9 @@ def test_round_syncs_and_event_fields_are_what_they_were(serve_rig, monkeypatch,
         "prefill_seconds", "slots", "chips", "kv_cache_dtype", "paged_kv", "prefill_buckets", "cache_bytes_resident",
         "peak_cache_bytes_in_use", "cache_bytes_per_token", "memory_account", "hbm_headroom_gib"}
     # PR 28 adds the static cache bytes by kind of leaf, beside cache_bytes_resident
-    assert set(summary) - {"peak_hbm_bytes"} == was | {"host_spans", "kv_bytes", "conv_state_bytes"}
+    assert set(summary) - {"peak_hbm_bytes"} == was | {
+        "host_spans", "kv_bytes", "conv_state_bytes", "prefill_waves_by_rows"}
+    assert summary["prefill_waves_by_rows"] == {"4": 3}  # 10 requests as 4 + 4 + 2; the mesh shards 4 rows
     assert summary["kv_bytes"] > 0 and summary["conv_state_bytes"] == 0
     host = summary["host_spans"]
     assert host["window_steps"] == rounds and host["spans"]["round"]["count"] == rounds

@@ -37,7 +37,7 @@ RUN pip install --no-cache-dir \
 
 WORKDIR /workspace
 COPY distributed_llms_example_tpu/ distributed_llms_example_tpu/
-COPY valohai.yaml bench.py __graft_entry__.py _dllm_env.py dllm_test_bootstrap.py pyproject.toml ./
+COPY valohai.yaml __graft_entry__.py _dllm_env.py dllm_test_bootstrap.py pyproject.toml ./
 
 # pre-build the native JSONL loader so first use doesn't pay the compile
 RUN python -c "from distributed_llms_example_tpu import native; assert native.available(), native.build_error()"

@@ -339,7 +339,7 @@ def int8_compression_missing_finding(
     carries NO s8 gradient collective: the partitioner folded the wire
     back to fp32 (a hoisted reshard, a dropped pin) and the run would
     silently pay uncompressed traffic while stamping itself compressed —
-    the lint-time twin of ``scripts/obs_gate.py
+    the lint-time twin of ``obs.report --strict
     --max-gradient-bytes-per-step``."""
     if grad_compression != "int8":
         return None

@@ -1,10 +1,10 @@
 """Where the persistent XLA compilation cache lives.
 
 The one place in the repo that sets it; every entry point (the four
-``launch/cli.py`` mains, ``chip_smoke.py``, ``bench.py``,
-``__graft_entry__.py``) calls :func:`place_compile_cache` first thing.  The
-directory is part of the cache key, so it is either what the caller
-exported or one fixed path per checkout — never a temp name.
+``launch/cli.py`` mains, ``chip_smoke.py``, ``__graft_entry__.py``) calls
+:func:`place_compile_cache` first thing.  The directory is part of the cache
+key, so it is either what the caller exported or one fixed path per
+checkout — never a temp name.
 """
 
 from __future__ import annotations

@@ -249,7 +249,7 @@ class BatchIterator:
                 # batch-fill the cache BEFORE the per-example length scan:
                 # one Rust-parallel tokenizer call per batch instead of a
                 # Python loop of singles (the pod-host feed-rate fix,
-                # bench.py host-input)
+                # BASELINE.md)
                 self.ds.ensure_encoded(global_idx[rows])
                 yield (
                     max(len(self.ds[int(i)].input_ids) for i in global_idx[rows]),

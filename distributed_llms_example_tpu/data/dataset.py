@@ -222,11 +222,11 @@ class SummarizationDataset:
     def ensure_encoded(self, indices: Sequence[int]) -> None:
         """Fill the cache for ``indices`` with ONE batch tokenizer call.
 
-        Per-example encoding caps a pod host's feed rate (bench.py
-        host-input: ~200k tok/s single-stream HF vs the ~480k a v5e-8
-        needs); the batch entry points let the Rust tokenizer fan the
-        work across cores.  ``__getitem__`` stays the correctness path —
-        ids are identical either way (tests/test_data.py)."""
+        Per-example encoding caps a pod host's feed rate (BASELINE.md,
+        "Host input-pipeline feed rate"); the batch entry points let the
+        Rust tokenizer fan the work across cores.  ``__getitem__`` stays
+        the correctness path — ids are identical either way
+        (tests/test_data.py)."""
         todo = [j for j in (int(i) for i in indices) if self._cache[j] is None]
         if not todo:
             return

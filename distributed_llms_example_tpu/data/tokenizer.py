@@ -90,7 +90,7 @@ class ByteTokenizer:
 
     def encode_source_batch(self, texts: Sequence[str], max_length: int) -> list[list[int]]:
         # byte encoding is memory-bandwidth work; a plain loop already
-        # clears the pod-host feed rate with >10x margin (bench.py host-input)
+        # clears the pod-host feed rate with >10x margin (BASELINE.md)
         return [self.encode_source(t, max_length) for t in texts]
 
     encode_target_batch = encode_source_batch

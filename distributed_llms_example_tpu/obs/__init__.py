@@ -45,13 +45,9 @@ one.  This package supplies those signals in four layers:
                 sync_block / host_overhead (additive, test-pinned), a
                 ``dispatch_efficiency`` gauge, and the runtime tripwire
                 for host-blocking transfers off the log cadence
-- ``trace``     span-instance capture + the Chrome-trace/Perfetto
-                exporter merging every rank's spans, budget gauges and
-                serving request lifecycles onto one timeline
 - ``report``    the offline consumer: merges the per-process JSONL into
                 a cross-host step timeline (``python -m
-                distributed_llms_example_tpu.obs.report <output_dir>``;
-                ``--trace out.json`` exports the merged Perfetto trace)
+                distributed_llms_example_tpu.obs.report <output_dir>``)
 
 Everything funnels through ``sink`` (stdout Valohai channel + optional
 JSONL file, same schema).  ``TrainerObs`` below is the one object the
@@ -186,17 +182,6 @@ class TrainerObs:
                 # stray transfer — the tripwire verdict stands down there
                 async_dispatch=jax.default_backend() != "cpu",
             )
-        # trace capture (obs/trace.py): individual span instances for the
-        # Perfetto export.  File-channel material (bulk records), so only
-        # worth collecting when a JSONL channel exists to receive them.
-        self.trace = None
-        if self.budget is not None and getattr(cfg, "obs", "") == "jsonl":
-            # imported here (not at module top) so `python -m ...obs.trace`
-            # runs the exporter without a double-import warning
-            from distributed_llms_example_tpu.obs.trace import TraceCollector
-
-            self.trace = TraceCollector()
-            self.spans.listener = self.trace
 
     def _build_profiler(self, start_step: int) -> ProfileController:
         ctl = ProfileController(
@@ -384,9 +369,6 @@ class TrainerObs:
         """
         self.profiler.after_step(step, metrics.get("loss"))
         self.spans.step_complete()
-        if self.trace is not None:
-            # the step-boundary mark the cross-host trace merge aligns on
-            self.trace.note_step(step)
         if self.recorder is not None:
             self.recorder.record(step, epoch, metrics, fingerprint)
         if self.watchdog is not None:
@@ -399,8 +381,6 @@ class TrainerObs:
             # emit_window's summary() resets
             if self.budget is not None:
                 self.budget.close_window(step, epoch)
-            if self.trace is not None:
-                self.trace.flush(step)
             if self.watchdog is not None:
                 action = self._health_cadence(step)
             if self.enabled:
@@ -538,8 +518,6 @@ class TrainerObs:
         if self.budget is not None:
             # the final partial window's account (before summary resets it)
             self.budget.close_window(step, epoch)
-        if self.trace is not None:
-            self.trace.flush(step)
         if self.watchdog is not None and self._pending_health:
             action = self._health_cadence(step)
         if self.enabled:

@@ -70,8 +70,8 @@ from distributed_llms_example_tpu.obs import sink as sink_mod
 from distributed_llms_example_tpu.obs.spans import SpanRecorder
 
 # the additive components, in emission order; "<name>_ms" fields on every
-# step_budget event.  obs/report.py and bench.py iterate this list — one
-# definition, three consumers.
+# step_budget event.  obs/report.py iterates this list too — one
+# definition, two consumers.
 COMPONENTS: tuple[str, ...] = (
     "data_wait",
     "dispatch",
@@ -138,10 +138,6 @@ class BudgetAccountant:
         self.warmup_windows = int(warmup_windows)
         self.history_size = int(history_size)
         self.history: list[dict] = []
-        # the newest device-side decomposition of device_busy (a parsed
-        # profile capture — obs/devprof.py via attach_device_account);
-        # bench reads it after a profiled trainer-loop pass
-        self.last_device_account: dict | None = None
         self._closed = 0
         # cadenced gauges riding the account (not partition components):
         # currently the optimizer-apply wall sample (probe_optimizer)
@@ -207,16 +203,13 @@ class BudgetAccountant:
         ``device_account`` event — the device-side decomposition of the
         host account's ``device_busy`` blob: per-module-bucket device
         time, per-collective time (+ achieved bandwidth when the byte
-        account joined), and the overlap/exposed-idle metrics.  Same
-        sink rules as ``trace_spans``: bulk (file channel only — the
-        lanes payload has no place on the Valohai stdout contract) and
-        local (every capturing rank's file carries its own account).
-        Retained as ``last_device_account`` for in-process consumers
-        (bench)."""
+        account joined), and the overlap/exposed-idle metrics.  Bulk
+        (file channel only — the lanes payload has no place on the
+        Valohai stdout contract) and local (every capturing rank's file
+        carries its own account)."""
         record = {"event": "device_account", **{
             k: v for k, v in account.items() if k != "event"
         }}
-        self.last_device_account = record
         sink_mod.emit(record, local=True, bulk=True)
         return record
 
@@ -298,10 +291,9 @@ class BudgetAccountant:
 
 
 def aggregate_accounts(accounts: list[dict]) -> dict | None:
-    """Fold ``step_budget`` accounts (one run / one bench pass) into
-    per-component totals plus the wall-weighted dispatch efficiency —
-    shared by bench.py's trainer-loop stamping and obs/report.py's
-    per-rank rollup, so the two cannot disagree on the arithmetic."""
+    """Fold ``step_budget`` accounts (one run) into per-component
+    totals plus the wall-weighted dispatch efficiency — obs/report.py's
+    per-rank rollup."""
     accounts = [a for a in accounts if a.get("wall_ms")]
     if not accounts:
         return None

@@ -37,7 +37,7 @@ the busy UNION — they are device·time, the union is wall coverage.
 Offline: ``python -m distributed_llms_example_tpu.obs.devprof
 <trace_dir>`` prints the account; at runtime TrainerObs parses each
 landed capture and emits it as a ``device_account`` event through
-obs/budget.py (bulk/local, like ``trace_spans``), so obs/report.py
+obs/budget.py (bulk/local), so obs/report.py
 renders the tables from the JSONL alone — no trace files needed at
 report time.
 """

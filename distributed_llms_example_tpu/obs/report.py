@@ -23,10 +23,6 @@ and reconstructs the run:
   overlap / exposed-idle metrics, all from the JSONL alone (no trace
   files needed at report time).  ``--min-overlap-frac X`` + ``--strict``
   gate on exposed collectives and on captures that produced no account;
-- ``--trace out.json`` additionally exports the merged **Perfetto /
-  Chrome trace** (obs/trace.py): every rank's span instances aligned on
-  shared step boundaries, budget counters, anomaly/chaos instants, and
-  serving request lifecycles — load at https://ui.perfetto.dev;
 - **window trends**: p50/p95 step time per process across the run (is it
   getting slower? did one host drift?);
 - **straggler attribution**: which ranks the heartbeat named laggards
@@ -1535,13 +1531,6 @@ def main(argv: list[str] | None = None) -> int:
              "or when NO memory account exists (0 = the gate is off); a "
              "missing measurement must never read as a pass",
     )
-    p.add_argument(
-        "--trace", type=str, default="",
-        help="also export the merged Chrome-trace/Perfetto JSON here "
-             "(every rank's spans aligned on shared step boundaries, "
-             "budget counters, serving request lifecycles) — open at "
-             "ui.perfetto.dev",
-    )
     args = p.parse_args(argv)
     if not os.path.isdir(os.path.join(args.output_dir, "obs")):
         print(f"no obs/ directory under {args.output_dir}", file=sys.stderr)
@@ -1551,15 +1540,6 @@ def main(argv: list[str] | None = None) -> int:
         print(json.dumps(report))
     else:
         print(render_markdown(report, last=args.last), end="")
-    if args.trace:
-        from distributed_llms_example_tpu.obs.trace import export_chrome_trace
-
-        summary = export_chrome_trace(args.output_dir, args.trace)
-        print(
-            f"trace: {summary['events']} events from ranks "
-            f"{summary['ranks']} → {summary['path']}",
-            file=sys.stderr,
-        )
     rc = 0
     if args.strict:
         if report["schema_errors"] or report["recovery"]["organic_faults"]:

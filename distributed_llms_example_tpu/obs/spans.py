@@ -103,11 +103,6 @@ class SpanRecorder:
         # double-count, so only depth-0 exits land here)
         self._step_spans: dict[str, float] = {}
         self._step_records: list[dict] = []  # rings with _ring
-        # optional span-instance listener (obs/trace.py TraceCollector):
-        # called with (name, t0, dur) on every OUTERMOST span exit — a
-        # None check per span, nothing else, so the zero-cost-when-off
-        # property of the recorder is untouched
-        self.listener = None
 
     # -- recording -------------------------------------------------------
 
@@ -131,8 +126,6 @@ class SpanRecorder:
                         agg[2] = dt
                 if self._depth == 0:
                     self._step_spans[name] = self._step_spans.get(name, 0.0) + dt
-                    if self.listener is not None:
-                        self.listener.on_span(name, sp.t0, dt)
 
     def step_complete(self) -> None:
         """One train-loop iteration finished: record its wall duration

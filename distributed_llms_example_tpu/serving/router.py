@@ -61,7 +61,7 @@ Obs events: ``router_window`` (cadence), ``replica_health``
 (transitions, ``local``), ``serve_retry`` / ``serve_shed`` per
 occurrence, and a final ``router_summary`` carrying request-level MTTR,
 retry rate, shed counts and the goodput fields — what
-``scripts/obs_gate.py --max-request-retry-rate /
+``obs.report --strict --max-request-retry-rate /
 --min-serve-goodput-frac`` gates on.
 """
 

@@ -818,7 +818,7 @@ def make_train_step(
             with activation_mesh(mesh):
                 return jitted(*args)
 
-        run.jitted = jitted  # AOT access (bench.py cost analysis, memory audits)
+        run.jitted = jitted  # AOT access (utils/memory_audit.py)
         run.mesh = mesh
         return run
 

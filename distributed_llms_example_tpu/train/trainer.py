@@ -1365,8 +1365,10 @@ class Trainer:
     def _batch_tokens(self, batch: dict) -> int:
         """Non-pad tokens processed in one host-local batch — source plus
         target for seq2seq; for causal LM the attention mask already covers
-        prompt+target, so counting labels again would double-count.  Must
-        stay consistent with bench.py so "tokens/sec" means one thing."""
+        prompt+target, so counting labels again would double-count.  The
+        benchmark keeps its own copy of this rule
+        (benchmarks/harness/text.py); tests/test_benchmark_copies.py
+        pins the two equal so "tokens/sec" means one thing."""
         tokens = int(np.sum(batch["attention_mask"]))
         if self.loaded.is_seq2seq:
             tokens += int(np.sum(batch["labels"] != LABEL_PAD))

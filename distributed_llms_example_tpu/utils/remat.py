@@ -4,7 +4,7 @@
 the backward pass.  The *policy* decides what still gets saved:
 
 - ``full``: save nothing — maximum memory savings, recomputes the whole
-  block (the ~27%-throughput cost measured in bench.py's comment).
+  block.
 - ``dots``: ``jax.checkpoint_policies.dots_with_no_batch_dims_saveable``
   — save matmul outputs (cheap to store, expensive to recompute on the
   MXU) and recompute only the elementwise/softmax glue (cheap to

@@ -50,9 +50,8 @@ Seventeen rules, all born from real regressions at TPU scale:
 5. **No raw dropout primitives in models/ and train/.**  ``nn.Dropout``
    or ``jax.random.bernoulli`` in a model or train file bypasses the
    shared dropout helper (``ops/fused_dropout.py``) — the call site would
-   silently miss the fused Pallas path (``--dropout-impl``), its mask
-   would be threefry-generated and HBM-materialized again, and the
-   fused-vs-xla A/B in bench.py would no longer cover it.  Dropout goes
+   silently miss the fused Pallas path (``--dropout-impl``) and its mask
+   would be threefry-generated and HBM-materialized again.  Dropout goes
    through ``ops.fused_dropout.Dropout`` / ``dropout``; raw primitives
    are allowed only inside ``ops/`` (the helper and the attention
    reference path are the implementation).
@@ -67,15 +66,8 @@ Seventeen rules, all born from real regressions at TPU scale:
    prevent.  Everything goes through ``Checkpointer.save`` /
    ``restore_latest`` / ``restore_before``.
 
-7. **No Chrome-trace event emission outside ``obs/trace.py``.**  The
-   Perfetto export's value is being the ONE merged timeline: a module
-   that builds its own ``{"ph": ..., "ts": ...}`` event dicts (or a
-   ``"traceEvents"`` container) produces a rogue trace file with its own
-   clock epoch, no cross-rank step alignment, and no schema the report
-   CLI knows — the same fragmentation the sink-bypass rule (3) exists to
-   prevent on the metric channel.  Trace event construction lives in
-   ``obs/trace.py``; everyone else emits spans through the span recorder
-   and lets the exporter render them.
+7. (Removed with ``obs/trace.py``, PR 31: a timeline is a
+   ``--profile-steps`` capture.  The other rules keep their numbers.)
 
 8. **No raw optimizer apply in models/ and train/ outside
    ``train/optim.py``.**  ``optax.apply_updates`` (or a hand-rolled
@@ -295,11 +287,6 @@ _GRAD_NAMES = ("grad", "grads", "gradient")
 # verify-with-fallback contracts a bare manager call would skip.
 CKPT_OWNER = os.path.join(PACKAGE, "io", "checkpoint.py")
 _MANAGER_NAMES = ("manager", "_manager", "checkpoint_manager", "ckpt_manager")
-
-# Rule 7: Chrome-trace/Perfetto event dicts are built only in the trace
-# exporter — a second producer means a second clock epoch and no
-# cross-rank alignment.
-TRACE_OWNER = os.path.join(PACKAGE, "obs", "trace.py")
 
 # rule 11: the ONE owner of mesh construction and the jax.distributed
 # lifecycle (init/shutdown/reinit) — the elastic-recovery path re-enters
@@ -772,27 +759,6 @@ def _spec_decode_violations(tree: ast.AST, rel: str) -> list[str]:
     return violations
 
 
-def _trace_emit_violations(tree: ast.AST, rel: str) -> list[str]:
-    violations: list[str] = []
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.Dict):
-            continue
-        keys = {
-            k.value
-            for k in node.keys
-            if isinstance(k, ast.Constant) and isinstance(k.value, str)
-        }
-        if "traceEvents" in keys or {"ph", "ts"} <= keys:
-            violations.append(
-                f"{rel}:{node.lineno}: Chrome-trace event dict "
-                "('traceEvents' container or 'ph'+'ts' keys) outside "
-                "obs/trace.py — a rogue trace producer has its own clock "
-                "epoch and no cross-rank step alignment; record spans "
-                "through obs/spans.py and let obs/trace.py export them"
-            )
-    return violations
-
-
 def _mesh_ownership_violations(tree: ast.AST, rel: str) -> list[str]:
     """Rule 11: ``Mesh(...)`` construction (``jax.sharding.Mesh`` /
     imported ``Mesh`` — ``AbstractMesh`` and mesh-SHAPED helpers are
@@ -1027,8 +993,6 @@ def lint_file(path: str, rel: str) -> list[str]:
         violations.extend(_ckpt_manager_violations(tree, rel))
     if rel != MESH_OWNER:
         violations.extend(_mesh_ownership_violations(tree, rel))
-    if rel != TRACE_OWNER:
-        violations.extend(_trace_emit_violations(tree, rel))
     if rel != BACKOFF_OWNER:
         violations.extend(_retry_sleep_violations(tree, rel))
     if rel not in RANK_CONDITIONAL_OWNERS:

@@ -1023,48 +1023,6 @@ def test_repo_lint_ckpt_manager_rule(tmp_path):
     assert repo_lint.lint_file(str(bad), rel) == []
 
 
-def test_repo_lint_chrome_trace_rule(tmp_path):
-    """Rule 7 (ISSUE 9): Chrome-trace event dicts (``"ph"``+``"ts"``
-    keys, or a ``"traceEvents"`` container) may only be built in
-    obs/trace.py — a second trace producer means a second clock epoch
-    and no cross-rank step alignment (the trace twin of the sink-bypass
-    rule)."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "repo_lint",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "repo_lint.py"),
-    )
-    repo_lint = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(repo_lint)
-
-    bad = tmp_path / "rogue_trace.py"
-    bad.write_text(
-        "import json\n"
-        "ev = {'name': 'x', 'ph': 'X', 'ts': 12.5, 'dur': 3.0}\n"
-        "doc = {'traceEvents': [ev]}\n"
-        "ok = {'ph': 'X'}\n"              # ph alone is not a trace event
-        "ok2 = {'ts': 1.0, 'dur': 2.0}\n"  # ts without ph neither
-    )
-    for layer in ("models", "train", "obs", "serving"):
-        rel = os.path.join("distributed_llms_example_tpu", layer, "rogue_trace.py")
-        violations = repo_lint.lint_file(str(bad), rel)
-        assert len(violations) == 2, (layer, violations)
-        assert all("obs/trace.py" in v for v in violations)
-    # the exporter itself IS the owner
-    rel = os.path.join("distributed_llms_example_tpu", "obs", "trace.py")
-    assert repo_lint.lint_file(str(bad), rel) == []
-    # and the repo stays clean under the new rule
-    assert repo_lint.main([]) == 0
-
-
-# ---------------------------------------------------------------------------
-# fused optimizer apply (ISSUE 10): the moment-mirror spec lint, the
-# fp32-param-copy census extension, repo-lint rule 8
-# ---------------------------------------------------------------------------
-
-
 def test_spec_lint_optimizer_moment_mirror_clean_and_catches_anchor():
     """The adam moments resolve to the param specs under the stock rules
     (their paths END with the param path and the regexes are unanchored);
@@ -1174,145 +1132,6 @@ def test_repo_lint_optim_apply_rule(tmp_path):
     assert repo_lint.main([]) == 0
 
 
-# ---------------------------------------------------------------------------
-# bench_diff (ISSUE 11 satellite): round-over-round regression gate
-# ---------------------------------------------------------------------------
-
-
-def _load_bench_diff():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "bench_diff.py"),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_diff_directions_and_thresholds():
-    bench_diff = _load_bench_diff()
-    old = {
-        "trainer_loop": {
-            "tokens_per_sec_chip_prefetch2": 100.0,
-            "dispatch_efficiency": 0.95,
-            "device_account": {"buckets_ms": {"attn": 10.0}},
-        },
-        "serve": {"ttft_p95_ms": 200.0, "slo_attainment": 0.9},
-        "chips": 8,
-    }
-    new = {
-        "trainer_loop": {
-            "tokens_per_sec_chip_prefetch2": 90.0,   # -10%: regression
-            "dispatch_efficiency": 0.96,             # improvement
-            "device_account": {"buckets_ms": {"attn": 11.0}},  # +10% device ms
-        },
-        "serve": {"ttft_p95_ms": 212.0, "slo_attainment": 0.9},  # +6% ttft
-        "chips": 8,
-    }
-    rows = {r["field"]: r for r in bench_diff.compare(old, new)}
-    assert rows["trainer_loop.tokens_per_sec_chip_prefetch2"]["verdict"] == "regressed"
-    assert rows["trainer_loop.dispatch_efficiency"]["verdict"] == "ok"  # +1% < 5%
-    assert rows["serve.ttft_p95_ms"]["verdict"] == "regressed"  # lower-better
-    assert rows["serve.slo_attainment"]["verdict"] == "ok"
-    assert rows["chips"]["verdict"] == "info"  # no direction: never gates
-    assert rows["trainer_loop.device_account.buckets_ms.attn"]["verdict"] == "regressed"
-    # per-field threshold override silences the ttft wiggle (leaf name)
-    rows2 = {
-        r["field"]: r
-        for r in bench_diff.compare(old, new, overrides={"ttft_p95_ms": 0.10})
-    }
-    assert rows2["serve.ttft_p95_ms"]["verdict"] == "ok"
-    # full-dot-path override beats the leaf override
-    rows3 = {
-        r["field"]: r
-        for r in bench_diff.compare(
-            old, new,
-            overrides={"ttft_p95_ms": 0.10, "serve.ttft_p95_ms": 0.01},
-        )
-    }
-    assert rows3["serve.ttft_p95_ms"]["verdict"] == "regressed"
-
-
-def test_bench_diff_cli_exit_codes_and_markdown(tmp_path, capsys):
-    import json as _json
-
-    bench_diff = _load_bench_diff()
-    a = tmp_path / "BENCH_a.json"
-    b = tmp_path / "BENCH_b.json"
-    a.write_text(_json.dumps({"tps": {"tokens_per_sec_chip": 100.0}, "n": 3}))
-    # a clean round: tiny wiggle under the default 5% threshold
-    b.write_text(_json.dumps({"tps": {"tokens_per_sec_chip": 98.0}, "n": 3}))
-    md_path = tmp_path / "delta.md"
-    assert bench_diff.main([str(a), str(b), "--markdown-out", str(md_path)]) == 0
-    md = md_path.read_text()
-    assert "bench diff" in md and "tokens_per_sec_chip" in md
-    capsys.readouterr()
-    # a regressed round exits nonzero (the CI contract) and names the field
-    b.write_text(_json.dumps({"tps": {"tokens_per_sec_chip": 80.0}, "n": 3}))
-    assert bench_diff.main([str(a), str(b)]) == 1
-    err = capsys.readouterr().err
-    assert "REGRESSED tps.tokens_per_sec_chip" in err
-    # loosening the threshold for that field greens it
-    assert bench_diff.main([
-        str(a), str(b), "--threshold", "tokens_per_sec_chip=0.5",
-    ]) == 0
-    capsys.readouterr()
-    # disjoint artifacts: no shared numeric fields is its own error
-    c = tmp_path / "BENCH_c.json"
-    c.write_text(_json.dumps({"other": {"x": "y"}}))
-    assert bench_diff.main([str(a), str(c)]) == 2
-    capsys.readouterr()
-
-
-def test_bench_diff_markdown_orders_regressions_first(tmp_path):
-    bench_diff = _load_bench_diff()
-    rows = bench_diff.compare(
-        {"a_ms": 100.0, "tokens_per_sec": 10.0, "count": 1},
-        {"a_ms": 150.0, "tokens_per_sec": 20.0, "count": 1},
-    )
-    md = bench_diff.render_markdown(rows, "old.json", "new.json")
-    lines = [ln for ln in md.splitlines() if ln.startswith("| ")]
-    # header row, then the regression, then the improvement, then info
-    assert "a_ms" in lines[1] and "REGRESSED" in lines[1]
-    assert "tokens_per_sec" in lines[2] and "improved" in lines[2]
-    assert "count" in lines[3]
-
-
-def test_repo_lint_rule7_covers_devprof(tmp_path):
-    """Rule 7 (trace-dict ownership) guards the NEW device-attribution
-    module: obs/devprof.py PARSES trace events but must never BUILD them
-    — a second producer would mean a second clock epoch with no
-    cross-rank step alignment.  The shipped module is clean; a rogue
-    version that constructs a Chrome-trace dict trips the lint."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "repo_lint",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "repo_lint.py"),
-    )
-    repo_lint = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(repo_lint)
-
-    root = os.path.join(os.path.dirname(__file__), "..")
-    rel = os.path.join("distributed_llms_example_tpu", "obs", "devprof.py")
-    assert repo_lint.lint_file(os.path.join(root, rel), rel) == []
-    rogue = tmp_path / "devprof.py"
-    rogue.write_text(
-        "def export(events):\n"
-        "    return [{'ph': 'X', 'ts': 1.0, 'dur': 2.0, 'name': n}\n"
-        "            for n in events]\n"
-    )
-    violations = repo_lint.lint_file(str(rogue), rel)
-    assert len(violations) == 1 and "obs/trace.py" in violations[0]
-    # ...while the owner itself is allowed to build them
-    rel_owner = os.path.join("distributed_llms_example_tpu", "obs", "trace.py")
-    assert repo_lint.lint_file(str(rogue), rel_owner) == []
-
-
 def test_repo_lint_rank_conditional_rule(tmp_path):
     """Rule 13 (ISSUE 16): a bare ``process_index()``/``process_count()``
     conditional outside the rank-branching owners is forbidden — raw rank
@@ -1368,19 +1187,3 @@ def test_repo_lint_rank_conditional_rule(tmp_path):
         "    sync()\n"
     )
     assert repo_lint.lint_file(str(waived), rel) == []
-
-
-def test_bench_diff_config_knobs_never_gate():
-    """SLO settings and thresholds are config stamped into the artifact,
-    not measurements — changing them between rounds must read as info,
-    not regression (ttft_slo_ms matches both 'ttft' and '_ms' needles)."""
-    bench_diff = _load_bench_diff()
-    rows = {
-        r["field"]: r
-        for r in bench_diff.compare(
-            {"serve": {"ttft_slo_ms": 500.0, "ttft_p95_ms": 100.0}},
-            {"serve": {"ttft_slo_ms": 250.0, "ttft_p95_ms": 100.0}},
-        )
-    }
-    assert rows["serve.ttft_slo_ms"]["verdict"] == "info"
-    assert rows["serve.ttft_p95_ms"]["verdict"] == "ok"

@@ -1,4 +1,4 @@
-"""Step-time budget accounting + unified trace export (ISSUE 9).
+"""Step-time budget accounting (ISSUE 9).
 
 Pins: the additive budget account on a fake-clock span recorder
 (components sum to wall, the unattributed remainder is the measured
@@ -6,11 +6,8 @@ residue); the off-cadence host-blocking-dispatch tripwire; the
 zero-new-syncs-off-cadence property of the budget probe (counting-leaf,
 same technique as PR 3's health pin); schema round-trip through
 obs/report.py's loader for every new event type (``step_budget``,
-``trace_spans``, ``serve_request``); the report's "Where did the time
-go" section + the --strict dispatch-efficiency floor; and the 2-process
-merged-trace golden test (hand-built rank streams with shifted clocks →
-one Perfetto-loadable JSON whose events interleave on the shared step
-timeline).
+``serve_request``); and the report's "Where did the time go" section +
+the --strict dispatch-efficiency floor.
 """
 
 from __future__ import annotations
@@ -34,12 +31,6 @@ from distributed_llms_example_tpu.obs.report import (
     render_markdown,
 )
 from distributed_llms_example_tpu.obs.spans import SpanRecorder
-from distributed_llms_example_tpu.obs.trace import (
-    TraceCollector,
-    build_trace,
-    export_chrome_trace,
-    rank_offsets,
-)
 
 
 @pytest.fixture(autouse=True)
@@ -292,54 +283,12 @@ def test_aggregate_accounts_weighted():
 
 
 # ---------------------------------------------------------------------------
-# trace collection + the bulk sink gate
-# ---------------------------------------------------------------------------
-
-
-def test_trace_collector_flush_is_file_only(tmp_path, capsys):
-    path = str(tmp_path / "obs" / "metrics-p000.jsonl")
-    sink_mod.install_sink(
-        sink_mod.TeeSink([sink_mod.StdoutSink(), sink_mod.JsonlFileSink(path)])
-    )
-    clock = FakeClock()
-    col = TraceCollector(clock=clock)
-    clock.advance(1.0)
-    col.on_span("step_dispatch", clock.t - 0.5, 0.5)
-    col.note_step(1)
-    col.flush(1)
-    sink_mod.current_sink().close()
-    # bulk records never hit the stdout platform channel...
-    assert capsys.readouterr().out == ""
-    # ...but land schema-stamped in the per-process file
-    recs, errs = load_jsonl(path)
-    assert errs == []
-    rec = next(r for r in recs if r.get("event") == "trace_spans")
-    assert rec["spans"] == [["step_dispatch", 0.5, 0.5]]
-    assert rec["steps"] == [[1, 1.0]]
-    # empty flush emits nothing
-    col.flush(2)
-
-
-def test_trace_collector_bounded_with_drop_count(tmp_path):
-    path = str(tmp_path / "obs" / "m.jsonl")
-    sink_mod.install_sink(sink_mod.JsonlFileSink(path))
-    col = TraceCollector(clock=FakeClock(), max_spans=4)
-    for i in range(10):
-        col.on_span("s", float(i), 0.1)
-    col.flush(1)
-    sink_mod.current_sink().close()
-    rec = next(r for r in load_jsonl(path)[0] if r.get("event") == "trace_spans")
-    assert len(rec["spans"]) == 4
-    assert rec["dropped_spans"] == 6  # truncation is counted, not silent
-
-
-# ---------------------------------------------------------------------------
 # schema round-trip: every new event type through the report loader
 # ---------------------------------------------------------------------------
 
 
 def test_schema_round_trip_new_event_types(tmp_path):
-    """step_budget, trace_spans and serve_request all parse back through
+    """step_budget and serve_request both parse back through
     obs/report.py's loader schema-checked, feed build_report, and the
     markdown renders the budget section."""
     from distributed_llms_example_tpu.utils.jsonlog import log_json
@@ -349,7 +298,7 @@ def test_schema_round_trip_new_event_types(tmp_path):
         health="off",
     )
     obs = TrainerObs(cfg, start_step=0)
-    assert obs.budget is not None and obs.trace is not None
+    assert obs.budget is not None
     for step in (1, 2):
         with obs.host_span():
             pass
@@ -371,7 +320,7 @@ def test_schema_round_trip_new_event_types(tmp_path):
     records, errors = load_jsonl(path)
     assert errors == []
     events = {r.get("event", "metric") for r in records}
-    assert {"step_budget", "trace_spans", "serve_request"} <= events
+    assert {"step_budget", "serve_request"} <= events
     budget = next(r for r in records if r.get("event") == "step_budget")
     for c in COMPONENTS:
         assert f"{c}_ms" in budget
@@ -463,6 +412,29 @@ def test_report_budget_section_and_strict_floor(tmp_path, capsys):
     assert report_main([str(tmp_path), "--strict"]) == 0
 
 
+@pytest.mark.parametrize(
+    "effs, floor, rc",
+    [
+        ((0.97, 0.95), "0.9", 0),
+        ((0.5,), "0.9", 1),
+        ((0.5,), "0.4", 0),
+    ],
+    ids=["above-floor", "below-floor", "below-a-lower-floor"],
+)
+def test_strict_dispatch_floor_by_run(tmp_path, capsys, effs, floor, rc):
+    """The trainer-loop-gap gate is one command: a run whose wall-weighted
+    dispatch_efficiency sits under the floor fails, one above it passes."""
+    from distributed_llms_example_tpu.obs.report import main as report_main
+
+    _write_rank(tmp_path, 0, [
+        _budget_event(2 * (i + 1), eff=e) for i, e in enumerate(effs)
+    ])
+    assert report_main([
+        str(tmp_path), "--strict", "--min-dispatch-efficiency", floor, "--json",
+    ]) == rc
+    capsys.readouterr()
+
+
 def test_report_strict_floor_without_budget_records(tmp_path, capsys):
     from distributed_llms_example_tpu.obs.report import main as report_main
 
@@ -476,109 +448,7 @@ def test_report_strict_floor_without_budget_records(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# the merged cross-host trace: golden 2-process alignment + Perfetto shape
-# ---------------------------------------------------------------------------
-
-
-def test_rank_offsets_alignment_and_fallback():
-    # shared steps: rank 1's clock runs 5.0 s ahead → offset −5.0
-    marks = {0: {1: 1.0, 2: 2.0, 3: 3.0}, 1: {1: 6.0, 2: 7.0, 3: 8.1}}
-    offs = rank_offsets(marks, {})
-    assert offs[0] == 0.0
-    assert offs[1] == pytest.approx(-5.0)  # median is robust to the 8.1
-    # no shared steps: NTP wall-clock fallback (wall0[r] − wall0[base])
-    offs = rank_offsets(
-        {0: {1: 1.0}, 1: {9: 1.0}}, {0: 1000.0, 1: 1002.5}
-    )
-    assert offs[1] == pytest.approx(2.5)
-    # nothing to go on: identity
-    assert rank_offsets({0: {1: 1.0}, 1: {}}, {})[1] == 0.0
-
-
-def _trace_rank(rank: int, shift: float) -> list[dict]:
-    """One rank's stream: two steps, spans inside each, clocks shifted by
-    ``shift`` (each host's perf_counter epoch is arbitrary)."""
-    return [
-        _stamp({
-            "event": "trace_spans", "step": 2, "wall0": 1000.0 + shift,
-            "spans": [
-                ["data_wait", 0.00 + shift, 0.10],
-                ["step_dispatch", 0.10 + shift, 0.80],
-                ["device_sync", 1.90 + shift, 0.05],
-            ],
-            "steps": [[1, 1.00 + shift], [2, 2.00 + shift]],
-        }),
-        _stamp({
-            "event": "step_budget", "step": 2, "window_steps": 2,
-            "wall_ms": 2000.0, "dispatch_efficiency": 0.9,
-        }),
-    ]
-
-
-def test_two_process_merged_trace_golden(tmp_path):
-    """Two hand-built rank streams with clocks 7 s apart merge into ONE
-    Chrome-trace JSON: valid Perfetto shape, both pids present, and the
-    ranks' spans INTERLEAVE on the shared step timeline after the
-    step-boundary alignment (the acceptance criterion)."""
-    _write_rank(tmp_path, 0, _trace_rank(0, 0.0))
-    _write_rank(tmp_path, 1, _trace_rank(1, 7.0))
-    out = tmp_path / "trace.json"
-    summary = export_chrome_trace(str(tmp_path), str(out))
-    assert summary["ranks"] == [0, 1]
-    trace = json.loads(open(out).read())
-    events = trace["traceEvents"]
-    assert isinstance(events, list) and events
-    assert trace["displayTimeUnit"] == "ms"
-    slices = [e for e in events if e.get("ph") == "X"]
-    assert {e["pid"] for e in slices} == {0, 1}
-    # alignment: the same span on both ranks lands at the same ts (the
-    # 7 s clock shift is gone), so the two ranks' events interleave
-    by_rank = {
-        pid: sorted(
-            e["ts"] for e in slices if e["pid"] == pid and e["name"] == "step_dispatch"
-        )
-        for pid in (0, 1)
-    }
-    assert by_rank[0] == pytest.approx(by_rank[1], abs=1e3)  # within 1 ms
-    # both ranks' dispatch spans sit INSIDE the merged step-1 window
-    r0_steps = [e for e in events if e["pid"] == 0 and e.get("ph") == "X"
-                and e["name"].startswith("step ")]
-    assert r0_steps, "step-boundary slices must be rendered"
-    lo = min(e["ts"] for e in r0_steps)
-    hi = max(e["ts"] + e["dur"] for e in r0_steps)
-    for pid in (0, 1):
-        sync = next(e for e in slices if e["pid"] == pid and e["name"] == "device_sync")
-        assert lo <= sync["ts"] <= hi
-    # budget counters ride the trace as Perfetto counter tracks
-    counters = [e for e in events if e.get("ph") == "C"]
-    assert {c["pid"] for c in counters} == {0, 1}
-    assert all(
-        c["args"]["dispatch_efficiency"] == 0.9 for c in counters
-    )
-
-
-def test_trace_includes_serving_request_lifecycles(tmp_path):
-    _write_rank(tmp_path, 0, [
-        _stamp({
-            "event": "serve_request", "request": 3, "slot": 2,
-            "queue_wait_ms": 100.0, "prefill_ms": 50.0, "ttft_ms": 160.0,
-            "decode_ms": 400.0, "tokens": 9, "t_admit_s": 0.1,
-            "t_done_s": 0.55, "finished_at_step": 40,
-        }),
-    ])
-    trace = build_trace(str(tmp_path))
-    names = [e.get("name", "") for e in trace["traceEvents"]]
-    assert any("req 3 queue" in n for n in names)
-    assert any("req 3 prefill" in n for n in names)
-    assert any("req 3 decode" in n for n in names)
-    q = next(e for e in trace["traceEvents"] if e.get("name") == "req 3 queue")
-    p = next(e for e in trace["traceEvents"] if e.get("name") == "req 3 prefill")
-    # the queue slice ends where prefill begins
-    assert q["ts"] + q["dur"] == pytest.approx(p["ts"], abs=1.0)
-
-
-# ---------------------------------------------------------------------------
-# the cadenced optimizer-apply gauge (ISSUE 10 satellite) + the gate script
+# the cadenced optimizer-apply gauge (ISSUE 10 satellite)
 # ---------------------------------------------------------------------------
 
 
@@ -654,35 +524,6 @@ def test_aggregate_accounts_carries_optimizer_gauge():
     assert agg["optimizer_apply_ms"] == pytest.approx(15.0)
     assert agg["optimizer_share_of_step"] == pytest.approx(0.3)
     assert "optimizer_apply_ms" not in (aggregate_accounts([c]) or {})
-
-
-def test_obs_gate_script(tmp_path, capsys):
-    """scripts/obs_gate.py: the pinned-flags wrapper fails a run whose
-    wall-weighted dispatch_efficiency sits under the floor, passes one
-    above it, and fails when NO step_budget records exist (a missing
-    measurement is never a pass)."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "obs_gate",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "obs_gate.py"),
-    )
-    obs_gate = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(obs_gate)
-
-    good = tmp_path / "good"
-    _write_rank(good, 0, [_budget_event(2, eff=0.97), _budget_event(4, eff=0.95)])
-    assert obs_gate.main([str(good)]) == 0
-
-    bad = tmp_path / "bad"
-    _write_rank(bad, 0, [_budget_event(2, eff=0.5)])
-    assert obs_gate.main([str(bad)]) == 1
-    assert obs_gate.main([str(bad), "--min-dispatch-efficiency", "0.4"]) == 0
-
-    empty = tmp_path / "empty"
-    _write_rank(empty, 0, [_stamp({"step": 1, "loss": 1.0})])
-    assert obs_gate.main([str(empty)]) == 1
-    capsys.readouterr()
 
 
 def test_report_renders_optimizer_gauge(tmp_path):

@@ -9,7 +9,6 @@ verdicts replay bit-for-bit with no wall clock anywhere.  The slow tier
 drives a real tiny engine and pins the determinism contract (open-loop
 tokens == the closed-loop oracle's) plus genuine queueing collapse."""
 
-import importlib.util
 import json
 import os
 
@@ -453,25 +452,28 @@ def test_report_bare_points_without_summary_still_render(tmp_path):
     )
 
 
-def test_strict_gates_cut_both_ways(tmp_path, capsys):
+@pytest.mark.parametrize(
+    "gates, rc, says",
+    [
+        # attainment: the best point reaches 1.0 → a 0.99 floor passes
+        (("--min-slo-attainment", "0.99"), 0, ""),
+        # p99: the best measured point is well under a generous ceiling
+        (("--max-p99-ttft-ms", "5000"), 0, ""),
+        # ...and over a 1 ms ceiling fails with the measured value named
+        (("--max-p99-ttft-ms", "1"), 1, "exceeds"),
+        # both gates in the one command
+        (("--min-slo-attainment", "0.8", "--max-p99-ttft-ms", "5000"), 0, ""),
+        (("--min-slo-attainment", "0.8", "--max-p99-ttft-ms", "1"), 1, "exceeds"),
+    ],
+    ids=["attainment", "p99-under", "p99-over", "both-pass", "both-one-fails"],
+)
+def test_strict_gates_cut_both_ways(tmp_path, capsys, gates, rc, says):
     from distributed_llms_example_tpu.obs.report import main as report_main
 
     cfg = LoadgenConfig(qps_grid=(1.0, 4.0, 40.0), ttft_slo_ms=400.0)
     _emit_fake_sweep_to(tmp_path, cfg)
-    d = str(tmp_path)
-    # attainment: the best point reaches 1.0 → a 0.99 floor passes
-    assert report_main(
-        [d, "--strict", "--min-slo-attainment", "0.99", "--json"]
-    ) == 0
-    # p99: the best measured point is well under a generous ceiling
-    assert report_main(
-        [d, "--strict", "--max-p99-ttft-ms", "5000", "--json"]
-    ) == 0
-    # ...and over a 1 ms ceiling fails with the measured value named
-    assert report_main(
-        [d, "--strict", "--max-p99-ttft-ms", "1", "--json"]
-    ) == 1
-    assert "exceeds" in capsys.readouterr().err
+    assert report_main([str(tmp_path), "--strict", *gates, "--json"]) == rc
+    assert says in capsys.readouterr().err
 
 
 def test_strict_gate_fails_without_loadgen_measurement(tmp_path, capsys):
@@ -517,68 +519,6 @@ def test_strict_p99_gate_fails_on_fully_collapsed_run(tmp_path, capsys):
     )
     assert rc == 1
     assert "no measured p99" in capsys.readouterr().err
-
-
-def test_obs_gate_passes_loadgen_flags_through(monkeypatch, tmp_path):
-    spec = importlib.util.spec_from_file_location(
-        "obs_gate",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "obs_gate.py"),
-    )
-    obs_gate = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(obs_gate)
-    seen = {}
-
-    def fake_main(flags):
-        seen["flags"] = flags
-        return 0
-
-    import distributed_llms_example_tpu.obs.report as report_mod
-
-    monkeypatch.setattr(report_mod, "main", fake_main)
-    assert obs_gate.main([
-        str(tmp_path), "--min-slo-attainment", "0.8",
-        "--max-p99-ttft-ms", "750",
-    ]) == 0
-    flags = seen["flags"]
-    i = flags.index("--min-slo-attainment")
-    assert flags[i + 1] == "0.8"
-    j = flags.index("--max-p99-ttft-ms")
-    assert flags[j + 1] == "750.0"
-    # off by default: no loadgen flags injected
-    assert obs_gate.main([str(tmp_path)]) == 0
-    assert "--min-slo-attainment" not in seen["flags"]
-
-
-def test_bench_diff_directions_for_loadgen_leaves():
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "bench_diff.py"),
-    )
-    bench_diff = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_diff)
-    d = bench_diff.direction_of
-    # curve quality: knee moving right / more goodput / attainment = better
-    assert d("loadgen.knee_qps") == 1
-    assert d("loadgen.points.goodput_qps") == 1
-    assert d("loadgen.points.slo_attainment") == 1
-    assert d("loadgen.points.achieved_qps") == 1
-    # tail latency and queueing delay: lower is better
-    assert d("loadgen.points.ttft_p99_ms") == -1
-    assert d("loadgen.points.queue_delay_p99_ms") == -1
-    # the experiment's shape knobs are config, never regressions —
-    # including max_wall_s, which would otherwise match "wall_s"
-    assert d("loadgen.qps_grid") == 0
-    assert d("loadgen.requests_per_point") == 0
-    assert d("loadgen.points.offered_qps") == 0
-    assert d("cfg.max_wall_s") == 0
-    assert d("cfg.burst_size") == 0
-    # ...while a genuine wall measurement still gates lower-better
-    assert d("loadgen.points.wall_s") == -1
-    # prefix-cache leaves: hit rate and tokens saved are higher-better,
-    # the LRU byte ceiling is config
-    assert d("serve_prefix.hit_rate") == 1
-    assert d("serve_prefix.prefill_tokens_saved") == 1
-    assert d("cfg.prefix_cache_budget") == 0
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,6 @@ Acceptance pins held here:
 from __future__ import annotations
 
 import glob
-import importlib.util
 import json
 import os
 
@@ -434,21 +433,23 @@ def test_report_rejects_torn_postmortem_as_error(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_strict_memory_gates_pass_and_fail(tmp_path, capsys):
+@pytest.mark.parametrize(
+    "gates, rc, says",
+    [
+        # peak_frac_of_budget = 5/16 GiB ≈ 0.3125 (params 4 GiB + act 1 GiB)
+        (("--max-peak-hbm-frac", "0.9", "--min-hbm-headroom-gib", "1.0"), 0, ""),
+        (("--max-peak-hbm-frac", "0.2"), 1, "exceeds"),
+        (("--min-hbm-headroom-gib", "14.0"), 1, "below the"),
+        # both gates in the one command, one of them missed
+        (("--max-peak-hbm-frac", "0.85", "--min-hbm-headroom-gib", "14.0"), 1,
+         "below the"),
+    ],
+    ids=["both-pass", "peak-over", "headroom-under", "both-one-fails"],
+)
+def test_strict_memory_gates_pass_and_fail(tmp_path, capsys, gates, rc, says):
     d = _write_jsonl(tmp_path, [{"step": 1, "loss": 1.0}, _account_event()])
-    # peak_frac_of_budget = 5/16 GiB ≈ 0.3125 (params 4 GiB + act 1 GiB)
-    assert report_main(
-        [d, "--strict", "--max-peak-hbm-frac", "0.9",
-         "--min-hbm-headroom-gib", "1.0", "--json"]
-    ) == 0
-    assert report_main(
-        [d, "--strict", "--max-peak-hbm-frac", "0.2", "--json"]
-    ) == 1
-    assert "exceeds" in capsys.readouterr().err
-    assert report_main(
-        [d, "--strict", "--min-hbm-headroom-gib", "14.0", "--json"]
-    ) == 1
-    assert "below the" in capsys.readouterr().err
+    assert report_main([d, "--strict", *gates, "--json"]) == rc
+    assert says in capsys.readouterr().err
 
 
 def test_strict_memory_gates_fail_without_measurement(tmp_path, capsys):
@@ -465,63 +466,6 @@ def test_strict_memory_gates_fail_without_measurement(tmp_path, capsys):
         [d, "--strict", "--min-hbm-headroom-gib", "1.0", "--json"]
     ) == 1
     assert "no memory account" in capsys.readouterr().err
-
-
-def test_obs_gate_passes_memory_flags_through(monkeypatch, tmp_path):
-    spec = importlib.util.spec_from_file_location(
-        "obs_gate",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "obs_gate.py"),
-    )
-    obs_gate = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(obs_gate)
-    seen = {}
-
-    def fake_main(flags):
-        seen["flags"] = flags
-        return 0
-
-    import distributed_llms_example_tpu.obs.report as report_mod
-
-    monkeypatch.setattr(report_mod, "main", fake_main)
-    assert obs_gate.main([
-        str(tmp_path), "--max-peak-hbm-frac", "0.85",
-        "--min-hbm-headroom-gib", "2.0",
-    ]) == 0
-    flags = seen["flags"]
-    i = flags.index("--max-peak-hbm-frac")
-    assert flags[i + 1] == "0.85"
-    j = flags.index("--min-hbm-headroom-gib")
-    assert flags[j + 1] == "2.0"
-    # off by default
-    assert obs_gate.main([str(tmp_path)]) == 0
-    assert "--max-peak-hbm-frac" not in seen["flags"]
-
-
-# ---------------------------------------------------------------------------
-# bench_diff directions for the memory leaves
-# ---------------------------------------------------------------------------
-
-
-def test_bench_diff_directions_for_memory_leaves():
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff",
-        os.path.join(os.path.dirname(__file__), "..", "scripts",
-                     "bench_diff.py"),
-    )
-    bench_diff = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_diff)
-    d = bench_diff.direction_of
-    # memory moving up is a regression
-    assert d("grad_accum.accum4.peak_hbm_new_high_water_gib") == -1
-    assert d("grad_accum.accum4.peak_hbm_gib_cumulative") == -1
-    assert d("memory_watermark.bytes_in_use") == -1
-    assert d("memory_account.peak_frac_of_budget") == -1
-    # headroom under the budget is the higher-better face
-    assert d("memory_account.hbm_headroom_gib") == 1
-    assert d("serve.hbm_headroom_gib") == 1
-    # the budget itself is a config knob, never a regression
-    assert d("memory_account.hbm_budget_gib") == 0
-    assert d("memory_account.hbm_budget_bytes") == 0
 
 
 # ---------------------------------------------------------------------------

@@ -544,12 +544,6 @@ def test_trainer_obs_jsonl_stream(tmp_path, compiled_t5_fsdp):
         # a healthy async loop must not trip the host-blocking tripwire
         assert acct["offcadence_sync_suspect"] is False
     assert any(a["device_busy_ms"] > 0 for a in budgets)
-    # trace capture rode the same run: span instances + step marks for
-    # the Perfetto export, bulk (file-channel-only) records
-    traces = by_event["trace_spans"]
-    assert traces and all("steps" in t for t in traces)
-    span_names = {s[0] for t in traces for s in t["spans"]}
-    assert {"step_dispatch", "device_sync"} <= span_names
     # the step-cadence metric lines tee into the same stream
     assert any("loss" in r and "step" in r for r in by_event["metric"])
     # heartbeat (single process: trivially zero skew, but alive)

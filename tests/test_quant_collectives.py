@@ -473,19 +473,12 @@ def test_cli_flag_and_config():
     assert TrainConfig().grad_compression == "off"
 
 
-def test_obs_gate_gradient_bytes_ceiling(tmp_path, capsys):
-    """scripts/obs_gate.py --max-gradient-bytes-per-step: fails a run
+def test_strict_gradient_bytes_ceiling(tmp_path, capsys):
+    """obs.report --strict --max-gradient-bytes-per-step: fails a run
     whose startup byte account exceeds the ceiling OR that emitted no
     account at all (silently lost compression must not pass); green
     under the ceiling."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "obs_gate",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "obs_gate.py"),
-    )
-    obs_gate = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(obs_gate)
+    from distributed_llms_example_tpu.obs.report import main as report_main
 
     def write(dirname, recs):
         d = tmp_path / dirname / "obs"
@@ -494,6 +487,12 @@ def test_obs_gate_gradient_bytes_ceiling(tmp_path, capsys):
             for r in recs:
                 f.write(json.dumps({"schema_version": 1, **r}) + "\n")
         return tmp_path / dirname
+
+    def gate(run, ceiling):
+        return report_main([
+            str(run), "--strict", "--json",
+            "--max-gradient-bytes-per-step", ceiling,
+        ])
 
     gauges = {
         "event": "obs_gauges", "mesh": {"data": 8}, "flops_per_step": 1.0,
@@ -505,52 +504,10 @@ def test_obs_gate_gradient_bytes_ceiling(tmp_path, capsys):
             "activation_bytes": 0,
         },
     }
-    # the wrapper always gates dispatch efficiency too — give the run a
-    # healthy step_budget record so only the byte ceiling is under test
-    budget = {
-        "event": "step_budget", "step": 2, "window_steps": 4,
-        "wall_ms": 1000.0, "data_wait_ms": 10.0, "dispatch_ms": 20.0,
-        "device_busy_ms": 940.0, "sync_block_ms": 10.0,
-        "host_overhead_ms": 10.0, "unattributed_ms": 10.0,
-        "accounted_frac": 0.99, "additivity_ok": True,
-        "dispatch_efficiency": 0.97,
-        "offcadence_sync_steps": 0, "offcadence_sync_suspect": False,
-    }
-    good = write("good", [gauges, budget])
-    assert obs_gate.main(
-        [str(good), "--max-gradient-bytes-per-step", "2000"]
-    ) == 0
-    assert obs_gate.main(
-        [str(good), "--max-gradient-bytes-per-step", "500"]
-    ) == 1
+    good = write("good", [gauges])
+    assert gate(good, "2000") == 0
+    assert gate(good, "500") == 1
     # no obs_gauges record at all: the gate must fail, not pass silently
-    empty = write("empty", [{"step": 1, "loss": 1.0}, budget])
-    assert obs_gate.main(
-        [str(empty), "--max-gradient-bytes-per-step", "2000"]
-    ) == 1
+    empty = write("empty", [{"step": 1, "loss": 1.0}])
+    assert gate(empty, "2000") == 1
     capsys.readouterr()
-
-
-def test_bench_diff_directions():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "bench_diff.py"),
-    )
-    bd = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bd)
-    assert bd.direction_of("comm_bytes_per_step.gradient_bytes_per_step") == -1
-    assert bd.direction_of("grad_compression_ab.gradient_wire_bytes") == -1
-    assert bd.direction_of("grad_compression") == 0
-    rows = bd.compare(
-        {"grad_compression_ab": {"int8_vs_off": 1.0}},
-        {"grad_compression_ab": {"int8_vs_off": 0.5}},
-    )
-    # *_vs_* carries no direction tokens by itself; the ratio rides
-    # tokens-per-sec fields which do — just pin it never crashes and the
-    # byte fields gate
-    rows = bd.compare(
-        {"gradient_bytes_per_step": 100.0}, {"gradient_bytes_per_step": 400.0}
-    )
-    assert rows[0]["verdict"] == "regressed"

@@ -13,7 +13,7 @@ drain losing zero requests with nothing persisted (serving is stateless
 by construction — proven, not asserted); session→replica affinity with
 failover remap; the stepwise ``ServeSession`` engine API (incremental
 submit == batch generate); the crash-safe product JSONL writer under
-kill -9; and the report/obs_gate serving gates
+kill -9; and the report's strict serving gates
 (--max-request-retry-rate / --min-serve-goodput-frac).
 """
 
@@ -494,7 +494,7 @@ def test_router_crash_acceptance_bit_identical_and_report(
     to the unfailed single-engine oracle, zero requests lost; the JSONL
     stream reports the fault as injected-only (obs.report --strict rc 0)
     with finite request-level MTTR in the recovery timeline; and the
-    obs_gate serving gates cut both ways."""
+    strict serving gates cut both ways."""
     from distributed_llms_example_tpu.obs.report import main as report_main
 
     lm, params, reqs, engines, oracle_outs = llama_pool
@@ -832,7 +832,6 @@ def test_prefix_report_section_and_gate(llama_pool, tmp_path, capsys):
     with NO prefix measurement fails the gate outright (missing
     measurement is never a pass)."""
     from distributed_llms_example_tpu.obs.report import main as report_main
-    from scripts.obs_gate import main as gate_main
 
     lm, params, _, _, _ = llama_pool
     rng = np.random.RandomState(43)
@@ -876,9 +875,10 @@ def test_prefix_report_section_and_gate(llama_pool, tmp_path, capsys):
         str(out), "--strict", "--json",
         "--min-prefix-hit-rate", str(rate + 0.01),
     ]) == 1
-    # ...and forwards through the pinned-flags wrapper
-    assert gate_main([
-        str(out), "--min-dispatch-efficiency", "0",
+    # ...and a dispatch floor of 0 leaves that gate off (a serving run
+    # holds no step_budget record and must not fail for it)
+    assert report_main([
+        str(out), "--strict", "--json", "--min-dispatch-efficiency", "0",
         "--min-prefix-hit-rate", str(rate - 0.01),
     ]) == 0
     # a run with no prefix-enabled summary: the gate fails as missing

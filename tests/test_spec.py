@@ -10,9 +10,8 @@ with speculation on and aggregates the acceptance ledger; the new
 ``serve_window``/``serve_summary``/``router_summary`` fields round-trip
 through ``obs.report``'s loader into the '## Speculative decode' section
 and the strict ``--min-acceptance-rate`` gate (missing measurement is
-never a pass); repo_lint rule 17 fences acceptance math to
-``serving/spec.py`` + ``serving/cache_pool.py``; and ``bench_diff``
-knows the new leaves' directions."""
+never a pass); and repo_lint rule 17 fences acceptance math to
+``serving/spec.py`` + ``serving/cache_pool.py``."""
 
 from __future__ import annotations
 
@@ -324,7 +323,6 @@ def test_spec_report_section_and_gate(llama_spec, tmp_path, capsys):
     floor the measured rate meets, fails one above it, and fails
     OUTRIGHT on a run with no spec measurement."""
     from distributed_llms_example_tpu.obs.report import main as report_main
-    from scripts.obs_gate import main as gate_main
 
     lm, params, _, _ = llama_spec
     rng = np.random.RandomState(43)
@@ -368,8 +366,10 @@ def test_spec_report_section_and_gate(llama_spec, tmp_path, capsys):
         str(out), "--strict", "--json",
         "--min-acceptance-rate", str(rate + 0.01),
     ]) == 1
-    assert gate_main([
-        str(out), "--min-dispatch-efficiency", "0",
+    # a floor of 0 leaves the dispatch gate off: a serving run holds no
+    # step_budget record and must not fail for it
+    assert report_main([
+        str(out), "--strict", "--json", "--min-dispatch-efficiency", "0",
         "--min-acceptance-rate", str(max(rate - 0.01, 1e-6)),
     ]) == 0
     # a run with NO spec-enabled summary: missing measurement = fail
@@ -384,7 +384,7 @@ def test_spec_report_section_and_gate(llama_spec, tmp_path, capsys):
     capsys.readouterr()
 
 
-# ------------------------------------------------------- lint + bench_diff
+# ------------------------------------------------------------------ lint
 
 
 def _load_script(name):
@@ -421,34 +421,6 @@ def test_repo_lint_rule17_fences_acceptance_math(tmp_path):
     assert repo_lint.lint_file(
         str(bad), "distributed_llms_example_tpu/ops/sneaky.py"
     ) == []
-
-
-def test_bench_diff_spec_directions():
-    """acceptance_rate / accepted_tokens_per_step / vs_plain regress
-    DOWNWARD; spec_tokens and spec_draft_model are config, never a
-    regression."""
-    bench_diff = _load_script("bench_diff")
-    old = {
-        "acceptance_rate": 0.8, "accepted_tokens_per_step": 2.5,
-        "vs_plain": 0.4, "spec_tokens": 3, "spec_draft_model": "ngram",
-    }
-    new = {
-        "acceptance_rate": 0.4, "accepted_tokens_per_step": 1.2,
-        "vs_plain": 0.04, "spec_tokens": 5, "spec_draft_model": "llama-test",
-    }
-    rows = {r["field"]: r for r in bench_diff.compare(old, new)}
-    assert rows["acceptance_rate"]["verdict"] == "regressed"
-    assert rows["accepted_tokens_per_step"]["verdict"] == "regressed"
-    assert rows["vs_plain"]["verdict"] == "regressed"
-    # config leaves never regress (the string draft-model leaf is not
-    # even compared numerically — absent or info, never a gate)
-    assert rows["spec_tokens"]["verdict"] != "regressed"
-    if "spec_draft_model" in rows:
-        assert rows["spec_draft_model"]["verdict"] != "regressed"
-    # improvements in the same leaves never flag
-    rows = {r["field"]: r for r in bench_diff.compare(new, old)}
-    for k in ("acceptance_rate", "accepted_tokens_per_step", "vs_plain"):
-        assert rows[k]["verdict"] != "regressed"
 
 
 def test_chatbot_requests_budgets_seed_stable():

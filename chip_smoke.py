@@ -236,7 +236,9 @@ def kernels_phase(out: dict, sz: dict, seed: int, interpret: bool) -> None:
     import jax.numpy as jnp
 
     from distributed_llms_example_tpu.ops.attention import dot_product_attention
-    from distributed_llms_example_tpu.ops.flash_attention import auto_block, decode_step_heads, flash_attention, flash_decode
+    from distributed_llms_example_tpu.ops.flash_attention import (
+        auto_block, decode_block, decode_step_heads, dequantize_kv, flash_attention, flash_decode, quantize_kv,
+    )
     from distributed_llms_example_tpu.ops.fused_dropout import fused_dropout
     from distributed_llms_example_tpu.ops.fused_optim import SCALARS, adamw_leaf_reference, fused_adamw_leaf
     from distributed_llms_example_tpu.ops.mha import decode_step_bias
@@ -297,13 +299,29 @@ def kernels_phase(out: dict, sz: dict, seed: int, interpret: bool) -> None:
         for q_len in (1, 8):
             q, k, v = rnd(11, (B, H, q_len, D)), rnd(12, (B, H, cache, D)), rnd(13, (B, H, cache, D))
             offsets = jax.random.randint(jax.random.fold_in(key, 14), (B,), 0, cache - q_len + 1).astype(jnp.int32)
-            got = jax.jit(lambda q, k, v, o: flash_decode(q, k, v, offsets=o, interpret=interpret))(q, k, v, offsets)
+            # the kernel takes K/V as the cache keeps them: (B, length, heads x d)
+            leaf = lambda x: x.transpose(0, 2, 1, 3).reshape(B, cache, H * D)  # noqa: E731
+            got = jax.jit(lambda q, k, v, o: flash_decode(q, k, v, offsets=o, interpret=interpret))(q, leaf(k), leaf(v), offsets)
             want = ref_attention(q, k, v, decode_step_bias(offsets, q_len, cache), False)
             name = f"{cache}" if q_len == 1 else f"{cache}x{q_len}rows"
             decode[name] = round(rel_err(got, want), 5)
             check(decode[name] <= TOL_FWD, f"flash_decode cache {cache}, {q_len} q rows, off by {decode[name]}")
         tiling[str(cache)] = decode_step_heads(H, auto_block(cache), D, 2)
         check(tiling[str(cache)] == H, f"flash_decode cache {cache}: a step holds {tiling[str(cache)]} of {H} heads")
+    # the int8 cache of a 7B's shape (32 heads of 128, kv tile 512): the heads go
+    # four a step, and every step takes all heads' scales and picks its group's
+    h7, d7, cache, slots = 32, 128, 1024, 4
+    q, k, v = rnd(15, (slots, h7, 1, d7)), rnd(16, (slots, h7, cache, d7), jnp.float32), rnd(17, (slots, h7, cache, d7), jnp.float32)
+    (qk, ks), (qv, vs) = quantize_kv(k), quantize_kv(v)
+    offsets = jnp.asarray([0, 300, 700, cache - 1], jnp.int32)
+    leaf = lambda x: x.transpose(0, 2, 1, 3).reshape(slots, cache, h7 * d7)  # noqa: E731
+    got = jax.jit(lambda q, k, v, o, ks, vs: flash_decode(q, k, v, offsets=o, k_scale=ks, v_scale=vs, interpret=interpret))(
+        q, leaf(qk), leaf(qv), offsets, ks.transpose(0, 2, 1), vs.transpose(0, 2, 1))
+    want = ref_attention(q, dequantize_kv(qk, ks), dequantize_kv(qv, vs), decode_step_bias(offsets, 1, cache), False)
+    decode["int8_32x128"] = round(rel_err(got, want), 5)
+    check(decode["int8_32x128"] <= TOL_FWD, f"flash_decode int8, 32 heads of 128, off by {decode['int8_32x128']}")
+    tiling["int8_32x128"] = decode_step_heads(h7, decode_block(cache), d7, 1, int8_scales=True)
+    check(1 < tiling["int8_32x128"] < h7, f"flash_decode int8, 32 heads of 128: a step holds {tiling['int8_32x128']} heads")
     out["flash_decode_rel_err"] = decode
     out["flash_decode_heads_per_step"] = tiling
 

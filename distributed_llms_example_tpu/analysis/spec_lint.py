@@ -291,10 +291,11 @@ def lint_cache_sharding(
     # the oversized-replicated check above only fires on rule FALLTHROUGH;
     # for the cache the contract is stronger — every K/V buffer must hit a
     # sharding rule (a cache leaf no rule matches decodes replicated).
-    # The int8 KV cache's 3-D ``*_scale`` leaves are held to the same bar:
-    # an unmatched scale leaf replicates batch×heads×len f32 per device
+    # The int8 KV cache's ``*_scale`` leaves are held to the same bar:
+    # an unmatched scale leaf replicates batch×len×heads f32 per device
     # AND desyncs from the s8 buffers it dequantizes (a GSPMD reshard on
-    # every decode step).
+    # every decode step).  So is every other leaf that holds state (3-D:
+    # (batch, len, heads x head_dim) K/V, the scales, a conv state).
     import jax.tree_util as jtu
 
     from distributed_llms_example_tpu.parallel.sharding import _path_str
@@ -302,8 +303,7 @@ def lint_cache_sharding(
     leaves: list[tuple[str, Any]] = []
     jtu.tree_map_with_path(lambda p, x: leaves.append((_path_str(p), x)), cache)
     for path, leaf in leaves:
-        nd = len(getattr(leaf, "shape", ()))
-        if nd != 4 and not (nd == 3 and path.endswith("_scale")):
+        if len(getattr(leaf, "shape", ())) < 3:
             continue
         if rules.match_path(path) is None:
             findings.append(

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from distributed_llms_example_tpu.parallel.activation import constrain_cache
+from distributed_llms_example_tpu.parallel.sharding import cache_kv_heads
 
 NEG_INF = -1.0e7
 
@@ -107,6 +108,7 @@ class Seq2SeqGenerator:
     def __init__(self, model: Any, config: Any, max_new_tokens: int,
                  num_beams: int = 1, length_penalty: float = 1.0):
         self.model, self.config = model, config
+        self.kv_heads = cache_kv_heads(config)
         self.L, self.K = max_new_tokens, num_beams
         self.length_penalty = length_penalty
         self.eos, self.pad = config.eos_token_id, config.pad_token_id
@@ -131,7 +133,7 @@ class Seq2SeqGenerator:
             enc_rep = jnp.repeat(enc, self.K, axis=0)
             mask_rep = jnp.repeat(attention_mask, self.K, axis=0)
             cache = constrain_cache(
-                _init_cache(self.model, params, B * self.K, self.L, enc_rep, mask_rep)
+                _init_cache(self.model, params, B * self.K, self.L, enc_rep, mask_rep), self.kv_heads
             )
             return {
                 "t": t0,
@@ -143,7 +145,7 @@ class Seq2SeqGenerator:
                 "state": _beam_init(B, self.K, self.L, self.pad),
             }
         cache = constrain_cache(
-            _init_cache(self.model, params, B, self.L, enc, attention_mask)
+            _init_cache(self.model, params, B, self.L, enc, attention_mask), self.kv_heads
         )
         return {
             "t": t0,
@@ -171,7 +173,7 @@ class Seq2SeqGenerator:
             method="decode",
             mutable=["cache"],
         )
-        cache = constrain_cache(mut["cache"])
+        cache = constrain_cache(mut["cache"], self.kv_heads)
         if self.K > 1:
             logp = jax.nn.log_softmax(logits[:, -1].astype(jnp.float32), axis=-1)  # (B*K, V)
             V = logp.shape[-1]
@@ -279,8 +281,9 @@ def _causal_prefill(
     B, P = input_ids.shape
     if cache_shapes is None:
         cache_shapes = causal_cache_shapes(model, params, B, P + new_tokens)
+    kv_heads = cache_kv_heads(model.config)
     cache = constrain_cache(
-        jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), cache_shapes)
+        jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), cache_shapes), kv_heads
     )
     full_mask = jnp.concatenate([attention_mask, jnp.zeros((B, new_tokens), jnp.int32)], axis=1)
     lengths = jnp.sum(attention_mask, axis=1).astype(jnp.int32)
@@ -294,7 +297,7 @@ def _causal_prefill(
         mutable=["cache"],
     )
     first = jnp.take_along_axis(logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    return constrain_cache(mut["cache"]), full_mask, lengths, first
+    return constrain_cache(mut["cache"], kv_heads), full_mask, lengths, first
 
 
 class CausalGenerator:
@@ -309,6 +312,7 @@ class CausalGenerator:
     def __init__(self, model: Any, config: Any, max_new_tokens: int,
                  num_beams: int = 1, length_penalty: float = 1.0):
         self.model, self.config = model, config
+        self.kv_heads = cache_kv_heads(config)
         self.L, self.K = max_new_tokens, num_beams
         self.length_penalty = length_penalty
         self.eos, self.pad = config.eos_token_id, config.pad_token_id
@@ -322,7 +326,7 @@ class CausalGenerator:
             logp0 = jax.nn.log_softmax(first.astype(jnp.float32), axis=-1)  # (B, V)
             # beams share the prefilled prompt: replicate cache rows K-ways
             cache = constrain_cache(
-                jax.tree.map(lambda x: jnp.repeat(x, self.K, axis=0) if x.ndim > 0 else x, cache)
+                jax.tree.map(lambda x: jnp.repeat(x, self.K, axis=0) if x.ndim > 0 else x, cache), self.kv_heads
             )
             full_mask = jnp.repeat(full_mask, self.K, axis=0)  # (B*K, width)
             lengths_rep = jnp.repeat(lengths, self.K, axis=0)  # (B*K,)
@@ -375,7 +379,7 @@ class CausalGenerator:
                 logp, t, carry["state"], eos=self.eos, K=self.K,
                 length_penalty=self.length_penalty, len_offset=P - 1,
             )
-            cache = _gather_beams(constrain_cache(mut["cache"]), parents, B, self.K)
+            cache = _gather_beams(constrain_cache(mut["cache"], self.kv_heads), parents, B, self.K)
             return {
                 **carry,
                 "t": t + 1,
@@ -400,7 +404,7 @@ class CausalGenerator:
         return {
             **carry,
             "t": t + 1,
-            "cache": constrain_cache(mut["cache"]),
+            "cache": constrain_cache(mut["cache"], self.kv_heads),
             "full_mask": full_mask,
             "last": nxt,
             "out": out,

@@ -143,78 +143,20 @@ class T5Attention(nn.Module):
     @nn.compact
     def _cache_kv(self, key: jnp.ndarray, value: jnp.ndarray,
                   cache_positions: jnp.ndarray | None = None) -> tuple:
-        """Append this step's k/v into the cache; returns full-length k/v,
-        the int8-KV scales (None on the f32 path) and the (pre-update)
-        cache index.  ``cache_positions`` (B,) switches to per-row writes
-        (continuous-batching slots at distinct offsets; q_len must be 1,
-        out-of-range positions drop — idle slots park there).  Under
-        ``kv_cache_context("int8")`` the buffers are s8 with per-head
-        per-position scale leaves, exactly like
-        ``MultiHeadAttention._cache_kv``."""
-        from distributed_llms_example_tpu.ops.flash_attention import quantize_kv
-        from distributed_llms_example_tpu.parallel.activation import (
-            current_kv_cache_dtype,
-        )
+        """Append this step's k/v ((B, heads, T, d_kv)) into the cache:
+        ``ops.mha.cache_kv``, the package's one cache layout (a leaf is
+        ``(batch, length, heads x d_kv)``, written and read where it rests).
+        Returns the full-length leaves, the int8-KV scales (None on the f32
+        path) and the (pre-update) cache index.  Per-row ``cache_positions``
+        (continuous-batching slots at distinct offsets) take one q row here:
+        the decode-step relative bias is built for one."""
+        from distributed_llms_example_tpu.ops.mha import cache_kv
 
-        # At creation time (init with full-length dummy inputs) the buffers
-        # are allocated but NOT written: cache_index must stay 0 so the first
-        # real decode step writes at position 0.
-        int8_kv = current_kv_cache_dtype() == "int8"
-        store_dtype = jnp.int8 if int8_kv else key.dtype
-        is_initialized = self.has_variable("cache", "cached_key")
-        cached_k = self.variable("cache", "cached_key", jnp.zeros, key.shape, store_dtype)
-        cached_v = self.variable("cache", "cached_value", jnp.zeros, value.shape, store_dtype)
-        if int8_kv:
-            k_scale = self.variable(
-                "cache", "key_scale", jnp.zeros, key.shape[:3], jnp.float32
+        if cache_positions is not None and key.shape[2] != 1:
+            raise ValueError(
+                f"per-row cache_positions requires q_len == 1, got {key.shape[2]}"
             )
-            v_scale = self.variable(
-                "cache", "value_scale", jnp.zeros, value.shape[:3], jnp.float32
-            )
-        cache_index = self.variable("cache", "cache_index", lambda: jnp.array(0, dtype=jnp.int32))
-        idx = cache_index.value
-        if is_initialized:
-            if int8_kv:
-                key, ks_new = quantize_kv(key)
-                value, vs_new = quantize_kv(value)
-            if cache_positions is not None:
-                if key.shape[2] != 1:
-                    raise ValueError(
-                        f"per-row cache_positions requires q_len == 1, got {key.shape[2]}"
-                    )
-                b = jnp.arange(key.shape[0])
-                k = cached_k.value.at[b, :, cache_positions].set(
-                    key[:, :, 0, :], mode="drop"
-                )
-                v = cached_v.value.at[b, :, cache_positions].set(
-                    value[:, :, 0, :], mode="drop"
-                )
-                cached_k.value, cached_v.value = k, v
-                if int8_kv:
-                    k_scale.value = k_scale.value.at[b, :, cache_positions].set(
-                        ks_new[:, :, 0], mode="drop"
-                    )
-                    v_scale.value = v_scale.value.at[b, :, cache_positions].set(
-                        vs_new[:, :, 0], mode="drop"
-                    )
-            else:
-                # buffers are stored (batch, heads, max_len, head_dim); write at idx on axis 2
-                k = jax.lax.dynamic_update_slice(cached_k.value, key, (0, 0, idx, 0))
-                v = jax.lax.dynamic_update_slice(cached_v.value, value, (0, 0, idx, 0))
-                cached_k.value, cached_v.value = k, v
-                if int8_kv:
-                    k_scale.value = jax.lax.dynamic_update_slice(
-                        k_scale.value, ks_new, (0, 0, idx)
-                    )
-                    v_scale.value = jax.lax.dynamic_update_slice(
-                        v_scale.value, vs_new, (0, 0, idx)
-                    )
-                cache_index.value = idx + key.shape[2]
-        else:
-            k, v = cached_k.value, cached_v.value
-        if int8_kv:
-            return k, v, k_scale.value, v_scale.value, idx
-        return k, v, None, None, idx
+        return cache_kv(self, key, value, cache_positions)
 
     def __call__(
         self,
@@ -260,13 +202,14 @@ class T5Attention(nn.Module):
             )
             from distributed_llms_example_tpu.ops.mha import (
                 _log_impl_once,
+                cache_heads_view,
                 decode_step_bias,
                 select_decode_impl,
             )
             from distributed_llms_example_tpu.parallel.activation import current_mesh
 
             k, v, k_scale, v_scale, idx = self._cache_kv(k, v, cache_positions)
-            kv_len = k.shape[2]
+            kv_len = k.shape[1]
             q_len = q.shape[2]
             offsets = (
                 cache_positions
@@ -284,6 +227,7 @@ class T5Attention(nn.Module):
                 mesh=mesh,
                 backend=jax.default_backend(),
                 device_count=jax.device_count(),
+                kv_dtype=k.dtype,
             )
             if (
                 impl == "flash_decode"
@@ -305,15 +249,8 @@ class T5Attention(nn.Module):
                     dtype=self.dtype,
                 )
                 return self.o_proj(self._merge(out))
-            if k_scale is not None:
-                # the XLA fallback dequantizes through the IDENTICAL
-                # expression the kernel evaluates per tile
-                from distributed_llms_example_tpu.ops.flash_attention import (
-                    dequantize_kv,
-                )
-
-                k = dequantize_kv(k, k_scale)
-                v = dequantize_kv(v, v_scale)
+            # XLA's path views the leaf as (B, heads, L, d_kv) inside the program
+            k, v = cache_heads_view(k, v, k_scale, v_scale, self.config.num_heads)
             # XLA path: per-row validity+causality mask merged into the bias
             step_bias = decode_step_bias(offsets, q_len, kv_len)
             bias = step_bias if bias is None else bias + step_bias

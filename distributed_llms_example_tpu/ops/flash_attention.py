@@ -906,7 +906,7 @@ def flash_supported(q_len: int, kv_len: int, head_dim: int,
 # int8 KV (--kv-cache-dtype int8): the cache buffers arrive as s8 with
 # per-head per-position f32 scales (quantize_kv below — THE owning
 # quantize/dequantize implementation, guarded by repo_lint rule 10).
-# The kernel dequantizes each (block_k, d) tile in VMEM right after the
+# The kernel dequantizes each (block_k, heads x d) tile in VMEM right after the
 # DMA, so HBM traffic and cache footprint are both s8 while the MXU math
 # stays f32 — the XLA fallback path dequantizes through the identical
 # dequantize_kv expression, which is what keeps the two paths
@@ -937,9 +937,10 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
     return q.astype(jnp.float32) * scale[..., None]
 
 
-# K and V blocks of one grid step, double-buffered by the pipeline: half of
-# the 16 MB a kernel may hold in VMEM by default; the rest is q, bias,
-# output, scratch and the step's own temporaries
+# K and V blocks of one grid step, double-buffered by the pipeline, and the q
+# block, the output and the fp32 accumulator beside them: half of the 16 MB a
+# kernel may hold in VMEM by default; the rest is bias, statistics and the
+# step's own temporaries
 DECODE_STEP_VMEM_BUDGET = 8 * 2**20
 
 
@@ -952,64 +953,142 @@ def _vmem_tile_bytes(rows: int, cols: int, itemsize: int) -> int:
 
 def decode_step_heads(
     heads: int, block_k: int, head_dim: int, itemsize: int,
-    *, int8_scales: bool = False,
+    *, q_len: int = 1, int8_scales: bool = False,
 ) -> int:
     """Heads of a cache slot that one grid step of ``flash_decode`` covers,
     from what the call's shapes say.
 
-    All of them when their K and V tiles fit ``DECODE_STEP_VMEM_BUDGET``
-    double-buffered, else the largest divisor of ``heads`` that does — down
-    to one head a step, the tiling before PR 26, when nothing larger fits.
-    Under ``int8_scales`` the tiles count as the f32 the step dequantizes
-    them to, and a split keeps 8 heads together: the (B, H, L) scales'
-    block has the head axis second to last, where the chip wants 8 rows or
-    the whole axis.  No such group fitting is an error here, not a Mosaic
-    failure far from its cause."""
-    head_bytes = 2 * _vmem_tile_bytes(block_k, head_dim, 4 if int8_scales else itemsize)
-    head_multiple = 8 if int8_scales else 1
-    fits = [
-        h for h in range(1, heads + 1)
-        if heads % h == 0 and 2 * h * head_bytes <= DECODE_STEP_VMEM_BUDGET
-        and (h % head_multiple == 0 or h == heads)
+    A K/V tile is (block_k, heads x head_dim): the heads lie side by side on
+    the lanes, as the cache leaf keeps them.  All of them when K and V tiles
+    (double-buffered) and the block-diagonal q block with its fp32
+    accumulator fit ``DECODE_STEP_VMEM_BUDGET``, else the largest divisor of
+    ``heads`` that does and whose lanes are whole vregs (a multiple of 128:
+    the chip takes a lane block only so, or whole).  Under ``int8_scales``
+    the tiles count as the f32 the step dequantizes them to, and the (B, L,
+    H) scales ride every step whole, padded to 128 lanes (their heads lie on
+    the lanes, where a part of them is no block: the step picks its group's
+    out of all, ``_head_lanes``).  Nothing fitting is an error here, not a
+    Mosaic failure far from its cause; ``decode_step_fits`` asks first."""
+    def fits(h: int) -> bool:
+        lanes, rows = h * head_dim, h * q_len
+        wide = 4 if int8_scales else itemsize  # q is never s8: count it as the f32 it may be
+        kv = 2 * 2 * _vmem_tile_bytes(block_k, lanes, wide)
+        scales = 2 * 2 * _vmem_tile_bytes(block_k, heads, 4) if int8_scales else 0
+        q_acc = 2 * _vmem_tile_bytes(rows, lanes, wide) + _vmem_tile_bytes(rows, lanes, 4)
+        return kv + scales + q_acc <= DECODE_STEP_VMEM_BUDGET
+
+    groups = [
+        h for h in range(heads, 0, -1)
+        if heads % h == 0 and (h == heads or (h * head_dim) % LANES == 0) and fits(h)
     ]
-    if fits:
-        return max(fits)
-    if int8_scales:
+    if not groups:
         raise ValueError(
-            f"flash_decode: int8 K/V with {heads} heads, kv tile {block_k} x "
-            f"{head_dim}: neither all heads nor a group of 8 fits the step's "
-            f"VMEM budget ({DECODE_STEP_VMEM_BUDGET} bytes, tiles counted as "
-            "f32); pass a smaller block_k or take the XLA path"
+            f"flash_decode: {heads} heads of {head_dim}, kv tile {block_k}, "
+            f"{q_len} q rows{', int8 K/V (tiles counted as f32)' if int8_scales else ''}: "
+            f"no group of heads with whole 128-lane tiles fits the step's VMEM "
+            f"budget ({DECODE_STEP_VMEM_BUDGET} bytes); pass a smaller block_k "
+            "or take the XLA path"
         )
-    return 1
+    return groups[0]
+
+
+def decode_step_fits(
+    heads: int, kv_len: int, head_dim: int, itemsize: int,
+    *, q_len: int = 1, int8_scales: bool = False,
+) -> bool:
+    """Whether ``flash_decode`` finds a group of heads for this cache at its
+    own kv tile (``decode_block``): what ``select_decode_impl`` asks before
+    it sends a step to the kernel, so that a shape no group fits takes XLA's
+    path and does not raise while the program is traced."""
+    try:
+        decode_step_heads(
+            heads, decode_block(kv_len), head_dim, itemsize,
+            q_len=q_len, int8_scales=int8_scales,
+        )
+    except ValueError:
+        return False
+    return True
+
+
+def decode_q_rows(q: jnp.ndarray, step_heads: int) -> jnp.ndarray:
+    """The decode kernel's q operand: (B, H, Q, d) query rows laid
+    block-diagonally over the cache leaf's merged (heads x head_dim) axis,
+    a group of ``step_heads`` heads at a time: (B, H / step_heads,
+    step_heads x Q, step_heads x d), row (h, r) holding q[h, r] on head h's
+    lanes and zeros on the others'.  One product of it against a (block_k,
+    step_heads x d) K tile is then every head's scores, each from its own
+    lanes, with no tile relaid per head; the zeros cost multiply-adds the
+    MXU has to spare at a handful of rows (a K tile is its stationary
+    operand either way)."""
+    b, heads, q_len, d = q.shape
+    g = heads // step_heads
+    eye = jnp.eye(step_heads, dtype=q.dtype)[None, None, :, None, :, None]
+    rows = q.reshape(b, g, step_heads, q_len, 1, d) * eye
+    return rows.reshape(b, g, step_heads * q_len, step_heads * d)
+
+
+def _decode_bias_rows(bias, heads: int, q_len: int, step_heads: int):
+    """A (b|1, h|1, q|1, k|1) additive bias as the kernel's rows see it:
+    (b|1, H / step_heads | 1, step_heads x Q | 1, k|1), row (h, r) of a head
+    group the bias of head h, q row r."""
+    b, h, q, k = bias.shape
+    if h == 1 and q == 1:
+        return bias
+    full = jnp.broadcast_to(bias, (b, heads, q_len, k))
+    return full.reshape(b, heads // step_heads, step_heads * q_len, k)
+
+
+def _head_lanes(x, head_dim: int, heads: int, first_head):
+    """(n, H) per-head values of which a step holds ``heads``, from
+    ``first_head`` on, spread over the step's merged axis: (n, heads x
+    head_dim), each repeated over its head's lanes.  A product with a 0/1
+    selection at the highest precision, which is exact (one term a result)
+    and is the lane broadcast the chip's compiler takes at any head_dim;
+    the selection is also how a group of heads is taken out of all (the
+    scales' lanes are no block of their own)."""
+    shape = (x.shape[-1], heads * head_dim)
+    sel = (
+        jax.lax.broadcasted_iota(jnp.int32, shape, 1) // head_dim + first_head
+        == jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    ).astype(x.dtype)
+    return jax.lax.dot_general(
+        x, sel, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )
 
 
 def _decode_tile(q, k, v, k_scale, v_scale, bias, valid, m_prev, l_prev, acc,
-                 *, scale: float):
+                 *, scale: float, head_dim: int, first_head=0):
     """One kv tile's online-softmax update for a group of heads — the
     arithmetic ``_decode_kernel`` and ``_decode_paged_kernel`` share, which
     is what keeps them bit-identical at equal tiling.
 
-    ``q``: (h, q_len, d); ``k``/``v``: (h, block_k, d), s8 with
-    ``k_scale``/``v_scale`` (h, block_k) under int8 KV; ``bias``: None or
-    broadcastable to (h, q_len, block_k); ``valid``: (q_len, block_k) bool,
-    the per-row length mask; ``m_prev``/``l_prev``: (h, q_len, 1) running
-    max and denominator, ``acc``: (h, q_len, d).  The heads are one batched
-    contraction: sixteen one-row products a slot are latency, not work, and
-    batched they overlap (v5e, PR 26: 0.90 ms for twelve calls at 64 slots
-    x 16 heads x cache 128, against 1.91 ms as an unrolled loop of 2-D
-    products).  Returns the new (m, l, acc)."""
+    ``q``: (h x q_len, h x d), block-diagonal (``decode_q_rows``);
+    ``k``/``v``: (block_k, h x d), the tile as the cache leaf holds it, s8
+    with ``k_scale``/``v_scale`` (block_k, H) under int8 KV, the scales of
+    all H heads of the slot, of which this group starts at ``first_head``;
+    ``bias``: None
+    or broadcastable to (h x q_len, block_k); ``valid``: (h x q_len,
+    block_k) bool, the per-row length mask; ``m_prev``/``l_prev``: (h x
+    q_len, 1) running max and denominator, ``acc``: (h x q_len, h x d), of
+    which row (h, r) means its own head's lanes (the others hold p of head h
+    times V of another head, finite and never read).  The heads are one
+    product: sixteen one-row products a slot are latency, not work (v5e,
+    PR 26: 0.90 ms batched against 1.91 ms as a loop of 2-D products).
+    Returns the new (m, l, acc)."""
     if k_scale is not None:
         # dequantize the tile in VMEM: HBM moved 1 byte/elem, the MXU sees
-        # f32 — the XLA fallback's own expression
-        k, v = dequantize_kv(k, k_scale), dequantize_kv(v, v_scale)
+        # f32 — ``dequantize_kv``'s expression with the scale on its lanes
+        heads = k.shape[-1] // head_dim
+        k = k.astype(jnp.float32) * _head_lanes(k_scale, head_dim, heads, first_head)
+        v = v.astype(jnp.float32) * _head_lanes(v_scale, head_dim, heads, first_head)
     s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
     s *= scale
     if bias is not None:
         s += bias.astype(jnp.float32)
-    s = jnp.where(valid[None], s, -jnp.inf)
+    s = jnp.where(valid, s, -jnp.inf)
     m_cur = jnp.max(s, axis=-1, keepdims=True)
     m_next = jnp.maximum(m_prev, m_cur)
     safe_m = jnp.where(m_next == -jnp.inf, 0.0, m_next)
@@ -1017,37 +1096,82 @@ def _decode_tile(q, k, v, k_scale, v_scale, bias, valid, m_prev, l_prev, acc,
     p = jnp.exp(s - safe_m)
     l_next = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
     pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     return m_next, l_next, acc * alpha + pv
 
 
-def _decode_valid(offset, ki, q_len: int, block_k: int, q_group: int = 1):
-    """(q_len, block_k) bottom-right aligned length mask of kv tile ``ki``:
-    q row r sits at absolute position offset + r // q_group (``q_group``
-    rows a position: the query heads that share a KV head) and may attend
-    cache slots <= its own."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (q_len, block_k), 0)
-    q_pos = offset + (row if q_group == 1 else row // q_group)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (q_len, block_k), 1)
+def _decode_valid(offset, ki, rows: int, q_len: int, block_k: int, q_group: int = 1):
+    """(rows, block_k) bottom-right aligned length mask of kv tile ``ki``:
+    row (h, r) of the step's heads (``rows`` = heads x ``q_len``) sits at
+    absolute position offset + r // q_group (``q_group`` rows a position:
+    the query heads that share a KV head) and may attend cache slots <= its
+    own."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 0) % q_len
+    q_pos = offset + (r if q_group == 1 else r // q_group)
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 1)
     return q_pos >= k_pos
 
 
-def _decode_bias_spec(bias_shape, hb: int, q_len: int, block_k: int):
-    """BlockSpec of a decode step's additive bias, every dim 1 or full: the
-    step's slot x heads x q rows x kv tile where the bias has them.  The
-    grid is (slot, head group, kv tile), whatever scalar-prefetch refs
-    follow."""
-    b1, h1, q1, k1 = (n == 1 for n in bias_shape)
+def _decode_bias_spec(bias_shape, rows: int, block_k: int):
+    """BlockSpec of a decode step's additive bias (``_decode_bias_rows``),
+    every dim 1 or full: the step's slot x head group x rows x kv tile where
+    the bias has them.  The grid is (slot, head group, kv tile), whatever
+    scalar-prefetch refs follow."""
+    b1, g1, r1, k1 = (n == 1 for n in bias_shape)
 
-    def index_map(b, h, ki, *_):
-        return (0 if b1 else b, 0 if h1 else h, 0, 0 if k1 else ki)
+    def index_map(b, g, ki, *_):
+        return (0 if b1 else b, 0 if g1 else g, 0, 0 if k1 else ki)
 
     return pl.BlockSpec(
-        (1, 1 if h1 else hb, 1 if q1 else q_len, 1 if k1 else block_k),
-        index_map,
+        (1, 1, 1 if r1 else rows, 1 if k1 else block_k), index_map,
     )
+
+
+def _decode_update(group, ki, offset, allocated, refs, *, scale, block_k, nk, q_group):
+    """What both decode kernels do with one grid step's blocks: start the
+    statistics on the first kv tile, fold the tile in where it is live
+    (``allocated``: None, or the paged kernel's "this tile has a block"),
+    and on the last tile hand each head its own lanes of the accumulator.
+    ``group`` is the step's head group, which only the int8 scales need: they
+    come whole, and a group that is not all heads picks its own."""
+    q_ref, k_ref, v_ref, ks_ref, vs_ref, bias_ref, o_ref, m_scr, l_scr, acc_scr = refs
+    _, hb, q_len, d = o_ref.shape  # the step's output block: slot x heads x q rows x d
+    first_head = 0 if ks_ref is None or ks_ref.shape[-1] == hb else group * hb
+    # every live position of this slot's tile is <= offset + q_len - 1:
+    # tiles past that contribute nothing — skip their DMA'd compute
+    live = ki * block_k <= offset + q_len - 1
+    if allocated is not None:
+        live = jnp.logical_and(live, allocated)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[:] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when(live)
+    def _compute():
+        m, l, acc = _decode_tile(
+            q_ref[0, 0], k_ref[0], v_ref[0],
+            None if ks_ref is None else ks_ref[0],
+            None if vs_ref is None else vs_ref[0],
+            None if bias_ref is None else bias_ref[0, 0],
+            _decode_valid(offset, ki, hb * q_len, q_len, block_k, q_group),
+            m_scr[:, :1], l_scr[:, :1], acc_scr[:],
+            scale=scale, head_dim=d, first_head=first_head,
+        )
+        m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l, l_scr.shape)
+        acc_scr[:] = acc
+
+    @pl.when(ki == nk - 1)
+    def _finish():
+        l = l_scr[:, :1]
+        out = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        for h in range(hb):  # a head's rows, its own lanes (the loop is what a trace of the kernel pays for most)
+            o_ref[0, h] = jax.lax.slice(out, (h * q_len, h * d), ((h + 1) * q_len, (h + 1) * d))
 
 
 def _decode_kernel(
@@ -1060,39 +1184,25 @@ def _decode_kernel(
     ks_ref = next(it) if has_scales else None
     vs_ref = next(it) if has_scales else None
     bias_ref = next(it) if has_bias else None
-    o_ref, m_scr, l_scr, acc_scr = it
-    ki = pl.program_id(2)
-    q_len = q_ref.shape[2]  # the step's blocks: one slot x a group of heads
-    offset = off_ref[pl.program_id(0)]
+    _decode_update(
+        pl.program_id(1), pl.program_id(2), off_ref[pl.program_id(0)], None,
+        (q_ref, k_ref, v_ref, ks_ref, vs_ref, bias_ref, *it),
+        scale=scale, block_k=block_k, nk=nk, q_group=q_group,
+    )
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
-        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    # every live position of this slot's tile is <= offset + q_len - 1:
-    # tiles past that contribute nothing — skip their DMA'd compute
-    @pl.when(ki * block_k <= offset + q_len - 1)
-    def _compute():
-        m, l, acc = _decode_tile(
-            q_ref[0], k_ref[0], v_ref[0],
-            None if ks_ref is None else ks_ref[0],
-            None if vs_ref is None else vs_ref[0],
-            None if bias_ref is None else bias_ref[0],
-            _decode_valid(offset, ki, q_len, block_k, q_group),
-            m_scr[:, :, :1], l_scr[:, :, :1], acc_scr[:],
-            scale=scale,
-        )
-        m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l, l_scr.shape)
-        acc_scr[:] = acc
+def _check_decode_bias(bias, batch, heads, q_len, kv_len):
+    for i, (bd, full) in enumerate(zip(bias.shape, (batch, heads, q_len, kv_len))):
+        if bd not in (1, full):
+            raise ValueError(f"bias dim {i} is {bd}, must be 1 or {full}")
 
-    @pl.when(ki == nk - 1)
-    def _finish():
-        l = l_scr[:, :, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+
+def _decode_scratch(hb: int, q_len: int, d: int):
+    return [
+        pltpu.VMEM((hb * q_len, LANES), jnp.float32),
+        pltpu.VMEM((hb * q_len, LANES), jnp.float32),
+        pltpu.VMEM((hb * q_len, hb * d), jnp.float32),
+    ]
 
 
 def flash_decode(
@@ -1113,19 +1223,25 @@ def flash_decode(
     """Decode-step attention: a short q block against a cached K/V buffer.
 
     ``q``: (B, H, Q, d) with Q the decode step width (1 for token-by-token
-    decode; beam batches flatten beams into B).  ``k``/``v``: (B, H, L, d)
-    full-length cache buffers.  ``offsets``: (B,) int32 — the absolute
-    cache position of each row's FIRST query; row r of the q block attends
-    cache slots <= offsets[b] + r, so not-yet-written slots never
-    contribute regardless of their (stale, reused) contents.  ``bias`` is
-    a constant additive mask, every dim 1 or full — the padding mask /
-    T5's decode-step relative-position bias.  ``k_scale``/``v_scale``
-    ((B, H, L) f32, both or neither): the int8 KV cache's per-head
+    decode; beam batches flatten beams into B).  ``k``/``v``: (B, L, H x d)
+    full-length cache buffers AS THE CACHE KEEPS THEM (``ops/mha.py``
+    ``_cache_kv``): a position's heads side by side, head_dim minor, which
+    is what ``k_proj`` returns and what XLA's row write fills, so the
+    buffer rests row-major between the two and no step relays it (PR 32;
+    before, (B, H, L, d) was copied three times a leaf a round).
+    ``offsets``: (B,) int32 — the absolute cache position of each row's
+    FIRST query; row r of the q block attends cache slots <= offsets[b] +
+    r, so not-yet-written slots never contribute regardless of their
+    (stale, reused) contents.  ``bias`` is a constant additive mask
+    (b|1, h|1, q|1, L|1), every dim 1 or full — the padding mask / T5's
+    decode-step relative-position bias.  ``k_scale``/``v_scale``
+    ((B, L, H) f32, both or neither): the int8 KV cache's per-head
     per-position scales — ``k``/``v`` are then s8 and each kv tile is
     dequantized in VMEM after the DMA, so decode HBM traffic drops ~4×
-    vs f32 buffers.  Inference only (no vjp); numerically identical to
-    masked ``dot_product_attention`` on the same (dequantized) inputs
-    (the parity tests pin greedy and beam decode against it).
+    vs f32 buffers.  Returns (B, H, Q, d).  Inference only (no vjp);
+    numerically identical to masked ``dot_product_attention`` on the same
+    (dequantized) inputs (the parity tests pin greedy and beam decode
+    against it).
 
     ``q_group`` > 1 is grouped-query attention without a repeated cache:
     H counts the KV heads, and the q block holds, position by position, the
@@ -1136,85 +1252,98 @@ def flash_decode(
     One grid step streams one kv tile of ALL heads of a cache slot (of
     fewer when they do not fit VMEM: ``decode_step_heads``, from the shapes
     alone), so the grid is (B, H / heads, L / block_k) — (64, 1, 1) at
-    bart-large-cnn's serving shape, 64 slots x 16 heads x cache 128 x d
-    64, where a step per (slot, head) had spent 0.51 us on 2 x 16 KB
-    (v5e, PR 26: twelve calls 6.96 -> 0.90 ms).  Per head the online
-    softmax, its order over kv tiles, the per-row mask, the dead-tile skip
-    and the fp32 accumulation are what they were.
+    bart-large-cnn's serving shape, 64 slots x cache 128 x 16 heads of 64.
+    The q rows are laid block-diagonally over the merged axis
+    (``decode_q_rows``), so one product a tile serves every head from its
+    own lanes.  Per head the online softmax, its order over kv tiles, the
+    per-row mask, the dead-tile skip and the fp32 accumulation are what
+    they were.
     """
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
     batch, heads, q_len, d = q.shape
-    kv_len = k.shape[2]
+    if scale is None:
+        scale = d ** -0.5
+    kv_len = k.shape[1]
+    if k.shape != (batch, kv_len, heads * d) or v.shape != k.shape:
+        raise ValueError(
+            f"k/v {k.shape}/{v.shape}: the cache leaf is (batch, length, heads x "
+            f"head_dim) = ({batch}, L, {heads * d}) for q {q.shape}"
+        )
     block_k = decode_block(kv_len) if block_k is None else min(block_k, kv_len)
     if not block_k or kv_len % block_k or block_k % 8:
         raise ValueError(
             f"kv_len {kv_len} not divisible into 8-aligned blocks ({block_k})"
         )
     if bias is not None:
-        for i, (bd, full) in enumerate(
-            zip(bias.shape, (batch, heads, q_len, kv_len))
-        ):
-            if bd not in (1, full):
-                raise ValueError(f"bias dim {i} is {bd}, must be 1 or {full}")
+        _check_decode_bias(bias, batch, heads, q_len, kv_len)
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
     has_scales = k_scale is not None
     if has_scales:
-        want = (batch, heads, kv_len)
+        want = (batch, kv_len, heads)
         for name, s in (("k_scale", k_scale), ("v_scale", v_scale)):
             if tuple(s.shape) != want:
                 raise ValueError(f"{name} shape {tuple(s.shape)} != {want}")
     if interpret is None:
         interpret = _default_interpret()
-    offsets = jnp.asarray(offsets, jnp.int32).reshape(batch)
-    nk = kv_len // block_k
     hb = decode_step_heads(
-        heads, block_k, d, k.dtype.itemsize, int8_scales=has_scales
+        heads, block_k, d, k.dtype.itemsize, q_len=q_len, int8_scales=has_scales
     )
-    grid = (batch, heads // hb, nk)
+    return _decode_call(
+        jnp.asarray(offsets, jnp.int32).reshape(batch), q, k, v, k_scale, v_scale, bias,
+        scale=float(scale), block_k=block_k, step_heads=hb, q_group=q_group,
+        interpret=bool(interpret), dtype=dtype,
+    )
 
-    def q_map(b, h, ki):
-        return (b, h, 0, 0)
 
-    def kv_map(b, h, ki):
-        return (b, h, ki, 0)
+@functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("scale", "block_k", "step_heads", "q_group", "interpret", "dtype"),
+)
+def _decode_call(offsets, q, k, v, k_scale, v_scale, bias, *, scale: float,
+                 block_k: int, step_heads: int, q_group: int, interpret: bool, dtype):
+    """``flash_decode``'s program on checked operands, every choice made from
+    the shapes passed in as a static.  Jitted so that a decode program's
+    call sites of one shape (twelve layers) trace the kernel once, and
+    inlined so that each stays an operation of its own call site (the
+    custom call keeps the site's name)."""
+    batch, heads, q_len, d = q.shape
+    hb, nk = step_heads, k.shape[1] // block_k
+    has_scales = k_scale is not None
 
-    def scale_map(b, h, ki):
-        return (b, h, ki)
+    def q_map(b, g, ki):
+        return (b, g, 0, 0)
+
+    def kv_map(b, g, ki):
+        return (b, ki, g)
 
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),  # offsets, whole array
-        pl.BlockSpec((1, hb, q_len, d), q_map),
-        pl.BlockSpec((1, hb, block_k, d), kv_map),
-        pl.BlockSpec((1, hb, block_k, d), kv_map),
+        pl.BlockSpec((1, 1, hb * q_len, hb * d), q_map),
+        pl.BlockSpec((1, block_k, hb * d), kv_map),
+        pl.BlockSpec((1, block_k, hb * d), kv_map),
     ]
-    if has_scales:
-        in_specs += [
-            pl.BlockSpec((1, hb, block_k), scale_map),
-            pl.BlockSpec((1, hb, block_k), scale_map),
-        ]
+    if has_scales:  # all heads' scales a step: a part of their lanes is no block
+        in_specs += [pl.BlockSpec((1, block_k, heads), lambda b, g, ki: (b, ki, 0))] * 2
     if bias is not None:
-        in_specs.append(_decode_bias_spec(bias.shape, hb, q_len, block_k))
+        bias = _decode_bias_rows(bias, heads, q_len, hb)
+        in_specs.append(_decode_bias_spec(bias.shape, hb * q_len, block_k))
     out = pl.pallas_call(
         functools.partial(
-            _decode_kernel, scale=float(scale), block_k=block_k, nk=nk,
+            _decode_kernel, scale=scale, block_k=block_k, nk=nk,
             has_bias=bias is not None, has_scales=has_scales, q_group=q_group,
         ),
-        grid=grid,
+        grid=(batch, heads // hb, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hb, q_len, d), q_map),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((hb, q_len, LANES), jnp.float32),
-            pltpu.VMEM((hb, q_len, LANES), jnp.float32),
-            pltpu.VMEM((hb, q_len, d), jnp.float32),
-        ],
+        scratch_shapes=_decode_scratch(hb, q_len, d),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(offsets, *[x for x in (q, k, v, k_scale, v_scale, bias) if x is not None])
+    )(offsets, *[
+        x for x in (decode_q_rows(q, hb), k, v, k_scale, v_scale, bias) if x is not None
+    ])
     return out if dtype is None else out.astype(dtype)
 
 
@@ -1248,12 +1377,12 @@ def flash_decode_supported(
 #
 # The paged-cache twin of flash_decode (serving/cache_pool.py owns the
 # pool/allocator; this kernel is the device half): K/V live in a SHARED
-# block pool of (num_blocks, H, block_size, d) and each slot maps its
+# block pool of (num_blocks, block_size, H x d) and each slot maps its
 # logical kv tiles onto pool blocks through a per-slot block table.  The
 # block size IS the kv tile size, so the kernel's tile loop indexes pool
 # blocks directly — the block table rides scalar prefetch and the
 # BlockSpec index maps read it, meaning the DMA fetches exactly the
-# slot's blocks and a flat (slots, H, L, d) view never exists anywhere.
+# slot's blocks and a flat (slots, L, H x d) view never exists anywhere.
 # A sentinel entry (>= num_blocks: an unallocated logical tile) clamps to
 # a valid block for the DMA and is masked to -inf in-kernel, so whatever
 # the clamped block holds contributes exactly nothing.
@@ -1269,45 +1398,17 @@ def _decode_paged_kernel(
     ks_ref = next(it) if has_scales else None
     vs_ref = next(it) if has_scales else None
     bias_ref = next(it) if has_bias else None
-    o_ref, m_scr, l_scr, acc_scr = it
     bi = pl.program_id(0)
     ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
-        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
-
-    offset = off_ref[bi]
-    q_len = q_ref.shape[2]
     # dead-tile skip as in _decode_kernel, plus: a sentinel block-table
-    # entry is an unallocated tile — nothing of it may contribute
-    allocated = bt_ref[bi, ki] < num_blocks
-    live = jnp.logical_and(ki * block_k <= offset + q_len - 1, allocated)
-
-    @pl.when(live)
-    def _compute():
-        # one pool block's heads, through the flat kernel's own tile
-        # arithmetic on the same group of heads: bit-identity rests on it
-        m, l, acc = _decode_tile(
-            q_ref[0], k_ref[0], v_ref[0],
-            None if ks_ref is None else ks_ref[0],
-            None if vs_ref is None else vs_ref[0],
-            None if bias_ref is None else bias_ref[0],
-            _decode_valid(offset, ki, q_len, block_k),
-            m_scr[:, :, :1], l_scr[:, :, :1], acc_scr[:],
-            scale=scale,
-        )
-        m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l, l_scr.shape)
-        acc_scr[:] = acc
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        l = l_scr[:, :, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+    # entry is an unallocated tile — nothing of it may contribute.  One pool
+    # block's heads go through the flat kernel's own tile arithmetic on the
+    # same group of heads: bit-identity rests on it
+    _decode_update(
+        pl.program_id(1), ki, off_ref[bi], bt_ref[bi, ki] < num_blocks,
+        (q_ref, k_ref, v_ref, ks_ref, vs_ref, bias_ref, *it),
+        scale=scale, block_k=block_k, nk=nk, q_group=1,
+    )
 
 
 def flash_decode_paged(
@@ -1326,35 +1427,31 @@ def flash_decode_paged(
 ) -> jnp.ndarray:
     """Decode attention straight off a shared block pool.
 
-    ``q``: (B, H, Q≤8, d).  ``k_pool``/``v_pool``: (num_blocks, H,
-    block_size, d) — the pool; ``block_tables``: (B, n_tiles) int32
-    mapping each row's logical tile to its pool block (entries >=
-    num_blocks are unallocated tiles and contribute nothing);
-    ``offsets``: (B,) as in ``flash_decode``.  The logical cache length
-    is ``n_tiles × block_size`` and ``bias`` (1-or-full dims) is indexed
-    in LOGICAL tile order.  ``k_scale_pool``/``v_scale_pool``
-    ((num_blocks, H, block_size) f32) compose the int8 KV cache with
-    paging.  Numerically identical to ``flash_decode`` over the
-    flattened view of the same blocks."""
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
+    ``q``: (B, H, Q≤8, d).  ``k_pool``/``v_pool``: (num_blocks,
+    block_size, H x d) — the pool, a block laid like a tile of the flat
+    cache leaf; ``block_tables``: (B, n_tiles) int32 mapping each row's
+    logical tile to its pool block (entries >= num_blocks are unallocated
+    tiles and contribute nothing); ``offsets``: (B,) as in
+    ``flash_decode``.  The logical cache length is ``n_tiles ×
+    block_size`` and ``bias`` (1-or-full dims) is indexed in LOGICAL tile
+    order.  ``k_scale_pool``/``v_scale_pool`` ((num_blocks, block_size, H)
+    f32) compose the int8 KV cache with paging.  Numerically identical to
+    ``flash_decode`` over the flattened view of the same blocks."""
     batch, heads, q_len, d = q.shape
-    num_blocks, pool_heads, block_k, pool_d = k_pool.shape
-    if pool_heads != heads or pool_d != d:
+    if scale is None:
+        scale = d ** -0.5
+    num_blocks, block_k, pool_lanes = k_pool.shape
+    if pool_lanes != heads * d:
         raise ValueError(
-            f"pool shape {k_pool.shape} does not match q heads/dim "
-            f"({heads}, {d})"
+            f"pool shape {k_pool.shape} does not match q heads x dim "
+            f"({heads} x {d})"
         )
     n_tiles = block_tables.shape[1]
     kv_len = n_tiles * block_k
     if block_k % 8:
         raise ValueError(f"block_size {block_k} must be 8-aligned")
     if bias is not None:
-        for i, (bd, full) in enumerate(
-            zip(bias.shape, (batch, heads, q_len, kv_len))
-        ):
-            if bd not in (1, full):
-                raise ValueError(f"bias dim {i} is {bd}, must be 1 or {full}")
+        _check_decode_bias(bias, batch, heads, q_len, kv_len)
     if (k_scale_pool is None) != (v_scale_pool is None):
         raise ValueError("k_scale_pool and v_scale_pool go together")
     has_scales = k_scale_pool is not None
@@ -1365,44 +1462,38 @@ def flash_decode_paged(
     # the flat kernel's head group (a pool block is one slot's tile, so a
     # step holds one slot): same blocks, same arithmetic, same bits
     hb = decode_step_heads(
-        heads, block_k, d, k_pool.dtype.itemsize, int8_scales=has_scales
+        heads, block_k, d, k_pool.dtype.itemsize, q_len=q_len, int8_scales=has_scales
     )
     grid = (batch, heads // hb, n_tiles)
     clamp = num_blocks - 1
 
-    def q_map(b, h, ki, bt_ref, off_ref):
-        return (b, h, 0, 0)
+    def q_map(b, g, ki, bt_ref, off_ref):
+        return (b, g, 0, 0)
 
-    def pool_map(b, h, ki, bt_ref, off_ref):
+    def pool_map(b, g, ki, bt_ref, off_ref):
         # sentinel tiles clamp to a real block for the DMA; the kernel
         # masks them to -inf so the clamped contents never contribute
-        return (jnp.minimum(bt_ref[b, ki], clamp), h, 0, 0)
-
-    def pool_scale_map(b, h, ki, bt_ref, off_ref):
-        return (jnp.minimum(bt_ref[b, ki], clamp), h, 0)
+        return (jnp.minimum(bt_ref[b, ki], clamp), 0, g)
 
     in_specs = [
-        pl.BlockSpec((1, hb, q_len, d), q_map),
-        pl.BlockSpec((1, hb, block_k, d), pool_map),
-        pl.BlockSpec((1, hb, block_k, d), pool_map),
+        pl.BlockSpec((1, 1, hb * q_len, hb * d), q_map),
+        pl.BlockSpec((1, block_k, hb * d), pool_map),
+        pl.BlockSpec((1, block_k, hb * d), pool_map),
     ]
-    if has_scales:
-        in_specs += [
-            pl.BlockSpec((1, hb, block_k), pool_scale_map),
-            pl.BlockSpec((1, hb, block_k), pool_scale_map),
-        ]
+    if has_scales:  # all heads' scales a step, as in the flat kernel
+        in_specs += [pl.BlockSpec(
+            (1, block_k, heads),
+            lambda b, g, ki, bt_ref, off_ref: (jnp.minimum(bt_ref[b, ki], clamp), 0, 0),
+        )] * 2
     if bias is not None:
-        in_specs.append(_decode_bias_spec(bias.shape, hb, q_len, block_k))
+        bias = _decode_bias_rows(bias, heads, q_len, hb)
+        in_specs.append(_decode_bias_spec(bias.shape, hb * q_len, block_k))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hb, q_len, d), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((hb, q_len, LANES), jnp.float32),
-            pltpu.VMEM((hb, q_len, LANES), jnp.float32),
-            pltpu.VMEM((hb, q_len, d), jnp.float32),
-        ],
+        scratch_shapes=_decode_scratch(hb, q_len, d),
     )
     out = pl.pallas_call(
         functools.partial(
@@ -1420,7 +1511,7 @@ def flash_decode_paged(
         block_tables, offsets,
         *[
             x
-            for x in (q, k_pool, v_pool, k_scale_pool, v_scale_pool, bias)
+            for x in (decode_q_rows(q, hb), k_pool, v_pool, k_scale_pool, v_scale_pool, bias)
             if x is not None
         ],
     )
@@ -1444,12 +1535,13 @@ def flash_decode_run(
 ) -> jnp.ndarray:
     """Run the decode kernel — directly on one device, per-shard under
     ``shard_map`` on a mesh (batch over data×fsdp×expert, heads over
-    ``tensor``, mirroring ``ops.mha.flash_run``).  ``offsets`` shard with
-    the batch rows; the int8 KV scales (``k_scale``/``v_scale``) shard
-    exactly like the buffers they dequantize (batch × heads); the kernel
-    body needs no collectives (decode never mixes rows or heads).  A bias
-    carrying a HEAD dim must be full-size (it shards with the heads);
-    batch dim 1-or-full as usual."""
+    ``tensor``, mirroring ``ops.mha.flash_run``: q's head axis, and the
+    merged last axis of the (B, L, H x d) buffers, in which the heads are
+    contiguous).  ``offsets`` shard with the batch rows; the int8 KV scales
+    (``k_scale``/``v_scale``, (B, L, H)) shard exactly like the buffers
+    they dequantize; the kernel body needs no collectives (decode never
+    mixes rows or heads).  A bias carrying a HEAD dim must be full-size (it
+    shards with the heads); batch dim 1-or-full as usual."""
     import math as _math
 
     from jax.sharding import PartitionSpec as P
@@ -1463,8 +1555,8 @@ def flash_decode_run(
         )
     batch_axes = tuple(a for a in BATCH_AXES if a in mesh.shape)
     head_axis = "tensor" if "tensor" in mesh.shape else None
-    qkv_spec = P(batch_axes or None, head_axis, None, None)
-    scale_spec = P(batch_axes or None, head_axis, None)
+    q_spec = P(batch_axes or None, head_axis, None, None)
+    kv_spec = P(batch_axes or None, None, head_axis)
     off_spec = P(batch_axes or None)
     has_scales = k_scale is not None
 
@@ -1480,10 +1572,10 @@ def flash_decode_run(
         )
 
     args = (q, k, v, jnp.asarray(offsets, jnp.int32).reshape(q.shape[0]))
-    in_specs = (qkv_spec, qkv_spec, qkv_spec, off_spec)
+    in_specs = (q_spec, kv_spec, kv_spec, off_spec)
     if has_scales:
         args = (*args, k_scale, v_scale)
-        in_specs = (*in_specs, scale_spec, scale_spec)
+        in_specs = (*in_specs, kv_spec, kv_spec)
     if bias is not None:
         bias_spec = P(
             (batch_axes or None) if bias.shape[0] != 1 else None,
@@ -1494,7 +1586,7 @@ def flash_decode_run(
         args = (*args, bias)
         in_specs = (*in_specs, bias_spec)
     return jax.shard_map(
-        run, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec, check_vma=False
+        run, mesh=mesh, in_specs=in_specs, out_specs=q_spec, check_vma=False
     )(*args)
 
 

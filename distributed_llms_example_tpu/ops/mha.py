@@ -25,6 +25,7 @@ from distributed_llms_example_tpu.ops.attention import (
 )
 from distributed_llms_example_tpu.ops.flash_attention import (
     MAX_DECODE_Q_ROWS,
+    decode_step_fits,
     flash_attention,
     flash_decode_run,
     flash_decode_supported,
@@ -158,6 +159,7 @@ def select_decode_impl(
     mesh: Mesh | None,
     backend: str,
     device_count: int,
+    kv_dtype: jnp.dtype = jnp.bfloat16,
 ) -> tuple[str, str]:
     """(impl, reason) for a CACHED decode step — the serving twin of
     ``select_attention_impl``, pure and unit-testable.
@@ -166,30 +168,26 @@ def select_decode_impl(
     short q block — a single decode row, or the speculative verify's
     k+1 rows — against the cached K/V buffer, per-row length mask,
     dead-tile skip; one grid step streams a kv tile of every head of a
-    cache slot, ``decode_step_heads``) on TPU when the cache length
-    tiles and — under a multi-device mesh — batch/heads split evenly
+    cache slot as the leaf holds it, ``decode_step_heads``) on TPU when the
+    cache length tiles and — under a multi-device mesh — batch/heads split evenly
     over (data×fsdp) and ``tensor`` (the kernel runs per-shard under
     ``shard_map``, like training flash; the step then holds the shard's
     heads).  ``flash`` forces the kernel wherever eligible; XLA attention
     (per-row masked ``dot_product_attention``) otherwise.  ``ring`` has no
-    KV-cache path and falls back to XLA.
+    KV-cache path and falls back to XLA.  So does a step of which no group
+    of heads fits the kernel's VMEM budget at the cache's kv tile
+    (``decode_step_fits``, from ``kv_dtype``, the dtype the leaf holds: s8
+    under the int8 cache, whose tiles count as the f32 they dequantize to).
 
-    Where the line is and why it did not move in PR 26: a cache shorter
-    than 128 takes XLA.  At 128 (bart-large-cnn's serving shape, 64 slots
-    x 16 heads x d 64, bf16, one row) the kernel's twelve calls of a round
-    take 0.60 ms inside the serving program on v5e (0.90 alone), under the
-    2 ms that would have moved the line.  The same cell with this shape
-    sent to XLA instead runs a round of the same length (14.5 ms either
-    way, ``gap_p95_ms`` 48.4 against 48.6): XLA's one-row fusions take
-    1.4 ms there, not the 0.56 they take alone, and the cache is relaid on
-    both paths, because it rests with its length on the lanes
-    (``{2,3,1,0}``: d = 64 would waste half of them) where neither the
-    custom call (row-major) nor XLA's own scatter and products
-    (``{3,1,2,0}``) read it: ``copy bf16[64,16,128,64]``, 4.4 ms a round
-    in front of the kernel, 3.2 around XLA's path.  That relayout, not the
-    kernel, is what the next change to this rule, to the kernel's operand
-    layout or to how the cache rests is judged by (PERF.md, Findings
-    PR 26)."""
+    Where the line is: a cache shorter than 128 takes XLA.  At 128
+    (bart-large-cnn's serving shape, 64 slots x 16 heads x d 64, bf16, one
+    row) the kernel's twelve calls of a round take 0.60 ms inside the
+    serving program on v5e, and the same cell with this shape sent to XLA
+    ran a round of the same length (PR 26).  What then cost 4.4 ms a round
+    on either route was the cache relaid around its reader; since PR 32
+    the leaf is (B, L, H x d), which the row write and the kernel both take
+    as it rests (``_cache_kv``), and XLA's path views it as (B, H, L, d)
+    inside the program (PERF.md, Findings PR 32)."""
     if attention_impl not in ("auto", "flash", "ring", "xla"):
         raise ValueError(
             f"attention_impl={attention_impl!r}: must be 'auto', 'flash', 'ring', or 'xla'"
@@ -208,6 +206,15 @@ def select_decode_impl(
         why = _uneven_split_blocker(mesh, heads=heads, batch=batch)
         if why is not None:
             return "xla", why
+    kv_dtype = jnp.dtype(kv_dtype)
+    shard_heads = heads // (mesh.shape.get("tensor", 1) if mesh is not None and device_count > 1 else 1)
+    if not decode_step_fits(
+        shard_heads, kv_len, head_dim, kv_dtype.itemsize, q_len=q_len, int8_scales=kv_dtype == jnp.int8,
+    ):
+        return "xla", (
+            f"no group of {shard_heads} heads of {head_dim} fits the decode kernel's "
+            f"VMEM budget (kv={kv_len}, {kv_dtype.name})"
+        )
     if attention_impl == "flash":
         return "flash_decode", "forced"
     if backend != "tpu":
@@ -272,6 +279,108 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     return (x * cos + rotated * sin).astype(x.dtype)
 
 
+def cache_kv(module: nn.Module, key: jnp.ndarray, value: jnp.ndarray,
+             cache_positions: jnp.ndarray | None = None):
+    """Append this step's k/v into ``module``'s cache (called inside its
+    ``@nn.compact`` ``_cache_kv``): the package's ONE cache layout, for
+    ``MultiHeadAttention`` and ``models/t5.py`` alike.
+
+    ``key``/``value``: (B, kv_heads, T, head_dim), after RoPE and the
+    q/k norms.  A cache leaf is ``(batch, length, kv_heads x
+    head_dim)``: a position's heads side by side, head_dim minor —
+    what ``k_proj`` returned before ``_split``.  That is the layout
+    XLA's row write fills and ``flash_decode`` reads, and the one the
+    chip lets the array rest in, so a decode step copies no leaf
+    (PR 32; (B, H, L, d) was relaid three times a leaf a round).
+
+    ``cache_positions`` (B,) int32 switches to PER-ROW writes — each
+    row lands at its own cache slot, the continuous-batching contract
+    where every serving slot sits at a different decode offset
+    (``mode="drop"`` makes an out-of-range position a no-op, which is
+    how idle slots park).  T may exceed 1: row b's queries write
+    the contiguous span ``cache_positions[b] + [0, T)`` — the
+    warm-admission contract, where each slot ingests its uncached
+    prompt tail at its own start offset.  Without ``cache_positions``
+    the whole batch writes at the shared ``cache_index`` (the
+    static-batch generation loops).
+
+    Under ``kv_cache_context("int8")`` the buffers are s8 with
+    per-head per-position f32 ``key_scale``/``value_scale`` leaves,
+    ``(batch, length, kv_heads)`` (``ops.flash_attention.quantize_kv``
+    — the owning quantize implementation): each write quantizes its
+    own rows, so nothing ever requantizes.  Returns ``(k, v, k_scale,
+    v_scale, idx)``, the leaves whole; scales are None on the f32
+    path."""
+    from distributed_llms_example_tpu.ops.flash_attention import quantize_kv
+    from distributed_llms_example_tpu.parallel.activation import (
+        current_kv_cache_dtype,
+    )
+
+    int8_kv = current_kv_cache_dtype() == "int8"
+    store_dtype = jnp.int8 if int8_kv else key.dtype
+    b, heads, t, d = key.shape
+    rows = lambda x: x.transpose(0, 2, 1, 3).reshape(b, t, heads * d)  # noqa: E731
+    is_initialized = module.has_variable("cache", "cached_key")
+    cached_k = module.variable("cache", "cached_key", jnp.zeros, (b, t, heads * d), store_dtype)
+    cached_v = module.variable("cache", "cached_value", jnp.zeros, (b, t, heads * d), store_dtype)
+    if int8_kv:
+        k_scale = module.variable(
+            "cache", "key_scale", jnp.zeros, (b, t, heads), jnp.float32
+        )
+        v_scale = module.variable(
+            "cache", "value_scale", jnp.zeros, (b, t, heads), jnp.float32
+        )
+    cache_index = module.variable("cache", "cache_index", lambda: jnp.array(0, dtype=jnp.int32))
+    idx = cache_index.value
+    if is_initialized:
+        if int8_kv:
+            # one scale a (position, head): quantize the head_dim rows,
+            # then lay values and scales as their leaves keep them
+            (key, ks_new), (value, vs_new) = quantize_kv(key), quantize_kv(value)
+        leaves = [(cached_k, rows(key)), (cached_v, rows(value))]
+        if int8_kv:
+            leaves += [(k_scale, ks_new.transpose(0, 2, 1)), (v_scale, vs_new.transpose(0, 2, 1))]
+        if cache_positions is not None:
+            batch = jnp.arange(b)
+            if t == 1:
+                # one contiguous row of kv_heads x head_dim lanes a slot
+                for leaf, new in leaves:
+                    leaf.value = leaf.value.at[batch, cache_positions].set(
+                        new[:, 0], mode="drop"
+                    )
+            else:
+                # per-row multi-token span: row b writes positions
+                # cache_positions[b] + [0, T)
+                pos = cache_positions[:, None] + jnp.arange(t)[None, :]
+                for leaf, new in leaves:
+                    leaf.value = leaf.value.at[batch[:, None], pos].set(new, mode="drop")
+            # the engine owns per-slot offsets; the shared counter is
+            # meaningless here and stays put
+        else:
+            for leaf, new in leaves:
+                leaf.value = jax.lax.dynamic_update_slice(leaf.value, new, (0, idx, 0))
+            cache_index.value = idx + t
+    if int8_kv:
+        return cached_k.value, cached_v.value, k_scale.value, v_scale.value, idx
+    return cached_k.value, cached_v.value, None, None, idx
+
+
+def cache_heads_view(k: jnp.ndarray, v: jnp.ndarray, k_scale, v_scale, kv_heads: int):
+    """XLA's cached path: the leaves ``k``/``v`` (B, L, kv_heads x d) viewed
+    as (B, kv_heads, L, d) inside the program, the int8 cache dequantized
+    through the IDENTICAL expression the kernel evaluates per tile
+    (``dequantize_kv``, scales (B, L, kv_heads)).  If the compiler copies
+    here, that is this path's cost: the leaf itself rests as it is."""
+    from distributed_llms_example_tpu.ops.flash_attention import dequantize_kv
+
+    b, kv_len, _ = k.shape
+    k, v = (x.reshape(b, kv_len, kv_heads, -1).transpose(0, 2, 1, 3) for x in (k, v))
+    if k_scale is not None:
+        k = dequantize_kv(k, k_scale.transpose(0, 2, 1))
+        v = dequantize_kv(v, v_scale.transpose(0, 2, 1))
+    return k, v
+
+
 class MultiHeadAttention(nn.Module):
     num_heads: int
     head_dim: int
@@ -334,104 +443,92 @@ class MultiHeadAttention(nn.Module):
     @nn.compact
     def _cache_kv(self, key: jnp.ndarray, value: jnp.ndarray,
                   cache_positions: jnp.ndarray | None = None):
-        """Append this step's k/v into the cache.
+        return cache_kv(self, key, value, cache_positions)
 
-        ``cache_positions`` (B,) int32 switches to PER-ROW writes — each
-        row lands at its own cache slot, the continuous-batching contract
-        where every serving slot sits at a different decode offset
-        (``mode="drop"`` makes an out-of-range position a no-op, which is
-        how idle slots park).  q_len may exceed 1: row b's queries write
-        the contiguous span ``cache_positions[b] + [0, q_len)`` — the
-        warm-admission contract, where each slot ingests its uncached
-        prompt tail at its own start offset.  Without ``cache_positions``
-        the whole batch writes at the shared ``cache_index`` (the
-        static-batch generation loops).
-
-        Under ``kv_cache_context("int8")`` the buffers are s8 with
-        per-head per-position f32 ``key_scale``/``value_scale`` leaves
-        (``ops.flash_attention.quantize_kv`` — the owning quantize
-        implementation): each write quantizes its own rows, so nothing
-        ever requantizes.  Returns ``(k, v, k_scale, v_scale, idx)``;
-        scales are None on the f32 path."""
-        from distributed_llms_example_tpu.ops.flash_attention import quantize_kv
-        from distributed_llms_example_tpu.parallel.activation import (
-            current_kv_cache_dtype,
+    @nn.nowrap  # no scope of its own: the kernel's call site stays ``self_attn`` (serve_decode_attn_ms finds it so)
+    def _cached_attend(
+        self,
+        q: jnp.ndarray,
+        k: jnp.ndarray,
+        v: jnp.ndarray,
+        k_scale: jnp.ndarray | None,
+        v_scale: jnp.ndarray | None,
+        bias: jnp.ndarray | None,
+        offsets: jnp.ndarray,
+        deterministic: bool,
+    ) -> jnp.ndarray:
+        """A cached decode step's attention: ``q`` (B, heads, T, d) against
+        the cache leaves ``k``/``v`` (B, L, kv_heads x d) (int8 scales (B,
+        L, kv_heads) or None), row b's first query at absolute position
+        ``offsets[b]``.  ``bias`` is the caller's constant padding mask
+        only: validity and causality are the dispatch's job here — the
+        decode kernel's in-kernel per-row length mask, or
+        ``decode_step_bias`` on XLA's path, which views the leaf as (B, H,
+        L, d) inside the program."""
+        b, _, t, d = q.shape
+        kv_len = k.shape[1]
+        mesh = current_mesh()
+        backend, devices = jax.default_backend(), jax.device_count()
+        dropout = float(self.probs_dropout_rate) if not deterministic else 0.0
+        # grouped-query attention: the decode kernel reads each KV head once
+        # for the ``rep`` query heads that share it, folded into its q rows;
+        # every other path gets K and V repeated to the q heads
+        rep = self.num_heads // self.kv_heads
+        fold_gqa = (
+            rep > 1
+            and t * rep <= MAX_DECODE_Q_ROWS
+            and (bias is None or bias.shape[1] == bias.shape[2] == 1)
+            and (mesh is None or self.kv_heads % mesh.shape.get("tensor", 1) == 0)
+            and not dropout
+            and select_decode_impl(
+                self.attention_impl, batch=b, heads=self.kv_heads,
+                head_dim=d, q_len=t * rep, kv_len=kv_len,
+                mesh=mesh, backend=backend, device_count=devices, kv_dtype=k.dtype,
+            )[0] == "flash_decode"
         )
-
-        int8_kv = current_kv_cache_dtype() == "int8"
-        store_dtype = jnp.int8 if int8_kv else key.dtype
-        is_initialized = self.has_variable("cache", "cached_key")
-        cached_k = self.variable("cache", "cached_key", jnp.zeros, key.shape, store_dtype)
-        cached_v = self.variable("cache", "cached_value", jnp.zeros, value.shape, store_dtype)
-        if int8_kv:
-            k_scale = self.variable(
-                "cache", "key_scale", jnp.zeros, key.shape[:3], jnp.float32
+        if fold_gqa:
+            _log_impl_once("flash_decode", f"grouped: {rep} query heads a KV head as q rows")
+            rows = q.reshape(b, self.kv_heads, rep, t, d).swapaxes(2, 3).reshape(b, self.kv_heads, t * rep, d)
+            out = flash_decode_run(
+                rows, k, v, bias, offsets=offsets, mesh=mesh,
+                k_scale=k_scale, v_scale=v_scale, dtype=self.dtype, q_group=rep,
             )
-            v_scale = self.variable(
-                "cache", "value_scale", jnp.zeros, value.shape[:3], jnp.float32
+            return out.reshape(b, self.kv_heads, t, rep, d).swapaxes(2, 3).reshape(b, self.num_heads, t, d)
+        impl, reason = select_decode_impl(
+            self.attention_impl, batch=b, heads=self.num_heads, head_dim=d,
+            q_len=t, kv_len=kv_len, mesh=mesh, backend=backend, device_count=devices,
+            kv_dtype=k.dtype,
+        )
+        if dropout > 0.0 and impl == "flash_decode":
+            # the decode kernel has no in-kernel mask stream; a decode
+            # pass that WANTS probs dropout (MC-dropout eval) keeps the
+            # old XLA semantics instead of silently going deterministic
+            impl, reason = "xla", "probs dropout requested on cached decode"
+        _log_impl_once(impl, reason)
+        if impl == "flash_decode":
+            if rep > 1:
+                # the leaf's merged axis apart, each KV head repeated in place
+                k, v = (
+                    jnp.repeat(x.reshape(b, kv_len, self.kv_heads, d), rep, axis=2).reshape(b, kv_len, -1)
+                    for x in (k, v)
+                )
+                if k_scale is not None:
+                    k_scale, v_scale = (jnp.repeat(x, rep, axis=2) for x in (k_scale, v_scale))
+            # int8 KV scales dequantize per kv tile inside the kernel
+            return flash_decode_run(
+                q, k, v, bias, offsets=offsets, mesh=mesh,
+                k_scale=k_scale, v_scale=v_scale, dtype=self.dtype,
             )
-        cache_index = self.variable("cache", "cache_index", lambda: jnp.array(0, dtype=jnp.int32))
-        idx = cache_index.value
-        if is_initialized:
-            if int8_kv:
-                key, ks_new = quantize_kv(key)
-                value, vs_new = quantize_kv(value)
-            if cache_positions is not None:
-                b = jnp.arange(key.shape[0])
-                if key.shape[2] == 1:
-                    k = cached_k.value.at[b, :, cache_positions].set(
-                        key[:, :, 0, :], mode="drop"
-                    )
-                    v = cached_v.value.at[b, :, cache_positions].set(
-                        value[:, :, 0, :], mode="drop"
-                    )
-                    cached_k.value, cached_v.value = k, v
-                    if int8_kv:
-                        k_scale.value = k_scale.value.at[b, :, cache_positions].set(
-                            ks_new[:, :, 0], mode="drop"
-                        )
-                        v_scale.value = v_scale.value.at[b, :, cache_positions].set(
-                            vs_new[:, :, 0], mode="drop"
-                        )
-                else:
-                    # per-row multi-token span: row b writes positions
-                    # cache_positions[b] + [0, T).  Advanced indexing with
-                    # a mid-axis slice puts the (B, T) index result in
-                    # front, so values transpose to (B, T, H[, D]).
-                    pos = cache_positions[:, None] + jnp.arange(key.shape[2])[None, :]
-                    k = cached_k.value.at[b[:, None], :, pos].set(
-                        key.transpose(0, 2, 1, 3), mode="drop"
-                    )
-                    v = cached_v.value.at[b[:, None], :, pos].set(
-                        value.transpose(0, 2, 1, 3), mode="drop"
-                    )
-                    cached_k.value, cached_v.value = k, v
-                    if int8_kv:
-                        k_scale.value = k_scale.value.at[b[:, None], :, pos].set(
-                            ks_new.transpose(0, 2, 1), mode="drop"
-                        )
-                        v_scale.value = v_scale.value.at[b[:, None], :, pos].set(
-                            vs_new.transpose(0, 2, 1), mode="drop"
-                        )
-                # the engine owns per-slot offsets; the shared counter is
-                # meaningless here and stays put
-            else:
-                k = jax.lax.dynamic_update_slice(cached_k.value, key, (0, 0, idx, 0))
-                v = jax.lax.dynamic_update_slice(cached_v.value, value, (0, 0, idx, 0))
-                cached_k.value, cached_v.value = k, v
-                if int8_kv:
-                    k_scale.value = jax.lax.dynamic_update_slice(
-                        k_scale.value, ks_new, (0, 0, idx)
-                    )
-                    v_scale.value = jax.lax.dynamic_update_slice(
-                        v_scale.value, vs_new, (0, 0, idx)
-                    )
-                cache_index.value = idx + key.shape[2]
-        else:
-            k, v = cached_k.value, cached_v.value
-        if int8_kv:
-            return k, v, k_scale.value, v_scale.value, idx
-        return k, v, None, None, idx
+        k, v = cache_heads_view(k, v, k_scale, v_scale, self.kv_heads)
+        if rep > 1:
+            k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+        step = decode_step_bias(offsets, t, kv_len)
+        return dot_product_attention(
+            q, k, v, step if bias is None else bias + step,
+            dtype=self.dtype,
+            dropout_rate=dropout,
+            dropout_rng=self.make_rng("dropout") if dropout > 0.0 else None,
+        )
 
     def __call__(
         self,
@@ -483,9 +580,16 @@ class MultiHeadAttention(nn.Module):
             if self.qk_norm_eps is not None:
                 q, k = self.q_norm(q), self.k_norm(k)
 
-        offset = 0
-        decode_offsets = None  # (B,) absolute position of q row 0, cached decode
-        k_scale = v_scale = None  # int8 KV cache scales (f32 path: None)
+        manual = current_manual_seq()
+        if manual is not None and use_cache:
+            # no KV-cache path inside the manual region: cache slots would
+            # be indexed with LOCAL shard positions — fail loudly rather
+            # than decode silently wrong logits
+            raise ValueError(
+                "use_cache is not supported inside a manual sequence region "
+                "(pipeline stage×sequence is training/teacher-forced only; "
+                "unstack the pipelined params to decode)"
+            )
         if use_cache and self.causal:
             # RoPE must see absolute positions, so rotate before caching
             if self.use_rope:
@@ -505,18 +609,20 @@ class MultiHeadAttention(nn.Module):
                 q = apply_rope(q, cos, sin)
                 k = apply_rope(k, cos, sin)
             k, v, k_scale, v_scale, offset = self._cache_kv(k, v, cache_positions)
-            # validity + causality are the DECODE dispatch's job below:
-            # per-row offsets feed either the decode kernel's in-kernel
-            # length mask or decode_step_bias on the XLA path
+            # (B,) absolute position of q row 0
             decode_offsets = (
                 cache_positions
                 if cache_positions is not None
                 else jnp.full((q.shape[0],), offset, jnp.int32)
             )
-        elif self.use_rope:
+            out = self._cached_attend(
+                q, k, v, k_scale, v_scale, bias, decode_offsets, deterministic
+            )
+            b, h, s, d = out.shape
+            return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b, s, h * d))
+        if self.use_rope:
             if positions is None:
                 pos = jnp.arange(q.shape[2])[None, :]
-                manual = current_manual_seq()
                 if manual is not None:
                     # inside a manual sequence region q holds a LOCAL shard;
                     # RoPE must see absolute positions
@@ -528,45 +634,17 @@ class MultiHeadAttention(nn.Module):
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
 
-        # grouped-query attention: the cached decode kernel reads each KV head
-        # once for the ``rep`` query heads that share it (folded into its q
-        # rows below); every other path gets K and V repeated to the q heads
+        # grouped-query attention: K and V repeated to the q heads (a cached
+        # step went its own way above and reads each KV head once)
         rep = self.num_heads // self.kv_heads
         mesh = current_mesh()
-        fold_gqa = (
-            rep > 1
-            and decode_offsets is not None
-            and q.shape[2] * rep <= MAX_DECODE_Q_ROWS
-            and (bias is None or bias.shape[1] == bias.shape[2] == 1)
-            and (mesh is None or self.kv_heads % mesh.shape.get("tensor", 1) == 0)
-            and (deterministic or not self.probs_dropout_rate)
-            and select_decode_impl(
-                self.attention_impl, batch=q.shape[0], heads=self.kv_heads,
-                head_dim=self.head_dim, q_len=q.shape[2] * rep, kv_len=k.shape[2],
-                mesh=mesh, backend=jax.default_backend(), device_count=jax.device_count(),
-            )[0] == "flash_decode"
-        )
-        if rep > 1 and not fold_gqa:
+        if rep > 1:
             k = jnp.repeat(k, rep, axis=1)
             v = jnp.repeat(v, rep, axis=1)
-            if k_scale is not None:
-                k_scale = jnp.repeat(k_scale, rep, axis=1)
-                v_scale = jnp.repeat(v_scale, rep, axis=1)
 
-        # causal masking for the non-cached path is applied here (the cached
-        # path built step_bias above): natively by the flash kernel, or as an
-        # additive bias for the XLA path.
+        # causal masking is applied here: natively by the flash kernel, or as
+        # an additive bias for the XLA path.
         causal_here = self.causal and not use_cache
-        manual = current_manual_seq()
-        if manual is not None and use_cache:
-            # no KV-cache path inside the manual region: cache slots would
-            # be indexed with LOCAL shard positions — fail loudly rather
-            # than decode silently wrong logits
-            raise ValueError(
-                "use_cache is not supported inside a manual sequence region "
-                "(pipeline stage×sequence is training/teacher-forced only; "
-                "unstack the pipelined params to decode)"
-            )
         if manual is not None:
             if self.attention_impl in ("xla", "flash"):
                 # the region is manual over the sequence axis: activations
@@ -599,67 +677,6 @@ class MultiHeadAttention(nn.Module):
                 # partitioner's copy-chain bug — ride the ring in fp32
                 plumb_fp32=True,
             )
-            b, h, s, d = out.shape
-            return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b, s, h * d))
-        if fold_gqa:
-            _log_impl_once("flash_decode", f"grouped: {rep} query heads a KV head as q rows")
-            b, _, t, d = q.shape
-            rows = q.reshape(b, self.kv_heads, rep, t, d).swapaxes(2, 3).reshape(b, self.kv_heads, t * rep, d)
-            out = flash_decode_run(
-                rows, k, v, bias, offsets=decode_offsets, mesh=mesh,
-                k_scale=k_scale, v_scale=v_scale, dtype=self.dtype, q_group=rep,
-            )
-            out = out.reshape(b, self.kv_heads, t, rep, d).swapaxes(2, 3).reshape(b, self.num_heads, t, d)
-            return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b, t, self.num_heads * d))
-        if decode_offsets is not None:
-            decode_dropout = (
-                float(self.probs_dropout_rate) if not deterministic else 0.0
-            )
-            impl, reason = select_decode_impl(
-                self.attention_impl,
-                batch=q.shape[0],
-                heads=self.num_heads,
-                head_dim=self.head_dim,
-                q_len=q.shape[2],
-                kv_len=k.shape[2],
-                mesh=mesh,
-                backend=jax.default_backend(),
-                device_count=jax.device_count(),
-            )
-            if decode_dropout > 0.0 and impl == "flash_decode":
-                # the decode kernel has no in-kernel mask stream; a decode
-                # pass that WANTS probs dropout (MC-dropout eval) keeps the
-                # old XLA semantics instead of silently going deterministic
-                impl, reason = "xla", "probs dropout requested on cached decode"
-            _log_impl_once(impl, reason)
-            if impl == "flash_decode":
-                # bias here is the caller's constant padding mask only —
-                # validity/causality ride the kernel's per-row length mask;
-                # int8 KV scales dequantize per kv tile inside the kernel
-                out = flash_decode_run(
-                    q, k, v, bias, offsets=decode_offsets, mesh=mesh,
-                    k_scale=k_scale, v_scale=v_scale,
-                    dtype=self.dtype,
-                )
-            else:
-                if k_scale is not None:
-                    # the XLA fallback dequantizes through the IDENTICAL
-                    # expression the kernel evaluates per tile
-                    from distributed_llms_example_tpu.ops.flash_attention import (
-                        dequantize_kv,
-                    )
-
-                    k = dequantize_kv(k, k_scale)
-                    v = dequantize_kv(v, v_scale)
-                step = decode_step_bias(decode_offsets, q.shape[2], k.shape[2])
-                out = dot_product_attention(
-                    q, k, v, step if bias is None else bias + step,
-                    dtype=self.dtype,
-                    dropout_rate=decode_dropout,
-                    dropout_rng=(
-                        self.make_rng("dropout") if decode_dropout > 0.0 else None
-                    ),
-                )
             b, h, s, d = out.shape
             return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b, s, h * d))
         impl, reason = select_attention_impl(

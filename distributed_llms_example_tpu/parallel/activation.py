@@ -162,12 +162,11 @@ def constrain_logits(x: jax.Array) -> jax.Array:
 
 
 def constrain_kv(x: jax.Array) -> jax.Array:
-    """(batch, heads, len, head_dim) cached K/V or precomputed cross-K/V:
-    batch rows over the batch axes, heads over ``tensor`` — the serving
-    twin of ``constrain_hidden``.  The layout (and its divisibility
-    fallbacks) is ``parallel/sharding.py kv_leaf_spec`` — the ONE
-    definition CACHE_RULES, this constraint, and the engine's host-side
-    placement all share."""
+    """(batch, heads, len, head_dim) precomputed cross-K/V: batch rows over
+    the batch axes, heads over ``tensor`` — the serving twin of
+    ``constrain_hidden``.  The layout (and its divisibility fallbacks) is
+    ``parallel/sharding.py kv_leaf_spec`` — the ONE definition this
+    constraint and the engine's host-side placement share."""
     mesh = current_mesh()
     if mesh is None or x.ndim != 4:
         return x
@@ -176,52 +175,32 @@ def constrain_kv(x: jax.Array) -> jax.Array:
     return constrain(x, kv_leaf_spec(x.shape, dict(mesh.shape)))
 
 
-def constrain_kv_scale(x: jax.Array) -> jax.Array:
-    """(batch, heads, len) int8-KV-cache scale leaf: same layout as the K/V
-    buffer it scales, minus the head_dim axis (``kv_scale_spec`` — the one
-    definition, like ``kv_leaf_spec`` for the buffers)."""
-    mesh = current_mesh()
-    if mesh is None or x.ndim != 3:
-        return x
-    from distributed_llms_example_tpu.parallel.sharding import kv_scale_spec
-
-    return constrain(x, kv_scale_spec(x.shape, dict(mesh.shape)))
-
-
-def constrain_conv_state(x: jax.Array) -> jax.Array:
-    """(batch, channels, taps-1) conv-layer decode state: batch rows over the
-    batch axes, channels over ``tensor`` (``sharding.conv_state_spec``)."""
-    mesh = current_mesh()
-    if mesh is None or x.ndim != 3:
-        return x
-    from distributed_llms_example_tpu.parallel.sharding import conv_state_spec
-
-    return constrain(x, conv_state_spec(x.shape, dict(mesh.shape)))
-
-
-def constrain_cache(tree):
+def constrain_cache(tree, kv_heads: int | None = None):
     """Pin a whole flax "cache" collection (or cross-KV tuple tree) to the
-    serving layout, leaf by leaf: a ``conv_state`` via
-    ``constrain_conv_state``, K/V buffers (and the unnamed 4-D leaves of a
-    cross-KV tuple) via ``constrain_kv``, 3-D ``*_scale``
-    leaves (the int8 KV cache's per-head per-position scales) via
-    ``constrain_kv_scale``, scalars (the ``cache_index`` counters)
-    replicated by GSPMD default.  No-op without an ambient mesh — the
-    decode/prefill programs call it unconditionally, exactly like the
-    models call ``constrain_hidden``."""
+    serving layout, leaf by leaf and by the leaf's name
+    (``sharding.cache_leaf_spec``, CACHE_RULES' one definition): K/V
+    buffers (batch, len, heads x head_dim), the int8 cache's (batch, len,
+    heads) scales, a ``conv_state``; the unnamed 4-D leaves of a cross-KV
+    tuple via ``constrain_kv``; scalars (the ``cache_index`` counters)
+    replicated by GSPMD default.  ``kv_heads`` (``sharding.cache_kv_heads``
+    of the model's config) is what a K/V buffer's merged axis is told apart
+    by, so a tree that holds one needs it; a cross-KV tree does not.  No-op
+    without an ambient mesh — the decode/prefill programs call it
+    unconditionally, exactly like the models call ``constrain_hidden``."""
     import jax.tree_util as jtu
 
-    def leaf_key(path) -> str:
-        return str(path[-1].key) if path and hasattr(path[-1], "key") else ""
+    mesh = current_mesh()
+    if mesh is None:
+        return tree
+    from distributed_llms_example_tpu.parallel.sharding import cache_leaf_spec
 
     def pin(path, x):
-        nd = getattr(x, "ndim", 0)
-        if leaf_key(path) == "conv_state":
-            return constrain_conv_state(x)
-        if nd == 4:
-            return constrain_kv(x)
-        if nd == 3 and leaf_key(path).endswith("_scale"):
-            return constrain_kv_scale(x)
-        return x
+        name = str(path[-1].key) if path and hasattr(path[-1], "key") else ""
+        if name in ("cached_key", "cached_value") and kv_heads is None:
+            raise ValueError(f"constrain_cache: the K/V leaf {name} needs the model's kv_heads")
+        spec = cache_leaf_spec(name, getattr(x, "shape", ()), dict(mesh.shape), kv_heads)
+        if spec is not None:
+            return constrain(x, spec)
+        return constrain_kv(x) if getattr(x, "ndim", 0) == 4 else x
 
     return jtu.tree_map_with_path(pin, tree)

@@ -135,21 +135,26 @@ def default_rules() -> ShardingRules:
 
 
 # Serving state: the per-layer KV cache is the SECOND long-lived sharded
-# tree (params being the first) — slot rows over the batch axes
-# (data×fsdp×expert, like the batch they decode), heads over ``tensor``
-# (like the attention projections that produce them), sequence position
-# and head_dim replicated.  The per-module ``cache_index`` counters are
-# scalars and stay replicated.  ``analysis/spec_lint.py
-# lint_cache_sharding`` validates this rule set against an abstract cache
-# tree exactly like the param rules; ``parallel/activation.py
-# constrain_cache`` applies it inside the compiled prefill/decode
-# programs.
+# tree (params being the first).  A K/V leaf is (slots, length, kv_heads x
+# head_dim) — a position's heads side by side, as ``k_proj`` returns them
+# and as the row write and ``flash_decode`` take them (``ops/mha.py``
+# ``_cache_kv``): slot rows over the batch axes (data×fsdp×expert, like the
+# batch they decode), the merged last axis over ``tensor`` (the heads are
+# contiguous in it, and a shard holds whole heads: where the KV head count
+# does not split, ``cache_leaf_spec`` replicates the axis even if its lanes
+# would divide), the length replicated.  The per-module ``cache_index``
+# counters are scalars and stay replicated.  ``analysis/spec_lint.py lint_cache_sharding`` validates this
+# rule set against an abstract cache tree exactly like the param rules (the
+# rules name the axes; the guard by head count is ``cache_leaf_spec``'s);
+# ``parallel/activation.py constrain_cache`` applies it inside the compiled
+# prefill/decode programs.
 CACHE_RULES: list[tuple[str, P]] = [
-    (r"(cached_key|cached_value)$", P(("data", "fsdp", "expert"), "tensor", None, None)),
+    (r"(cached_key|cached_value)$", P(("data", "fsdp", "expert"), None, "tensor")),
     # int8 KV cache (--kv-cache-dtype int8): per-head per-position f32
-    # scales, (batch, heads, len) — the K/V layout minus head_dim, so the
-    # scales always live next to the buffers they dequantize
-    (r"(key_scale|value_scale)$", P(("data", "fsdp", "expert"), "tensor", None)),
+    # scales, (batch, len, heads) — the K/V layout with a head's lanes
+    # drawn into one, so the scales always live next to the buffers they
+    # dequantize
+    (r"(key_scale|value_scale)$", P(("data", "fsdp", "expert"), None, "tensor")),
     # a conv layer's decode state (models/lfm2.py): (batch, channels, taps-1),
     # the channels over ``tensor`` like the in-projection that produces them
     (r"conv_state$", P(("data", "fsdp", "expert"), "tensor", None)),
@@ -159,7 +164,7 @@ CACHE_RULES: list[tuple[str, P]] = [
 # The cache leaves that grow with the cache length, and the axis it lies on:
 # what widens when a bucket-width prefill lands in a full-width slot.  Any
 # other leaf (a conv state, a counter) has the same shape at every width.
-CACHE_LENGTH_AXIS = {"cached_key": 2, "cached_value": 2, "key_scale": 2, "value_scale": 2}
+CACHE_LENGTH_AXIS = {"cached_key": 1, "cached_value": 1, "key_scale": 1, "value_scale": 1}
 
 
 def cache_leaf_name(path: tuple) -> str:
@@ -172,16 +177,17 @@ def cache_rules() -> ShardingRules:
 
 
 # Paged serving state (--paged-kv): the shared block pool replaces the
-# per-slot K/V buffers as the resident serving tree.  Blocks belong to
-# individual slots, so the block dim cannot shard over the batch axes the
-# way slot rows do (a slot's blocks would scatter across devices and every
-# gather would cross the mesh); heads still split over ``tensor`` like the
-# projections that produce them.  ``analysis/spec_lint.py
+# per-slot K/V buffers as the resident serving tree, a block laid like a
+# tile of the slot leaf: (num_blocks, block, kv_heads x head_dim).  Blocks
+# belong to individual slots, so the block dim cannot shard over the batch
+# axes the way slot rows do (a slot's blocks would scatter across devices
+# and every gather would cross the mesh); the heads still split over
+# ``tensor`` like the projections that produce them.  ``analysis/spec_lint.py
 # lint_cache_sharding`` validates this rule set over the abstract pool
 # exactly like CACHE_RULES over the slot cache.
 POOL_RULES: list[tuple[str, P]] = [
-    (r"(cached_key|cached_value)$", P(None, "tensor", None, None)),
-    (r"(key_scale|value_scale)$", P(None, "tensor", None)),
+    (r"(cached_key|cached_value)$", P(None, None, "tensor")),
+    (r"(key_scale|value_scale)$", P(None, None, "tensor")),
     (r"cache_index$", P()),
 ]
 
@@ -190,42 +196,61 @@ def pool_rules() -> ShardingRules:
     return ShardingRules(rules=POOL_RULES)
 
 
-def kv_leaf_spec(shape: tuple, mesh_axes: Any) -> P:
-    """The CACHE_RULES layout for one (batch, heads, len, head_dim) K/V
-    leaf, divisibility-guarded per-dim (ragged batch or head counts
-    replicate that dim, mirroring ``divisible_spec``).  THE single
-    definition of the serving K/V layout — ``activation.constrain_kv``
-    (in-graph constraints) and the engine's host-side placement both
-    derive from it, so they cannot drift."""
+def _batch_axes_if_even(rows: int, mesh_axes: Any):
     batch_shards = 1
     for a in ("data", "fsdp", "expert"):
         batch_shards *= mesh_axes.get(a, 1)
-    batch = (
-        ("data", "fsdp", "expert")
-        if shape[0] % max(batch_shards, 1) == 0
-        else None
-    )
-    heads = (
-        "tensor" if shape[1] % max(mesh_axes.get("tensor", 1), 1) == 0 else None
-    )
-    return P(batch, heads, None, None)
+    return ("data", "fsdp", "expert") if rows % max(batch_shards, 1) == 0 else None
 
 
-def kv_scale_spec(shape: tuple, mesh_axes: Any) -> P:
-    """The CACHE_RULES layout for one (batch, heads, len) int8-KV scale
-    leaf — ``kv_leaf_spec`` minus the head_dim axis, divisibility-guarded
-    the same way.  THE single definition of the scale layout:
-    ``activation.constrain_kv_scale`` and the engine's host placement
-    both derive from it."""
-    full = kv_leaf_spec((*shape, 1), mesh_axes)
-    return P(full[0], full[1], None)
+def _tensor_if_even(n: int, mesh_axes: Any):
+    return "tensor" if n % max(mesh_axes.get("tensor", 1), 1) == 0 else None
 
 
-def conv_state_spec(shape: tuple, mesh_axes: Any) -> P:
-    """The CACHE_RULES layout for one (batch, channels, taps-1) conv-state
-    leaf, divisibility-guarded like ``kv_leaf_spec``."""
-    full = kv_leaf_spec((*shape, 1), mesh_axes)
-    return P(full[0], full[1], None)
+def kv_leaf_spec(shape: tuple, mesh_axes: Any) -> P:
+    """The layout of one (batch, heads, len, head_dim) K/V array as the
+    attention mathematics holds it — a precomputed cross-K/V leaf —
+    divisibility-guarded per-dim (ragged batch or head counts replicate
+    that dim, mirroring ``divisible_spec``): ``activation.constrain_kv``
+    (in-graph constraints) and the engine's host-side placement both
+    derive from it, so they cannot drift."""
+    return P(_batch_axes_if_even(shape[0], mesh_axes), _tensor_if_even(shape[1], mesh_axes), None, None)
+
+
+def cache_kv_heads(config: Any) -> int:
+    """K/V heads of the decoder's self-attention cache across the model
+    families' config spellings (llama / lfm2, bart, t5): what tells a K/V
+    leaf's merged (kv_heads x head_dim) axis apart into heads."""
+    for attr in ("num_key_value_heads", "decoder_attention_heads", "num_heads", "num_attention_heads"):
+        n = getattr(config, attr, None)
+        if n:
+            return int(n)
+    raise ValueError(f"no attention head count on {type(config).__name__}")
+
+
+def cache_leaf_spec(name: str, shape: tuple, mesh_axes: Any, kv_heads: int, *, pool: bool = False) -> P | None:
+    """The CACHE_RULES layout of the slot cache's leaf ``name`` (the last key
+    of its path), or with ``pool`` the POOL_RULES layout of the block pool's
+    (the block dim never shards), divisibility-guarded like
+    ``kv_leaf_spec``; None for a leaf the rules do not shard (a counter, or
+    no cache leaf at all).  A K/V leaf's merged axis goes over ``tensor``
+    only where the ``kv_heads`` in it do, so a shard holds whole heads, as
+    the (batch, len, heads) scales beside it and ``flash_decode``'s
+    eligibility have it: fewer KV heads than ``tensor`` replicate, and no
+    step pays collectives inside a head.  THE single definition of the
+    serving cache layout: ``activation.constrain_cache`` and the engine's
+    host placement both derive from it."""
+    if len(shape) != 3:
+        return None
+    batch = None if pool else _batch_axes_if_even(shape[0], mesh_axes)
+    if name in ("cached_key", "cached_value"):  # (batch, len, kv_heads x head_dim)
+        whole = shape[2] % kv_heads == 0
+        return P(batch, None, _tensor_if_even(kv_heads, mesh_axes) if whole else None)
+    if name in CACHE_LENGTH_AXIS:  # the int8 cache's (batch, len, kv_heads) scales
+        return P(batch, None, _tensor_if_even(shape[2], mesh_axes))
+    if name == "conv_state" and not pool:  # (batch, channels, taps-1)
+        return P(batch, _tensor_if_even(shape[1], mesh_axes), None)
+    return None
 
 
 # Pipelined (stage>1) param layout: stacked block trees shard their leading

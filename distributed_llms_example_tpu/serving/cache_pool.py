@@ -7,8 +7,9 @@ Here slots become BLOCK LISTS over a shared pool (vLLM-style paging,
 restated for the fixed-shape SPMD engine):
 
 - the resident serving state is one fixed-shape pool tensor per cache
-  leaf — ``(num_blocks, heads, block_size, head_dim)`` — so admitting or
-  evicting a request never changes a compiled shape (no recompiles);
+  leaf — ``(num_blocks, block_size, heads x head_dim)``, a block laid like
+  a tile of the slot leaf — so admitting or evicting a request never
+  changes a compiled shape (no recompiles);
 - a request holds ``ceil(prompt_len / block_size)`` prompt blocks plus
   ``ceil(budget / block_size)`` decode blocks — bytes scale with the
   ACTUAL prompt, not the worst case;
@@ -44,6 +45,8 @@ from typing import Any, Iterable, Sequence
 
 import jax
 import jax.numpy as jnp
+
+from distributed_llms_example_tpu.parallel.sharding import CACHE_LENGTH_AXIS, cache_leaf_name
 
 
 # --------------------------------------------------- block content identity
@@ -346,26 +349,49 @@ def build_block_row(
 # ------------------------------------------------ in-program pool plumbing
 #
 # These run INSIDE the engine's jitted admit/step programs.  Leaf
-# conventions mirror the flax cache collection: 4-D (slots, heads, len,
-# head_dim) K/V buffers, 3-D (slots, heads, len) int8-KV scale leaves,
-# scalars (cache_index) pass through untouched.
+# conventions mirror the flax cache collection (``ops/mha.py``
+# ``_cache_kv``): 3-D (slots, len, heads x head_dim) K/V buffers and
+# (slots, len, heads) int8-KV scale leaves — one order of axes, so one
+# branch serves both — and scalars (cache_index), which pass through
+# untouched.
+
+
+def pad_axis(x, axis: int, width: int):
+    """Right-pad one axis to ``width`` with zeros — how a bucket-width
+    admission chunk lands in full-width slot state.  The padding is
+    mask-invisible: enc_mask/full_mask stay 0 there, so padded
+    positions contribute exactly nothing (the bucketed == unbucketed
+    bit-identity argument)."""
+    if x.shape[axis] == width:
+        return x
+    pads = [(0, 0)] * x.ndim
+    pads[axis] = (0, width - x.shape[axis])
+    return jnp.pad(x, pads)
+
+
+def pad_cache_length(cache: Any, width: int):
+    """Bucket-width chunk cache → slot width, by leaf: K/V and the int8
+    scale leaves grow along their length axis (``CACHE_LENGTH_AXIS``); a
+    conv state or a counter has one shape at every width."""
+
+    def pad(path, x):
+        axis = CACHE_LENGTH_AXIS.get(cache_leaf_name(path))
+        return x if axis is None else pad_axis(x, axis, width)
+
+    return jax.tree_util.tree_map_with_path(pad, cache)
 
 
 def pool_cache_tree(abstract_cache: Any, num_blocks: int, block_size: int):
     """Zeroed pool tree with the same structure as a slot-view cache tree:
-    every K/V leaf becomes ``(num_blocks, heads, block_size[, head_dim])``,
+    every (slots, len, x) leaf becomes ``(num_blocks, block_size, x)``,
     scalars stay scalars.  The ONE place slot-view shapes map to pool
     shapes."""
 
     def to_pool(x):
-        nd = len(getattr(x, "shape", ()))
-        if nd == 4:
-            return jnp.zeros(
-                (num_blocks, x.shape[1], block_size, x.shape[3]), x.dtype
-            )
-        if nd == 3:
-            return jnp.zeros((num_blocks, x.shape[1], block_size), x.dtype)
-        return jnp.zeros(getattr(x, "shape", ()), x.dtype)
+        shape = tuple(getattr(x, "shape", ()))
+        if len(shape) == 3:
+            shape = (num_blocks, block_size, shape[2])
+        return jnp.zeros(shape, x.dtype)
 
     return jax.tree.map(to_pool, abstract_cache)
 
@@ -374,21 +400,18 @@ def gather_cache(pool_tree: Any, block_tables: jnp.ndarray):
     """Slot-view cache tree from the pool through the block tables —
     ``mode="fill"`` zeros for sentinel (unallocated) tiles, which the
     attention masks make contribute exactly nothing (the paged==flat
-    bit-identity argument).  The view is a STEP-TRANSIENT on the XLA
-    path — only the pool is resident between steps; the kernel path
-    (``flash_decode_paged``) never materializes it at all."""
+    bit-identity argument).  A slot's blocks follow one another on the
+    length axis, so the view is the gathered blocks, reshaped.  The view is
+    a STEP-TRANSIENT on the XLA path — only the pool is resident between
+    steps; the kernel path (``flash_decode_paged``) never materializes it
+    at all."""
     n_tiles = block_tables.shape[1]
 
     def view(x):
-        if x.ndim == 4:
-            g = jnp.take(x, block_tables, axis=0, mode="fill", fill_value=0)
-            g = g.transpose(0, 2, 1, 3, 4)  # (S, H, nt, bs, D)
-            return g.reshape(g.shape[0], g.shape[1], n_tiles * x.shape[2], x.shape[3])
-        if x.ndim == 3:
-            g = jnp.take(x, block_tables, axis=0, mode="fill", fill_value=0)
-            g = g.transpose(0, 2, 1, 3)
-            return g.reshape(g.shape[0], g.shape[1], n_tiles * x.shape[2])
-        return x
+        if x.ndim != 3:
+            return x
+        g = jnp.take(x, block_tables, axis=0, mode="fill", fill_value=0)  # (S, nt, bs, x)
+        return g.reshape(g.shape[0], n_tiles * x.shape[1], x.shape[2])
 
     return jax.tree.map(view, pool_tree)
 
@@ -417,13 +440,9 @@ def scatter_step(
     safe = jnp.clip(offsets, 0, width - 1)
 
     def scat(pool, flat):
-        if pool.ndim == 4:
-            row = flat[rows, :, safe, :]  # (S, H, D)
-            return pool.at[blocks, :, inb, :].set(row, mode="drop")
-        if pool.ndim == 3:
-            row = flat[rows, :, safe]
-            return pool.at[blocks, :, inb].set(row, mode="drop")
-        return pool
+        if pool.ndim != 3:
+            return pool
+        return pool.at[blocks, inb].set(flat[rows, safe], mode="drop")  # one row a slot
 
     return jax.tree.map(scat, pool_tree, new_cache)
 
@@ -461,7 +480,7 @@ def scatter_admit(
 ):
     """Copy a prefilled admission chunk's allocated tiles into the pool.
 
-    ``chunk_cache`` leaves are (chunk, heads, width, head_dim) at the
+    ``chunk_cache`` leaves are (chunk, width, heads x head_dim) at the
     BUCKET width; ``admit_blocks`` is the flat (chunk × tiles,) block
     assignment with sentinel entries for tiles that must not copy
     (padding rows, the prompt-gap region).  Decode tiles DO copy — the
@@ -469,26 +488,11 @@ def scatter_admit(
     and keeps the paged==flat bit-identity argument airtight."""
 
     def scat(pool, chunk):
-        nd = chunk.ndim
-        if nd == 4:
-            c, h, lc, d = chunk.shape
-            nt = lc // block_size
-            tiles = (
-                chunk.reshape(c, h, nt, block_size, d)
-                .transpose(0, 2, 1, 3, 4)
-                .reshape(c * nt, h, block_size, d)
-            )
-            return pool.at[admit_blocks].set(tiles, mode="drop")
-        if nd == 3:
-            c, h, lc = chunk.shape
-            nt = lc // block_size
-            tiles = (
-                chunk.reshape(c, h, nt, block_size)
-                .transpose(0, 2, 1, 3)
-                .reshape(c * nt, h, block_size)
-            )
-            return pool.at[admit_blocks].set(tiles, mode="drop")
-        return pool
+        if chunk.ndim != 3:
+            return pool
+        c, lc, x = chunk.shape
+        tiles = chunk.reshape(c * (lc // block_size), block_size, x)
+        return pool.at[admit_blocks].set(tiles, mode="drop")
 
     return jax.tree.map(scat, pool_tree, chunk_cache)
 

@@ -76,7 +76,7 @@ from distributed_llms_example_tpu.parallel.activation import (
     kv_cache_context,
 )
 from distributed_llms_example_tpu.obs.spans import SpanRecorder, percentiles
-from distributed_llms_example_tpu.parallel.sharding import CACHE_LENGTH_AXIS, _path_str, cache_leaf_name
+from distributed_llms_example_tpu.parallel.sharding import CACHE_LENGTH_AXIS, _path_str, cache_kv_heads, cache_leaf_name
 from distributed_llms_example_tpu.serving import cache_pool
 from distributed_llms_example_tpu.serving import spec as spec_decode
 from distributed_llms_example_tpu.utils.jsonlog import log_json
@@ -316,6 +316,7 @@ class ServingEngine:
     def __init__(self, model: Any, config: Any, mesh: Any,
                  serve: ServeConfig | None = None, *, is_seq2seq: bool = True):
         self.model, self.config, self.mesh = model, config, mesh
+        self.kv_heads = cache_kv_heads(config)
         self.serve = serve or ServeConfig()
         self.is_seq2seq = is_seq2seq
         self.eos = config.eos_token_id
@@ -539,19 +540,6 @@ class ServingEngine:
         mask-invisible to the others, so the size changes no served token."""
         return self.wave_sizes[0] if n <= self.wave_sizes[0] else self.prefill_batch
 
-    @staticmethod
-    def _pad_axis(x, axis: int, width: int):
-        """Right-pad one axis to ``width`` with zeros — how a bucket-width
-        admission chunk lands in full-width slot state.  The padding is
-        mask-invisible: enc_mask/full_mask stay 0 there, so padded
-        positions contribute exactly nothing (the bucketed == unbucketed
-        bit-identity argument)."""
-        if x.shape[axis] == width:
-            return x
-        pads = [(0, 0)] * x.ndim
-        pads[axis] = (0, width - x.shape[axis])
-        return jnp.pad(x, pads)
-
     def _build_programs(self) -> None:
         model, L, S = self.model, self.L, self.S
 
@@ -565,10 +553,10 @@ class ServingEngine:
                 put = lambda dst, src: dst.at[slot_idx].set(src, mode="drop")  # noqa: E731
                 # bucket-width chunks pad to the slot width here, inside
                 # the (per-bucket-compiled) admit program
-                enc = self._pad_axis(enc, 1, self.W)
-                mask = self._pad_axis(mask, 1, self.W)
+                enc = cache_pool.pad_axis(enc, 1, self.W)
+                mask = cache_pool.pad_axis(mask, 1, self.W)
                 ckv = jax.tree.map(
-                    lambda x: self._pad_axis(x, 2, self.W) if x.ndim == 4 else x,
+                    lambda x: cache_pool.pad_axis(x, 2, self.W) if x.ndim == 4 else x,
                     ckv,
                 )
                 return {
@@ -606,7 +594,7 @@ class ServingEngine:
                 nxt = jnp.where(active, nxt, self.pad)
                 return nxt, {
                     **state,
-                    "cache": constrain_cache(mut["cache"]),
+                    "cache": constrain_cache(mut["cache"], self.kv_heads),
                     "last": nxt[:, None],
                 }
         else:
@@ -632,16 +620,6 @@ class ServingEngine:
 
             width_full = self.W + L
 
-            def _pad_cache_tree(cache):
-                # bucket-width chunk cache → slot width, by leaf: K/V and the
-                # int8 scale leaves grow along their length axis; a conv state
-                # or a counter has one shape at every width
-                def pad(path, x):
-                    axis = CACHE_LENGTH_AXIS.get(cache_leaf_name(path))
-                    return x if axis is None else self._pad_axis(x, axis, width_full)
-
-                return jax.tree_util.tree_map_with_path(pad, cache)
-
             if self.paged:
                 n_blocks, bs = self.pool.num_blocks, self.block_size
 
@@ -655,7 +633,7 @@ class ServingEngine:
                         "pool": cache_pool.scatter_admit(
                             state["pool"], cache, admit_blocks, bs
                         ),
-                        "mask": put(state["mask"], self._pad_axis(full_mask, 1, width_full)),
+                        "mask": put(state["mask"], cache_pool.pad_axis(full_mask, 1, width_full)),
                         "last": put(state["last"], first_tok),
                     }
 
@@ -674,7 +652,7 @@ class ServingEngine:
                     tiles scatter back (``admit_blocks`` sentinels the
                     shared chain, which is never written)."""
                     view = constrain_cache(
-                        cache_pool.gather_cache(state["pool"], block_tables)
+                        cache_pool.gather_cache(state["pool"], block_tables), self.kv_heads
                     )
                     positions = start[:, None] + jnp.arange(ids_tail.shape[1])[None, :]
                     logits, mut = model.apply(
@@ -711,7 +689,7 @@ class ServingEngine:
                     # the slot view is a step-transient: only the pool is
                     # resident between steps (serving/cache_pool.py)
                     cache = constrain_cache(
-                        cache_pool.gather_cache(state["pool"], block_tables)
+                        cache_pool.gather_cache(state["pool"], block_tables), self.kv_heads
                     )
                     logits, mut = model.apply(
                         {"params": params, "cache": cache},
@@ -741,8 +719,8 @@ class ServingEngine:
                     )
                     return {
                         **state,
-                        "cache": jax.tree.map(put, state["cache"], _pad_cache_tree(cache)),
-                        "mask": put(state["mask"], self._pad_axis(full_mask, 1, width_full)),
+                        "cache": jax.tree.map(put, state["cache"], cache_pool.pad_cache_length(cache, width_full)),
+                        "mask": put(state["mask"], cache_pool.pad_axis(full_mask, 1, width_full)),
                         "last": put(state["last"], first_tok),
                     }
 
@@ -763,7 +741,7 @@ class ServingEngine:
                     nxt = jnp.where(active, nxt, self.pad)
                     state = {
                         **state,
-                        "cache": constrain_cache(mut["cache"]),
+                        "cache": constrain_cache(mut["cache"], self.kv_heads),
                         "mask": mask,
                         "last": nxt,
                     }
@@ -798,10 +776,8 @@ class ServingEngine:
         from jax.sharding import PartitionSpec as P
 
         from distributed_llms_example_tpu.parallel.sharding import (
-            conv_state_spec,
+            cache_leaf_spec,
             kv_leaf_spec,
-            kv_scale_spec,
-            pool_rules,
         )
 
         mesh_axes = dict(self.mesh.shape)
@@ -811,17 +787,16 @@ class ServingEngine:
         nd = getattr(x, "ndim", 0)
         if nd == 0:
             return P()
-        if path.startswith("pool"):
-            # shared block pool: blocks belong to single slots, so the
-            # block dim never shards over the batch axes — POOL_RULES
-            leaf = path.rsplit("/", 1)[-1]
-            return pool_rules().spec_for(leaf, nd)
-        if path.endswith("conv_state"):
-            return conv_state_spec(x.shape, mesh_axes)
-        if nd == 4:  # cached/cross K/V: the ONE shared layout definition
+        leaf = path.rsplit("/", 1)[-1]
+        if path.startswith(("pool", "cache")):
+            # the slot cache, or the shared block pool (blocks belong to
+            # single slots, so the block dim never shards over the batch
+            # axes — POOL_RULES): the ONE shared layout definition
+            spec = cache_leaf_spec(leaf, x.shape, mesh_axes, self.kv_heads, pool=path.startswith("pool"))
+            if spec is not None:
+                return spec
+        if nd == 4:  # precomputed cross K/V, (slots, heads, len, head_dim)
             return kv_leaf_spec(x.shape, mesh_axes)
-        if nd == 3 and path.endswith("_scale"):  # int8 KV scales
-            return kv_scale_spec(x.shape, mesh_axes)
         batch = BATCH_AXES if x.shape[0] % max(batch_shards, 1) == 0 else None
         return P(batch, *([None] * (nd - 1)))
 

@@ -149,9 +149,12 @@ def build_verify(
     next round's ``write_pos' = write_pos + m + 1`` — exactly where the
     rejected tail starts, so stale K/V is overwritten before its mask bit
     can ever be re-set (write-before-attend)."""
+    from distributed_llms_example_tpu.parallel.sharding import cache_kv_heads
+
     S, K = slots, k
     span = jnp.arange(K + 1)
     rows = jnp.arange(S)
+    kv_heads = cache_kv_heads(model.config)
 
     def _verify_core(params, state, x, block_tables, write_pos, rope_pos,
                      active, room):
@@ -168,7 +171,7 @@ def build_verify(
             )
 
             cache = constrain_cache(
-                cache_pool.gather_cache(state["pool"], block_tables)
+                cache_pool.gather_cache(state["pool"], block_tables), kv_heads
             )
         else:
             cache = state["cache"]
@@ -204,7 +207,7 @@ def build_verify(
                 constrain_cache,
             )
 
-            out["cache"] = constrain_cache(mut["cache"])
+            out["cache"] = constrain_cache(mut["cache"], kv_heads)
         return target, n_emit, out
 
     if paged:
@@ -274,24 +277,15 @@ class DraftRunner:
             return cache, full_mask
 
         def admit(state, cache, full_mask, slot_idx):
-            def pad_axis(x):
-                if getattr(x, "ndim", 0) >= 3 and x.shape[2] != width:
-                    pads = [(0, 0)] * x.ndim
-                    pads[2] = (0, width - x.shape[2])
-                    return jnp.pad(x, pads)
-                return x
-
             put = lambda dst, src: (  # noqa: E731
                 dst.at[slot_idx].set(src, mode="drop") if dst.ndim > 0 else dst
             )
-            fm = full_mask
-            if fm.shape[1] != width:
-                fm = jnp.pad(fm, ((0, 0), (0, width - fm.shape[1])))
+            # bucket-width chunk -> slot width, along each leaf's length axis
             return {
                 "cache": jax.tree.map(
-                    put, state["cache"], jax.tree.map(pad_axis, cache)
+                    put, state["cache"], cache_pool.pad_cache_length(cache, width)
                 ),
-                "mask": put(state["mask"], fm),
+                "mask": put(state["mask"], cache_pool.pad_axis(full_mask, 1, width)),
             }
 
         def round_(params, state, fed, n_fed, pos0, rope0, active):

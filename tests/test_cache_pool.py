@@ -96,12 +96,14 @@ def test_blocks_needed_and_block_row():
 def test_gather_scatter_round_trip():
     """Pool plumbing unit: admit-scatter then gather reconstructs the
     chunk view exactly (zeros at sentinel tiles); step-scatter lands one
-    row in the owning block; sentinel/parked writes drop."""
-    S, H, bs, D, nt = 2, 2, 4, 3, 3
+    row in the owning block; sentinel/parked writes drop.  Leaves are
+    (slots, length, heads x head_dim), pool blocks (blocks, block, heads x
+    head_dim)."""
+    S, HD, bs, nt = 2, 6, 4, 3
     N = 5
     rng = np.random.RandomState(1)
-    chunk = jnp.asarray(rng.randn(S, H, nt * bs, D).astype(np.float32))
-    pool_tree = {"cached_key": jnp.zeros((N, H, bs, D), jnp.float32)}
+    chunk = jnp.asarray(rng.randn(S, nt * bs, HD).astype(np.float32))
+    pool_tree = {"cached_key": jnp.zeros((N, bs, HD), jnp.float32)}
     # row 0: tiles 0,1 → blocks 0,1; row 1: tile 0 → block 2; rest sentinel
     admit = jnp.asarray(np.array([0, 1, N, 2, N, N], np.int32))
     pool_tree = cache_pool.scatter_admit(
@@ -110,12 +112,12 @@ def test_gather_scatter_round_trip():
     bt = jnp.asarray(np.array([[0, 1, N], [2, N, N]], np.int32))
     view = cache_pool.gather_cache(pool_tree, bt)["cached_key"]
     want = np.asarray(chunk).copy()
-    want[0, :, 2 * bs :, :] = 0.0
-    want[1, :, bs:, :] = 0.0
+    want[0, 2 * bs :, :] = 0.0
+    want[1, bs:, :] = 0.0
     np.testing.assert_array_equal(np.asarray(view), want)
     # step write at position 5 of row 0 (tile 1, in-block 1) and a PARKED
     # row 1 (offset = width → must drop)
-    new_cache = {"cached_key": jnp.asarray(rng.randn(S, H, nt * bs, D).astype(np.float32))}
+    new_cache = {"cached_key": jnp.asarray(rng.randn(S, nt * bs, HD).astype(np.float32))}
     offs = jnp.asarray(np.array([5, nt * bs], np.int32))
     before = np.asarray(pool_tree["cached_key"]).copy()
     pool_tree = cache_pool.scatter_step(
@@ -125,12 +127,12 @@ def test_gather_scatter_round_trip():
     # row 0's position 5 = tile 1, in-block slot 1 → exactly block 1
     # changed, at exactly that slot
     np.testing.assert_array_equal(
-        after[1, :, 1, :], np.asarray(new_cache["cached_key"])[0, :, 5, :]
+        after[1, 1, :], np.asarray(new_cache["cached_key"])[0, 5, :]
     )
     untouched = np.ones((bs,), bool)
     untouched[1] = False
     np.testing.assert_array_equal(
-        after[1][:, untouched, :], before[1][:, untouched, :]
+        after[1][untouched, :], before[1][untouched, :]
     )
     # every other block untouched — including row 1's (PARKED: offset =
     # width → the write dropped) and the never-allocated spares
@@ -164,6 +166,18 @@ def test_quantize_kv_round_trip_bound():
     assert np.asarray(dequantize_kv(q0, s0)).sum() == 0.0
 
 
+def _leaf(x):
+    """(B, H, L, d) as the cache (or, with B blocks, the pool) keeps it: (B, L, H x d)."""
+    x = jnp.asarray(x)
+    b, h, length, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, length, h * d)
+
+
+def _scale_leaf(s):
+    """(B, H, L) int8 scales as the cache keeps them: (B, L, H)."""
+    return jnp.asarray(s).transpose(0, 2, 1)
+
+
 def test_flash_decode_int8_scales_parity():
     """Kernel in-VMEM dequant == XLA dequantize_kv + dense attention —
     the identical-expression contract the dispatches rely on."""
@@ -178,7 +192,10 @@ def test_flash_decode_int8_scales_parity():
     offsets = jnp.array([0, 17, L - 1], jnp.int32)
     qk, ks = quantize_kv(k)
     qv, vs = quantize_kv(v)
-    out = flash_decode(q, qk, qv, bias, offsets=offsets, k_scale=ks, v_scale=vs)
+    out = flash_decode(
+        q, _leaf(qk), _leaf(qv), bias, offsets=offsets,
+        k_scale=_scale_leaf(ks), v_scale=_scale_leaf(vs),
+    )
     k_pos = jnp.arange(L)[None, None, None, :]
     step = jnp.where(k_pos <= offsets[:, None, None, None], 0.0, NEG_INF)
     ref = dot_product_attention(
@@ -191,6 +208,8 @@ def test_flash_decode_int8_scales_parity():
 
 
 def _paged_fixture(rng, B, H, L, d, bs, extra_blocks=2):
+    """K/V (B, H, L, d) and the same tiles scattered over a pool, a pool
+    block held as (H, bs, d) here (``_leaf`` lays it as the pool keeps it)."""
     nt = L // bs
     N = B * nt + extra_blocks
     k = rng.randn(B, H, L, d).astype(np.float32)
@@ -208,36 +227,37 @@ def _paged_fixture(rng, B, H, L, d, bs, extra_blocks=2):
     return k, v, k_pool, v_pool, bt, N
 
 
-def test_flash_decode_paged_matches_flat():
+@pytest.mark.parametrize("q_len,H,d", [(1, 4, 16), (4, 4, 16), (8, 16, 64)], ids=["q1", "q4", "q8-cell-heads"])
+def test_flash_decode_paged_matches_flat(q_len, H, d):
     """The block-table kernel (scalar-prefetch indexed pool blocks) is
     bit-identical to flash_decode over the flattened view of the same
     blocks — scrambled block order and all."""
     rng = np.random.RandomState(4)
-    B, H, L, d, bs = 3, 4, 64, 16, 16
+    B, L, bs = 3, 64, 16
     k, v, k_pool, v_pool, bt, N = _paged_fixture(rng, B, H, L, d, bs)
-    q = jnp.asarray(rng.randn(B, H, 1, d).astype(np.float32))
+    q = jnp.asarray(rng.randn(B, H, q_len, d).astype(np.float32))
     bias = jnp.asarray(
         np.where(rng.rand(B, 1, 1, L) > 0.2, 0.0, NEG_INF).astype(np.float32)
     )
-    offsets = jnp.array([0, 30, L - 1], jnp.int32)
+    offsets = jnp.array([0, 30, L - q_len], jnp.int32)
     # same tile size on both sides: the online softmax accumulates in
     # tile order, so bit-identity is a same-tiling property
     flat = flash_decode(
-        q, jnp.asarray(k), jnp.asarray(v), bias, offsets=offsets, block_k=bs
+        q, _leaf(k), _leaf(v), bias, offsets=offsets, block_k=bs
     )
     paged = flash_decode_paged(
-        q, jnp.asarray(k_pool), jnp.asarray(v_pool), bias,
+        q, _leaf(k_pool), _leaf(v_pool), bias,
         block_tables=jnp.asarray(bt), offsets=offsets,
     )
     np.testing.assert_array_equal(np.asarray(paged), np.asarray(flat))
-    # sentinel (unallocated) tiles beyond each row's offset change nothing
+    # sentinel (unallocated) tiles beyond each row's last position change nothing
     bt2 = bt.copy()
     for b in range(B):
         for t in range(L // bs):
-            if t * bs > int(offsets[b]):
+            if t * bs > int(offsets[b]) + q_len - 1:
                 bt2[b, t] = N
     paged2 = flash_decode_paged(
-        q, jnp.asarray(k_pool), jnp.asarray(v_pool), bias,
+        q, _leaf(k_pool), _leaf(v_pool), bias,
         block_tables=jnp.asarray(bt2), offsets=offsets,
     )
     np.testing.assert_array_equal(np.asarray(paged), np.asarray(paged2))
@@ -266,12 +286,13 @@ def test_flash_decode_paged_int8_compose():
     q = jnp.asarray(rng.randn(B, H, 1, d).astype(np.float32))
     offsets = jnp.array([7, L - 1], jnp.int32)
     flat = flash_decode(
-        q, qk, qv, offsets=offsets, k_scale=ks, v_scale=vs, block_k=bs
+        q, _leaf(qk), _leaf(qv), offsets=offsets,
+        k_scale=_scale_leaf(ks), v_scale=_scale_leaf(vs), block_k=bs,
     )
     paged = flash_decode_paged(
-        q, jnp.asarray(kqp), jnp.asarray(vqp),
+        q, _leaf(kqp), _leaf(vqp),
         block_tables=jnp.asarray(bt), offsets=offsets,
-        k_scale_pool=jnp.asarray(ksp), v_scale_pool=jnp.asarray(vsp),
+        k_scale_pool=_scale_leaf(ksp), v_scale_pool=_scale_leaf(vsp),
     )
     np.testing.assert_array_equal(np.asarray(paged), np.asarray(flat))
 
@@ -585,12 +606,14 @@ def test_int8_cache_scale_leaves_lint_green():
 def test_pool_rules_lint_and_scale_spec(mesh8):
     """POOL_RULES validates the pool tree like CACHE_RULES validates the
     flat cache (blocks never shard over batch axes, heads over tensor) —
-    and kv_scale_spec resolves the scale layout on the real mesh."""
+    and cache_leaf_spec resolves the scale layout on the real mesh."""
+    from jax.sharding import PartitionSpec as P
+
     from distributed_llms_example_tpu.analysis.spec_lint import lint_cache_sharding
     from distributed_llms_example_tpu.evaluation.generation import abstract_cache
     from distributed_llms_example_tpu.parallel.sharding import (
+        cache_leaf_spec,
         cache_rules,
-        kv_scale_spec,
         pool_rules,
         resolve_shardings,
     )
@@ -617,9 +640,26 @@ def test_pool_rules_lint_and_scale_spec(mesh8):
     assert scales
     for path, spec in scales:
         assert spec[0] == ("data", "fsdp", "expert"), (path, spec)
-        assert spec[1] == "tensor", (path, spec)
-    # the one definition both sides derive from
-    assert kv_scale_spec((8, 4, 24), dict(mesh8.shape))[1] == "tensor"
+        assert spec[1] is None and spec[2] == "tensor", (path, spec)  # (slots, length, heads)
+    # the one definition both sides derive from, by the leaf's name
+    axes = dict(mesh8.shape)
+    assert cache_leaf_spec("key_scale", (8, 24, 4), axes, 4)[2] == "tensor"
+    assert cache_leaf_spec("cached_value", (8, 24, 64), axes, 4)[1:] == (None, "tensor")
+    assert cache_leaf_spec("conv_state", (8, 64, 2), axes, 4)[1:] == ("tensor", None)
+    assert cache_leaf_spec("cached_key", (8, 24, 3), axes, 3)[2] is None  # a ragged axis replicates
+    assert cache_leaf_spec("cache_index", (), axes, 4) is None
+    # ragged HEADS: llama-test's 2 KV heads of 16 on tensor=4.  The merged
+    # axis' 32 lanes would divide, the heads do not: K/V replicate over
+    # tensor as their scales do (and as the kernel's eligibility reads it),
+    # so no shard holds half a head
+    axes4 = {"data": 2, "fsdp": 1, "tensor": 4}
+    for name, width in (("cached_key", 32), ("cached_value", 32), ("key_scale", 2)):
+        assert cache_leaf_spec(name, (8, 24, width), axes4, 2)[2] is None, name
+        assert cache_leaf_spec(name, (40, 16, width), axes4, 2, pool=True) == P(None, None, None), name
+    assert cache_leaf_spec("cached_key", (8, 24, 64), axes4, 4)[2] == "tensor"
+    # the block pool: the K/V layout with the block dim never sharded
+    assert cache_leaf_spec("cached_key", (40, 16, 64), axes4, 4, pool=True) == P(None, None, "tensor")
+    assert cache_leaf_spec("value_scale", (40, 16, 4), axes4, 4, pool=True) == P(None, None, "tensor")
 
 
 # ----------------------------------------------- prefix cache: pool unit
@@ -894,18 +934,18 @@ def test_engine_prefix_warm_beam_bit_identical(llama_runs):
     for cold, warm in zip(
         jax.tree.leaves(carry_cold["cache"]), jax.tree.leaves(warm_view)
     ):
-        if getattr(cold, "ndim", 0) == 4:
+        if getattr(cold, "ndim", 0) == 3:  # a K/V leaf, (rows, length, heads x head_dim)
             # warm pool bytes ≈ cold prefill bytes over the cached prefix
             # (exact within one program; here across two compilations)
             np.testing.assert_allclose(
-                np.asarray(warm)[0, :, :kbs, :],
-                np.asarray(cold)[0, :, :kbs, :], atol=1e-5,
+                np.asarray(warm)[0, :kbs, :],
+                np.asarray(cold)[0, :kbs, :], atol=1e-5,
             )
 
     def splice(c, w):
-        if getattr(c, "ndim", 0) == 4 and c.shape[-1] == w.shape[-1]:
-            rep = jnp.repeat(w[:, :, :kbs, :], 2, axis=0)  # K beams share it
-            return c.at[:, :, :kbs, :].set(rep)
+        if getattr(c, "ndim", 0) == 3 and c.shape[-1] == w.shape[-1]:
+            rep = jnp.repeat(w[:, :kbs, :], 2, axis=0)  # K beams share it
+            return c.at[:, :kbs, :].set(rep)
         return c
 
     carry_warm = dict(carry_cold)

@@ -16,6 +16,7 @@ runs in the test's own process, and all of it lives in this one file.
 
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +83,36 @@ def _compile(fn, sharding, *shapes):
     return text
 
 
+_COPY = re.compile(r"= (?:\([^=]*\)|\S+) copy(?:-start)?\(")
+_SHAPE = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _large_copies(text: str, elements: int) -> list[str]:
+    """The ``copy`` / ``copy-start`` lines of a compiled program whose result
+    holds at least ``elements`` elements.  A ``copy-start`` whose two buffers
+    have one shape and one tiling and differ in the memory they lie in
+    (``S(1)``) is the compiler prefetching an operand whole (bart's bfloat16
+    embedding table, every round): a move, not a relayout, and not counted."""
+    found = []
+    for line in text.splitlines():
+        if _COPY.search(line):
+            result = line.split(" copy", 1)[0]
+            buffers = re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", result)
+            if len(buffers) >= 2 and len({re.sub(r"S\(\d+\)", "", b) for b in buffers[:2]}) == 1:
+                continue
+            sizes = [math.prod(int(n) for n in dims.split(",") if n) for dims in _SHAPE.findall(result)]
+            if sizes and max(sizes) >= elements:
+                found.append(line.strip()[:200])
+    return found
+
+
+def _entry_layouts(text: str) -> list[str]:
+    """``dtype[shape]{layout...}`` of every parameter of the entry computation."""
+    line = next(ln for ln in text.splitlines() if "entry_computation_layout" in ln)
+    params = line.split("entry_computation_layout={(", 1)[1].split(")->", 1)[0]
+    return re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", params)
+
+
 def _qkv(q_len, kv_len, heads=H, d=D):
     return [((B, heads, q_len, d), BF16), ((B, heads, kv_len, d), BF16), ((B, heads, kv_len, d), BF16)]
 
@@ -139,14 +170,23 @@ def test_flash_llama7b_shape_compiles(one_chip, compiled_kernels):
 
 
 DECODE_CASES = {
-    # name: (slots, q rows, cache length, int8 K/V with (B, H, L) scales)
-    "train-batch-cache128": (B, 1, TGT, False),
-    "train-batch-cache1024": (B, 1, SRC, False),
+    # name: (slots, q rows, cache length, int8 K/V with (B, L, H) scales, heads, head_dim)
+    "train-batch-cache128": (B, 1, TGT, False, H, D),
+    "train-batch-cache1024": (B, 1, SRC, False, H, D),
     # bart-large-cnn.serve-steady's decode step, exactly: 64 slots, one row, cache 128
-    "serve-cell": (64, 1, TGT, False),
-    "serve-cell-verify-8-rows": (64, 8, TGT, False),
-    "serve-cell-cache1024": (64, 1, SRC, False),
-    "serve-cell-int8": (64, 1, TGT, True),
+    "serve-cell": (64, 1, TGT, False, H, D),
+    "serve-cell-verify-8-rows": (64, 8, TGT, False, H, D),
+    "serve-cell-cache1024": (64, 1, SRC, False, H, D),
+    "serve-cell-int8": (64, 1, TGT, True, H, D),
+    # the registered 7B / 13B shapes (32 / 40 heads of 128): the heads go in
+    # groups of whole 128-lane tiles, under the int8 cache too, where every
+    # step takes all heads' scales and picks its group's (4 and 5 heads a step)
+    "llama-7b-cache4096": (8, 1, 4096, False, 32, 128),
+    "llama-7b-cache4096-int8": (8, 1, 4096, True, 32, 128),
+    "llama-7b-cache128-int8": (8, 1, 128, True, 32, 128),
+    "llama-13b-cache4096-int8-verify-8-rows": (8, 8, 4096, True, 40, 128),
+    # heads of 64 under int8, split in pairs of pairs (12 heads, kv tile 512)
+    "heads-of-64-cache2048-int8": (8, 1, 2048, True, 24, 64),
 }
 
 
@@ -154,16 +194,18 @@ DECODE_CASES = {
 def test_flash_decode_compiles(case, one_chip, compiled_kernels):
     from distributed_llms_example_tpu.ops.flash_attention import flash_decode
 
-    slots, q_len, cache_len, int8 = DECODE_CASES[case]
-    kv = ((slots, H, cache_len, D), jnp.int8 if int8 else BF16)
-    shapes = [((slots, H, q_len, D), BF16), kv, kv, ((slots,), jnp.int32)]
+    slots, q_len, cache_len, int8, heads, d = DECODE_CASES[case]
+    kv = ((slots, cache_len, heads * d), jnp.int8 if int8 else BF16)  # the cache leaf: (slots, length, heads x d)
+    shapes = [((slots, heads, q_len, d), BF16), kv, kv, ((slots,), jnp.int32)]
     if int8:
-        shapes += [((slots, H, cache_len), F32)] * 2
+        shapes += [((slots, cache_len, heads), F32)] * 2
 
     def step(q, k, v, offsets, k_scale=None, v_scale=None):
         return flash_decode(q, k, v, offsets=offsets, k_scale=k_scale, v_scale=v_scale)
 
-    _compile(step, one_chip, *shapes)
+    text = _compile(step, one_chip, *shapes)
+    # the kernel takes the leaf as it rests: nothing the size of a leaf is relaid around it
+    assert not _large_copies(text, slots * cache_len * heads * d)
 
 
 @pytest.mark.parametrize("with_residual", [False, True], ids=["plain", "residual"])
@@ -231,11 +273,12 @@ def test_flash_decode_grouped_query_cell_shape_compiles(one_chip, compiled_kerne
 
     cache = LFM2_PROMPT + LFM2_NEW
     assert decode_block(cache) == 256 and decode_block(128) == 128 and decode_block(1024) == 512
-    kv = ((LFM2_SLOTS, 8, cache, D), BF16)
-    _compile(
+    kv = ((LFM2_SLOTS, cache, 8 * D), BF16)
+    text = _compile(
         lambda q, k, v, bias, offsets: flash_decode(q, k, v, bias, offsets=offsets, q_group=4),
         one_chip, ((LFM2_SLOTS, 8, 4, D), BF16), kv, kv, ((LFM2_SLOTS, 1, 1, cache), F32), ((LFM2_SLOTS,), jnp.int32),
     )
+    assert not _large_copies(text, LFM2_SLOTS * cache * 8 * D)
 
 
 @pytest.fixture
@@ -261,8 +304,9 @@ def lfm2_cell_engine(topo, compiled_kernels, monkeypatch):
 def test_lfm2_cell_serving_programs_compile_and_fit(lfm2_cell_engine, one_chip):
     """Decode step, prefill wave and admit at 128 slots, 1 x and 4 x 1024, cache 1280,
     32 query / 8 KV heads of 64, every expert: what the chip's compiler says of
-    their memory, and that the decode step holds no K/V repeated to the query
-    heads (3.0 GB of temporaries before the heads were grouped, 1.35 GB after)."""
+    their memory, that the decode step holds no K/V repeated to the query
+    heads (3.0 GB of temporaries before the heads were grouped, 1.35 GB after),
+    and that it relays no K/V leaf (PR 32)."""
     lm, eng = lfm2_cell_engine
 
     def abstract(tree, dtype=None):
@@ -275,16 +319,17 @@ def test_lfm2_cell_serving_programs_compile_and_fit(lfm2_cell_engine, one_chip):
     zeros = lambda n: (jnp.zeros((n, LFM2_PROMPT), jnp.int32),) * 2  # noqa: E731
     slots_cache, slots_mask, _, _ = jax.eval_shape(lambda p: eng._prefill_core(p, *zeros(LFM2_SLOTS)), params)
     shapes = {jax.tree_util.keystr(p): x.shape for p, x in jax.tree_util.tree_leaves_with_path(slots_cache)}
-    assert sorted(set(shapes.values())) == [(), (128, 8, 1280, 64), (128, 2048, 2)], shapes
+    assert sorted(set(shapes.values())) == [(), (128, 1280, 8 * 64), (128, 2048, 2)], shapes  # K/V: (slots, length, kv_heads x d)
     state = {"cache": abstract(slots_cache), "mask": abstract(slots_mask), "last": i32(LFM2_SLOTS)}
     active = jax.ShapeDtypeStruct((LFM2_SLOTS,), jnp.bool_, sharding=one_chip)
 
     step = eng._step.lower(params, state, i32(LFM2_SLOTS), i32(LFM2_SLOTS), active).compile()
     text, mem = step.as_text(), step.memory_analysis()
     assert "tpu_custom_call" in text and "%gmm" in text and "ragged-dot" not in text  # the Pallas grouped product
-    assert "bf16[128,32,1280,64]" not in text  # K/V are read at their 8 heads
+    assert "bf16[128,32,1280,64]" not in text and "bf16[128,1280,2048]" not in text  # K/V are read at their 8 heads
     assert mem.argument_size_in_bytes < 3.8e9 and mem.temp_size_in_bytes < 1.6e9, mem
-    assert mem.alias_size_in_bytes > 0.33e9  # the cache is updated in place
+    _assert_cache_rests_where_it_is_read(text, mem, (128, 1280, 512), leaves=2)
+    assert len(_decode_attn_calls(text, (128, 8, 4, 64))) == 1  # the one attention layer, 4 query heads a KV head
 
     assert eng.wave_sizes == (1, LFM2_WAVE)  # a wave of one request runs a one-row program (PR 30)
     for rows in eng.wave_sizes:
@@ -293,6 +338,72 @@ def test_lfm2_cell_serving_programs_compile_and_fit(lfm2_cell_engine, one_chip):
         assert "%gmm" in wave.as_text() and wave.memory_analysis().temp_size_in_bytes < 1.5e9
         admit = eng._admit.lower(state, abstract(wave_cache), abstract(wave_mask), abstract(wave_first), i32(rows)).compile()
         assert admit.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def _assert_cache_rests_where_it_is_read(text, mem, leaf, leaves):
+    """The static evidence of PR 32 on a compiled decode step: no ``copy`` of a
+    K/V leaf's size or more is left (before, three a leaf a round: rest ->
+    the scatter's layout -> the kernel's, and back), every K/V leaf enters the
+    program in the descending layout the row write and ``flash_decode`` take,
+    and the cache is still updated in place."""
+    elements = math.prod(leaf)
+    assert not _large_copies(text, elements), _large_copies(text, elements)
+    dims = ",".join(str(n) for n in leaf)
+    entry = [p for p in _entry_layouts(text) if p.startswith(f"bf16[{dims}]")]
+    assert len(entry) == leaves, entry
+    assert all(p.startswith(f"bf16[{dims}]{{2,1,0:") for p in entry), entry
+    assert mem.alias_size_in_bytes >= leaves * elements * 2, mem  # bf16 leaves, donated
+
+
+def _decode_attn_calls(text, result):
+    """The decode kernel's custom calls as the chip's trace will name them: call
+    site ``self_attn`` alone and the q block as result, which is how
+    ``benchmarks/layer_metrics/serve_decode_attn_ms.py`` finds them."""
+    dims = ",".join(str(n) for n in result)
+    return re.findall(rf"%self_attn\.\d+ = bf16\[{dims}\]\{{[^}}]*\}} custom-call\(", text)
+
+
+BART_SLOTS, BART_WAVE, BART_PROMPT, BART_NEW = 64, 8, 1024, 128
+
+
+def test_bart_cell_decode_step_copies_no_cache_leaf(topo, one_chip, compiled_kernels, monkeypatch):
+    """``bart-large-cnn.serve-steady``'s decode step, whole, for the described chip:
+    64 slots, prompt 1024, 128 new tokens, abstract float32 weights, bfloat16
+    compute.  24 K/V leaves of (64, 128, 16 x 64)."""
+    from benchmarks.harness import program, spec as spec_mod
+    from distributed_llms_example_tpu.core.config import MeshConfig
+    from distributed_llms_example_tpu.core.mesh import build_mesh
+    from distributed_llms_example_tpu.evaluation.generation import _init_cache
+    from distributed_llms_example_tpu.models import registry
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels' auto rules and donation ask it
+    cfg = spec_mod.load_json(os.path.join(spec_mod.BENCH_DIR, "configs", "bart-large-cnn.json"))
+    lm = registry.load_model(
+        program.register_bench_model(cfg, spec_mod.load_module("adapters", cfg["family"])), dtype=BF16)
+    serve = ServeConfig(max_slots=BART_SLOTS, prefill_batch=BART_WAVE, max_new_tokens=BART_NEW,
+                        max_source_length=BART_PROMPT)
+    eng = ServingEngine(lm.module, lm.config, build_mesh(MeshConfig(data=-1), devices=topo.devices[:1]),
+                        serve, is_seq2seq=True)
+    abstract = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    slots = lambda tree: jax.tree.map(  # noqa: E731 — a wave's rows -> the slots
+        lambda x: jax.ShapeDtypeStruct((BART_SLOTS, *x.shape[1:]), x.dtype, sharding=one_chip), tree)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    params = abstract(jax.eval_shape(lambda: lm.init_params(0)))  # float32, as the cell serves them
+    ids = jnp.zeros((1, BART_PROMPT), jnp.int32)
+    enc, mask, ckv = slots(eng._prefill.eval_shape(params, ids, ids))
+    cache = abstract(jax.eval_shape(lambda p: _init_cache(
+        eng.model, p, BART_SLOTS, BART_NEW, jnp.zeros(enc.shape, enc.dtype), jnp.zeros(mask.shape, mask.dtype)), params))
+    shapes = {x.shape for x in jax.tree.leaves(cache)}
+    assert shapes == {(), (64, 128, 16 * 64)}, shapes  # K/V: (slots, length, heads x d)
+    state = {"cache": cache, "enc": enc, "enc_mask": mask, "ckv": ckv, "last": i32(BART_SLOTS, 1)}
+    active = jax.ShapeDtypeStruct((BART_SLOTS,), jnp.bool_, sharding=one_chip)
+
+    step = eng._step.lower(params, state, i32(BART_SLOTS), active).compile()
+    text = step.as_text()
+    assert len(_decode_attn_calls(text, (64, 16, 1, 64))) == 12  # flash_decode, one a decoder layer
+    _assert_cache_rests_where_it_is_read(text, step.memory_analysis(), (64, 128, 1024), leaves=24)
 
 
 def test_lfm2_seeded_weights_are_made_in_one_copy(one_chip):

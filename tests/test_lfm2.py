@@ -117,7 +117,7 @@ def test_the_cache_holds_conv_state_beside_kv_and_the_summary_counts_both():
     lm, params, _ = seeded(6)
     sess = _engine(lm).open(params)
     leaves = {cache_leaf_name(p): x.shape for p, x in jax.tree_util.tree_leaves_with_path(sess.state["cache"])}
-    assert leaves["conv_state"] == (3, 64, 2) and leaves["cached_key"] == (3, 2, 34, 16)
+    assert leaves["conv_state"] == (3, 64, 2) and leaves["cached_key"] == (3, 34, 2 * 16)
     assert sess._cache_bytes_by_kind == {"kv_bytes": 2 * 2 * 3 * 2 * 34 * 16 * 4, "conv_state_bytes": 3 * 3 * 64 * 2 * 4}
     sess.finalize()
 

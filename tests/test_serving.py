@@ -21,37 +21,65 @@ from distributed_llms_example_tpu.ops.mha import decode_step_bias, select_decode
 # ------------------------------------------------------ kernel unit parity
 
 
-def _dense_decode_ref(q, k, v, bias, offsets, scale=None):
-    """Masked dot_product_attention with the kernel's per-row length mask."""
+def _dense_decode_ref(q, k, v, bias, offsets, scale=None, q_group=1):
+    """Masked dot_product_attention with the kernel's per-row length mask
+    (``q_group`` q rows a position: the query heads that share a KV head)."""
     L = k.shape[2]
     Q = q.shape[2]
     k_pos = jnp.arange(L)[None, None, None, :]
-    q_pos = offsets[:, None, None, None] + jnp.arange(Q)[None, None, :, None]
+    q_pos = offsets[:, None, None, None] + (jnp.arange(Q) // q_group)[None, None, :, None]
     step = jnp.where(k_pos <= q_pos, 0.0, NEG_INF)
     return dot_product_attention(q, k, v, step if bias is None else bias + step, scale=scale)
 
 
-# name: (B, H, L, d, q_len, bias, int8 K/V, block_k, K+V VMEM budget) — bias
-# is None, "pad" (B, 1, 1, L) or "full" (B, H, q, L: T5's decode-step bias)
+def _leaf(x):
+    """(B, H, L, d) as the cache keeps it: (B, L, H x d), heads side by side."""
+    b, h, length, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, length, h * d)
+
+
+def _scale_leaf(s):
+    """(B, H, L) int8 scales as the cache keeps them: (B, L, H)."""
+    return s.transpose(0, 2, 1)
+
+
+# name: (B, H, L, d, q_len, bias, int8 K/V, block_k, step's VMEM budget, q rows a
+# position) — bias is None, "pad" (B, 1, 1, L) or "full" (B, H, q, L: T5's
+# decode-step bias)
 DECODE_CASES = {
-    "tiny-q1": (3, 4, 64, 16, 1, "pad", False, None, None),
-    "tiny-q4": (3, 4, 64, 16, 4, "pad", False, None, None),
+    "tiny-q1": (3, 4, 64, 16, 1, "pad", False, None, None, 1),
+    "tiny-q4": (3, 4, 64, 16, 4, "pad", False, None, None, 1),
     # the serve cell's head shape (bart-large-cnn: 16 heads x 64, cache 128):
     # a grid step holds every head of a slot
-    "cell-q1": (4, 16, 128, 64, 1, None, False, None, None),
-    "cell-q8": (4, 16, 128, 64, 8, None, False, None, None),
-    "cell-q1-pad-bias": (4, 16, 128, 64, 1, "pad", False, None, None),
-    "cell-q8-pad-bias": (4, 16, 128, 64, 8, "pad", False, None, None),
-    "cell-q1-full-bias": (4, 16, 128, 64, 1, "full", False, None, None),
-    "cell-q8-full-bias": (4, 16, 128, 64, 8, "full", False, None, None),
-    "cell-q1-int8": (4, 16, 128, 64, 1, None, True, None, None),
-    "cell-q8-int8-full-bias": (4, 16, 128, 64, 8, "full", True, None, None),
-    # four kv tiles, offsets in the first, a middle and the last one
-    "tiles-q1": (4, 4, 256, 16, 1, "pad", False, 64, None),
-    "tiles-q4-full-bias": (4, 4, 256, 16, 4, "full", False, 64, None),
-    # a budget that holds two of eight heads: the head axis splits
-    "split-heads-q1": (3, 8, 64, 16, 1, "full", False, None, 4 * 2 * 64 * 128 * 4),
-    "split-heads-q4-int8": (3, 16, 64, 16, 4, "pad", True, None, 16 * 2 * 64 * 128 * 4),
+    "cell-q1": (4, 16, 128, 64, 1, None, False, None, None, 1),
+    "cell-q8": (4, 16, 128, 64, 8, None, False, None, None, 1),
+    "cell-q1-pad-bias": (4, 16, 128, 64, 1, "pad", False, None, None, 1),
+    "cell-q8-pad-bias": (4, 16, 128, 64, 8, "pad", False, None, None, 1),
+    "cell-q1-full-bias": (4, 16, 128, 64, 1, "full", False, None, None, 1),
+    "cell-q8-full-bias": (4, 16, 128, 64, 8, "full", False, None, None, 1),
+    "cell-q1-int8": (4, 16, 128, 64, 1, None, True, None, None, 1),
+    "cell-q1-int8-pad-bias": (4, 16, 128, 64, 1, "pad", True, None, None, 1),
+    "cell-q8-int8-full-bias": (4, 16, 128, 64, 8, "full", True, None, None, 1),
+    # lfm2's head shape (8 KV heads x 64, four query heads a KV head as q rows)
+    "grouped-q4": (4, 8, 256, 64, 4, None, False, None, None, 4),
+    "grouped-q4-pad-bias": (4, 8, 256, 64, 4, "pad", False, None, None, 4),
+    "grouped-q8-two-positions-pad-bias": (4, 8, 256, 64, 8, "pad", False, None, None, 4),
+    "grouped-q4-int8-pad-bias": (4, 8, 256, 64, 4, "pad", True, None, None, 4),
+    # four kv tiles, offsets in the first, a middle and the last one: the
+    # first two rows leave dead tiles behind them
+    "tiles-q1": (4, 4, 256, 16, 1, "pad", False, 64, None, 1),
+    "tiles-q4-full-bias": (4, 4, 256, 16, 4, "full", False, 64, None, 1),
+    "tiles-q8": (4, 4, 256, 16, 8, None, False, 64, None, 1),
+    "tiles-grouped-q4-pad-bias": (4, 8, 256, 64, 4, "pad", False, 64, None, 4),
+    "tiles-q1-int8": (4, 16, 256, 64, 1, None, True, 64, None, 1),
+    # a budget that holds two of eight heads (128 lanes): the merged axis splits
+    "split-heads-q1": (3, 8, 64, 64, 1, "full", False, None, 200_000, 1),
+    "split-heads-grouped-q4": (3, 8, 64, 64, 4, "pad", False, None, 220_000, 4),
+    # int8 K/V in groups of heads: the (B, L, H) scales come whole every step
+    # and a group picks its own (a 7B cache's 32 heads of 128 need this at
+    # every kv tile the cache has)
+    "split-heads-q4-int8": (3, 8, 64, 64, 4, "pad", True, None, 400_000, 1),
+    "split-heads-tiles-q1-int8-full-bias": (3, 8, 128, 64, 1, "full", True, 64, 400_000, 1),
 }
 
 
@@ -59,12 +87,10 @@ DECODE_CASES = {
 def test_flash_decode_matches_dense(case, monkeypatch):
     from distributed_llms_example_tpu.ops import flash_attention as fa
 
-    B, H, L, d, q_len, bias_kind, int8, block_k, budget = DECODE_CASES[case]
+    B, H, L, d, q_len, bias_kind, int8, block_k, budget, q_group = DECODE_CASES[case]
     if budget is not None:
         monkeypatch.setattr(fa, "DECODE_STEP_VMEM_BUDGET", budget)
-        hb = fa.decode_step_heads(
-            H, block_k or L, d, 1 if int8 else 4, int8_scales=int8
-        )
+        hb = fa.decode_step_heads(H, block_k or L, d, 1 if int8 else 4, q_len=q_len, int8_scales=int8)
         assert 1 < hb < H  # the case is what its name says
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(B, H, q_len, d).astype(np.float32))
@@ -87,47 +113,69 @@ def test_flash_decode_matches_dense(case, monkeypatch):
         qk, ks = fa.quantize_kv(k)
         qv, vs = fa.quantize_kv(v)
         out = flash_decode(
-            q, qk, qv, bias, offsets=offsets, k_scale=ks, v_scale=vs, block_k=block_k
+            q, _leaf(qk), _leaf(qv), bias, offsets=offsets, k_scale=_scale_leaf(ks),
+            v_scale=_scale_leaf(vs), block_k=block_k, q_group=q_group,
         )
         k, v = fa.dequantize_kv(qk, ks), fa.dequantize_kv(qv, vs)
     else:
-        out = flash_decode(q, k, v, bias, offsets=offsets, block_k=block_k)
-    ref = _dense_decode_ref(q, k, v, bias, offsets)
+        out = flash_decode(q, _leaf(k), _leaf(v), bias, offsets=offsets, block_k=block_k, q_group=q_group)
+    ref = _dense_decode_ref(q, k, v, bias, offsets, q_group=q_group)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
 
 
 def test_flash_decode_grid_is_slots_by_tiles():
     """At the serve cell's shape one call takes at most batch x kv-tiles grid
     steps: a step per (slot, head) cost 0.51 us for 2 x 16 KB on the chip
-    (PERF.md, PR 26), and no CPU test would notice its return."""
+    (PERF.md, PR 26), and no CPU test would notice its return.  Its K/V blocks
+    are tiles of the cache leaf as it rests, (1, block_k, heads x head_dim)."""
     B, H, L, d = 64, 16, 128, 64
     q = jax.ShapeDtypeStruct((B, H, 1, d), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((B, H, L, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, L, H * d), jnp.bfloat16)
     off = jax.ShapeDtypeStruct((B,), jnp.int32)
     jaxpr = jax.make_jaxpr(lambda q, k, v, o: flash_decode(q, k, v, offsets=o))(q, kv, kv, off)
     calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
-    grid = calls[0].params["grid_mapping"].grid
-    assert int(np.prod(grid)) <= B * (L // 128), grid
+    mapping = calls[0].params["grid_mapping"]
+    assert int(np.prod(mapping.grid)) <= B * (L // 128), mapping.grid
+    blocks = [tuple(getattr(n, "block_size", n) for n in bm.block_shape) for bm in mapping.block_mappings]
+    assert blocks.count((1, 128, H * d)) == 2, blocks
+    assert calls[0].outvars[0].aval.shape == (B, H, 1, d)  # what serve_decode_attn_ms looks for
+
+
+def test_flash_decode_refuses_the_old_leaf():
+    q = jnp.zeros((2, 4, 1, 16))
+    kv = jnp.zeros((2, 4, 64, 16))
+    with pytest.raises(ValueError, match=r"\(batch, length, heads x head_dim\)"):
+        flash_decode(q, kv, kv, offsets=jnp.zeros((2,), jnp.int32))
 
 
 def test_decode_step_heads_rule():
     from distributed_llms_example_tpu.ops.flash_attention import decode_step_heads
 
-    # bart-large-cnn's serve cell: all 16 heads of a slot, 1 MB of VMEM a step
+    # bart-large-cnn's serve cell: all 16 heads of a slot side by side, 1 MB of VMEM a step
     assert decode_step_heads(16, 128, 64, 2) == 16
-    # a 7B cache tile (512 x 128 bf16) is 256 KB a head for K and V: 16 of 32 heads
-    assert decode_step_heads(32, 512, 128, 2) == 16
-    # five heads of 2 MB do not fit and 5 has no smaller group: one head a
-    # step, the tiling before PR 26
+    # lfm2's: 8 KV heads, four q rows each, a 256-row tile
+    assert decode_step_heads(8, 256, 64, 2, q_len=4) == 8
+    # a 7B cache tile (512 x 128 bf16) is 256 KB a head for K and V: 16 of 32
+    # heads' tiles fit alone, 8 with their q block and accumulator beside them
+    assert decode_step_heads(32, 512, 128, 2) == 8
+    # a group's lanes are whole vregs: heads of 64 split in pairs, not singly
+    assert decode_step_heads(32, 512, 64, 4, q_len=8) % 2 == 0
+    # five heads of 2 MB do not fit and 5 has no smaller group: one head a step
     assert decode_step_heads(5, 512, 1024, 2) == 1
-    # int8 K/V: the scales' block wants 8 heads or all of them
+    # int8 K/V: tiles count as the f32 they dequantize to, and all heads'
+    # scales ride every step (padded to 128 lanes)
     assert decode_step_heads(16, 128, 64, 1, int8_scales=True) == 16
-    assert decode_step_heads(32, 512, 128, 1, int8_scales=True) == 8
-    # 12 heads x a 512-row tile as f32 do not fit and no group of 8 divides
-    # them: named here, not a Mosaic failure on a (1, 1, block_k) scale block
-    with pytest.raises(ValueError, match="int8 K/V with 12 heads"):
-        decode_step_heads(12, 512, 64, 1, int8_scales=True)
+    assert decode_step_heads(12, 512, 64, 1, int8_scales=True) == 12
+    # llama-2-7b / 13b under --kv-cache-dtype int8 (32 / 40 heads of 128, kv
+    # tile 512): 8 heads' tiles alone are the budget, so 4 and 5 a step
+    assert decode_step_heads(32, 512, 128, 1, int8_scales=True) == 4
+    assert decode_step_heads(40, 512, 128, 1, int8_scales=True) == 5
+    assert decode_step_heads(8, 512, 128, 1, int8_scales=True) == 4  # mixtral-8x7b's 8 KV heads
+    # five heads of 1024 as f32 fit not even singly: named here, not a Mosaic
+    # failure far from its cause, and asked first by select_decode_impl
+    with pytest.raises(ValueError, match="5 heads of 1024.*int8 K/V"):
+        decode_step_heads(5, 512, 1024, 1, int8_scales=True)
 
 
 def test_flash_decode_stale_cache_unreachable():
@@ -139,17 +187,41 @@ def test_flash_decode_stale_cache_unreachable():
     k = jnp.asarray(rng.randn(B, H, L, d).astype(np.float32))
     v = jnp.asarray(rng.randn(B, H, L, d).astype(np.float32))
     offsets = jnp.array([3, 9], jnp.int32)
-    out = flash_decode(q, k, v, offsets=offsets)
+    out = flash_decode(q, _leaf(k), _leaf(v), offsets=offsets)
     # poison everything beyond each row's offset with huge garbage
     k_pos = jnp.arange(L)[None, None, :, None]
     beyond = k_pos > offsets[:, None, None, None]
     out_poisoned = flash_decode(
         q,
-        jnp.where(beyond, 1e6, k),
-        jnp.where(beyond, -1e6, v),
+        _leaf(jnp.where(beyond, 1e6, k)),
+        _leaf(jnp.where(beyond, -1e6, v)),
         offsets=offsets,
     )
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out_poisoned))
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_flash_decode_run_shards_the_leaf_like_its_heads(mesh8, int8):
+    """Per shard under ``shard_map`` the kernel sees its batch rows and its
+    heads: q's head axis and the leaf's merged last axis split over ``tensor``
+    together (the heads are contiguous in it), the (B, L, H) scales with them."""
+    from distributed_llms_example_tpu.ops import flash_attention as fa
+
+    rng = np.random.RandomState(5)
+    B, H, L, d, Q = 8, 4, 64, 16, 2
+    q = jnp.asarray(rng.randn(B, H, Q, d).astype(np.float32))
+    k = jnp.asarray(rng.randn(B, H, L, d).astype(np.float32))
+    v = jnp.asarray(rng.randn(B, H, L, d).astype(np.float32))
+    bias = jnp.asarray(rng.randn(1, H, Q, L).astype(np.float32))  # a head axis: it shards with the heads
+    offsets = jnp.asarray(rng.randint(0, L - Q, (B,)), jnp.int32)
+    scales = {}
+    if int8:
+        (k, ks), (v, vs) = fa.quantize_kv(k), fa.quantize_kv(v)
+        scales = {"k_scale": _scale_leaf(ks), "v_scale": _scale_leaf(vs)}
+    one = flash_decode(q, _leaf(k), _leaf(v), bias, offsets=offsets, **scales)
+    sharded = jax.jit(lambda q, k, v, bias, off, sc: fa.flash_decode_run(
+        q, k, v, bias, offsets=off, mesh=mesh8, **sc))(q, _leaf(k), _leaf(v), bias, offsets, scales)
+    np.testing.assert_array_equal(np.asarray(sharded), np.asarray(one))
 
 
 def test_flash_decode_supported_gating():
@@ -172,6 +244,18 @@ def test_select_decode_impl_pure():
     assert select_decode_impl("flash", **{**kw, "backend": "cpu"})[0] == "flash_decode"
     # untileable cache falls back even when forced
     assert select_decode_impl("flash", **{**kw, "kv_len": 12})[0] == "xla"
+    # the registered 7B / 13B shapes take the kernel under the int8 cache too
+    for heads in (32, 40):
+        big = {**kw, "heads": heads, "head_dim": 128, "kv_len": 4096}
+        assert select_decode_impl("auto", **big, kv_dtype=jnp.int8)[0] == "flash_decode"
+        assert select_decode_impl("auto", **big)[0] == "flash_decode"
+    # a step of which no group of heads fits VMEM takes XLA, forced or not,
+    # and does not raise while the program is traced
+    huge = {**kw, "heads": 5, "head_dim": 1024, "kv_len": 512}
+    for impl in ("auto", "flash"):
+        got, why = select_decode_impl(impl, **huge, kv_dtype=jnp.int8)
+        assert got == "xla" and "VMEM" in why
+    assert select_decode_impl("auto", **huge)[0] == "flash_decode"  # bf16: one head a step
 
 
 def test_decode_step_bias_per_row():
@@ -306,7 +390,7 @@ def test_cache_rules_lint_catches_unmatched_leaf():
     # a typo'd rule set: cached_value leaves match nothing → they decode
     # fully replicated
     bad = ShardingRules(rules=[
-        (r"cached_key$", P(("data", "fsdp"), "tensor", None, None)),
+        (r"cached_key$", P(("data", "fsdp"), None, "tensor")),
         (r"cache_index$", P()),
     ])
     findings = lint_cache_sharding(cache, {"data": 2, "fsdp": 2, "tensor": 2}, rules=bad)
@@ -315,8 +399,8 @@ def test_cache_rules_lint_catches_unmatched_leaf():
 
 def test_cache_resolves_on_mesh8(mesh8):
     """The cache rule set drives real NamedSharding resolution for the
-    serving state — cached K/V shards batch over data×fsdp and heads over
-    tensor on the 8-device mesh."""
+    serving state — cached K/V shards batch over data×fsdp and the merged
+    heads x head_dim axis over tensor on the 8-device mesh."""
     from distributed_llms_example_tpu.evaluation.generation import abstract_cache
     from distributed_llms_example_tpu.parallel.sharding import (
         cache_rules,
@@ -336,7 +420,7 @@ def test_cache_resolves_on_mesh8(mesh8):
     for path, s in kv:
         spec = s.spec
         assert spec[0] == ("data", "fsdp", "expert"), (path, spec)
-        assert spec[1] == "tensor", (path, spec)
+        assert spec[1] is None and spec[2] == "tensor", (path, spec)  # (slots, length, heads x d)
 
 
 def test_aot_decode_program_carries_cache_rules_sharding(mesh8):
@@ -366,7 +450,27 @@ def test_aot_decode_program_carries_cache_rules_sharding(mesh8):
         batch_axes = spec[0] if len(spec) > 0 else None
         batch_axes = batch_axes if isinstance(batch_axes, tuple) else (batch_axes,)
         assert {"data", "fsdp"} <= set(batch_axes), (path, spec)
-        assert len(spec) > 1 and spec[1] == "tensor", (path, spec)
+        assert len(spec) > 2 and spec[1] is None and spec[2] == "tensor", (path, spec)
+
+
+def test_ragged_kv_heads_replicate_in_compiled_decode_step():
+    """llama-test's 2 KV heads on tensor=4: the cache's merged axis would
+    divide by its lanes, and a shard of half a head costs every layer of
+    every step collectives inside a head (RoPE's halves permuted, scores
+    all-reduced).  ``cache_leaf_spec`` goes by the heads, so the compiled
+    step keeps K/V whole over ``tensor`` and permutes nothing."""
+    from distributed_llms_example_tpu.analysis.ir_lint import lint_decode_step
+    from distributed_llms_example_tpu.core.config import MeshConfig
+
+    collect: dict = {}
+    lint_decode_step(
+        "llama-test", mesh_config=MeshConfig(data=2, fsdp=1, sequence=1, tensor=4),
+        slots=8, src_len=32, max_new_tokens=16, collect=collect,
+    )
+    text = collect["decode"]
+    assert "collective-permute" not in text
+    # per device: 8 slots over data=2, the cache's 48 positions, both heads' 32 lanes
+    assert "f32[4,48,32]" in text and "f32[4,48,8]" not in text
 
 
 # ---------------------------------------- AOT decode step: spec + IR lint

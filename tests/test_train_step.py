@@ -515,3 +515,42 @@ def test_grad_accum_carry_sharded_and_optimizer_outside_scan(setup):
             f"tree has {need} leaves with that shard shape — an accumulator "
             f"lost its param sharding (replicated into the carry full-size)"
         )
+
+
+# sha256 (16 hex) of the StableHLO of the gradient of each family's UNCACHED
+# forward, dropout on, matmul precision pinned, locations stripped — read at
+# c993dc2, the commit before the K/V cache moved to (slots, length, kv_heads
+# x head_dim) (PR 32).
+# That PR pulled the cached step out of ``MultiHeadAttention.__call__`` and
+# ``T5Attention.__call__`` and promised the train step the parent's program:
+# this holds it to that.  A change that means to move the train forward
+# replaces these with its own reading (`python -m pytest -k uncached_forward`
+# prints the one it got).
+UNCACHED_FORWARD_STABLEHLO = {
+    "bart-test": "5e92d8ee0010ac50",
+    "t5-test": "e8058bedc3fd5580",
+    "llama-test": "eb36f48211af733e",
+    "lfm2-moe-test": "af0a81de6fffcc8e",
+}
+
+
+@pytest.mark.parametrize("name", sorted(UNCACHED_FORWARD_STABLEHLO))
+def test_uncached_forward_lowers_to_the_same_program(name):
+    import hashlib
+    import re
+
+    lm = load_model(name, load_weights=False)
+    a_params = jax.eval_shape(lambda: lm.init_params(0))
+    ids = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+
+    def loss(params, key, *args):
+        out = lm.module.apply({"params": params}, *args, deterministic=False, rngs={"dropout": key})
+        return (out[0] if isinstance(out, tuple) else out).astype(jnp.float32).sum()
+
+    args = (ids, ids, ids) if lm.is_seq2seq else (ids, ids)
+    # pinned, because two test modules set the process's default at import
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(jax.grad(loss)).lower(a_params, key, *args).as_text()
+    got = hashlib.sha256(re.sub(r"loc\(.*?\)", "", text).encode()).hexdigest()[:16]
+    assert got == UNCACHED_FORWARD_STABLEHLO[name], f"{name}: {got} ({len(text)} chars)"

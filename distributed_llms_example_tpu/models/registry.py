@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from distributed_llms_example_tpu.models import t5 as t5_mod
 from distributed_llms_example_tpu.models.bart import BartConfig, BartForConditionalGeneration
+from distributed_llms_example_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
 from distributed_llms_example_tpu.models.convert import convert_state_dict
 from distributed_llms_example_tpu.models.lfm2 import Lfm2Config, Lfm2ForCausalLM
 from distributed_llms_example_tpu.models.llama import LlamaConfig, LlamaForCausalLM
@@ -123,6 +124,20 @@ LFM2_CONFIGS: dict[str, Lfm2Config] = {
         param_dtype=None,
     ),
     "lfm2-8b-a1b": Lfm2Config(),
+}
+
+
+# Brumby (models/brumby.py): every layer a power-retention layer, whose whole
+# memory is a fixed-size matrix state a KV head (no K/V).  Sizes from
+# manifestai/Brumby-14B-Base's config.json; pad/eos are the byte tokenizer's.
+BRUMBY_CONFIGS: dict[str, BrumbyConfig] = {
+    # 5 query heads a KV head, as published; head_dim 16 (the state is 9 x 16 x 16 a KV head)
+    "brumby-test": BrumbyConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+        num_attention_heads=10, num_key_value_heads=2, head_dim=16,
+        max_position_embeddings=256, param_dtype=None,
+    ),
+    "brumby-14b": BrumbyConfig(),
 }
 
 
@@ -263,6 +278,9 @@ def _build(family: str, cfg: Any, dtype: jnp.dtype, remat: bool, params: Any = N
     if family == "lfm2":
         module = Lfm2ForCausalLM(cfg, dtype=dtype, remat=remat, remat_policy=remat_policy)
         return LoadedModel("lfm2", cfg, module, params, is_seq2seq=False)
+    if family == "brumby":
+        module = BrumbyForCausalLM(cfg, dtype=dtype, remat=remat, remat_policy=remat_policy)
+        return LoadedModel("brumby", cfg, module, params, is_seq2seq=False)
     raise ValueError(f"unsupported model family {family!r}")
 
 
@@ -359,7 +377,10 @@ def load_model(
         return _build("llama", _apply_impl(LLAMA_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
     if short in LFM2_CONFIGS:
         return _build("lfm2", _apply_impl(LFM2_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
-    known = sorted(T5_CONFIGS) + sorted(BART_CONFIGS) + sorted(LLAMA_CONFIGS) + sorted(LFM2_CONFIGS)
+    if short in BRUMBY_CONFIGS:
+        return _build("brumby", _apply_impl(BRUMBY_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
+    known = (sorted(T5_CONFIGS) + sorted(BART_CONFIGS) + sorted(LLAMA_CONFIGS) + sorted(LFM2_CONFIGS)
+             + sorted(BRUMBY_CONFIGS))
     raise ValueError(
         f"unknown model {name_or_path!r}: not a local checkpoint dir and not one of {known}"
     )
@@ -372,5 +393,6 @@ __all__ = [
     "BART_CONFIGS",
     "LLAMA_CONFIGS",
     "LFM2_CONFIGS",
+    "BRUMBY_CONFIGS",
     "t5_mod",
 ]

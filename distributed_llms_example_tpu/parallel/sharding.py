@@ -106,8 +106,9 @@ DEFAULT_RULES: list[tuple[str, P]] = [
     (r"lm_head/kernel", P("fsdp", "tensor")),
     # attention projections: q/k/v are column-parallel (d_model, heads*head_dim),
     # o is row-parallel (heads*head_dim, d_model)
-    (r"(self_attn|cross_attn|attention)/(q|k|v)_proj/kernel", P("fsdp", "tensor")),
-    (r"(self_attn|cross_attn|attention)/o_proj/kernel", P("tensor", "fsdp")),
+    # (a power-retention layer's q/k/v/o are the same splits; its 8-column gate is replicated)
+    (r"(self_attn|cross_attn|attention|retention)/(q|k|v)_proj/kernel", P("fsdp", "tensor")),
+    (r"(self_attn|cross_attn|attention|retention)/o_proj/kernel", P("tensor", "fsdp")),
     # gated short convolution (LFM2): in column-parallel (d, 3d), out
     # row-parallel; the (d, taps) depthwise weight is small and replicated
     (r"conv/in_proj/kernel", P("fsdp", "tensor")),
@@ -158,6 +159,12 @@ CACHE_RULES: list[tuple[str, P]] = [
     # a conv layer's decode state (models/lfm2.py): (batch, channels, taps-1),
     # the channels over ``tensor`` like the in-projection that produces them
     (r"conv_state$", P(("data", "fsdp", "expert"), "tensor", None)),
+    # a power-retention layer's state (models/brumby.py): (batch, kv_heads,
+    # rotations, head_dim, head_dim) and its normaliser (batch, kv_heads,
+    # rotations, head_dim): no length axis; a KV head's state never leaves
+    # the shard that holds the head's projections
+    (r"retention_state$", P(("data", "fsdp", "expert"), "tensor", None, None, None)),
+    (r"retention_norm$", P(("data", "fsdp", "expert"), "tensor", None, None)),
     (r"cache_index$", P()),
 ]
 
@@ -240,6 +247,9 @@ def cache_leaf_spec(name: str, shape: tuple, mesh_axes: Any, kv_heads: int, *, p
     step pays collectives inside a head.  THE single definition of the
     serving cache layout: ``activation.constrain_cache`` and the engine's
     host placement both derive from it."""
+    if name in ("retention_state", "retention_norm") and not pool:  # (batch, kv_heads, rotations, ...)
+        return P(_batch_axes_if_even(shape[0], mesh_axes), _tensor_if_even(shape[1], mesh_axes),
+                 *([None] * (len(shape) - 2)))
     if len(shape) != 3:
         return None
     batch = None if pool else _batch_axes_if_even(shape[0], mesh_axes)

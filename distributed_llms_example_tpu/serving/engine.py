@@ -299,6 +299,13 @@ def device_peak_bytes() -> int | None:
 # experts that received a row, the largest expert's rows, all rows routed.
 # Every slot of the round is routed, idle ones too: the program computes them.
 MOE_COUNTERS = ("moe_experts_hit", "moe_max_load", "moe_assignments")
+# The summary's byte account of the flat cache: a leaf that is not K/V (or its
+# int8 scales) by the kind it is counted under.
+CACHE_BYTES_KIND = {
+    "conv_state": "conv_state_bytes",
+    "retention_state": "retention_state_bytes",
+    "retention_norm": "retention_state_bytes",
+}
 
 
 class UnsupportedServeMode(ValueError):
@@ -343,10 +350,11 @@ class ServingEngine:
         self.buckets = tuple(
             sorted({int(b) for b in self.serve.prefill_buckets if 0 < int(b) < self.W})
         ) + (self.W,)
-        # a model whose cache holds state other than K/V (LFM2's conv state)
-        # serves on the flat cache only: the block pool pages K/V by cache
-        # position, a state leaf has none; the speculative verify and the
-        # warm prefix admission would have to roll a state back
+        # a model whose cache holds state other than K/V (LFM2's conv state,
+        # Brumby's retention state) serves on the flat cache only: the block
+        # pool pages K/V by cache position, a state leaf has none; the
+        # speculative verify and the warm prefix admission would have to roll
+        # a state back or continue one
         if getattr(config, "has_recurrent_state", False):
             for mode, on in (("paged_kv", self.serve.paged_kv),
                              ("prefix_cache", self.serve.prefix_cache),
@@ -354,9 +362,10 @@ class ServingEngine:
                 if on:
                     raise UnsupportedServeMode(
                         f"{mode} is not supported for {type(config).__name__}: its "
-                        "cache holds a convolution state beside K/V, which the "
-                        "block pool cannot page and a rejected draft cannot roll "
-                        "back; serve it on the flat cache (the default)"
+                        "cache holds a recurrent state (a convolution state "
+                        "beside K/V, or a retention state), which the block pool "
+                        "cannot page and a rejected draft cannot roll back; serve "
+                        "it on the flat cache (the default)"
                     )
         # experts: the decode round reports their load (moe_* counters)
         self.moe = getattr(config, "num_experts", 0) > 0
@@ -1087,13 +1096,14 @@ class ServeSession:
             eng._state_byte_account(self.state)
         )
         # the flat cache's static bytes by kind of leaf (K/V with their int8
-        # scales, conv state): metadata arithmetic, no device fetch
+        # scales, conv state; retention state with its normaliser where the
+        # model has one): metadata arithmetic, no device fetch
         by_kind = {"kv_bytes": 0, "conv_state_bytes": 0}
         for path, x in jax.tree_util.tree_leaves_with_path(self.state.get("cache", self.state.get("pool", {}))):
             leaf = cache_leaf_name(path)
             if leaf != "cache_index":  # a counter, not state
-                kind = "conv_state_bytes" if leaf == "conv_state" else "kv_bytes"
-                by_kind[kind] += int(np.prod(x.shape)) * x.dtype.itemsize
+                kind = CACHE_BYTES_KIND.get(leaf, "kv_bytes")
+                by_kind[kind] = by_kind.get(kind, 0) + int(np.prod(x.shape)) * x.dtype.itemsize
         self._cache_bytes_by_kind = by_kind
         if eng.paged and eng.prefix:
             # the device pool tensor was just re-zeroed (_init_state), so
@@ -1871,6 +1881,8 @@ class ServeSession:
                     jnp.asarray(rope.astype(np.int32)),
                     jnp.asarray(self.active),
                 )
+            # the flat cache streams every slot's state a round, live or not
+            dispatch.set(slots_live=int(self.active.sum()), slots_streamed=eng.S)
         with self.spans.span("token_fetch") as fetch:  # the host waits for the device here
             if eng.spec:
                 spec_toks = np.asarray(jax.device_get(target))

@@ -67,9 +67,9 @@ def compiled_kernels(monkeypatch):
     precision is put back to jax's default, which is what the program runs
     with: other test files raise it to "highest" as they are imported, and
     Mosaic refuses an fp32-precision matmul on bf16 operands."""
-    from distributed_llms_example_tpu.ops import flash_attention, fused_dropout, fused_optim
+    from distributed_llms_example_tpu.ops import flash_attention, fused_dropout, fused_optim, retention
 
-    for mod in (flash_attention, fused_dropout, fused_optim):
+    for mod in (flash_attention, fused_dropout, fused_optim, retention):
         monkeypatch.setattr(mod, "_default_interpret", lambda: False)
     with jax.default_matmul_precision(None):
         yield
@@ -436,3 +436,80 @@ def test_grouped_expert_product_compiles_at_the_cell_shapes(rows, k, n, one_chip
     text = _compile(lambda x, w, load: gmm(x, w, load, BF16, tiling), one_chip,
                     ((rows, k), BF16), ((32, k, n), BF16), ((32,), jnp.int32))
     assert "ragged-dot" not in text
+
+
+# ---- brumby-14b.serve-steady: the retention step's kernel, and the cell's three serving programs, whole ----
+
+BRUMBY_SLOTS, BRUMBY_WAVE, BRUMBY_PROMPT, BRUMBY_NEW = 24, 4, 1024, 128
+_RETENTION_STEP = re.compile(r"%retention_step(?:\.\d+)? = \(f32\[24,8,5,128\]\{[^}]*\}, f32\[24,8,65,128,128\]\{[^}]*\}, ")
+
+
+def test_retention_step_kernel_compiles_and_updates_the_state_in_place(one_chip, compiled_kernels):
+    """The decode kernel at the cell's shapes (24 slots, 40 query / 8 KV heads of
+    128, state (24, 8, 65, 128, 128) float32): Mosaic takes the tiling, the
+    custom call carries the kernel's name (what ``serve_retention_step_ms``
+    looks for), and the state is aliased to the result, not copied."""
+    from distributed_llms_example_tpu.ops import retention
+
+    s_shape, z_shape = retention.state_shapes(BRUMBY_SLOTS, 8, 128, 128)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((BRUMBY_SLOTS, 40, 128), BF16), ((BRUMBY_SLOTS, 8, 128), BF16), ((BRUMBY_SLOTS, 8, 128), BF16),
+        ((BRUMBY_SLOTS, 8), F32), (s_shape, F32), (z_shape, F32))]
+    step = jax.jit(retention.retention_step, donate_argnums=(4, 5)).lower(*args).compile()
+    text, mem = step.as_text(), step.memory_analysis()
+    assert len(_RETENTION_STEP.findall(text)) == 1, [ln[:120] for ln in text.splitlines() if "custom-call(" in ln]
+    assert mem.alias_size_in_bytes >= math.prod(s_shape) * 4 and mem.temp_size_in_bytes < 0.1e9, mem
+    assert not _large_copies(text, math.prod(s_shape))
+
+
+def test_brumby_cell_serving_programs_compile_and_fit(topo, one_chip, compiled_kernels, monkeypatch):
+    """Decode step, prefill wave (1 and 4 rows) and admit at 24 slots x 1,024 +
+    128 tokens, four layers at the published widths, bfloat16 weights: one
+    ``retention_step`` call a layer on the state as it rests (3.25 GB, aliased,
+    no copy of a state leaf), and a four-row wave whose temporaries are one KV
+    head's (13 GB when the wave's heads were computed at once)."""
+    from benchmarks.harness import program, spec as spec_mod
+    from distributed_llms_example_tpu.core.config import MeshConfig
+    from distributed_llms_example_tpu.core.mesh import build_mesh
+    from distributed_llms_example_tpu.models import registry
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel's choice and donation ask it
+    cfg = spec_mod.load_json(os.path.join(spec_mod.BENCH_DIR, "configs", "brumby-14b.json"))
+    lm = registry.load_model(
+        program.register_bench_model(cfg, spec_mod.load_module("adapters", cfg["family"])), dtype=BF16)
+    assert lm.config.num_hidden_layers == 4 and lm.config.vocab_size == 18992 and lm.config.eos_token_id is None
+    serve = ServeConfig(max_slots=BRUMBY_SLOTS, prefill_batch=BRUMBY_WAVE, max_new_tokens=BRUMBY_NEW,
+                        max_source_length=BRUMBY_PROMPT)
+    eng = ServingEngine(lm.module, lm.config, build_mesh(MeshConfig(data=-1), devices=topo.devices[:1]),
+                        serve, is_seq2seq=False)
+
+    def abstract(tree, dtype=None):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype if dtype is not None and jnp.issubdtype(x.dtype, jnp.floating) else x.dtype,
+            sharding=one_chip), tree)
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    params = abstract(jax.eval_shape(lambda: lm.init_params(0)), BF16)
+    zeros = lambda n: (jnp.zeros((n, BRUMBY_PROMPT), jnp.int32),) * 2  # noqa: E731
+    slots_cache, slots_mask, _, _ = jax.eval_shape(lambda p: eng._prefill_core(p, *zeros(BRUMBY_SLOTS)), params)
+    shapes = sorted({x.shape for x in jax.tree.leaves(slots_cache)})
+    assert shapes == [(), (24, 8, 65, 128), (24, 8, 65, 128, 128)], shapes  # no leaf with a length axis
+    state = {"cache": abstract(slots_cache), "mask": abstract(slots_mask), "last": i32(BRUMBY_SLOTS)}
+    active = jax.ShapeDtypeStruct((BRUMBY_SLOTS,), jnp.bool_, sharding=one_chip)
+    state_bytes = 4 * 24 * 8 * 65 * 128 * 129 * 4
+
+    step = eng._step.lower(params, state, i32(BRUMBY_SLOTS), i32(BRUMBY_SLOTS), active).compile()
+    text, mem = step.as_text(), step.memory_analysis()
+    assert len(_RETENTION_STEP.findall(text)) == 4  # one call a layer, named after the kernel
+    assert mem.alias_size_in_bytes >= state_bytes and mem.temp_size_in_bytes < 0.3e9, mem
+    assert not _large_copies(text, 24 * 8 * 65 * 128 * 128)
+
+    assert eng.wave_sizes == (1, BRUMBY_WAVE)
+    for rows in eng.wave_sizes:
+        wave_cache, wave_mask, _, wave_first = jax.eval_shape(lambda p: eng._prefill_core(p, *zeros(rows)), params)
+        wave = eng._prefill.lower(params, i32(rows, BRUMBY_PROMPT), i32(rows, BRUMBY_PROMPT)).compile()
+        assert wave.memory_analysis().temp_size_in_bytes < 1.0e9, wave.memory_analysis()
+        admit = eng._admit.lower(state, abstract(wave_cache), abstract(wave_mask), abstract(wave_first), i32(rows)).compile()
+        assert admit.memory_analysis().temp_size_in_bytes < 0.1e9
+        assert admit.memory_analysis().alias_size_in_bytes >= state_bytes

@@ -710,8 +710,14 @@ def test_round_spans_partition_the_round(serve_rig, capsys):
             assert kids[0]["stats"] == {}
             covered = sum(k["end"] - k["start"] for k in kids)
             plain_self.append(1.0 - covered / (rd["end"] - rd["start"]))
-        # the admitting admit_prep and its prefill_dispatch alone carry counters
-        assert all(e["stats"] == {} for e in [rd] + kids[2 if wave else 1:])
+        # the admitting admit_prep and its prefill_dispatch carry counters, and every round's
+        # decode_dispatch says how many slots held a request and how many the cache streamed (PR 35)
+        for e in [rd] + kids[2 if wave else 1:]:
+            if e["name"] == "serve/decode_dispatch":
+                assert set(e["stats"]) == {"slots_live", "slots_streamed"}
+                assert 1 <= e["stats"]["slots_live"] <= e["stats"]["slots_streamed"] == sess.eng.S
+            else:
+                assert e["stats"] == {}
     assert kinds == {True, False}
     assert sorted(plain_self)[len(plain_self) // 2] < 0.05
     logged = sum("serve/window_log" in [k["name"] for k in notes.children(rd)] for rd in rounds)

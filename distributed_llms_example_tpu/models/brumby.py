@@ -35,7 +35,8 @@ leaf ``(batch, kv_heads, rotations, head_dim, head_dim)`` and its normaliser
 ``retention_norm`` ``(batch, kv_heads, rotations, head_dim)``, float32, in
 the ``cache`` collection (``ops/retention.py`` says why that layout and
 dtype).  A cached call of ONE token is a recurrent step (the Pallas kernel
-``retention_step`` on a TPU at lane-aligned head sizes, plain ``jnp``
+``retention_step`` on a TPU at lane-aligned head sizes, which streams the
+state of the rows that hold a sequence and no other; plain ``jnp``
 elsewhere); a cached call of MORE tokens is a prompt and starts the sequence:
 the state it finds is not read, the state it leaves is that of its valid
 tokens alone (``mask``: a right-padded prompt's tail is kept out).  Continuing
@@ -103,6 +104,13 @@ class BrumbyConfig:
         """True: the ``cache`` collection holds a retention state and no K/V."""
         return True
 
+    @property
+    def decode_streams_live_slots(self) -> bool:
+        """Whether a decode round moves the state of the live slots alone (the
+        step kernel's list) or of every slot (the plain step): what chooses
+        the layer's step, and what the serving engine's counter asks."""
+        return retention.step_kernel_runs(self.head_dim, self.head_dim)
+
 
 # the gate's bias at init: sigmoid(5.4) = 0.9955, a memory of ~220 tokens (a
 # zero bias would forget in two)
@@ -155,16 +163,11 @@ class PowerRetention(nn.Module):
                     pos = start[:, None] + jnp.arange(t)[None, :]
                     real = jnp.take_along_axis(mask, jnp.clip(pos, 0, mask.shape[1] - 1), axis=1) * (pos < mask.shape[1])
                 if t == 1:
-                    # an idle slot's row: gate 1 and no key, so its state stays as it was
-                    on = real[:, None, :, None].astype(k.dtype)
-                    step = (
-                        retention.retention_step
-                        if jax.default_backend() == "tpu" and retention.step_kernel_supported(d, d)
-                        else retention.retention_step_reference
-                    )
+                    # a row the mask leaves out (an idle serving slot) is not live: its state stays as it was
+                    step = retention.retention_step if cfg.decode_streams_live_slots else retention.retention_step_reference
                     y, state.value, norm.value = step(
-                        q[:, :, 0], (k * on)[:, :, 0], v[:, :, 0], (log_g * real[:, None, :])[:, :, 0],
-                        state.value, norm.value, eps=cfg.retention_eps,
+                        q[:, :, 0], k[:, :, 0], v[:, :, 0], log_g[:, :, 0],
+                        state.value, norm.value, live=real[:, 0], eps=cfg.retention_eps,
                     )
                     y = y[:, :, None]
                 else:

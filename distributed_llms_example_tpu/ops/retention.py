@@ -239,70 +239,95 @@ def retention_prefill(q, k, v, log_g, valid=None, eps: float = EPS):
 # ------------------------------------------------------------ decode step
 
 
-def retention_step_reference(q, k, v, log_g, state, norm, eps: float = EPS):
+def _live_rows(live, like):
+    """``live`` (slots,) as a boolean that broadcasts over ``like``'s trailing axes."""
+    return live.astype(bool).reshape(live.shape + (1,) * (like.ndim - 1))
+
+
+def retention_step_reference(q, k, v, log_g, state, norm, *, live=None, eps: float = EPS):
     """One recurrent step in plain ``jnp`` (what the kernel computes; the
     path off the chip).  q (B, H, d); k, v (B, KV, d); ``log_g`` (B, KV);
-    returns (y (B, H, d_v) float32, state, normaliser)."""
+    ``live`` (B,) says which rows hold a sequence (None: all): an idle row's
+    state and normaliser stay as they were and its ``y`` is zero.  Returns
+    (y (B, H, d_v) float32, state, normaliser)."""
     b, h, d = q.shape
     kv = k.shape[1]
     g = jnp.exp(log_g.astype(jnp.float32))
     pk = phi(k)  # (B, KV, R, d)
-    state = g[..., None, None, None] * state + v.astype(jnp.float32)[:, :, None, :, None] * pk[:, :, :, None, :]
-    norm = g[..., None, None] * norm + pk
+    new_state = g[..., None, None, None] * state + v.astype(jnp.float32)[:, :, None, :, None] * pk[:, :, :, None, :]
+    new_norm = g[..., None, None] * norm + pk
     pq = phi(q.reshape(b, kv, h // kv, d))  # (B, KV, rep, R, d)
-    num = jnp.einsum("bgrfm,bgfvm->bgrv", pq, state, precision=jax.lax.Precision.HIGHEST)
-    den = jnp.einsum("bgrfm,bgfm->bgr", pq, norm, precision=jax.lax.Precision.HIGHEST)
-    return (num / (den[..., None] + eps)).reshape(b, h, v.shape[-1]), state, norm
+    num = jnp.einsum("bgrfm,bgfvm->bgrv", pq, new_state, precision=jax.lax.Precision.HIGHEST)
+    den = jnp.einsum("bgrfm,bgfm->bgr", pq, new_norm, precision=jax.lax.Precision.HIGHEST)
+    y = (num / (den[..., None] + eps)).reshape(b, h, v.shape[-1])
+    if live is None:
+        return y, new_state, new_norm
+    return (jnp.where(_live_rows(live, y), y, 0.0), jnp.where(_live_rows(live, state), new_state, state),
+            jnp.where(_live_rows(live, norm), new_norm, norm))
 
 
 STEP_ROTATIONS = 13  # feature rows a grid step streams: a (13, 128, 128) float32 tile is 852 KB
 STEP_ROWS = 32  # value rows (sublanes) held in registers while a tile's rotations pass
 
 
-def _step_kernel(g_ref, v_ref, pk_ref, pq_ref, s_ref, z_ref, y_ref, so_ref, zo_ref, acc_ref, den_ref, vb_ref,
-                 *, kv_heads: int, tile: int, rows: int, eps: float):
-    """One (slot, KV head, tile of rotations): scale the tile by the gate, add
-    the rank-one update ``v phi(k)_r^T``, write it back in place, and add its
-    part of every query head's read ``phi(q)_r . S_r`` to the accumulators;
-    the last tile reduces them over the lanes and normalises."""
+def _step_kernel(order_ref, n_ref, g_ref, v_ref, pk_ref, pq_ref, s_ref, z_ref, y_ref, so_ref, zo_ref,
+                 acc_ref, den_ref, vb_ref, *, kv_heads: int, tile: int, rows: int, eps: float):
+    """One (live slot, KV head, tile of rotations): scale the tile by the gate,
+    add the rank-one update ``v phi(k)_r^T``, write it back in place, and add
+    its part of every query head's read ``phi(q)_r . S_r`` to the accumulators;
+    the last tile reduces them over the lanes and normalises.  The grid's first
+    axis walks ``order_ref`` (slot indices, the live ones first) as far as
+    ``n_ref[0]``; past it a step does nothing and holds the block of the last
+    live step (``_step_call``'s index maps), so nothing is copied either way."""
     b, h, ri = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     rep, d_v, d = acc_ref.shape
-    g = g_ref[b * kv_heads + h]
+    n_live = n_ref[0]
 
-    @pl.when(ri == 0)
+    @pl.when((n_live == 0) & (b == 0) & (h == 0) & (ri == 0))
     def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        den_ref[...] = jnp.zeros_like(den_ref)
-        vb_ref[...] = jnp.broadcast_to(v_ref[0, 0], (d_v, d))  # v down the sublanes, across every lane
+        # no live slot: every step holds one block (slot 0's last), which is
+        # written back when the grid ends, so it must hold what was read
+        so_ref[...] = s_ref[...]
+        zo_ref[...] = z_ref[...]
 
-    den = den_ref[...]
-    for r in range(tile):
-        pk = pk_ref[0, 0, r]  # (1, d)
-        z_new = g * z_ref[0, 0, r] + pk
-        zo_ref[0, 0, r] = z_new
-        den = den + pq_ref[0, 0, r] * z_new  # (rep, d)
-    den_ref[...] = den
+    @pl.when(b < n_live)
+    def _():
+        g = g_ref[order_ref[b] * kv_heads + h]
 
-    def chunk(c, carry):
-        sl = pl.ds(pl.multiple_of(c * rows, rows), rows)
-        vb = vb_ref[sl, :]
-        accs = [acc_ref[i, sl, :] for i in range(rep)]
+        @pl.when(ri == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            den_ref[...] = jnp.zeros_like(den_ref)
+            vb_ref[...] = jnp.broadcast_to(v_ref[0, 0], (d_v, d))  # v down the sublanes, across every lane
+
+        den = den_ref[...]
         for r in range(tile):
-            s_new = g * s_ref[0, 0, r, sl, :] + vb * pk_ref[0, 0, r]
-            so_ref[0, 0, r, sl, :] = s_new
+            pk = pk_ref[0, 0, r]  # (1, d)
+            z_new = g * z_ref[0, 0, r] + pk
+            zo_ref[0, 0, r] = z_new
+            den = den + pq_ref[0, 0, r] * z_new  # (rep, d)
+        den_ref[...] = den
+
+        def chunk(c, carry):
+            sl = pl.ds(pl.multiple_of(c * rows, rows), rows)
+            vb = vb_ref[sl, :]
+            accs = [acc_ref[i, sl, :] for i in range(rep)]
+            for r in range(tile):
+                s_new = g * s_ref[0, 0, r, sl, :] + vb * pk_ref[0, 0, r]
+                so_ref[0, 0, r, sl, :] = s_new
+                for i in range(rep):
+                    accs[i] = accs[i] + s_new * pq_ref[0, 0, r, pl.ds(i, 1), :]
             for i in range(rep):
-                accs[i] = accs[i] + s_new * pq_ref[0, 0, r, pl.ds(i, 1), :]
-        for i in range(rep):
-            acc_ref[i, sl, :] = accs[i]
-        return carry
+                acc_ref[i, sl, :] = accs[i]
+            return carry
 
-    jax.lax.fori_loop(0, d_v // rows, chunk, 0)
+        jax.lax.fori_loop(0, d_v // rows, chunk, 0)
 
-    @pl.when(ri == pl.num_programs(2) - 1)
-    def _():
-        num = jnp.sum(acc_ref[...], axis=-1)  # (rep, d_v)
-        total = jnp.sum(den_ref[...], axis=-1, keepdims=True)  # (rep, 1)
-        y_ref[0, 0] = num / (total + eps)
+        @pl.when(ri == pl.num_programs(2) - 1)
+        def _():
+            num = jnp.sum(acc_ref[...], axis=-1)  # (rep, d_v)
+            total = jnp.sum(den_ref[...], axis=-1, keepdims=True)  # (rep, 1)
+            y_ref[0, 0] = num / (total + eps)
 
 
 def _default_interpret() -> bool:
@@ -314,68 +339,107 @@ def step_tile(r: int) -> int:
     return max(t for t in range(1, min(r, STEP_ROTATIONS) + 1) if r % t == 0)
 
 
-def retention_step(q, k, v, log_g, state, norm, *, eps: float = EPS, interpret: bool | None = None):
-    """One recurrent step as a Pallas kernel, one call a layer: every slot's
-    state is read once and written once, in place (``state`` and ``norm`` are
-    aliased to the results), all the query heads of a KV head inside the call.
-    Arguments and results as ``retention_step_reference``."""
+def retention_step(q, k, v, log_g, state, norm, *, live=None, eps: float = EPS, interpret: bool | None = None):
+    """One recurrent step as a Pallas kernel, one call a layer: the state of
+    every LIVE slot (``live`` (slots,), None: all of them) is read once and
+    written once, in place (``state`` and ``norm`` are aliased to the results),
+    all the query heads of a KV head inside the call; an idle slot's state is
+    neither read nor written and its ``y`` is zero.  Arguments and results as
+    ``retention_step_reference``."""
     d, d_v = q.shape[-1], v.shape[-1]
     return _step_call(
-        q, k, v, log_g, state, norm, eps=float(eps), tile=step_tile(rotations(d)),
-        rows=STEP_ROWS if d_v % STEP_ROWS == 0 else d_v,
+        q, k, v, log_g, state, norm, jnp.ones((q.shape[0],), bool) if live is None else live,
+        eps=float(eps), tile=step_tile(rotations(d)), rows=STEP_ROWS if d_v % STEP_ROWS == 0 else d_v,
         interpret=_default_interpret() if interpret is None else bool(interpret),
     )
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "tile", "rows", "interpret"))
-def _step_call(q, k, v, log_g, state, norm, *, eps: float, tile: int, rows: int, interpret: bool):
+def _step_call(q, k, v, log_g, state, norm, live, *, eps: float, tile: int, rows: int, interpret: bool):
     """``retention_step``'s program, every choice made from the shapes passed
     in as a static.  Jitted (not inlined) so that the custom call takes this
-    kernel's name in a device trace, ``retention_step``, whatever its call site."""
+    kernel's name in a device trace, ``retention_step``, whatever its call site.
+
+    The live slots reach the kernel by scalar prefetch: ``order`` (the slot
+    indices, live ones first) and their number.  A grid step past the last live
+    slot maps every operand to the block of the last live step (last live slot,
+    last KV head, last tile), so the pipeline sees an unchanged block, copies
+    nothing in and writes nothing back; a slot the grid never visits keeps its
+    bytes where they lie, the results being aliased to ``state`` and ``norm``.
+    That also makes every grid axis sequential: a second core given the idle
+    tail of an axis would write back a block it never computed."""
     b, h, d = q.shape
     kv, d_v = k.shape[1], v.shape[-1]
     rep, r = h // kv, rotations(d)
+    live = live.astype(bool)
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    n_live = jnp.sum(live, dtype=jnp.int32).reshape(1)
     g = jnp.exp(log_g.astype(jnp.float32)).reshape(b * kv)
     pk = phi(k)[:, :, :, None, :]  # (B, KV, R, 1, d)
     pq = jnp.swapaxes(phi(q.reshape(b, kv, rep, d)), 2, 3)  # (B, KV, R, rep, d)
     z = norm[:, :, :, None, :]
+    last_h, last_r = kv - 1, r // tile - 1
 
-    head = lambda bi, hi, ri: (bi, hi, 0, 0)  # noqa: E731
-    rot4 = lambda bi, hi, ri: (bi, hi, ri, 0, 0)  # noqa: E731
+    def walk(bi, hi, ri, order_ref, n_ref):
+        on = bi < n_ref[0]
+        slot = order_ref[jnp.where(on, bi, jnp.maximum(n_ref[0] - 1, 0))]
+        return slot, jnp.where(on, hi, last_h), jnp.where(on, ri, last_r)
+
+    def head(*at):
+        slot, hi, _ = walk(*at)
+        return slot, hi, 0, 0
+
+    def rot4(*at):
+        return (*walk(*at), 0, 0)
+
     y, state, z = pl.pallas_call(
         functools.partial(_step_kernel, kv_heads=kv, tile=tile, rows=rows, eps=eps),
-        grid=(b, kv, r // tile),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # the gates, whole
-            pl.BlockSpec((1, 1, d_v, 1), head),
-            pl.BlockSpec((1, 1, tile, 1, d), rot4),
-            pl.BlockSpec((1, 1, tile, rep, d), rot4),
-            pl.BlockSpec((1, 1, tile, d_v, d), rot4),
-            pl.BlockSpec((1, 1, tile, 1, d), rot4),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, rep, d_v), head),
-            pl.BlockSpec((1, 1, tile, d_v, d), rot4),
-            pl.BlockSpec((1, 1, tile, 1, d), rot4),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, kv, r // tile),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),  # the gates, whole
+                pl.BlockSpec((1, 1, d_v, 1), head),
+                pl.BlockSpec((1, 1, tile, 1, d), rot4),
+                pl.BlockSpec((1, 1, tile, rep, d), rot4),
+                pl.BlockSpec((1, 1, tile, d_v, d), rot4),
+                pl.BlockSpec((1, 1, tile, 1, d), rot4),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, rep, d_v), head),
+                pl.BlockSpec((1, 1, tile, d_v, d), rot4),
+                pl.BlockSpec((1, 1, tile, 1, d), rot4),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((rep, d_v, d), jnp.float32),
+                pltpu.VMEM((rep, d), jnp.float32),
+                pltpu.VMEM((d_v, d), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((b, kv, rep, d_v), jnp.float32),
             jax.ShapeDtypeStruct(state.shape, jnp.float32),
             jax.ShapeDtypeStruct(z.shape, jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((rep, d_v, d), jnp.float32),
-            pltpu.VMEM((rep, d), jnp.float32),
-            pltpu.VMEM((d_v, d), jnp.float32),
-        ],
-        input_output_aliases={4: 1, 5: 2},
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        input_output_aliases={6: 1, 7: 2},  # state and normaliser, counted from the scalar operands
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="retention_step",
-    )(g, v.astype(jnp.float32)[..., None], pk, pq, state, z)
+    )(order, n_live, g, v.astype(jnp.float32)[..., None], pk, pq, state, z)
+    # a slot the grid did not visit has no row of y written: zero, not what the buffer held
+    y = jnp.where(_live_rows(live, y), y, 0.0)
     return y.reshape(b, h, d_v), state, z[:, :, :, 0, :]
 
 
 def step_kernel_supported(d: int, d_v: int) -> bool:
     """The kernel's tiles are whole (8, 128) float32 tiles at these head sizes."""
     return d % 128 == 0 and d_v % 8 == 0
+
+
+def step_kernel_runs(d: int, d_v: int) -> bool:
+    """Whether a decode step at these head sizes is the kernel, which streams
+    the state of the live slots alone (a TPU, whole tiles), or the plain
+    ``jnp`` step, where XLA reads and writes every slot's.  The model's config
+    asks (``decode_streams_live_slots``): the layer chooses its step by the
+    answer and the serving engine counts ``slots_streamed`` by it."""
+    return jax.default_backend() == "tpu" and step_kernel_supported(d, d_v)

@@ -369,6 +369,9 @@ class ServingEngine:
                     )
         # experts: the decode round reports their load (moe_* counters)
         self.moe = getattr(config, "num_experts", 0) > 0
+        # what a decode round streams (the ``slots_streamed`` counter): the
+        # model's own predicate, the one that chooses its decode step
+        self.streams_live_slots = bool(getattr(config, "decode_streams_live_slots", False))
         self.paged = bool(self.serve.paged_kv)
         self.pool: cache_pool.CachePool | None = None
         if self.paged:
@@ -1881,8 +1884,10 @@ class ServeSession:
                     jnp.asarray(rope.astype(np.int32)),
                     jnp.asarray(self.active),
                 )
-            # the flat cache streams every slot's state a round, live or not
-            dispatch.set(slots_live=int(self.active.sum()), slots_streamed=eng.S)
+            # slots whose state the round moves: every one, live or not, except
+            # where the decode program's steps walk the live slots alone
+            n_live = int(self.active.sum())
+            dispatch.set(slots_live=n_live, slots_streamed=n_live if eng.streams_live_slots else eng.S)
         with self.spans.span("token_fetch") as fetch:  # the host waits for the device here
             if eng.spec:
                 spec_toks = np.asarray(jax.device_get(target))

@@ -50,6 +50,16 @@ def _engine(lm, slots=3, wave=2, new=16, **kw):
     return ServingEngine(lm.module, config, None, serve, is_seq2seq=False)
 
 
+@pytest.fixture(params=["plain-step", "kernel-step"])
+def kernel_step(request, monkeypatch):
+    """Both decode steps: the plain ``jnp`` step a CPU run takes, and the Pallas
+    kernel (interpreted here), chosen by handing the model and the engine
+    another answer from the one predicate both ask.  True on the kernel's path."""
+    if request.param == "kernel-step":
+        monkeypatch.setattr(retention, "step_kernel_runs", lambda d, d_v: True)
+    return request.param == "kernel-step"
+
+
 def test_gates_are_drawn_to_remember():
     """The toy's gate weights put g in ~0.92-0.996 (the cell's file: 0.98-0.999):
     a state that forgot in two tokens could hide a wrong slot or position."""
@@ -81,7 +91,7 @@ def test_full_forward_in_bfloat16_stays_within_bfloat16_of_the_reference():
     assert np.median(rel) < 0.06 and rel.max() < 0.15, (np.median(rel), rel.max())
 
 
-def test_engine_prefill_then_decode_follow_the_references_full_forward():
+def test_engine_prefill_then_decode_follow_the_references_full_forward(kernel_step):
     """Ragged right-padded prompts (lengths 1 to the full width) and more
     requests than slots, 16 decode steps through the slot cache: every served
     token must be the reference's best at its position, or lie within float32
@@ -171,9 +181,39 @@ def test_a_reused_slot_starts_from_a_zero_state():
     assert together == alone
 
 
-def test_an_idle_slots_state_is_left_as_it_is():
-    """A decode round streams every slot; one that holds no request (its cache
-    position lies outside the mask) keeps its state bit for bit."""
+def test_a_slot_idle_beside_a_live_one_and_then_reused_serves_the_references_tokens(kernel_step):
+    """Two slots.  A short request leaves slot 1 while a long one goes on in
+    slot 0; slot 1 then sits idle for several rounds (not on the kernel's list:
+    never streamed; on the plain step: selected back) before a third request is
+    admitted into it.  The long request's state was not disturbed by its idle
+    neighbour and the reused slot is clean: every served token is the float32
+    reference's best at its position."""
+    lm, params, ref_params = seeded(10)
+    rng = np.random.default_rng(6)
+    prompts = [list(rng.integers(2, 250, size=n)) for n in (17, 6, 11)]
+    with jax.default_matmul_precision("highest"):
+        sess = _engine(lm, slots=2, wave=1, new=16).open(params)
+        long_, short = sess.submit(prompts[0], max_new=16), sess.submit(prompts[1], max_new=3)
+        idle_rounds = 0
+        while len(sess.outputs[long_]) < 9:
+            sess.step()
+            idle_rounds += int(sess.active.sum() == 1)
+        assert idle_rounds >= 4 and len(sess.outputs[short]) == 3
+        third = sess.submit(prompts[2], max_new=6)
+        while sess.has_work():
+            sess.step()
+        sess.finalize()
+    assert [len(sess.outputs[r]) for r in (long_, short, third)] == [16, 3, 6]
+    for prompt, served in zip(prompts, sess.outputs):
+        logits = reference_logits(ref_params, prompt + served[:-1])[len(prompt) - 1:]
+        below = logits.max(axis=-1) - logits[np.arange(len(served)), served]
+        assert below.max() < 1e-4, (len(prompt), below)
+
+
+def test_an_idle_slots_state_is_left_as_it_is(kernel_step):
+    """A slot that holds no request (its cache position lies outside the mask)
+    keeps its state bit for bit through a decode round, whether the round's
+    steps walk the live slots alone (the kernel) or every slot (the plain step)."""
     lm, params, _ = seeded(9)
     sess = _engine(lm).open(params)
     sess.submit(list(range(2, 12)), max_new=6)
@@ -219,7 +259,10 @@ def test_continuing_a_stored_state_with_several_tokens_is_refused():
                         use_cache=True, cache_positions=jnp.zeros((1,), jnp.int32), mutable=["cache"])
 
 
-def test_a_decode_round_reports_its_live_and_streamed_slots_on_the_dispatch_span():
+def test_a_decode_round_reports_its_live_and_streamed_slots_on_the_dispatch_span(kernel_step):
+    """``slots_streamed`` says what the decode program moves: the round's live
+    slots where its retention steps walk the live list (the kernel), every slot
+    where the plain step runs and XLA touches them all."""
     from distributed_llms_example_tpu.obs.spans import SpanRecorder
 
     seen = []
@@ -239,14 +282,16 @@ def test_a_decode_round_reports_its_live_and_streamed_slots_on_the_dispatch_span
 
     lm, params, _ = seeded(8)
     sess = _engine(lm).open(params, spans=SpanRecorder(scope="serve", annotate=Annotation))
-    for n in (9, 4):
-        sess.submit(list(range(2, 2 + n)), max_new=4)
+    for n, new in ((9, 5), (4, 3)):
+        sess.submit(list(range(2, 2 + n)), max_new=new)
     while sess.has_work():
         sess.step()
     sess.finalize()
     rounds = [kw for name, kw in seen if name == "serve/decode_dispatch"]
-    assert rounds and all(kw["slots_streamed"] == 3 and 1 <= kw["slots_live"] <= 2 for kw in rounds)
-    assert rounds[0]["slots_live"] == 2
+    assert sess.eng.streams_live_slots is kernel_step and lm.config.decode_streams_live_slots is kernel_step
+    assert rounds and all(1 <= kw["slots_live"] <= 2 for kw in rounds)
+    assert all(kw["slots_streamed"] == (kw["slots_live"] if kernel_step else 3) for kw in rounds)
+    assert rounds[0]["slots_live"] == 2 and {kw["slots_live"] for kw in rounds} == {1, 2}
 
 
 def test_state_shards_by_kv_heads_on_tensor_and_the_decode_step_needs_no_collective():

@@ -442,22 +442,29 @@ def test_grouped_expert_product_compiles_at_the_cell_shapes(rows, k, n, one_chip
 
 BRUMBY_SLOTS, BRUMBY_WAVE, BRUMBY_PROMPT, BRUMBY_NEW = 24, 4, 1024, 128
 _RETENTION_STEP = re.compile(r"%retention_step(?:\.\d+)? = \(f32\[24,8,5,128\]\{[^}]*\}, f32\[24,8,65,128,128\]\{[^}]*\}, ")
+# the live-slot list and its length lead the call's operands (scalar prefetch), and the state
+# (operand 6, counted from them) and the normaliser are the results' buffers
+_RETENTION_STEP_OPERANDS = re.compile(
+    r"%retention_step(?:\.\d+)? = .*operand_layout_constraints=\{s32\[24\]\{0\}, s32\[1\]\{0\}, f32\[192\]\{0\}, "
+    r".*output_to_operand_aliasing=\{\{1\}: \(6, \{\}\), \{2\}: \(7, \{\}\)\}")
 
 
 def test_retention_step_kernel_compiles_and_updates_the_state_in_place(one_chip, compiled_kernels):
     """The decode kernel at the cell's shapes (24 slots, 40 query / 8 KV heads of
-    128, state (24, 8, 65, 128, 128) float32): Mosaic takes the tiling, the
-    custom call carries the kernel's name (what ``serve_retention_step_ms``
-    looks for), and the state is aliased to the result, not copied."""
+    128, state (24, 8, 65, 128, 128) float32) with a live-slot mask: Mosaic
+    takes the tiling and the scalar-prefetched list, the custom call carries
+    the kernel's name (what ``serve_retention_step_ms`` looks for), and the
+    state is aliased to the result, not copied."""
     from distributed_llms_example_tpu.ops import retention
 
     s_shape, z_shape = retention.state_shapes(BRUMBY_SLOTS, 8, 128, 128)
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
         ((BRUMBY_SLOTS, 40, 128), BF16), ((BRUMBY_SLOTS, 8, 128), BF16), ((BRUMBY_SLOTS, 8, 128), BF16),
-        ((BRUMBY_SLOTS, 8), F32), (s_shape, F32), (z_shape, F32))]
-    step = jax.jit(retention.retention_step, donate_argnums=(4, 5)).lower(*args).compile()
+        ((BRUMBY_SLOTS, 8), F32), (s_shape, F32), (z_shape, F32), ((BRUMBY_SLOTS,), jnp.bool_))]
+    step = jax.jit(lambda *a: retention.retention_step(*a[:6], live=a[6]), donate_argnums=(4, 5)).lower(*args).compile()
     text, mem = step.as_text(), step.memory_analysis()
     assert len(_RETENTION_STEP.findall(text)) == 1, [ln[:120] for ln in text.splitlines() if "custom-call(" in ln]
+    assert len(_RETENTION_STEP_OPERANDS.findall(text)) == 1
     assert mem.alias_size_in_bytes >= math.prod(s_shape) * 4 and mem.temp_size_in_bytes < 0.1e9, mem
     assert not _large_copies(text, math.prod(s_shape))
 
@@ -502,6 +509,7 @@ def test_brumby_cell_serving_programs_compile_and_fit(topo, one_chip, compiled_k
     step = eng._step.lower(params, state, i32(BRUMBY_SLOTS), i32(BRUMBY_SLOTS), active).compile()
     text, mem = step.as_text(), step.memory_analysis()
     assert len(_RETENTION_STEP.findall(text)) == 4  # one call a layer, named after the kernel
+    assert len(_RETENTION_STEP_OPERANDS.findall(text)) == 4  # each walks the round's live slots
     assert mem.alias_size_in_bytes >= state_bytes and mem.temp_size_in_bytes < 0.3e9, mem
     assert not _large_copies(text, 24 * 8 * 65 * 128 * 128)
 

@@ -172,6 +172,9 @@ def test_a_decode_round_reports_its_expert_load_on_the_fetch_span():
     for kw in rounds:  # three slots (idle ones too) x top-4, in each of the four expert layers
         assert kw["moe_assignments"] == 3 * 4 * 4
         assert 4 * 4 <= kw["moe_experts_hit"] <= 4 * 8 and 2 <= kw["moe_max_load"] <= 3
+    # K/V and a conv state, no step that walks a live list: the round streams every slot (PR 36)
+    streamed = [kw["slots_streamed"] for name, kw in seen if name == "serve/decode_dispatch"]
+    assert not sess.eng.streams_live_slots and streamed and set(streamed) == {sess.eng.S} == {3}
 
 
 def _skewed_moe():

@@ -1,8 +1,11 @@
 """Power retention (ops/retention.py): the three forms of one layer agree on
 seeded inputs (gates in 0.9-0.999, degree 2, 5 query heads a KV head), the
 feature map is the second power, padding stays out of a state, the decode
-kernel (interpreted) is the plain step, and bfloat16 state products are not
+kernel (interpreted) is the plain step on the slots its live list names and
+leaves every other slot's state alone, and bfloat16 state products are not
 good enough.  Tolerances are written with their reasons."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -104,23 +107,60 @@ def test_the_form_is_chosen_from_the_shape(monkeypatch):
         assert y.shape == (1, H, t, D) and state.shape == (1, KV, 9, D, D) and norm.shape == (1, KV, 9, D)
 
 
+def step_inputs(b, d=128, seed=6):
+    """One decode step's operands at head size ``d``, 5 query heads a KV head, and a state that is not empty."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q, k, v = (jax.random.normal(ks[i], (b, n, d)) for i, n in ((0, H), (1, KV), (2, KV)))
+    log_g = jnp.log(jax.random.uniform(ks[3], (b, KV), minval=0.9, maxval=0.999))
+    s_shape, z_shape = R.state_shapes(b, KV, d, d)
+    return q, k, v, log_g, jax.random.normal(ks[4], s_shape), jnp.abs(jax.random.normal(ks[5], z_shape)) + 1.0
+
+
 def test_decode_kernel_is_the_plain_step_at_lane_aligned_heads():
     """The Pallas kernel, interpreted, at head size 128 with 5 query heads a KV
     head: one call updates the state in place and reads it for every head."""
-    d = 128
-    ks = jax.random.split(jax.random.PRNGKey(6), 6)
-    q, k, v = (jax.random.normal(ks[i], (2, n, d)) for i, n in ((0, H), (1, KV), (2, KV)))
-    log_g = jnp.log(jax.random.uniform(ks[3], (2, KV), minval=0.9, maxval=0.999))
-    s_shape, z_shape = R.state_shapes(2, KV, d, d)
-    state = jax.random.normal(ks[4], s_shape)
-    norm = jnp.abs(jax.random.normal(ks[5], z_shape)) + 1.0
-    assert R.step_kernel_supported(d, d) and not R.step_kernel_supported(16, 16) and R.step_tile(65) == 13
-    want = R.retention_step_reference(q, k, v, log_g, state, norm)
-    got = R.retention_step(q, k, v, log_g, state, norm, interpret=True)
+    args = step_inputs(2)
+    assert R.step_kernel_supported(128, 128) and not R.step_kernel_supported(16, 16) and R.step_tile(65) == 13
+    want = R.retention_step_reference(*args)
+    got = R.retention_step(*args, interpret=True)
     # the same float32 products in another order (a lane-wise accumulator reduced at the end)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), atol=1e-3, rtol=1e-4)  # |y| up to ~300 here
     np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]), atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("live", [
+    pytest.param([1, 1, 1, 1, 1], id="all-live"),
+    pytest.param([0, 0, 1, 0, 0], id="one-live"),
+    pytest.param([0, 0, 0, 0, 0], id="none-live"),
+    pytest.param([1, 0, 1, 0, 0], id="live-slots-not-contiguous"),
+    pytest.param([0, 0, 0, 0, 1], id="the-last-slot-alone"),
+])
+def test_decode_kernel_walks_the_live_slots_and_leaves_the_idle_ones_alone(live):
+    """The kernel's grid walks the live slots handed to it by scalar prefetch:
+    a live slot is the plain step's, to the tolerances above; an idle slot's
+    state and normaliser are the input's bit for bit and its ``y`` is exactly
+    zero (the interpreter hands the kernel a NaN-filled ``y``: a row the grid
+    never writes shows), in the kernel and in the reference alike."""
+    args = step_inputs(len(live))
+    state, norm = args[4], args[5]
+    on = np.asarray(live, bool)
+    want = R.retention_step_reference(*args, live=jnp.asarray(on))
+    got = R.retention_step(*args, live=jnp.asarray(live, jnp.int32), interpret=True)  # a 0/1 vector reads as a boolean one
+    for out in (want, got):
+        y, s, z = (np.asarray(x) for x in out)
+        np.testing.assert_array_equal(s[~on], np.asarray(state)[~on])
+        np.testing.assert_array_equal(z[~on], np.asarray(norm)[~on])
+        np.testing.assert_array_equal(y[~on], np.zeros_like(y[~on]))
+        assert np.isfinite(y).all() and (not on.any() or np.abs(y[on]).max() > 0)
+        assert not on.any() or not np.array_equal(s[on], np.asarray(state)[on])
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), atol=1e-3, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]), atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]), atol=1e-5, rtol=1e-5)
+    if on.all():  # no list given is every slot: the same kernel on the identity list, the same bits
+        for step in (R.retention_step_reference, functools.partial(R.retention_step, interpret=True)):
+            for a, b in zip(step(*args), step(*args, live=jnp.asarray(on))):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_state_products_in_bfloat16_fail_the_tolerance(monkeypatch):

@@ -711,14 +711,15 @@ def test_round_spans_partition_the_round(serve_rig, capsys):
             covered = sum(k["end"] - k["start"] for k in kids)
             plain_self.append(1.0 - covered / (rd["end"] - rd["start"]))
         # the admitting admit_prep and its prefill_dispatch carry counters, and every round's
-        # decode_dispatch says how many slots held a request and how many the cache streamed (PR 35)
+        # decode_dispatch says how many slots held a request and how many the cache streamed (PR 35):
+        # every one here, K/V caches having no step that walks the live slots alone (PR 36)
         for e in [rd] + kids[2 if wave else 1:]:
             if e["name"] == "serve/decode_dispatch":
                 assert set(e["stats"]) == {"slots_live", "slots_streamed"}
                 assert 1 <= e["stats"]["slots_live"] <= e["stats"]["slots_streamed"] == sess.eng.S
             else:
                 assert e["stats"] == {}
-    assert kinds == {True, False}
+    assert kinds == {True, False} and not sess.eng.streams_live_slots
     assert sorted(plain_self)[len(plain_self) // 2] < 0.05
     logged = sum("serve/window_log" in [k["name"] for k in notes.children(rd)] for rd in rounds)
     assert logged == len(rounds) // 3  # log_every_steps=3

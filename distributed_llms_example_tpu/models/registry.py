@@ -25,6 +25,8 @@ from distributed_llms_example_tpu.models.bart import BartConfig, BartForConditio
 from distributed_llms_example_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
 from distributed_llms_example_tpu.models.convert import convert_state_dict
 from distributed_llms_example_tpu.models.lfm2 import Lfm2Config, Lfm2ForCausalLM
+from distributed_llms_example_tpu.models.mellum import MellumConfig, MellumForCausalLM
+from distributed_llms_example_tpu.ops.mha import YarnRope
 from distributed_llms_example_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from distributed_llms_example_tpu.models.t5 import T5Config, T5ForConditionalGeneration
 
@@ -138,6 +140,24 @@ BRUMBY_CONFIGS: dict[str, BrumbyConfig] = {
         max_position_embeddings=256, param_dtype=None,
     ),
     "brumby-14b": BrumbyConfig(),
+}
+
+
+# Mellum (models/mellum.py): three sliding-window layers to one full layer, each
+# kind with its own rotation, 64 softmax-routed experts in every layer.  Sizes
+# from JetBrains/Mellum2-12B-A2.5B-Instruct's config.json; pad/eos are the byte
+# tokenizer's.
+MELLUM_CONFIGS: dict[str, MellumConfig] = {
+    # one period, a window a prompt of 3 windows wraps twice, 4 query heads a KV head
+    "mellum-test": MellumConfig(
+        vocab_size=256, hidden_size=64, moe_intermediate_size=32, num_hidden_layers=4,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        num_attention_heads=8, num_key_value_heads=2, head_dim=16, sliding_window=16,
+        num_experts=8, num_experts_per_tok=2, max_position_embeddings=512,
+        rope_yarn=YarnRope(factor=16.0, original_max_position_embeddings=32, attention_factor=1.2772588722239782),
+        param_dtype=None,
+    ),
+    "mellum2-12b-a2.5b": MellumConfig(),
 }
 
 
@@ -281,6 +301,9 @@ def _build(family: str, cfg: Any, dtype: jnp.dtype, remat: bool, params: Any = N
     if family == "brumby":
         module = BrumbyForCausalLM(cfg, dtype=dtype, remat=remat, remat_policy=remat_policy)
         return LoadedModel("brumby", cfg, module, params, is_seq2seq=False)
+    if family == "mellum":
+        module = MellumForCausalLM(cfg, dtype=dtype, remat=remat, remat_policy=remat_policy)
+        return LoadedModel("mellum", cfg, module, params, is_seq2seq=False)
     raise ValueError(f"unsupported model family {family!r}")
 
 
@@ -379,8 +402,10 @@ def load_model(
         return _build("lfm2", _apply_impl(LFM2_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
     if short in BRUMBY_CONFIGS:
         return _build("brumby", _apply_impl(BRUMBY_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
+    if short in MELLUM_CONFIGS:
+        return _build("mellum", _apply_impl(MELLUM_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
     known = (sorted(T5_CONFIGS) + sorted(BART_CONFIGS) + sorted(LLAMA_CONFIGS) + sorted(LFM2_CONFIGS)
-             + sorted(BRUMBY_CONFIGS))
+             + sorted(BRUMBY_CONFIGS) + sorted(MELLUM_CONFIGS))
     raise ValueError(
         f"unknown model {name_or_path!r}: not a local checkpoint dir and not one of {known}"
     )
@@ -394,5 +419,6 @@ __all__ = [
     "LLAMA_CONFIGS",
     "LFM2_CONFIGS",
     "BRUMBY_CONFIGS",
+    "MELLUM_CONFIGS",
     "t5_mod",
 ]

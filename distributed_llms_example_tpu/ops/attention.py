@@ -132,6 +132,13 @@ def make_causal_bias(q_len: int, kv_len: int, offset: int = 0) -> jnp.ndarray:
     return jnp.where(mask, 0.0, NEG_INF)[None, None, :, :]
 
 
+def make_band_bias(q_len: int, kv_len: int, window: int) -> jnp.ndarray:
+    """(1, 1, q_len, kv_len) additive sliding-window mask: query i reads keys j
+    with ``0 <= i - j < window`` (causal, and the window counts the query)."""
+    delta = jnp.arange(q_len)[:, None] - jnp.arange(kv_len)[None, :]
+    return jnp.where((delta >= 0) & (delta < window), 0.0, NEG_INF)[None, None, :, :]
+
+
 def mask_to_bias(attention_mask: jnp.ndarray) -> jnp.ndarray:
     """(batch, kv_len) {0,1} padding mask → (batch, 1, 1, kv_len) additive bias."""
     return jnp.where(attention_mask[:, None, None, :] > 0, 0.0, NEG_INF)

@@ -81,13 +81,16 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _bias_spec(bias_shape, block_q: int, block_k: int):
-    """BlockSpec for an additive bias whose dims are each 1 or full-size."""
+def _bias_spec(bias_shape, block_q: int, block_k: int, kv_tile=None):
+    """BlockSpec for an additive bias whose dims are each 1 or full-size.
+    ``kv_tile(qi, ki)``: the kv tile a grid step reads where that is not
+    ``ki`` itself (the window flavour walks a band)."""
     b1, h1, q1, k1 = (d == 1 for d in bias_shape)
     block = (1, 1, 1 if q1 else block_q, bias_shape[3] if k1 else block_k)
 
     def index_map(b, h, qi, ki):
-        return (0 if b1 else b, 0 if h1 else h, 0 if q1 else qi, 0 if k1 else ki)
+        kt = ki if kv_tile is None else kv_tile(qi, ki)
+        return (0 if b1 else b, 0 if h1 else h, 0 if q1 else qi, 0 if k1 else kt)
 
     return pl.BlockSpec(block, index_map)
 
@@ -109,14 +112,46 @@ def _causal_mask(s, qi, ki, block_q: int, block_k: int):
     return jnp.where(q_pos >= k_pos, s, -jnp.inf)
 
 
+def _band_tiles(q_len: int, block_q: int, block_k: int, window: int) -> int:
+    """kv tiles a q tile's band can touch: query i reads keys j with
+    ``0 <= i - j < window``, so q tile ``qi`` reads keys ``qi * block_q -
+    (window - 1) .. (qi + 1) * block_q - 1``; the most tiles any q tile's
+    range meets (its first q tiles meet fewer)."""
+    most = 1
+    for qi in range(q_len // block_q):
+        first = max(qi * block_q - (window - 1), 0) // block_k
+        last = ((qi + 1) * block_q - 1) // block_k
+        most = max(most, last - first + 1)
+    return most
+
+
+def _band_tile(qi, ki, block_q: int, block_k: int, nkb: int):
+    """The kv tile step ``ki`` of ``nkb`` reads for q tile ``qi`` in the window
+    flavour: the band's tiles end at the diagonal's, so they are counted back
+    from it; below zero there is none (the step is skipped, its block index
+    clamped to the first tile so that no other block is fetched for it)."""
+    return ((qi + 1) * block_q - 1) // block_k - (nkb - 1) + ki
+
+
+def _window_mask(s, qi, kt, block_q: int, block_k: int, window: int):
+    """``_causal_mask`` with the band's far edge: key j of query i survives
+    where ``0 <= i - j < window`` (the window counts the query itself)."""
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = kt * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where((q_pos >= k_pos) & (q_pos - k_pos < window), s, -jnp.inf)
+
+
 # ---------------------------------------------------------------- forward
 
 
 def _fwd_kernel(
     *refs, scale: float, causal: bool, block_q: int, block_k: int, nk: int,
     has_bias: bool, has_lbias: bool, dropout_rate: float = 0.0,
-    hw_rng: bool = False,
+    hw_rng: bool = False, window: int = 0,
 ):
+    """``window`` > 0 is the sliding-window flavour: the kv grid axis walks
+    the ``nk`` tiles of the q tile's band (``_band_tile``), not the whole
+    sequence, so a key tile wholly outside the band is never visited."""
     it = iter(refs)
     seed_ref = next(it) if dropout_rate > 0.0 else None
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
@@ -136,6 +171,12 @@ def _fwd_kernel(
 
     # with causal masking, tiles strictly above the diagonal contribute nothing
     diag_ok = (qi + 1) * block_q > ki * block_k if causal else True
+    kt = ki
+    if window:
+        # the band's tiles, counted back from the diagonal's: one before the
+        # sequence's first, or wholly before the band's far edge, has nothing
+        kt = _band_tile(qi, ki, block_q, block_k, nk)
+        diag_ok = (kt >= 0) & ((kt + 1) * block_k > qi * block_q - (window - 1))
 
     @pl.when(diag_ok)
     def _compute():
@@ -149,7 +190,9 @@ def _fwd_kernel(
             s += bias_ref[0, 0].astype(jnp.float32)
         if lbias_ref is not None:
             s += lbias_ref[0, 0].astype(jnp.float32)
-        if causal:
+        if window:
+            s = _window_mask(s, qi, kt, block_q, block_k, window)
+        elif causal:
             s = _causal_mask(s, qi, ki, block_q, block_k)
 
         m_prev = m_scr[:, :1]  # (block_q, 1)
@@ -199,17 +242,22 @@ def _seed_arg(dropout_seed):
 
 
 def _fwd(q, k, v, bias, lbias, *, scale, causal, block_q, block_k, interpret,
-         dropout_rate=0.0, dropout_seed=None, hw_rng=False):
+         dropout_rate=0.0, dropout_seed=None, hw_rng=False, window=0, name=None):
     batch, heads, q_len, d = q.shape
     kv_len = k.shape[2]
     nq, nk = q_len // block_q, kv_len // block_k
+    kv_tile = None
+    if window:
+        # the kv axis of the grid is the band, not the sequence
+        nk = _band_tiles(q_len, block_q, block_k, window)
+        kv_tile = lambda qi, ki: jnp.maximum(_band_tile(qi, ki, block_q, block_k, nk), 0)  # noqa: E731
     grid = (batch, heads, nq, nk)
 
     def q_map(b, h, qi, ki):
         return (b, h, qi, 0)
 
     def kv_map(b, h, qi, ki):
-        return (b, h, ki, 0)
+        return (b, h, ki if kv_tile is None else kv_tile(qi, ki), 0)
 
     seed_args, in_specs = _seed_arg(dropout_seed if dropout_rate > 0.0 else None)
     in_specs += [
@@ -218,7 +266,7 @@ def _fwd(q, k, v, bias, lbias, *, scale, causal, block_q, block_k, interpret,
         pl.BlockSpec((1, 1, block_k, d), kv_map),
     ]
     if bias is not None:
-        in_specs.append(_bias_spec(bias.shape, block_q, block_k))
+        in_specs.append(_bias_spec(bias.shape, block_q, block_k, kv_tile))
     if lbias is not None:
         in_specs.append(_bias_spec(lbias.shape, block_q, block_k))
     out_shape = [
@@ -233,7 +281,7 @@ def _fwd(q, k, v, bias, lbias, *, scale, causal, block_q, block_k, interpret,
         _fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, nk=nk,
         has_bias=bias is not None, has_lbias=lbias is not None,
-        dropout_rate=dropout_rate, hw_rng=hw_rng,
+        dropout_rate=dropout_rate, hw_rng=hw_rng, window=window,
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -250,6 +298,7 @@ def _fwd(q, k, v, bias, lbias, *, scale, causal, block_q, block_k, interpret,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=name,
     )(*seed_args, *[x for x in (q, k, v, bias, lbias) if x is not None])
     return o, lse
 
@@ -715,13 +764,24 @@ MAX_BLOCK_CAUSAL_WIDE = 1024  # v5e sweep at the 7B regime (4/8, 32,
 #                  the causal cap is head_dim-dependent.
 
 
+MAX_BLOCK_WINDOW = 1024  # v5e at (1, 32, 8192, 128), window 1,024 (PR 37): tiles of 1,024
+#                  2.61 ms (2 tiles a q tile: 2,048 keys visited for the ~1,536 of
+#                  its band), 512 3.73 ms (3 tiles), 256 6.73 ms, 1024 x 512 4.33 ms:
+#                  as on the causal flavour at head_dim 128 (6.44 ms at 1,024, 12.03 at
+#                  512), the wider tile's MXU occupancy beats its masked work
+
+
 def _block_caps(causal: bool, has_learned_bias: bool,
-                head_dim: int = 64) -> tuple[int, int]:
+                head_dim: int = 64, window: int = 0) -> tuple[int, int]:
     """(cap_q, cap_k) for the given attention flavor — see the constants'
     comments for the v5e measurements behind each choice.  The learned-
     bias cap applies even when causal: its backward's bias tile + dlbias
     accumulator overflow VMEM at 1024×1024 regardless of masking (and
-    tiles only grow with head_dim)."""
+    tiles only grow with head_dim).  The window flavour's tiles are capped
+    at ``MAX_BLOCK_WINDOW`` and at the window itself."""
+    if window:
+        cap = min(MAX_BLOCK_WINDOW, max(window, 128))
+        return cap, cap
     if has_learned_bias:
         return MAX_BLOCK, MAX_BLOCK_NONCAUSAL
     if causal:
@@ -867,14 +927,14 @@ def flash_attention(
 def flash_supported(q_len: int, kv_len: int, head_dim: int,
                     block_q: int | None = None, block_k: int | None = None,
                     *, causal: bool = False,
-                    has_learned_bias: bool = False) -> bool:
+                    has_learned_bias: bool = False, window: int = 0) -> bool:
     """True when shapes are flash-eligible (divisible seqs, sane head_dim).
     ``None`` blocks mirror ``flash_attention``'s ``auto_block`` defaults,
     including its per-path block caps (``_block_caps``) — pass ``causal``/
     ``has_learned_bias`` as the eventual kernel call will, or a length only
     tileable above 512 (e.g. 592 = 16*37) would be reported eligible for a
     path whose cap rejects it."""
-    cap_q, cap_k = _block_caps(causal, has_learned_bias, head_dim)
+    cap_q, cap_k = _block_caps(causal, has_learned_bias, head_dim, window)
     bq = auto_block(q_len, cap_q) if block_q is None else min(block_q, q_len)
     bk = auto_block(kv_len, cap_k) if block_k is None else min(block_k, kv_len)
     return (
@@ -886,6 +946,60 @@ def flash_supported(q_len: int, kv_len: int, head_dim: int,
         and bk % 8 == 0
         and head_dim % 8 == 0
     )
+
+
+PROMPT_ATTN = "prompt_attn"  # a cached prompt's attention in a device trace
+
+
+def flash_prompt_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    bias: jnp.ndarray | None = None,
+    *,
+    window: int | None = None,
+    scale: float | None = None,
+    block_q: int | None = None,
+    block_k: int | None = None,
+    interpret: bool | None = None,
+    dtype: jnp.dtype | None = None,
+) -> jnp.ndarray:
+    """A cached PROMPT's attention (``ops/mha.py``: the prefill of a causal
+    model): the forward kernel alone, causal, square, its ``T`` new tokens
+    against each other while the cache is written beside.  ``bias``: the keys'
+    padding mask, every dim 1 or full.  FORWARD ONLY: no vjp is defined.
+
+    ``window``: sliding-window attention, query i reads keys j with ``0 <= i -
+    j < window`` (the window counts the query).  The kv axis of the grid is
+    then the BAND of the q tile (``_band_tile``), so a key tile wholly outside
+    it is neither fetched nor computed: at 8,192 tokens and a window of 1,024
+    two tiles of 1,024 a q tile, not eight.
+
+    The custom call is ``prompt_attn`` in a device trace, whatever its call
+    site (``_prompt_call``: not inlined, the kernel named)."""
+    if q.shape[2] != k.shape[2] or k.shape != v.shape:
+        raise ValueError(f"a prompt attends itself: q {q.shape}, k {k.shape}, v {v.shape}")
+    if window is not None and window < 1:
+        raise ValueError(f"window={window}: at least the query itself")
+    cap_q, cap_k = _block_caps(True, False, q.shape[-1], window or 0)
+    block_q = auto_block(q.shape[2], cap_q) if block_q is None else min(block_q, q.shape[2])
+    block_k = auto_block(k.shape[2], cap_k) if block_k is None else min(block_k, k.shape[2])
+    if not block_q or not block_k or q.shape[2] % block_q or k.shape[2] % block_k or block_q % 8 or block_k % 8:
+        raise ValueError(f"prompt of {q.shape[2]} tokens not divisible into 8-aligned blocks {block_q}/{block_k}")
+    if bias is not None:
+        _check_decode_bias(bias, q.shape[0], q.shape[1], q.shape[2], k.shape[2])
+    out = _prompt_call(
+        q, k, v, bias, scale=float(q.shape[-1] ** -0.5 if scale is None else scale), window=int(window or 0),
+        block_q=int(block_q), block_k=int(block_k),
+        interpret=bool(_default_interpret() if interpret is None else interpret),
+    )
+    return out if dtype is None else out.astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "block_q", "block_k", "interpret"))
+def _prompt_call(q, k, v, bias, *, scale: float, window: int, block_q: int, block_k: int, interpret: bool):
+    return _fwd(q, k, v, bias, None, scale=scale, causal=True, block_q=block_q, block_k=block_k,
+                interpret=interpret, window=window, name=PROMPT_ATTN)[0]
 
 
 # ------------------------------------------------------- decode variant
@@ -1219,6 +1333,7 @@ def flash_decode(
     interpret: bool | None = None,
     dtype: jnp.dtype | None = None,
     q_group: int = 1,
+    ring: bool = False,
 ) -> jnp.ndarray:
     """Decode-step attention: a short q block against a cached K/V buffer.
 
@@ -1258,11 +1373,25 @@ def flash_decode(
     own lanes.  Per head the online softmax, its order over kv tiles, the
     per-row mask, the dead-tile skip and the fp32 accumulation are what
     they were.
+
+    ``ring``: ``k``/``v`` are a window layer's leaves, (B, window, H x d),
+    written at ``position mod window`` (``ops/mha.py`` ``cache_window_kv``),
+    and ``offsets`` are the rows' absolute POSITIONS: an entry is valid by the
+    row's position, not by its index (index r holds the newest position
+    congruent to r, there from position r on), so the step reads entries ``<=
+    min(position, window - 1)``; the order of a ring's entries does not
+    matter to a softmax over keys cached after RoPE.  One q position a row.
+    The same kernel under its own name in a device trace, ``window_decode``
+    (``_window_decode_call``: not inlined), whatever its call site.
     """
     batch, heads, q_len, d = q.shape
     if scale is None:
         scale = d ** -0.5
     kv_len = k.shape[1]
+    if ring:
+        if q_len != q_group:
+            raise ValueError(f"a ring step is one position a row; got {q_len} q rows at q_group {q_group}")
+        offsets = jnp.clip(jnp.asarray(offsets, jnp.int32), 0, kv_len - 1)
     if k.shape != (batch, kv_len, heads * d) or v.shape != k.shape:
         raise ValueError(
             f"k/v {k.shape}/{v.shape}: the cache leaf is (batch, length, heads x "
@@ -1288,24 +1417,37 @@ def flash_decode(
     hb = decode_step_heads(
         heads, block_k, d, k.dtype.itemsize, q_len=q_len, int8_scales=has_scales
     )
-    return _decode_call(
+    return (_window_decode_call if ring else _decode_call)(
         jnp.asarray(offsets, jnp.int32).reshape(batch), q, k, v, k_scale, v_scale, bias,
         scale=float(scale), block_k=block_k, step_heads=hb, q_group=q_group,
         interpret=bool(interpret), dtype=dtype,
     )
 
 
-@functools.partial(
-    jax.jit, inline=True,
-    static_argnames=("scale", "block_k", "step_heads", "q_group", "interpret", "dtype"),
-)
-def _decode_call(offsets, q, k, v, k_scale, v_scale, bias, *, scale: float,
-                 block_k: int, step_heads: int, q_group: int, interpret: bool, dtype):
+_DECODE_STATICS = ("scale", "block_k", "step_heads", "q_group", "interpret", "dtype")
+WINDOW_DECODE = "window_decode"  # a ring step's custom call in a device trace
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=_DECODE_STATICS)
+def _decode_call(offsets, q, k, v, k_scale, v_scale, bias, **statics):
     """``flash_decode``'s program on checked operands, every choice made from
     the shapes passed in as a static.  Jitted so that a decode program's
     call sites of one shape (twelve layers) trace the kernel once, and
     inlined so that each stays an operation of its own call site (the
     custom call keeps the site's name)."""
+    return _decode_program(offsets, q, k, v, k_scale, v_scale, bias, **statics)
+
+
+@functools.partial(jax.jit, static_argnames=_DECODE_STATICS)
+def _window_decode_call(offsets, q, k, v, k_scale, v_scale, bias, **statics):
+    """The same program for a ring leaf, NOT inlined and the kernel named, so
+    that the custom call is ``window_decode`` in a device trace and no metric
+    has to tell a window layer's step from a full layer's by its shape."""
+    return _decode_program(offsets, q, k, v, k_scale, v_scale, bias, name=WINDOW_DECODE, **statics)
+
+
+def _decode_program(offsets, q, k, v, k_scale, v_scale, bias, *, scale: float, block_k: int,
+                    step_heads: int, q_group: int, interpret: bool, dtype, name: str | None = None):
     batch, heads, q_len, d = q.shape
     hb, nk = step_heads, k.shape[1] // block_k
     has_scales = k_scale is not None
@@ -1341,6 +1483,7 @@ def _decode_call(offsets, q, k, v, k_scale, v_scale, bias, *, scale: float,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=name,
     )(offsets, *[
         x for x in (decode_q_rows(q, hb), k, v, k_scale, v_scale, bias) if x is not None
     ])
@@ -1532,6 +1675,7 @@ def flash_decode_run(
     dtype: jnp.dtype | None = None,
     interpret: bool | None = None,
     q_group: int = 1,
+    ring: bool = False,
 ) -> jnp.ndarray:
     """Run the decode kernel — directly on one device, per-shard under
     ``shard_map`` on a mesh (batch over data×fsdp×expert, heads over
@@ -1551,7 +1695,7 @@ def flash_decode_run(
     if mesh is None or _math.prod(mesh.devices.shape) == 1:
         return flash_decode(
             q, k, v, bias, offsets=offsets, k_scale=k_scale, v_scale=v_scale,
-            scale=scale, dtype=dtype, interpret=interpret, q_group=q_group,
+            scale=scale, dtype=dtype, interpret=interpret, q_group=q_group, ring=ring,
         )
     batch_axes = tuple(a for a in BATCH_AXES if a in mesh.shape)
     head_axis = "tensor" if "tensor" in mesh.shape else None
@@ -1568,7 +1712,7 @@ def flash_decode_run(
         return flash_decode(
             q, k, v, rest[0] if rest else None, offsets=off,
             k_scale=ks, v_scale=vs, scale=scale,
-            dtype=dtype, interpret=interpret, q_group=q_group,
+            dtype=dtype, interpret=interpret, q_group=q_group, ring=ring,
         )
 
     args = (q, k, v, jnp.asarray(offsets, jnp.int32).reshape(q.shape[0]))

@@ -9,6 +9,7 @@ scores + relative position bias are peculiar to it).
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import flax.linen as nn
@@ -21,6 +22,7 @@ from distributed_llms_example_tpu.ops.attention import (
     NEG_INF,
     beam_grouped_attention,
     dot_product_attention,
+    make_band_bias,
     make_causal_bias,
 )
 from distributed_llms_example_tpu.ops.flash_attention import (
@@ -29,6 +31,7 @@ from distributed_llms_example_tpu.ops.flash_attention import (
     flash_attention,
     flash_decode_run,
     flash_decode_supported,
+    flash_prompt_attention,
     flash_supported,
 )
 from distributed_llms_example_tpu.ops.norms import RMSNorm
@@ -84,6 +87,8 @@ def select_attention_impl(
     causal: bool = False,
     bias_kv_only: bool | None = None,
     has_learned_bias: bool = False,
+    prompt: bool = False,
+    window: int = 0,
 ) -> tuple[str, str]:
     """(impl, reason) — pure selection logic, unit-testable without TPUs.
 
@@ -99,6 +104,18 @@ def select_attention_impl(
 
     ``bias_kv_only``: None = no bias, True = (b|1, 1, 1, K) padding-style
     bias (the only form the ring can rotate), False = anything wider.
+
+    ``use_cache``: a cached call.  A decode step (or any call that continues
+    a stored cache) is not this function's: ``select_decode_impl``.  A
+    ``prompt`` is a cached call that STARTS its sequence (the prefill of a
+    causal model: ``q_len`` new tokens attend each other, ``kv_len`` =
+    ``q_len``, and the cache is written beside).  It takes the flash kernel
+    under the kernel's usual conditions, as an uncached causal call does:
+    XLA's path materialises float32 scores (8.6 GB a layer for one
+    8,192-token row of 32 heads), and at lfm2's 1,024-token wave, whose
+    0.13 GB would fit, the kernel read no slower (``serve_prefill_device_ms``
+    10.84 -> 10.67; ``PERF.md``, PR 37).  ``window`` > 0 is a sliding-window
+    layer: the kernel's window flavour, and the same rule.
     """
     if attention_impl not in ("auto", "flash", "ring", "xla"):
         raise ValueError(
@@ -107,8 +124,11 @@ def select_attention_impl(
     if attention_impl == "xla":
         return "xla", "forced"
     if use_cache:
-        return "xla", "kv-cache decode step"
-    seq_shards = mesh.shape.get("sequence", 1) if mesh is not None else 1
+        if not prompt:
+            return "xla", "kv-cache decode step"
+        if attention_impl == "ring":
+            return "xla", "ring attention has no KV-cache path"
+    seq_shards = mesh.shape.get("sequence", 1) if mesh is not None and not use_cache else 1
     if attention_impl == "ring" or (attention_impl == "auto" and seq_shards > 1):
         why = _ring_blocker(
             seq_shards, batch=batch, heads=heads, q_len=q_len, kv_len=kv_len,
@@ -127,7 +147,7 @@ def select_attention_impl(
         # correct (GSPMD gathers the sequence) but loses the SP memory win
         return "xla", f"sequence axis present but {why}"
     if not flash_supported(
-        q_len, kv_len, head_dim, causal=causal, has_learned_bias=has_learned_bias
+        q_len, kv_len, head_dim, causal=causal, has_learned_bias=has_learned_bias, window=window
     ):
         # 'flash' means "wherever eligible": single-token decode steps and
         # other non-tileable shapes silently use the XLA path
@@ -262,13 +282,52 @@ def _ring_blocker(
     return _uneven_split_blocker(mesh, heads=heads, batch=batch)
 
 
-def rope_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float = 10000.0) -> tuple:
-    """(..., head_dim) cos/sin tables for the given integer positions, in the
-    HF half-rotation layout (freqs repeated, not interleaved)."""
+@dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN's numbers as a published ``rope_parameters`` section gives them
+    (``rope_type`` "yarn"): the context is stretched ``factor`` times past
+    ``original_max_position_embeddings``, the dimensions that turn more than
+    ``beta_fast`` times over that length are left alone, those that turn less
+    than ``beta_slow`` times are slowed ``factor`` times, the ones between are
+    blended linearly; cos and sin are multiplied by ``attention_factor``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+def rope_inv_freq(head_dim: int, theta: float, yarn: YarnRope | None = None) -> jnp.ndarray:
+    """(head_dim / 2,) rotation frequencies, float32.  Plain: ``theta^(-2n /
+    head_dim)``.  YaRN: with ``dim(b) = head_dim ln(L0 / (2 pi b)) / (2 ln
+    theta)`` (the dimension that turns ``b`` times over the original length
+    ``L0``), ``lo = max(floor(dim(beta_fast)), 0)``, ``hi = min(ceil(dim(
+    beta_slow)), head_dim - 1)`` and the ramp ``r_n = clip((n - lo) / (hi -
+    lo), 0, 1)``: ``inv_freq_n / factor`` where ``r_n`` = 1, ``inv_freq_n``
+    where it is 0, their blend between."""
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if yarn is None:
+        return inv_freq
+    turns_at = lambda b: head_dim * math.log(yarn.original_max_position_embeddings / (2 * math.pi * b)) / (  # noqa: E731
+        2 * math.log(theta))
+    lo = max(math.floor(turns_at(yarn.beta_fast)), 0)
+    hi = min(math.ceil(turns_at(yarn.beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return inv_freq / yarn.factor * ramp + inv_freq * (1.0 - ramp)
+
+
+def rope_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float = 10000.0,
+                 yarn: YarnRope | None = None) -> tuple:
+    """(..., head_dim) cos/sin tables for the given integer positions, in the
+    HF half-rotation layout (freqs repeated, not interleaved), float32; under
+    ``yarn`` at its frequencies and times its attention factor."""
+    inv_freq = rope_inv_freq(head_dim, theta, yarn)
     freqs = positions.astype(jnp.float32)[..., None] * inv_freq  # (..., head_dim/2)
     emb = jnp.concatenate([freqs, freqs], axis=-1)
-    return jnp.cos(emb), jnp.sin(emb)
+    if yarn is None:
+        return jnp.cos(emb), jnp.sin(emb)
+    return jnp.cos(emb) * yarn.attention_factor, jnp.sin(emb) * yarn.attention_factor
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
@@ -365,6 +424,62 @@ def cache_kv(module: nn.Module, key: jnp.ndarray, value: jnp.ndarray,
     return cached_k.value, cached_v.value, None, None, idx
 
 
+def cache_window_kv(module: nn.Module, key: jnp.ndarray, value: jnp.ndarray, window: int,
+                    positions: jnp.ndarray, real: jnp.ndarray, count: bool = True):
+    """A sliding-window layer's cache: ``cache_kv``'s twin for a layer whose
+    queries read the last ``window`` positions and no more.  The leaves
+    ``window_key`` / ``window_value`` are ``(batch, window, kv_heads x
+    head_dim)`` at ANY context: the memory is the mechanism.  A key is cached
+    after RoPE and the head norms, so the order of the entries does not matter
+    to the softmax, and position p rests at entry ``p mod window``: entry r
+    holds the newest position congruent to r, which is there from position r
+    on (``flash_decode``'s ``ring``: validity by the row's position).
+
+    ``key``/``value``: (B, kv_heads, T, head_dim).  ``positions`` (B, T): the
+    tokens' absolute positions; ``real`` (B, T) bool: which of them are tokens
+    (a right-padded prompt's tail is not, an idle serving slot's step is not).
+
+    * T == 1, a decode step: row b writes entry ``positions[b] mod window``
+      where ``real``; nothing else moves.
+    * T > 1, a PROMPT, which starts its sequence: the leaf is written whole
+      from the prompt's last ``window`` real positions, whatever the padding
+      (entry r takes the newest real position congruent to r), so nothing a
+      slot's last occupant left can be read: its entries are overwritten or
+      lie past the new sequence's position.  The real tokens are the row's
+      first ``sum(real)`` (right padding, as everywhere in the package).
+
+    ``count``: the call advances the shared ``cache_index`` counter (the
+    static generation loops; a serving engine owns per-slot positions and
+    passes False).  The int8 K/V cache is not implemented for a window leaf.
+    Returns the two leaves whole."""
+    from distributed_llms_example_tpu.parallel.activation import current_kv_cache_dtype
+
+    if current_kv_cache_dtype() == "int8":
+        raise NotImplementedError("the int8 K/V cache has no window leaf: serve a sliding-window model with f32")
+    b, heads, t, d = key.shape
+    rows = lambda x: x.transpose(0, 2, 1, 3).reshape(b, t, heads * d)  # noqa: E731
+    is_initialized = module.has_variable("cache", "window_key")
+    ring_k = module.variable("cache", "window_key", jnp.zeros, (b, window, heads * d), key.dtype)
+    ring_v = module.variable("cache", "window_value", jnp.zeros, (b, window, heads * d), key.dtype)
+    cache_index = module.variable("cache", "cache_index", lambda: jnp.array(0, dtype=jnp.int32))
+    if is_initialized:
+        if count:
+            cache_index.value = cache_index.value + t
+        positions = jnp.broadcast_to(positions, (b, t))
+        if t == 1:
+            at = jnp.where(real[:, 0], positions[:, 0] % window, window)  # past the leaf: the write drops
+            for leaf, new in ((ring_k, rows(key)), (ring_v, rows(value))):
+                leaf.value = leaf.value.at[jnp.arange(b), at].set(new[:, 0], mode="drop")
+        else:
+            n = jnp.sum(real, axis=1, dtype=jnp.int32)[:, None]  # (B, 1) real tokens, the row's first
+            r = jnp.arange(window, dtype=jnp.int32)[None, :]
+            newest = r + window * ((n - 1 - r) // window)  # the newest real position congruent to r
+            src = jnp.where(r < n, newest, jnp.minimum(r, t - 1))  # past the prompt: never valid, any row
+            for leaf, new in ((ring_k, rows(key)), (ring_v, rows(value))):
+                leaf.value = jnp.take_along_axis(new, src[:, :, None], axis=1)
+    return ring_k.value, ring_v.value
+
+
 def cache_heads_view(k: jnp.ndarray, v: jnp.ndarray, k_scale, v_scale, kv_heads: int):
     """XLA's cached path: the leaves ``k``/``v`` (B, L, kv_heads x d) viewed
     as (B, kv_heads, L, d) inside the program, the int8 cache dequantized
@@ -406,6 +521,12 @@ class MultiHeadAttention(nn.Module):
     # RMSNorm over head_dim of every q and k head, before RoPE (LFM2,
     # Qwen3-class ``q_layernorm``/``k_layernorm``); None = the model has none
     qk_norm_eps: float | None = None
+    # sliding-window attention (causal self-attention only): query i reads
+    # keys j with 0 <= i - j < window, and the layer's cache holds the last
+    # ``window`` positions and no more (``cache_window_kv``); None = all keys
+    window: int | None = None
+    # YaRN's scaling of the rotation (``rope_cos_sin``); None = the plain one
+    rope_yarn: YarnRope | None = None
 
     @property
     def kv_heads(self) -> int:
@@ -442,7 +563,11 @@ class MultiHeadAttention(nn.Module):
 
     @nn.compact
     def _cache_kv(self, key: jnp.ndarray, value: jnp.ndarray,
-                  cache_positions: jnp.ndarray | None = None):
+                  cache_positions: jnp.ndarray | None = None, ring: tuple | None = None):
+        """The layer's cache write: ``cache_kv``, or for a ``window`` layer
+        ``cache_window_kv`` with ``ring`` = (positions, real)."""
+        if ring is not None:
+            return cache_window_kv(self, key, value, self.window, *ring, count=cache_positions is None)
         return cache_kv(self, key, value, cache_positions)
 
     @nn.nowrap  # no scope of its own: the kernel's call site stays ``self_attn`` (serve_decode_attn_ms finds it so)
@@ -456,6 +581,7 @@ class MultiHeadAttention(nn.Module):
         bias: jnp.ndarray | None,
         offsets: jnp.ndarray,
         deterministic: bool,
+        ring: bool = False,
     ) -> jnp.ndarray:
         """A cached decode step's attention: ``q`` (B, heads, T, d) against
         the cache leaves ``k``/``v`` (B, L, kv_heads x d) (int8 scales (B,
@@ -464,7 +590,10 @@ class MultiHeadAttention(nn.Module):
         only: validity and causality are the dispatch's job here — the
         decode kernel's in-kernel per-row length mask, or
         ``decode_step_bias`` on XLA's path, which views the leaf as (B, H,
-        L, d) inside the program."""
+        L, d) inside the program.  ``ring``: the leaves are a window layer's
+        (``cache_window_kv``) and ``offsets`` the rows' absolute positions;
+        an entry is valid by the row's position, the first ``min(position,
+        window - 1) + 1`` of them, on either path."""
         b, _, t, d = q.shape
         kv_len = k.shape[1]
         mesh = current_mesh()
@@ -491,7 +620,7 @@ class MultiHeadAttention(nn.Module):
             rows = q.reshape(b, self.kv_heads, rep, t, d).swapaxes(2, 3).reshape(b, self.kv_heads, t * rep, d)
             out = flash_decode_run(
                 rows, k, v, bias, offsets=offsets, mesh=mesh,
-                k_scale=k_scale, v_scale=v_scale, dtype=self.dtype, q_group=rep,
+                k_scale=k_scale, v_scale=v_scale, dtype=self.dtype, q_group=rep, ring=ring,
             )
             return out.reshape(b, self.kv_heads, t, rep, d).swapaxes(2, 3).reshape(b, self.num_heads, t, d)
         impl, reason = select_decode_impl(
@@ -517,12 +646,13 @@ class MultiHeadAttention(nn.Module):
             # int8 KV scales dequantize per kv tile inside the kernel
             return flash_decode_run(
                 q, k, v, bias, offsets=offsets, mesh=mesh,
-                k_scale=k_scale, v_scale=v_scale, dtype=self.dtype,
+                k_scale=k_scale, v_scale=v_scale, dtype=self.dtype, ring=ring,
             )
         k, v = cache_heads_view(k, v, k_scale, v_scale, self.kv_heads)
         if rep > 1:
             k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-        step = decode_step_bias(offsets, t, kv_len)
+        # a ring's entries are valid by the row's position: the first min(position, window - 1) + 1
+        step = decode_step_bias(jnp.clip(offsets, 0, kv_len - 1) if ring else offsets, t, kv_len)
         return dot_product_attention(
             q, k, v, step if bias is None else bias + step,
             dtype=self.dtype,
@@ -540,6 +670,7 @@ class MultiHeadAttention(nn.Module):
         cross_kv: tuple[jnp.ndarray, jnp.ndarray] | None = None,
         deterministic: bool = True,
         cache_positions: jnp.ndarray | None = None,
+        mask: jnp.ndarray | None = None,
     ) -> jnp.ndarray:
         """``positions``: optional (batch, q_len) absolute positions for RoPE
         — needed when cache slots don't equal sequence positions (right-
@@ -553,7 +684,18 @@ class MultiHeadAttention(nn.Module):
         contiguous span starting there — warm prefix admission and the
         speculative verify block both ride this, up to the decode
         kernel's ``MAX_DECODE_Q_ROWS``) — defaults to the shared
-        ``cache_index`` counter."""
+        ``cache_index`` counter.  ``mask`` (batch, cache width), the 0/1
+        mask ``bias`` was made from: a cached ``window`` layer asks it which
+        of the new tokens are real (its ring keeps a prompt's last real
+        positions; an idle slot's step writes nothing); None = all are.
+
+        A cached call of several tokens without ``cache_positions`` is a
+        PROMPT and starts its sequence: where
+        ``select_attention_impl`` sends it to the flash kernel (or the layer
+        has a ``window``), the new tokens attend each other and what the
+        cache held before is not read."""
+        if self.window is not None and not (self.causal and cross_kv is None and kv_hidden is None):
+            raise ValueError("window attention is causal self-attention")
         q = self._split(self.q_proj(hidden), self.num_heads)
         if cross_kv is not None:
             k, v = cross_kv
@@ -592,32 +734,42 @@ class MultiHeadAttention(nn.Module):
             )
         if use_cache and self.causal:
             # RoPE must see absolute positions, so rotate before caching
+            t = q.shape[2]
+            if positions is None and (self.use_rope or self.window is not None):
+                if cache_positions is not None:
+                    positions = cache_positions[:, None] + jnp.arange(t)[None, :]
+                else:
+                    # peek the index without mutating (mutation happens in _cache_kv)
+                    idx = (
+                        self.get_variable("cache", "cache_index")
+                        if self.has_variable("cache", "cache_index")
+                        else 0
+                    )
+                    positions = (jnp.arange(t) + idx)[None, :]
             if self.use_rope:
-                if positions is None:
-                    if cache_positions is not None:
-                        positions = cache_positions[:, None] + jnp.arange(q.shape[2])[None, :]
-                    else:
-                        # peek the index without mutating (mutation happens in _cache_kv)
-                        idx = (
-                            self.get_variable("cache", "cache_index")
-                            if self.has_variable("cache", "cache_index")
-                            else 0
-                        )
-                        positions = (jnp.arange(q.shape[2]) + idx)[None, :]
-                cos, sin = rope_cos_sin(positions, self.head_dim, self.rope_theta)
+                cos, sin = rope_cos_sin(positions, self.head_dim, self.rope_theta, self.rope_yarn)
                 cos, sin = cos[:, None], sin[:, None]  # add heads axis
                 q = apply_rope(q, cos, sin)
                 k = apply_rope(k, cos, sin)
-            k, v, k_scale, v_scale, offset = self._cache_kv(k, v, cache_positions)
-            # (B,) absolute position of q row 0
-            decode_offsets = (
-                cache_positions
-                if cache_positions is not None
-                else jnp.full((q.shape[0],), offset, jnp.int32)
-            )
-            out = self._cached_attend(
-                q, k, v, k_scale, v_scale, bias, decode_offsets, deterministic
-            )
+            prompt = cache_positions is None and t > 1
+            if self.window is not None:
+                out = self._window_cached(q, k, v, bias, positions, cache_positions, mask, prompt, deterministic)
+            else:
+                k_new, v_new = k, v
+                k, v, k_scale, v_scale, offset = self._cache_kv(k, v, cache_positions)
+                dropout = not deterministic and self.probs_dropout_rate > 0.0  # the prompt's kernel draws no mask
+                if prompt and not dropout and self._prompt_impl(q, bias) == "flash":
+                    out = self._prompt_attend(q, k_new, v_new, bias)
+                else:
+                    # (B,) absolute position of q row 0
+                    decode_offsets = (
+                        cache_positions
+                        if cache_positions is not None
+                        else jnp.full((q.shape[0],), offset, jnp.int32)
+                    )
+                    out = self._cached_attend(
+                        q, k, v, k_scale, v_scale, bias, decode_offsets, deterministic
+                    )
             b, h, s, d = out.shape
             return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b, s, h * d))
         if self.use_rope:
@@ -629,7 +781,7 @@ class MultiHeadAttention(nn.Module):
                     pos = pos + jax.lax.axis_index(manual[0]) * q.shape[2]
             else:
                 pos = positions
-            cos, sin = rope_cos_sin(pos, self.head_dim, self.rope_theta)
+            cos, sin = rope_cos_sin(pos, self.head_dim, self.rope_theta, self.rope_yarn)
             cos, sin = cos[:, None], sin[:, None]  # add heads axis
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
@@ -679,20 +831,28 @@ class MultiHeadAttention(nn.Module):
             )
             b, h, s, d = out.shape
             return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b, s, h * d))
-        impl, reason = select_attention_impl(
-            self.attention_impl,
-            batch=q.shape[0],
-            heads=self.num_heads,
-            head_dim=self.head_dim,
-            q_len=q.shape[2],
-            kv_len=k.shape[2],
-            use_cache=use_cache,
-            mesh=mesh,
-            backend=jax.default_backend(),
-            device_count=jax.device_count(),
-            causal=causal_here,
-            bias_kv_only=None if bias is None else (bias.shape[1] == 1 and bias.shape[2] == 1),
-        )
+        if self.window is not None:
+            # an uncached window layer (teacher forcing, training): the band as a
+            # bias on XLA's path; the window kernel is forward only (a prompt's)
+            impl, reason = "xla", "sliding window without a cache: the band as a bias"
+            causal_here = False
+            band = make_band_bias(q.shape[2], k.shape[2], self.window)
+            bias = band if bias is None else bias + band
+        else:
+            impl, reason = select_attention_impl(
+                self.attention_impl,
+                batch=q.shape[0],
+                heads=self.num_heads,
+                head_dim=self.head_dim,
+                q_len=q.shape[2],
+                kv_len=k.shape[2],
+                use_cache=use_cache,
+                mesh=mesh,
+                backend=jax.default_backend(),
+                device_count=jax.device_count(),
+                causal=causal_here,
+                bias_kv_only=None if bias is None else (bias.shape[1] == 1 and bias.shape[2] == 1),
+            )
         _log_impl_once(impl, reason)
         probs_dropout = (
             float(self.probs_dropout_rate) if not deterministic else 0.0
@@ -734,6 +894,74 @@ class MultiHeadAttention(nn.Module):
         b, h, s, d = out.shape
         return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b, s, h * d))
 
+    @nn.nowrap
+    def _prompt_impl(self, q: jnp.ndarray, bias: jnp.ndarray | None) -> str:
+        """Which path a cached PROMPT's attention takes (``select_attention_impl``)."""
+        t = q.shape[2]
+        impl, reason = select_attention_impl(
+            self.attention_impl, batch=q.shape[0], heads=self.num_heads, head_dim=self.head_dim,
+            q_len=t, kv_len=t, use_cache=True, prompt=True, window=self.window or 0, mesh=current_mesh(),
+            backend=jax.default_backend(), device_count=jax.device_count(), causal=True,
+            bias_kv_only=None if bias is None else (bias.shape[1] == 1 and bias.shape[2] == 1),
+        )
+        if impl == "flash" and bias is not None and not (bias.shape[1] == 1 and bias.shape[2] == 1):
+            impl, reason = "xla", "cached prompt with a bias wider than the keys' padding mask"
+        if impl == "flash":  # XLA's path logs itself where it runs (``_cached_attend``)
+            _log_impl_once(impl, "cached prompt, " + reason)
+        return impl
+
+    @nn.nowrap
+    def _prompt_attend(self, q, k, v, bias):
+        """A cached prompt's attention on the flash kernel: its ``T`` new
+        tokens against each other, causal (a ``window`` layer's band), the
+        padding mask cut to the prompt's own keys.  ``k``/``v`` (B, kv_heads,
+        T, d) as projected, repeated to the query heads."""
+        t, rep = q.shape[2], self.num_heads // self.kv_heads
+        if rep > 1:
+            k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+        if bias is not None:
+            bias = bias[..., :t]
+        return flash_run(q, k, v, bias, causal=True, mesh=current_mesh(), dtype=self.dtype, prompt=True,
+                         window=self.window)
+
+    @nn.nowrap
+    def _window_cached(self, q, k, v, bias, positions, cache_positions, mask, prompt: bool, deterministic: bool):
+        """A cached call of a ``window`` layer: a decode step over the ring
+        (one token a row), or a prompt (band attention over its own tokens,
+        then the ring written from its last real positions)."""
+        b, _, t, _ = q.shape
+        if t > 1 and not prompt:
+            raise NotImplementedError(
+                "a cached sliding-window call of several tokens starts a sequence; continuing a ring at "
+                "per-row positions (warm admission, speculative verify) is not implemented"
+            )
+        if not deterministic and self.probs_dropout_rate:
+            raise NotImplementedError("probs dropout on a cached sliding-window layer")
+        # which of the new tokens are real: the cache-width mask at their slots
+        # (a prompt fills slots 0 .. T-1; a step's slot is ``cache_positions``,
+        # parked past the mask for an idle serving slot)
+        if mask is None:
+            real = jnp.ones((b, t), bool)
+        elif prompt:
+            real = mask[:, :t] > 0
+        elif cache_positions is not None:
+            real = (cache_positions < mask.shape[1])[:, None]
+        else:
+            real = jnp.ones((b, 1), bool)
+        ring_k, ring_v = self._cache_kv(k, v, cache_positions, ring=(positions, real))
+        if not prompt:
+            return self._cached_attend(
+                q, ring_k, ring_v, None, None, None,
+                jnp.broadcast_to(positions[:, 0], (b,)).astype(jnp.int32), deterministic, ring=True,
+            )
+        if self._prompt_impl(q, bias) == "flash":
+            return self._prompt_attend(q, k, v, bias)
+        rep = self.num_heads // self.kv_heads
+        if rep > 1:
+            k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+        band = make_band_bias(t, t, self.window)
+        return dot_product_attention(q, k, v, band if bias is None else bias[..., :t] + band, dtype=self.dtype)
+
     def _flash_run(
         self,
         q: jnp.ndarray,
@@ -763,6 +991,8 @@ def flash_run(
     scale: float | None = None,
     dropout_rate: float = 0.0,
     dropout_seed=None,
+    prompt: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Run the Pallas kernel — directly on one device, per-shard under
     ``shard_map`` on a mesh (batch over data×fsdp×expert, heads over
@@ -775,9 +1005,19 @@ def flash_run(
 
     ``dropout_rate`` > 0 (with an int32 ``dropout_seed``) turns on the
     in-kernel attention-probs dropout; each shard folds its axis indices
-    into the seed so shards draw independent masks."""
+    into the seed so shards draw independent masks.
+
+    ``prompt``: a cached prompt's attention, ``flash_prompt_attention`` (the
+    forward kernel alone, causal, its ``window`` flavour where given)."""
+    if prompt:
+        if not causal or dropout_rate:
+            raise ValueError("a cached prompt's attention is causal and takes no probs dropout")
+        attend = lambda q, k, v, bias, **_: flash_prompt_attention(  # noqa: E731
+            q, k, v, bias, window=window, scale=scale, dtype=dtype)
+    else:
+        attend = flash_attention
     if mesh is None or math.prod(mesh.devices.shape) == 1:
-        return flash_attention(
+        return attend(
             q, k, v, bias, causal=causal, dtype=dtype, scale=scale,
             dropout_rate=dropout_rate, dropout_seed=dropout_seed,
         )
@@ -794,7 +1034,7 @@ def flash_run(
             from distributed_llms_example_tpu.ops.fused_dropout import _shard_seed
 
             seed = _shard_seed(seed, fold_axes)
-        return flash_attention(
+        return attend(
             q, k, v, rest[0] if rest else None, causal=causal, dtype=dtype,
             scale=scale, dropout_rate=dropout_rate, dropout_seed=seed,
         )

@@ -192,11 +192,11 @@ def constrain_cache(tree, kv_heads: int | None = None):
     mesh = current_mesh()
     if mesh is None:
         return tree
-    from distributed_llms_example_tpu.parallel.sharding import cache_leaf_spec
+    from distributed_llms_example_tpu.parallel.sharding import KV_LEAVES, cache_leaf_spec
 
     def pin(path, x):
         name = str(path[-1].key) if path and hasattr(path[-1], "key") else ""
-        if name in ("cached_key", "cached_value") and kv_heads is None:
+        if name in KV_LEAVES and kv_heads is None:
             raise ValueError(f"constrain_cache: the K/V leaf {name} needs the model's kv_heads")
         spec = cache_leaf_spec(name, getattr(x, "shape", ()), dict(mesh.shape), kv_heads)
         if spec is not None:

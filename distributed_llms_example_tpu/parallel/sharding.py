@@ -143,14 +143,17 @@ def default_rules() -> ShardingRules:
 # batch they decode), the merged last axis over ``tensor`` (the heads are
 # contiguous in it, and a shard holds whole heads: where the KV head count
 # does not split, ``cache_leaf_spec`` replicates the axis even if its lanes
-# would divide), the length replicated.  The per-module ``cache_index``
+# would divide), the length replicated.  A sliding-window layer's leaves
+# (``window_key`` / ``window_value``, ``ops/mha.py`` ``cache_window_kv``) are the
+# same layout at their own length, the window, whatever the context: a rule, a
+# spec and a constraint go by the leaf, never by one length for the tree.  The per-module ``cache_index``
 # counters are scalars and stay replicated.  ``analysis/spec_lint.py lint_cache_sharding`` validates this
 # rule set against an abstract cache tree exactly like the param rules (the
 # rules name the axes; the guard by head count is ``cache_leaf_spec``'s);
 # ``parallel/activation.py constrain_cache`` applies it inside the compiled
 # prefill/decode programs.
 CACHE_RULES: list[tuple[str, P]] = [
-    (r"(cached_key|cached_value)$", P(("data", "fsdp", "expert"), None, "tensor")),
+    (r"(cached_key|cached_value|window_key|window_value)$", P(("data", "fsdp", "expert"), None, "tensor")),
     # int8 KV cache (--kv-cache-dtype int8): per-head per-position f32
     # scales, (batch, len, heads) — the K/V layout with a head's lanes
     # drawn into one, so the scales always live next to the buffers they
@@ -170,7 +173,10 @@ CACHE_RULES: list[tuple[str, P]] = [
 
 # The cache leaves that grow with the cache length, and the axis it lies on:
 # what widens when a bucket-width prefill lands in a full-width slot.  Any
-# other leaf (a conv state, a counter) has the same shape at every width.
+# other leaf (a window layer's ring, a conv state, a counter) has the same
+# shape at every width.
+KV_LEAVES = ("cached_key", "cached_value", "window_key", "window_value")  # (batch, length, kv_heads x head_dim)
+WINDOW_LEAVES = ("window_key", "window_value")
 CACHE_LENGTH_AXIS = {"cached_key": 1, "cached_value": 1, "key_scale": 1, "value_scale": 1}
 
 
@@ -253,7 +259,9 @@ def cache_leaf_spec(name: str, shape: tuple, mesh_axes: Any, kv_heads: int, *, p
     if len(shape) != 3:
         return None
     batch = None if pool else _batch_axes_if_even(shape[0], mesh_axes)
-    if name in ("cached_key", "cached_value"):  # (batch, len, kv_heads x head_dim)
+    if name in KV_LEAVES:  # (batch, len, kv_heads x head_dim), len the context's or the window's
+        if pool and name in WINDOW_LEAVES:
+            return None  # the block pool pages no ring (the engine refuses the mode)
         whole = shape[2] % kv_heads == 0
         return P(batch, None, _tensor_if_even(kv_heads, mesh_axes) if whole else None)
     if name in CACHE_LENGTH_AXIS:  # the int8 cache's (batch, len, kv_heads) scales

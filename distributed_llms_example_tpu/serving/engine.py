@@ -76,7 +76,13 @@ from distributed_llms_example_tpu.parallel.activation import (
     kv_cache_context,
 )
 from distributed_llms_example_tpu.obs.spans import SpanRecorder, percentiles
-from distributed_llms_example_tpu.parallel.sharding import CACHE_LENGTH_AXIS, _path_str, cache_kv_heads, cache_leaf_name
+from distributed_llms_example_tpu.parallel.sharding import (
+    CACHE_LENGTH_AXIS,
+    WINDOW_LEAVES,
+    _path_str,
+    cache_kv_heads,
+    cache_leaf_name,
+)
 from distributed_llms_example_tpu.serving import cache_pool
 from distributed_llms_example_tpu.serving import spec as spec_decode
 from distributed_llms_example_tpu.utils.jsonlog import log_json
@@ -351,21 +357,35 @@ class ServingEngine:
             sorted({int(b) for b in self.serve.prefill_buckets if 0 < int(b) < self.W})
         ) + (self.W,)
         # a model whose cache holds state other than K/V (LFM2's conv state,
-        # Brumby's retention state) serves on the flat cache only: the block
-        # pool pages K/V by cache position, a state leaf has none; the
-        # speculative verify and the warm prefix admission would have to roll
-        # a state back or continue one
+        # Brumby's retention state), or K/V of a sliding window (Mellum's
+        # ``window_key`` / ``window_value``: a ring of the last positions),
+        # serves on the flat cache only: the block pool pages K/V by cache
+        # position (a state leaf has none; a window layer's blocks would have to
+        # be freed as they slide out); the speculative verify and the warm prefix
+        # admission would have to roll a state back or continue one, or continue
+        # a ring with several tokens at per-row positions
+        flat_only = None
         if getattr(config, "has_recurrent_state", False):
+            flat_only = (
+                "a recurrent state (a convolution state beside K/V, or a retention "
+                "state), which the block pool cannot page and a rejected draft cannot "
+                "roll back"
+            )
+        elif getattr(config, "has_window_cache", False):
+            flat_only = (
+                "a window leaf (window_key / window_value: the K/V of a sliding-window "
+                "layer's last positions, written as a ring), whose blocks the pool "
+                "cannot free as they slide out and which a prefix hit or a draft's "
+                "verify cannot continue at per-row positions"
+            )
+        if flat_only:
             for mode, on in (("paged_kv", self.serve.paged_kv),
                              ("prefix_cache", self.serve.prefix_cache),
                              ("spec_tokens", self.serve.spec_tokens)):
                 if on:
                     raise UnsupportedServeMode(
-                        f"{mode} is not supported for {type(config).__name__}: its "
-                        "cache holds a recurrent state (a convolution state "
-                        "beside K/V, or a retention state), which the block pool "
-                        "cannot page and a rejected draft cannot roll back; serve "
-                        "it on the flat cache (the default)"
+                        f"{mode} is not supported for {type(config).__name__}: its cache "
+                        f"holds {flat_only}; serve it on the flat cache (the default)"
                     )
         # experts: the decode round reports their load (moe_* counters)
         self.moe = getattr(config, "num_experts", 0) > 0
@@ -1102,12 +1122,25 @@ class ServeSession:
         # scales, conv state; retention state with its normaliser where the
         # model has one): metadata arithmetic, no device fetch
         by_kind = {"kv_bytes": 0, "conv_state_bytes": 0}
+        window_bytes = 0
+        # the lengths of the K/V leaves, a (K, V) pair an attention layer: what a
+        # decode round's ``kv_positions_*`` counters sum over (flat causal cache)
+        kv_lengths = []
         for path, x in jax.tree_util.tree_leaves_with_path(self.state.get("cache", self.state.get("pool", {}))):
             leaf = cache_leaf_name(path)
             if leaf != "cache_index":  # a counter, not state
                 kind = CACHE_BYTES_KIND.get(leaf, "kv_bytes")
                 by_kind[kind] = by_kind.get(kind, 0) + int(np.prod(x.shape)) * x.dtype.itemsize
+            if leaf in WINDOW_LEAVES:
+                window_bytes += int(np.prod(x.shape)) * x.dtype.itemsize
+            if leaf in ("cached_key", "window_key") and not (eng.paged or eng.is_seq2seq):
+                kv_lengths.append(int(x.shape[1]))
+        if window_bytes:
+            # a model with window layers: ``kv_bytes`` split by kind of leaf
+            by_kind["kv_window_bytes"] = window_bytes
+            by_kind["kv_full_bytes"] = by_kind["kv_bytes"] - window_bytes
         self._cache_bytes_by_kind = by_kind
+        self._kv_lengths = np.asarray(kv_lengths, np.int64)
         if eng.paged and eng.prefix:
             # the device pool tensor was just re-zeroed (_init_state), so
             # any warm chains a PREVIOUS session retained now index
@@ -1887,7 +1920,15 @@ class ServeSession:
             # slots whose state the round moves: every one, live or not, except
             # where the decode program's steps walk the live slots alone
             n_live = int(self.active.sum())
-            dispatch.set(slots_live=n_live, slots_streamed=n_live if eng.streams_live_slots else eng.S)
+            counters = {"slots_live": n_live, "slots_streamed": n_live if eng.streams_live_slots else eng.S}
+            if len(self._kv_lengths) and not eng.spec:
+                # K/V positions the round's attention needs (a live slot's own, the
+                # step's included; on a window leaf at most the window) against those
+                # its program reads (every slot's whole leaf), over the attention layers
+                held = (self.lengths + self.emitted)[self.active].astype(np.int64)
+                counters["kv_positions_live"] = int(np.minimum(held[:, None], self._kv_lengths[None, :]).sum())
+                counters["kv_positions_streamed"] = int(eng.S * self._kv_lengths.sum())
+            dispatch.set(**counters)
         with self.spans.span("token_fetch") as fetch:  # the host waits for the device here
             if eng.spec:
                 spec_toks = np.asarray(jax.device_get(target))

@@ -336,6 +336,7 @@ def test_lfm2_cell_serving_programs_compile_and_fit(lfm2_cell_engine, one_chip):
         wave_cache, wave_mask, _, wave_first = jax.eval_shape(lambda p: eng._prefill_core(p, *zeros(rows)), params)
         wave = eng._prefill.lower(params, i32(rows, LFM2_PROMPT), i32(rows, LFM2_PROMPT)).compile()
         assert "%gmm" in wave.as_text() and wave.memory_analysis().temp_size_in_bytes < 1.5e9
+        assert "%prompt_attn" in wave.as_text()  # the cached prompt's attention on the flash kernel (PR 37)
         admit = eng._admit.lower(state, abstract(wave_cache), abstract(wave_mask), abstract(wave_first), i32(rows)).compile()
         assert admit.memory_analysis().temp_size_in_bytes < 0.1e9
 
@@ -521,3 +522,85 @@ def test_brumby_cell_serving_programs_compile_and_fit(topo, one_chip, compiled_k
         admit = eng._admit.lower(state, abstract(wave_cache), abstract(wave_mask), abstract(wave_first), i32(rows)).compile()
         assert admit.memory_analysis().temp_size_in_bytes < 0.1e9
         assert admit.memory_analysis().alias_size_in_bytes >= state_bytes
+
+
+MELLUM_SLOTS, MELLUM_WAVE, MELLUM_PROMPT, MELLUM_NEW = 48, 1, 8192, 256
+_WINDOW_DECODE = re.compile(r"%window_decode(?:\.\d+)? = bf16\[48,4,8,128\]\{[^}]*\} custom-call\(")
+_PROMPT_ATTN = re.compile(r"%prompt_attn(?:\.\d+)? = \(bf16\[1,32,8192,128\]\{[^}]*\}, ")
+
+
+def test_window_kernels_compile_at_the_cell_shapes_under_their_own_names(one_chip, compiled_kernels):
+    """The window flavour of the forward kernel over one 8,192-token row of 32
+    heads (grid: 8 q tiles x 2 band tiles of 1,024, not x 8) and the ring step
+    over 48 slots' 1,024-entry leaves, 8 query heads a KV head as q rows: what
+    Mosaic accepts, and the names a device trace will show."""
+    from distributed_llms_example_tpu.ops import flash_attention as fa
+
+    assert fa._band_tiles(8192, 1024, 1024, 1024) == 2 and fa._band_tiles(8192, 512, 512, 1024) == 3
+    qkv = [((1, 32, MELLUM_PROMPT, 128), BF16)] * 3
+    for window, calls in ((1024, 1), (None, 1)):
+        text = _compile(lambda q, k, v: fa.flash_prompt_attention(q, k, v, window=window), one_chip, *qkv)  # noqa: B023
+        assert len(_PROMPT_ATTN.findall(text)) == calls, [ln[:120] for ln in text.splitlines() if "custom-call(" in ln]
+    text = _compile(
+        lambda q, k, v, pos: fa.flash_decode(q, k, v, offsets=pos, q_group=8, ring=True), one_chip,
+        ((MELLUM_SLOTS, 4, 8, 128), BF16), ((MELLUM_SLOTS, 1024, 512), BF16), ((MELLUM_SLOTS, 1024, 512), BF16),
+        ((MELLUM_SLOTS,), jnp.int32))
+    assert len(_WINDOW_DECODE.findall(text)) == 1, [ln[:120] for ln in text.splitlines() if "custom-call(" in ln]
+
+
+def test_mellum_cell_serving_programs_compile_and_fit(topo, one_chip, compiled_kernels, monkeypatch):
+    """Decode step, the one-row prefill wave and admit at 48 slots x 8,192 + 256
+    tokens, one period (3 window layers + 1 full) at the published widths,
+    bfloat16 weights: the window leaves hold 1,024 positions and no more, a
+    window layer's step is ``window_decode`` and the full layer's is found at
+    its call site, no K/V leaf is copied, and the prompt's attention is four
+    ``prompt_attn`` calls with no (8192, 8192) scores anywhere."""
+    from benchmarks.harness import program, spec as spec_mod
+    from distributed_llms_example_tpu.core.config import MeshConfig
+    from distributed_llms_example_tpu.core.mesh import build_mesh
+    from distributed_llms_example_tpu.models import registry
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels' auto rules and donation ask it
+    cfg = spec_mod.load_json(os.path.join(spec_mod.BENCH_DIR, "configs", "mellum2-12b-a2.5b.json"))
+    lm = registry.load_model(
+        program.register_bench_model(cfg, spec_mod.load_module("adapters", cfg["family"])), dtype=BF16)
+    assert lm.config.layer_types == ("sliding_attention",) * 3 + ("full_attention",) and lm.config.vocab_size == 24576
+    serve = ServeConfig(max_slots=MELLUM_SLOTS, prefill_batch=MELLUM_WAVE, max_new_tokens=MELLUM_NEW,
+                        max_source_length=MELLUM_PROMPT)
+    eng = ServingEngine(lm.module, lm.config, build_mesh(MeshConfig(data=-1), devices=topo.devices[:1]),
+                        serve, is_seq2seq=False)
+
+    def abstract(tree, dtype=None):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype if dtype is not None and jnp.issubdtype(x.dtype, jnp.floating) else x.dtype,
+            sharding=one_chip), tree)
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    params = abstract(jax.eval_shape(lambda: lm.init_params(0)), BF16)
+    zeros = lambda n: (jnp.zeros((n, MELLUM_PROMPT), jnp.int32),) * 2  # noqa: E731
+    slots_cache = eng._slot_cache_shapes(params)
+    shapes = sorted({x.shape for x in jax.tree.leaves(slots_cache)})
+    assert shapes == [(), (48, 1024, 512), (48, 8448, 512)], shapes  # two lengths of K/V leaf in one slot cache
+    state = {"cache": abstract(slots_cache), "mask": i32(MELLUM_SLOTS, MELLUM_PROMPT + MELLUM_NEW), "last": i32(MELLUM_SLOTS)}
+    active = jax.ShapeDtypeStruct((MELLUM_SLOTS,), jnp.bool_, sharding=one_chip)
+
+    step = eng._step.lower(params, state, i32(MELLUM_SLOTS), i32(MELLUM_SLOTS), active).compile()
+    text, mem = step.as_text(), step.memory_analysis()
+    assert len(_WINDOW_DECODE.findall(text)) == 3, [ln[:120] for ln in text.splitlines() if "custom-call(" in ln]
+    assert len(_decode_attn_calls(text, (48, 4, 8, 128))) == 1  # the full layer: 8 query heads a KV head as q rows
+    assert "%gmm" in text and "ragged-dot" not in text  # the Pallas grouped product at 64 experts of 896
+    kv_bytes = 2 * 48 * (3 * 1024 + 8448) * 512 * 2
+    assert mem.alias_size_in_bytes >= kv_bytes and mem.temp_size_in_bytes < 0.5e9, mem
+    assert not _large_copies(text, 48 * 1024 * 512)
+
+    assert eng.wave_sizes == (1,)
+    wave_cache, wave_mask, _, wave_first = jax.eval_shape(lambda p: eng._prefill_core(p, *zeros(1)), params)
+    assert sorted({x.shape for x in jax.tree.leaves(wave_cache)}) == [(), (1, 1024, 512), (1, 8448, 512)]
+    wave = eng._prefill.lower(params, i32(1, MELLUM_PROMPT), i32(1, MELLUM_PROMPT)).compile()
+    text = wave.as_text()
+    assert len(_PROMPT_ATTN.findall(text)) == 4, [ln[:120] for ln in text.splitlines() if "custom-call(" in ln]
+    assert "8192,8192]" not in text and "8192,8448]" not in text  # no materialised scores
+    assert "%gmm" in text and wave.memory_analysis().temp_size_in_bytes < 2.5e9, wave.memory_analysis()
+    admit = eng._admit.lower(state, abstract(wave_cache), abstract(wave_mask), abstract(wave_first), i32(1)).compile()
+    assert admit.memory_analysis().temp_size_in_bytes < 0.1e9 and admit.memory_analysis().alias_size_in_bytes >= kv_bytes

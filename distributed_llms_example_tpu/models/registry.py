@@ -346,8 +346,9 @@ def load_model(
     ``attention_impl`` overrides the config's attention path ("auto" /
     "flash" / "ring" / "xla", see ops/mha.py) for every family.  T5's
     learned relative-position bias rides the flash kernel's differentiable
-    ``learned_bias`` input on any mesh (multi-device via the sharded path
-    whose hand-written vjp psums dbias across batch shards); T5
+    ``relative_bias`` input (a per-diagonal vector) on any mesh (multi-device
+    via the sharded path whose hand-written vjp psums the bias's diagonal
+    sums across batch shards); T5
     cross-attention takes the same flash/ring paths as BART/LLaMA.
 
     ``moe_capacity_factor`` overrides the MoE expert capacity factor for

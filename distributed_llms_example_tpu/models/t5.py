@@ -18,6 +18,7 @@ Supports both T5 v1.0 (relu FFN, tied) and v1.1/flan (gated-gelu, untied).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 from typing import Any, Optional
@@ -33,7 +34,7 @@ from distributed_llms_example_tpu.ops.attention import (
     make_causal_bias,
     mask_to_bias,
 )
-from distributed_llms_example_tpu.ops.flash_attention import flash_attention
+from distributed_llms_example_tpu.ops.flash_attention import flash_attention, relative_bias_matrix
 from distributed_llms_example_tpu.ops.fused_dropout import Dropout
 from distributed_llms_example_tpu.ops.norms import RMSNorm
 from distributed_llms_example_tpu.utils.remat import remat_block
@@ -67,8 +68,9 @@ class T5Config:
     decoder_start_token_id: int = 0
     # "auto": Pallas flash attention where eligible — the learned
     # relative-position bias rides the kernel's differentiable
-    # ``learned_bias`` input (multi-device meshes use the sharded path
-    # whose hand-written vjp psums dbias across batch shards,
+    # ``relative_bias`` input as a per-diagonal vector (multi-device meshes
+    # use the sharded path whose hand-written vjp psums the diagonal sums
+    # across batch shards,
     # ops/flash_attention.flash_attention_lbias_sharded), and mask-only
     # cross-attention takes the same paths as BART/LLaMA.
     attention_impl: str = "auto"
@@ -105,6 +107,41 @@ def relative_position_bucket(
     ).astype(jnp.int32)
     if_large = jnp.minimum(if_large, num_buckets - 1)
     return ret + jnp.where(is_small, rel, if_large)
+
+
+def relative_bias_vector(table: jnp.ndarray, config: T5Config, q_len: int, kv_len: int,
+                         *, bidirectional: bool) -> jnp.ndarray:
+    """(heads, q_len + kv_len - 1) fp32 from a stack's (buckets, heads) table:
+    entry ``(k - q) + q_len - 1`` is ``table[bucket(k - q)]``."""
+    buckets = relative_position_bucket(
+        jnp.arange(-(q_len - 1), kv_len),
+        bidirectional=bidirectional,
+        num_buckets=config.relative_attention_num_buckets,
+        max_distance=config.relative_attention_max_distance,
+    )
+    return jnp.take(table.astype(jnp.float32), buckets, axis=0).T
+
+
+# attention sites with a relative bias traced since the last flush, by how the
+# bias's gradient is made: "diagonal" = the flash kernel's diagonal sums,
+# "matrix" = XLA's path through the (1, H, Q, K) matrix
+_RELATIVE_BIAS_SITES: collections.Counter = collections.Counter()
+
+
+def flush_relative_bias_sites() -> dict[str, int]:
+    """Write the tally of one traced forward to the run's log (once a distinct
+    tally, like every ``attention_impl`` record) and start it again."""
+    from distributed_llms_example_tpu.ops.mha import _log_impl_once
+
+    sites = {k: _RELATIVE_BIAS_SITES.pop(k, 0) for k in ("diagonal", "matrix")}
+    if any(sites.values()):
+        _log_impl_once(
+            "t5:relative_bias",
+            f"bias gradient by diagonal sums at {sites['diagonal']} sites, "
+            f"through the full matrix at {sites['matrix']}",
+            **sites,
+        )
+    return sites
 
 
 class T5Attention(nn.Module):
@@ -165,16 +202,17 @@ class T5Attention(nn.Module):
         bias: jnp.ndarray | None = None,
         *,
         use_cache: bool = False,
-        learned_bias: jnp.ndarray | None = None,
+        relative_bias: jnp.ndarray | None = None,
         cross_kv: tuple[jnp.ndarray, jnp.ndarray] | None = None,
         deterministic: bool = True,
         cache_positions: jnp.ndarray | None = None,
     ) -> jnp.ndarray:
-        """``bias``: constant (mask-like) additive bias.  ``learned_bias``:
-        the (1, H, Q, K) relative-position bias, kept SEPARATE so the flash
-        kernel can treat the mask as constant while computing the learned
-        bias's gradient in its dbias kernel.  When the caller pre-combines
-        everything into ``bias`` (cache decode, the pipeline adapter), the
+        """``bias``: constant (mask-like) additive bias.  ``relative_bias``:
+        the relative-position bias as its (H, Q + K - 1) per-diagonal vector
+        (``T5Stack.relative_bias``), kept SEPARATE so the flash kernel can
+        treat the mask as constant while its dbias kernel hands the vector
+        its gradient as diagonal sums.  When the caller pre-combines
+        everything into ``bias`` (cache decode), the
         XLA path reproduces round-2 behavior exactly.  ``cross_kv``:
         precomputed ``project_kv`` output — skips the k/v projections.
         ``deterministic`` gates ``config.attn_dropout_rate`` (probs
@@ -186,10 +224,7 @@ class T5Attention(nn.Module):
                 # beam decode: beams share the row's cross K/V — one
                 # shared fold/unfold convention (ops/attention.py); T5
                 # attention is unscaled
-                out = beam_grouped_attention(
-                    q, k, v, bias, scale=1.0, dtype=self.dtype,
-                    learned_bias=learned_bias,
-                )
+                out = beam_grouped_attention(q, k, v, bias, scale=1.0, dtype=self.dtype)
                 return self.o_proj(self._merge(out))
         else:
             kv_src = hidden if kv_hidden is None else kv_hidden
@@ -256,19 +291,21 @@ class T5Attention(nn.Module):
             bias = step_bias if bias is None else bias + step_bias
             causal_in_bias = True
         out = self._attend(
-            q, k, v, bias, learned_bias, use_cache, causal_in_bias,
+            q, k, v, bias, relative_bias, use_cache, causal_in_bias,
             deterministic,
         )
         return self.o_proj(self._merge(out))
 
-    def _attend(self, q, k, v, bias, learned_bias, use_cache, causal_in_bias,
+    def _attend(self, q, k, v, bias, relative_bias, use_cache, causal_in_bias,
                 deterministic=True):
         """T5 attention is UNSCALED (scale=1.0).  Selection mirrors
         MultiHeadAttention: ring on sequence meshes (cross-attention /
         mask-only biases), Pallas flash on TPU where tileable, XLA
-        otherwise.  With a learned bias, multi-device meshes use the
-        dedicated sharded path whose hand-written vjp psums dbias across
-        batch shards (flash_attention_lbias_sharded)."""
+        otherwise.  With a relative bias, multi-device meshes use the
+        dedicated sharded path whose hand-written vjp psums the bias's
+        diagonal sums across batch shards (flash_attention_lbias_sharded);
+        XLA's path lays the vector out as the (1, H, Q, K) matrix it stands
+        for and differentiates through that."""
         from distributed_llms_example_tpu.ops.flash_attention import (
             flash_attention_lbias_sharded,
         )
@@ -296,12 +333,16 @@ class T5Attention(nn.Module):
             causal=causal_here,
             bias_kv_only=(
                 False
-                if learned_bias is not None
+                if relative_bias is not None
                 else None if bias is None else (bias.shape[1] == 1 and bias.shape[2] == 1)
             ),
-            has_learned_bias=learned_bias is not None,
+            has_learned_bias=relative_bias is not None,
         )
         _log_impl_once(f"t5:{impl}", reason)
+        if relative_bias is not None:
+            # trace-time tally of how this site's bias gradient is made
+            # (``flush_relative_bias_sites`` writes it to the run's log)
+            _RELATIVE_BIAS_SITES["diagonal" if impl == "flash" else "matrix"] += 1
         probs_dropout = (
             float(self.config.attn_dropout_rate) if not deterministic else 0.0
         )
@@ -322,17 +363,17 @@ class T5Attention(nn.Module):
                 )
 
                 seed = seed_from_key(self.make_rng("dropout"))
-            if learned_bias is not None:
+            if relative_bias is not None:
                 if mesh is not None and math.prod(mesh.devices.shape) > 1:
                     return flash_attention_lbias_sharded(
-                        q, k, v, bias, learned_bias, mesh=mesh,
+                        q, k, v, bias, relative_bias, mesh=mesh,
                         batch_axes=tuple(a for a in BATCH_AXES if a in mesh.shape),
                         head_axis="tensor" if "tensor" in mesh.shape else None,
                         causal=causal_here, scale=1.0, dtype=self.dtype,
                         dropout_rate=probs_dropout, dropout_seed=seed,
                     )
                 return flash_attention(
-                    q, k, v, bias, learned_bias=learned_bias,
+                    q, k, v, bias, relative_bias=relative_bias,
                     causal=causal_here, scale=1.0, dtype=self.dtype,
                     dropout_rate=probs_dropout, dropout_seed=seed,
                 )
@@ -343,8 +384,9 @@ class T5Attention(nn.Module):
         if causal_here:
             step = make_causal_bias(q.shape[2], k.shape[2])
             bias = step if bias is None else bias + step
-        if learned_bias is not None:
-            bias = learned_bias if bias is None else bias + learned_bias
+        if relative_bias is not None:
+            learned = relative_bias_matrix(relative_bias, q.shape[2], k.shape[2]).astype(self.dtype)
+            bias = learned if bias is None else bias + learned
         return dot_product_attention(
             q, k, v, bias, scale=1.0, dtype=self.dtype,
             dropout_rate=probs_dropout,
@@ -401,11 +443,12 @@ class T5Block(nn.Module):
     ) -> jnp.ndarray:
         # deterministic/use_cache are positional so nn.remat can mark them
         # static (argnums 5, 6 counting self at 0); pos_bias is the learned
-        # relative-position bias kept separate from the (constant) mask in
-        # self_bias so the flash kernel can compute its gradient
+        # relative-position bias (its per-diagonal vector) kept separate from
+        # the (constant) mask in self_bias so the flash kernel can compute
+        # its gradient
         h = self.self_attn(
             self.self_attn_norm(hidden), bias=self_bias, use_cache=use_cache,
-            learned_bias=pos_bias, deterministic=deterministic,
+            relative_bias=pos_bias, deterministic=deterministic,
             cache_positions=cache_positions,
         )
         # residual rides the dropout kernel (one fused pass on TPU)
@@ -446,8 +489,22 @@ class T5Stack(nn.Module):
         self.final_norm = RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=self.dtype, name="final_norm")
         self.dropout = Dropout(cfg.dropout_rate)
 
+    def relative_bias(self, q_len: int, kv_len: int) -> jnp.ndarray:
+        """The relative-position bias of an uncached call as what it is:
+        (heads, q_len + kv_len - 1) fp32, one entry a diagonal — index
+        ``(k - q) + q_len - 1`` holds ``table[bucket(k - q)]``, the bias of
+        every (q, k) pair at that offset
+        (``ops.flash_attention.relative_bias_matrix`` is the matrix).  A
+        ``take`` over 2,047 offsets where the matrix's was one over 1,048,576
+        pairs, and the table's gradient is that small take's transpose."""
+        return relative_bias_vector(
+            self.relative_attention_bias.embedding, self.config, q_len, kv_len,
+            bidirectional=not self.causal,
+        )
+
     def position_bias(self, q_len: int, kv_len: int, offset: int | jnp.ndarray = 0) -> jnp.ndarray:
-        """(1, heads, q_len, kv_len) additive relative-position bias.
+        """(1, heads, q_len, kv_len) additive relative-position bias of a
+        cached decode step (a constant there: no gradient).
 
         ``offset`` may be a (B,) array — per-ROW decode offsets for
         continuous-batching slots, yielding a (B, heads, q_len, kv_len)
@@ -505,9 +562,9 @@ class T5Stack(nn.Module):
         else:
             # keep the LEARNED bias separate from the constant mask:
             # T5Attention routes it through the flash kernel's
-            # differentiable learned_bias input (causality is the
+            # differentiable relative_bias input (causality is the
             # attention impl's job — flash applies it natively)
-            pos_bias = self.position_bias(q_len, q_len)
+            pos_bias = self.relative_bias(q_len, q_len)
             self_bias = mask_to_bias(attention_mask) if attention_mask is not None else None
         cross_bias = mask_to_bias(encoder_mask) if encoder_mask is not None else None
         hidden = self.dropout(hidden, deterministic=deterministic)
@@ -613,14 +670,17 @@ class T5ForConditionalGeneration(nn.Module):
         *,
         deterministic: bool = True,
     ) -> jnp.ndarray:
+        _RELATIVE_BIAS_SITES.clear()  # sites of a stack traced alone before (encode, the pipelined adapter)
         enc = self.encode(input_ids, attention_mask, deterministic=deterministic)
-        return self.decode(
+        logits = self.decode(
             decoder_input_ids,
             enc,
             encoder_mask=attention_mask,
             decoder_attention_mask=decoder_attention_mask,
             deterministic=deterministic,
         )
+        flush_relative_bias_sites()  # the whole forward is traced: say how its bias gradients are made
+        return logits
 
 
 def shift_right(labels: jnp.ndarray, decoder_start_token_id: int, pad_token_id: int) -> jnp.ndarray:
@@ -683,19 +743,10 @@ class PipelinedT5:
         if not cfg.tie_word_embeddings:
             self._head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=dtype)
 
-    def _position_bias(self, table: jnp.ndarray, q_len: int, causal: bool) -> jnp.ndarray:
-        """(1, heads, q, q) additive bias from a stack's bucket table —
-        the functional twin of T5Stack.position_bias."""
-        cfg = self.config
-        rel = jnp.arange(q_len)[None, :] - jnp.arange(q_len)[:, None]
-        buckets = relative_position_bucket(
-            rel,
-            bidirectional=not causal,
-            num_buckets=cfg.relative_attention_num_buckets,
-            max_distance=cfg.relative_attention_max_distance,
-        )
-        bias = jnp.take(table, buckets, axis=0)  # (q, kv, heads)
-        return bias.transpose(2, 0, 1)[None].astype(self.dtype)
+    def _relative_bias(self, table: jnp.ndarray, q_len: int, causal: bool) -> jnp.ndarray:
+        """(heads, 2 q - 1) per-diagonal bias from a stack's bucket table —
+        the functional twin of T5Stack.relative_bias."""
+        return relative_bias_vector(table, self.config, q_len, q_len, bidirectional=not causal)
 
     def _dropout(self, x, key):
         from distributed_llms_example_tpu.parallel.pipeline import dropout
@@ -709,10 +760,11 @@ class PipelinedT5:
         the encoder's final-norm + dropout become the SEAM transform
         (applied once per microbatch where the encoder output enters the
         decoder pipeline, differentiated for the norm scale's gradient);
-        the learned relative-position biases ride ``diff_extras`` — the
-        executor accumulates their cotangents across every (chunk,
-        microbatch) vjp, and the bucket tables get their gradients through
-        an outer ``jax.vjp`` of the bias construction."""
+        the learned relative-position biases ride ``diff_extras`` as their
+        per-diagonal vectors — the executor accumulates their cotangents
+        across every (chunk, microbatch) vjp, and the bucket tables get
+        their gradients through an outer ``jax.vjp`` of the vectors'
+        construction."""
         from distributed_llms_example_tpu.parallel.activation import activation_mesh
         from distributed_llms_example_tpu.parallel.pipeline_seq2seq import (
             pipeline_value_and_grad_seq2seq,
@@ -794,8 +846,8 @@ class PipelinedT5:
             def pos_biases(tables):
                 et, dt = tables
                 return (
-                    self._position_bias(et, batch["input_ids"].shape[1], causal=False),
-                    self._position_bias(dt, dec_ids.shape[1], causal=True),
+                    self._relative_bias(et, batch["input_ids"].shape[1], causal=False),
+                    self._relative_bias(dt, dec_ids.shape[1], causal=True),
                 )
 
             (enc_pos, dec_pos), pos_vjp = jax.vjp(
@@ -863,11 +915,12 @@ class PipelinedT5:
         ex = {k: v for k, v in extras.items() if v is not None}
         if self_bias is not None:
             ex["self_bias"] = self_bias
-        if pos_bias is not None:
-            # the LEARNED bias rides its own slot all the way into
-            # T5Attention.learned_bias — pre-combining it into the constant
-            # mask would zero its gradient on any flash-selected path
-            ex["pos_bias"] = pos_bias
+        # the LEARNED bias rides its own slot all the way into
+        # T5Attention.relative_bias — pre-combining it into the constant
+        # mask would zero its gradient on any flash-selected path.  A
+        # leading 1: the executor splits an extra whose first axis is
+        # the batch's size into microbatches, and heads can equal it
+        ex["pos_bias"] = pos_bias[None]
 
         # T5Stack applies dropout on the embedded input and after the
         # final norm; mirror that around the pipeline
@@ -879,11 +932,11 @@ class PipelinedT5:
                 if key is None:
                     return block.apply(
                         {"params": lp}, h, e.get("self_bias"), e.get("enc"),
-                        e.get("cross_bias"), True, False, e.get("pos_bias"),
+                        e.get("cross_bias"), True, False, e["pos_bias"][0],
                     )
                 return block.apply(
                     {"params": lp}, h, e.get("self_bias"), e.get("enc"),
-                    e.get("cross_bias"), False, False, e.get("pos_bias"),
+                    e.get("cross_bias"), False, False, e["pos_bias"][0],
                     rngs={"dropout": key},
                 )
 
@@ -910,7 +963,7 @@ class PipelinedT5:
 
         q_len = input_ids.shape[1]
         enc_table = p["encoder"]["relative_attention_bias"]["embedding"]
-        enc_pos = self._position_bias(enc_table, q_len, causal=False)
+        enc_pos = self._relative_bias(enc_table, q_len, causal=False)
         enc_mask = mask_to_bias(attention_mask) if attention_mask is not None else None
         enc = self._run_stack(
             p["encoder"], self._enc_block, shared(input_ids), enc_mask, enc_pos, {},
@@ -919,7 +972,7 @@ class PipelinedT5:
 
         d_len = decoder_input_ids.shape[1]
         dec_table = p["decoder"]["relative_attention_bias"]["embedding"]
-        dec_pos = self._position_bias(dec_table, d_len, causal=True)
+        dec_pos = self._relative_bias(dec_table, d_len, causal=True)
         # causality is the attention impl's job (T5Block's decoder
         # self-attention has causal=True); only the padding mask goes in
         dec_mask = (

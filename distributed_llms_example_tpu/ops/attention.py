@@ -100,23 +100,20 @@ def beam_grouped_attention(
     *,
     scale: float | None = None,
     dtype: jnp.dtype | None = None,
-    learned_bias: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Beam-decode front end for ``grouped_dot_product_attention``: the ONE
     home for the fold/slice/unfold convention both attention modules use.
 
     ``q``: (B·G, H, Q, d) flattened beam batch; ``k``/``v``: (B, H, K, d)
     shared per row.  A per-beam ``bias`` (leading dim B·G) is stride-
-    sliced to one row per group (beams of a row share their mask);
-    ``learned_bias`` (1, H, Q, K) adds on top.  Returns (B·G, H, Q, d)."""
+    sliced to one row per group (beams of a row share their mask).
+    Returns (B·G, H, Q, d)."""
     B = k.shape[0]
     G = q.shape[0] // B
     H, Q, d = q.shape[1], q.shape[2], q.shape[3]
     bb = None
     if bias is not None:
         bb = bias if bias.shape[0] in (1, B) else bias[::G]
-    if learned_bias is not None:
-        bb = learned_bias if bb is None else bb + learned_bias
     out = grouped_dot_product_attention(
         q.reshape(B, G, H, Q, d), k, v, bb, scale=scale, dtype=dtype
     )

@@ -13,16 +13,22 @@ Layout/conventions
     full size — e.g. a (B, 1, 1, K) padding mask from
     ``ops.attention.mask_to_bias``.  Size-1 dims are handled in the
     BlockSpec index maps, so the bias is never broadcast in HBM.
-  - ``learned_bias`` is a second additive bias of shape exactly
-    (1, H, Q, K) — T5's relative-position bias — that DOES receive a
-    gradient: a third backward kernel accumulates dbias = p·(dp − δ)
-    tile-by-tile with batch as the innermost (sequential) grid axis, so
-    the (B, H, Q, K) un-reduced gradient is never materialized in HBM.
+  - ``relative_bias`` is a second additive bias that DOES receive a
+    gradient — T5's relative-position bias — handed over as what it is: a
+    (H, Q + K - 1) vector of per-DIAGONAL values, entry ``(k - q) + Q - 1``
+    being the bias of every (q, k) pair at that offset.  The forward, dq
+    and dkv kernels read the (1, H, Q, K) matrix XLA lays out from it
+    (``relative_bias_matrix``); a third backward kernel accumulates
+    dbias = p·(dp − δ) tile-by-tile with batch as the innermost
+    (sequential) grid axis and writes each tile's sums ALONG ITS DIAGONALS
+    in fp32, so neither the (B, H, Q, K) un-reduced gradient nor the
+    (1, H, Q, K) reduced one is ever materialized in HBM: the vector's
+    cotangent is an overlap-add of a few hundred KB.
   - ``causal=True`` applies the triangular mask inside the kernel (and
     skips fully-masked kv tiles); don't also encode causality in ``bias``.
   - The backward pass treats ``bias`` as a constant (zero gradient) —
-    padding/causal masks only; learned additive biases go through
-    ``learned_bias``.
+    padding/causal masks only; the learned relative bias goes through
+    ``relative_bias``.
   - Softmax statistics (running max ``m``, denominator ``l``) live in
     (block_q, 128) fp32 scratch — TPU vector layout wants a full 128-lane
     last dim — and the logsumexp residual is saved as (B, H, S, 128) with
@@ -40,6 +46,7 @@ interpret mode; numerics are checked against ``dot_product_attention``.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -441,22 +448,99 @@ def _bwd_dkv_kernel(
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd_dlbias_kernel(
+def relative_bias_matrix(rel: jnp.ndarray, q_len: int, kv_len: int) -> jnp.ndarray:
+    """The (1, H, Q, K) bias a (H, Q + K - 1) per-diagonal vector stands for:
+    ``out[0, h, q, k] = rel[h, (k - q) + q_len - 1]``.  Built from static
+    slices alone, never a gather: a strip of ``rows`` query rows is ``rows``
+    shifted views of the vector, and a strip ``f`` times as tall is ``f``
+    shifted views of that strip, so 1,024 rows take 8 + 16 + 8 slices, the
+    last level's at whole 128-lane offsets."""
+    strip, rows = rel[:, None, :], 1
+    for factor in _row_factors(q_len):
+        width = strip.shape[-1] - rows * (factor - 1)
+        strip = jnp.concatenate(
+            [strip[:, :, rows * (factor - 1 - i):rows * (factor - 1 - i) + width] for i in range(factor)],
+            axis=1,
+        )
+        rows *= factor
+    assert strip.shape[1:] == (q_len, kv_len), (strip.shape, q_len, kv_len)
+    return strip[None]
+
+
+def _row_factors(n: int) -> list[int]:
+    """``n`` as a product of strip heights: the sublane tile, then 128-row
+    blocks, then whatever is left (1,024 = 8 x 16 x 8)."""
+    factors = []
+    for f in (8, 16):
+        if n % f == 0 and n > f:
+            factors.append(f)
+            n //= f
+    return factors + [n]
+
+
+def _diagonal_width(block_q: int, block_k: int) -> int:
+    """Lanes a tile's diagonal sums are written in: whole ``block_k``-wide
+    segments that hold the tile's ``block_q + block_k - 1`` diagonals, eight
+    sublanes still apart (``_tile_diagonal_sums``); at least ``block_q +
+    block_k``."""
+    return ((block_q - 8) // block_k + 2) * block_k
+
+
+def _tile_diagonal_sums(tile_ref, block_q: int, block_k: int):
+    """A (block_q, block_k) fp32 tile summed along its diagonals, as far as
+    whole sublane groups go: (8, ``_diagonal_width``) with
+    ``out[r, c] = Σ_g tile[8g + r, c - (block_q - 8 - 8g)]``, so tile
+    diagonal ``d = (k - q) + block_q - 1`` lies at ``out[r, d - 7 + r]`` and
+    the last eight-way sum, a different shift a sublane, is XLA's
+    (``_overlap_add``: the output is a few KB).  Row group ``g`` wants a lane
+    shift of ``block_q - 8 - 8g``.  The tile is taken a slab of 128 rows at a
+    time, (16 groups, 8, block_k): a slab's shift is whole vregs, so the slab
+    is an address in the (16, 8, width) sum of slabs, and group ``p`` of every
+    slab wants the same ``8 (15 - p)`` lanes more — sixteen rotates of
+    (8, width) in all, no mask (nothing wraps: width >= block_q + block_k),
+    and a few dozen operations to lower where one a vreg was 2,000 a call
+    site and 37 s of every run's set-up.  A tile that is no whole slab, or
+    narrower than a vreg's lanes (interpret mode, toy shapes), is taken in
+    slabs as tall as divide it and placed by as many lanes."""
+    rows = math.gcd(block_q, LANES) if block_k % LANES == 0 else 8
+    groups, slabs = rows // 8, block_q // rows
+    width = _diagonal_width(block_q, block_k)
+    acc = None
+    for s in range(slabs):
+        slab = tile_ref[rows * s:rows * (s + 1), :].reshape(groups, 8, block_k)
+        left = rows * (slabs - 1 - s)
+        pads = [jnp.zeros((groups, 8, n), jnp.float32) for n in (left, width - block_k - left)]
+        placed = jnp.concatenate([x for x in (pads[0], slab, pads[1]) if x.shape[2]], axis=2)
+        acc = placed if acc is None else acc + placed
+    total = None
+    for p in range(groups):
+        part, shift = acc[p], 8 * (groups - 1 - p)
+        if shift:
+            part = pltpu.roll(part, shift, 1)
+        total = part if total is None else total + part
+    return total
+
+
+def _bwd_drel_kernel(
     *refs, scale: float, causal: bool, block_q: int, block_k: int, nb: int,
     has_bias: bool, dropout_rate: float = 0.0, hw_rng: bool = False,
 ):
-    """Gradient of the LEARNED (1, H, Q, K) bias: dbias = Σ_batch p·(dp−δ).
+    """Gradient of the relative bias: dbias = Σ_batch p·(dp−δ), handed back
+    as sums along diagonals (every (q, k) pair of one offset reads the same
+    entry of the vector, so that is its whole gradient).
 
     Grid is (heads, q-tiles, k-tiles, batch) with batch innermost and
     "arbitrary", so the (block_q, block_k) scratch accumulates the batch
-    reduction across grid steps and the un-reduced (B, H, Q, K) gradient
-    never exists in HBM.  Recomputes s/p per tile from the residuals (same
-    trade the dq/dkv kernels make)."""
+    reduction across grid steps and neither the un-reduced (B, H, Q, K)
+    gradient nor the reduced (1, H, Q, K) one ever exists in HBM: the last
+    batch step reduces the tile where it lies (``_tile_diagonal_sums``).
+    Recomputes s/p per tile from the residuals (same trade the dq/dkv
+    kernels make)."""
     it = iter(refs)
     seed_ref = next(it) if dropout_rate > 0.0 else None
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
     bias_ref = next(it) if has_bias else None
-    lbias_ref, do_ref, lse_ref, delta_ref, dlb_ref, dlb_scr = it
+    lbias_ref, do_ref, lse_ref, delta_ref, drel_ref, dlb_scr = it
     hi = pl.program_id(0)
     qi, ki, bi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
 
@@ -501,16 +585,40 @@ def _bwd_dlbias_kernel(
 
     @pl.when(bi == nb - 1)
     def _finish():
-        dlb_ref[0, 0] = dlb_scr[:].astype(dlb_ref.dtype)
+        # a causal tile above the diagonal never computed: its zeros sum to zeros
+        drel_ref[0, 0, 0] = _tile_diagonal_sums(dlb_scr, block_q, block_k)
 
 
-def _bwd_dlbias(q, k, v, bias, lbias, lse, delta, do, *, scale, causal,
-                block_q, block_k, interpret,
-                dropout_rate=0.0, dropout_seed=None, hw_rng=False):
+def _overlap_add(sums, q_len: int, kv_len: int, block_q: int, block_k: int, causal: bool):
+    """(H, nq, nk, 8, ``_diagonal_width``) tile sums -> the (H, Q + K - 1)
+    per-diagonal gradient: the eight sublanes' last shift-and-add
+    (``_tile_diagonal_sums``), then every tile's ``block_q + block_k - 1``
+    diagonals added in at the tile's place (tiles whose offsets coincide,
+    as those of one block diagonal do where the tile is square, first)."""
+    nq, nk = sums.shape[1:3]
+    span = block_q + block_k - 1
+    flat = jnp.pad(sums, [(0, 0)] * 4 + [(7, 0)])
+    tiles = sum(flat[..., r, r:r + span] for r in range(8))  # (H, nq, nk, span)
+    by_start: dict[int, list] = {}
+    for qi in range(nq):
+        for ki in range(nk):
+            if causal and (qi + 1) * block_q <= ki * block_k:
+                continue  # never computed: zeros
+            start = ki * block_k - qi * block_q + q_len - block_q
+            by_start.setdefault(start, []).append(tiles[:, qi, ki])
+    # pads and adds (one fusion), not scatters into a zero vector
+    end = q_len + kv_len - 1 - span
+    return sum(jnp.pad(sum(parts), ((0, 0), (start, end - start))) for start, parts in by_start.items())
+
+
+def _bwd_drel(q, k, v, bias, lbias, lse, delta, do, *, scale, causal,
+              block_q, block_k, interpret,
+              dropout_rate=0.0, dropout_seed=None, hw_rng=False):
     batch, heads, q_len, d = q.shape
     kv_len = k.shape[2]
     nq, nk = q_len // block_q, kv_len // block_k
     grid = (heads, nq, nk, batch)
+    width = _diagonal_width(block_q, block_k)
 
     def q_map(h, qi, ki, b):
         return (b, h, qi, 0)
@@ -547,26 +655,30 @@ def _bwd_dlbias(q, k, v, bias, lbias, lse, delta, do, *, scale, causal,
     args = seed_args + [
         x for x in (q, k, v, bias, lbias, do, lse, delta) if x is not None
     ]
-    return pl.pallas_call(
+    sums = pl.pallas_call(
         functools.partial(
-            _bwd_dlbias_kernel, scale=scale, causal=causal,
+            _bwd_drel_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, nb=batch, has_bias=bias is not None,
             dropout_rate=dropout_rate, hw_rng=hw_rng,
         ),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, block_k), lb_map),
-        out_shape=jax.ShapeDtypeStruct(lbias.shape, lbias.dtype),
+        out_specs=pl.BlockSpec((1, 1, 1, 8, width), lambda h, qi, ki, b: (h, qi, ki, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((heads, nq, nk, 8, width), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(*args)
+    return _overlap_add(sums, q_len, kv_len, block_q, block_k, causal)
 
 
 def _bwd(q, k, v, bias, lbias, o, lse, do, *, scale, causal, block_q, block_k,
          interpret, dropout_rate=0.0, dropout_seed=None, hw_rng=False):
+    """(dq, dk, dv, drel): ``lbias`` is the relative bias as the kernels read
+    it, the (1, H, Q, K) matrix of its per-diagonal vector (or None);
+    ``drel`` is that VECTOR's gradient, fp32."""
     batch, heads, q_len, d = q.shape
     kv_len = k.shape[2]
     nq, nk = q_len // block_q, kv_len // block_k
@@ -679,15 +791,15 @@ def _bwd(q, k, v, bias, lbias, o, lse, do, *, scale, causal, block_q, block_k,
         ),
         interpret=interpret,
     )(*args)
-    dlbias = None
+    drel = None
     if lbias is not None:
-        dlbias = _bwd_dlbias(
+        drel = _bwd_drel(
             q, k, v, bias, lbias, lse, delta, do,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k,
             interpret=interpret, dropout_rate=dropout_rate,
             dropout_seed=dropout_seed, hw_rng=hw_rng,
         )
-    return dq, dk, dv, dlbias
+    return dq, dk, dv, drel
 
 
 # ------------------------------------------------------------- public API
@@ -696,18 +808,20 @@ def _bwd(q, k, v, bias, lbias, o, lse, do, *, scale, causal, block_q, block_k,
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11, 12)
 )
-def _flash(q, k, v, bias, lbias, dropout_seed,
+def _flash(q, k, v, bias, rel, dropout_seed,
            scale, causal, block_q, block_k, interpret, dropout_rate, hw_rng):
-    o, _ = _fwd(
-        q, k, v, bias, lbias, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-        dropout_rate=dropout_rate, dropout_seed=dropout_seed, hw_rng=hw_rng,
-    )
-    return o
+    return _flash_fwd(q, k, v, bias, rel, dropout_seed, scale, causal,
+                      block_q, block_k, interpret, dropout_rate, hw_rng)[0]
 
 
-def _flash_fwd(q, k, v, bias, lbias, dropout_seed,
+def _flash_fwd(q, k, v, bias, rel, dropout_seed,
                scale, causal, block_q, block_k, interpret, dropout_rate, hw_rng):
+    # the kernels read the relative bias as the matrix XLA lays out from its
+    # vector; every attention site of a stack lays out the same one from the
+    # same operand, which XLA merges into one (t5-large's compiled step
+    # holds one layout a microbatch and stack, not 24), so the residual each
+    # site keeps for its backward is that one buffer
+    lbias = None if rel is None else relative_bias_matrix(rel.astype(q.dtype), q.shape[2], k.shape[2])
     o, lse = _fwd(
         q, k, v, bias, lbias, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, interpret=interpret,
@@ -726,13 +840,13 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, dropout_rate,
     lse = jax.lax.broadcast_in_dim(
         lse_lane[..., 0], (*lse_lane.shape[:-1], LANES), (0, 1, 2)
     )
-    dq, dk, dv, dlbias = _bwd(
+    dq, dk, dv, drel = _bwd(
         q, k, v, bias, lbias, o, lse, do, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, interpret=interpret,
         dropout_rate=dropout_rate, dropout_seed=dropout_seed, hw_rng=hw_rng,
     )
     dbias = None if bias is None else jnp.zeros_like(bias)  # bias is a mask
-    return dq, dk, dv, dbias, dlbias, None  # seed: int, no cotangent
+    return dq, dk, dv, dbias, drel, None  # seed: int, no cotangent
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -749,10 +863,10 @@ MAX_BLOCK_NONCAUSAL = 1024  # v5e sweep at (16, 16, 1024, 64) fwd+bwd:
 #                  tile).  CAUSAL at head_dim 64 stays at 512: the
 #                  tile-skip guard works per-block, so 1024-tiles waste
 #                  half of each diagonal block on masked work (74.5 ms vs
-#                  71.0 at 512).  The learned-bias path caps block_q at
+#                  71.0 at 512).  The relative-bias path caps block_q at
 #                  512 but block_k at 1024 (71.1 ms vs 73.9 at 512x512):
 #                  its backward carries the (1, H, Q, K) bias tile +
-#                  dlbias accumulator on top of the plain path's scratch,
+#                  dbias accumulator on top of the plain path's scratch,
 #                  and 1024x1024 overflows the 16 MB VMEM stack (measured
 #                  18.07 MB on v5e).
 
@@ -774,8 +888,8 @@ MAX_BLOCK_WINDOW = 1024  # v5e at (1, 32, 8192, 128), window 1,024 (PR 37): tile
 def _block_caps(causal: bool, has_learned_bias: bool,
                 head_dim: int = 64, window: int = 0) -> tuple[int, int]:
     """(cap_q, cap_k) for the given attention flavor — see the constants'
-    comments for the v5e measurements behind each choice.  The learned-
-    bias cap applies even when causal: its backward's bias tile + dlbias
+    comments for the v5e measurements behind each choice.  The relative-
+    bias cap applies even when causal: its backward's bias tile + dbias
     accumulator overflow VMEM at 1024×1024 regardless of masking (and
     tiles only grow with head_dim).  The window flavour's tiles are capped
     at ``MAX_BLOCK_WINDOW`` and at the window itself."""
@@ -826,7 +940,7 @@ def flash_attention(
     v: jnp.ndarray,
     bias: jnp.ndarray | None = None,
     *,
-    learned_bias: jnp.ndarray | None = None,
+    relative_bias: jnp.ndarray | None = None,
     causal: bool = False,
     scale: float | None = None,
     block_q: int | None = None,
@@ -841,7 +955,7 @@ def flash_attention(
 
     ``block_q``/``block_k`` default to ``auto_block``: the largest
     16-aligned tile dividing each sequence length, capped per attention
-    flavor (512 causal, 512/1024 learned-bias, 1024 otherwise — see
+    flavor (512 causal, 512/1024 relative-bias, 1024 otherwise — see
     ``_block_caps``; one seq-sized tile for short sequences).  Each seq
     len must divide by its (auto-clamped) block size — the framework's
     bucketed batching guarantees this for training shapes; call
@@ -852,11 +966,15 @@ def flash_attention(
 
     - ``bias`` is treated as a CONSTANT mask: its gradient is zero.  Do not
       route a *learned* additive bias through it — that bias would silently
-      stop training.  Learned biases go through ``learned_bias``.
-    - ``learned_bias`` must be exactly (1, heads, q_len, kv_len) — T5's
-      relative-position bias shape.  It is differentiable: the backward
-      pass runs a third kernel that accumulates its gradient over the
-      batch grid axis without materializing (B, H, Q, K) in HBM.
+      stop training.  T5's learned bias goes through ``relative_bias``.
+    - ``relative_bias`` must be exactly (heads, q_len + kv_len - 1): the
+      bias of every (q, k) pair at offset ``k - q``, at index ``(k - q) +
+      q_len - 1`` (``relative_bias_matrix`` is the (1, H, Q, K) matrix it
+      stands for).  It is differentiable: the backward pass runs a third
+      kernel that accumulates the bias gradient over the batch grid axis
+      and reduces each tile along its diagonals in VMEM, so the vector's
+      cotangent comes back in fp32 and no (1, H, Q, K) gradient is
+      written, let alone a (B, H, Q, K) one.
     - ``causal=True`` requires ``q_len == kv_len``.  The mask is top-left
       aligned (q_pos >= k_pos with no kv offset), which is only meaningful
       for square self-attention; decode-style bottom-right alignment with
@@ -877,7 +995,7 @@ def flash_attention(
         )
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    cap_q, cap_k = _block_caps(causal, learned_bias is not None, q.shape[-1])
+    cap_q, cap_k = _block_caps(causal, relative_bias is not None, q.shape[-1])
     block_q = auto_block(q.shape[2], cap_q) if block_q is None else min(block_q, q.shape[2])
     block_k = auto_block(k.shape[2], cap_k) if block_k is None else min(block_k, k.shape[2])
     if (
@@ -898,13 +1016,14 @@ def flash_attention(
         ):
             if bd not in (1, full):
                 raise ValueError(f"bias dim {i} is {bd}, must be 1 or {full}")
-    if learned_bias is not None:
-        want = (1, q.shape[1], q.shape[2], k.shape[2])
-        if tuple(learned_bias.shape) != want:
+    if relative_bias is not None:
+        want = (q.shape[1], q.shape[2] + k.shape[2] - 1)
+        if tuple(relative_bias.shape) != want:
             raise ValueError(
-                f"learned_bias shape {tuple(learned_bias.shape)} must be exactly "
-                f"{want} (batch dim 1 is what the dbias kernel reduces over)"
+                f"relative_bias shape {tuple(relative_bias.shape)} must be exactly "
+                f"{want}: (heads, q_len + kv_len - 1), one entry a diagonal"
             )
+        relative_bias = relative_bias.astype(jnp.float32)  # its cotangent comes back in fp32
     if interpret is None:
         interpret = _default_interpret()
     if hw_rng is None:
@@ -918,7 +1037,7 @@ def flash_attention(
         dropout_seed = jnp.asarray(dropout_seed, jnp.int32).reshape(())
     else:
         dropout_seed = None
-    out = _flash(q, k, v, bias, learned_bias, dropout_seed,
+    out = _flash(q, k, v, bias, relative_bias, dropout_seed,
                  float(scale), bool(causal), int(block_q), int(block_k),
                  bool(interpret), dropout_rate, bool(hw_rng))
     return out if dtype is None else out.astype(dtype)
@@ -1734,7 +1853,7 @@ def flash_decode_run(
     )(*args)
 
 
-# --------------------------------------------- multi-device learned bias
+# -------------------------------------------- multi-device relative bias
 
 
 def make_flash_lbias_sharded(
@@ -1752,18 +1871,19 @@ def make_flash_lbias_sharded(
     dropout_rate: float = 0.0,
     hw_rng: bool = False,
 ):
-    """Multi-device flash attention WITH a differentiable (1, H, Q, K)
-    learned bias: per-shard Pallas kernels under ``shard_map`` (batch over
+    """Multi-device flash attention WITH a differentiable relative bias
+    (the (H, Q + K - 1) per-diagonal vector of ``flash_attention``):
+    per-shard Pallas kernels under ``shard_map`` (batch over
     ``batch_axes``, heads over ``head_axis``) and a HAND-WRITTEN vjp whose
-    backward psums the per-batch-shard dbias partials inside the manual
+    backward psums the per-batch-shard diagonal sums inside the manual
     region.  The generic ``flash_run`` path can't do this: its shard_map
     runs ``check_vma=False``, under which autodiff would silently drop the
     cross-shard reduction a replicated input's cotangent needs — here the
     reduction is explicit, so T5's relative-position bias trains correctly
     on any mesh, not just a single chip.
 
-    Returns ``f(q, k, v[, bias], lbias[, seed]) -> o``.  ``bias`` (present
-    iff ``has_bias``) is a constant (b|1, 1, 1, K) mask; ``lbias`` is
+    Returns ``f(q, k, v[, bias], rel[, seed]) -> o``.  ``bias`` (present
+    iff ``has_bias``) is a constant (b|1, 1, 1, K) mask; ``rel`` is
     heads-sharded over ``head_axis`` and replicated across the batch
     shards.  ``seed`` (present iff ``dropout_rate > 0``) is the replicated
     int32 probs-dropout seed — each shard folds its axis indices in, so
@@ -1778,7 +1898,7 @@ def make_flash_lbias_sharded(
     fold_axes = batch_axes + ((head_axis,) if head_axis else ())
 
     qkv_spec = P(batch_axes or None, head_axis, None, None)
-    lb_spec = P(None, head_axis, None, None)
+    rel_spec = P(head_axis, None)
     lse_spec = P(batch_axes or None, head_axis, None, None)
 
     def mask_spec(b):
@@ -1791,14 +1911,14 @@ def make_flash_lbias_sharded(
               interpret=interpret)
 
     def split(args):
-        """(q, k, v[, bias], lbias[, seed]) → (q, k, v, bias|None, lbias,
+        """(q, k, v[, bias], rel[, seed]) → (q, k, v, bias|None, rel,
         seed|None)."""
         args, seed = (args[:-1], args[-1]) if has_dropout else (args, None)
         if has_bias:
-            q, k, v, bias, lbias = args
+            q, k, v, bias, rel = args
         else:
-            (q, k, v, lbias), bias = args, None
-        return q, k, v, bias, lbias, seed
+            (q, k, v, rel), bias = args, None
+        return q, k, v, bias, rel, seed
 
     def drop_kw(seed):
         if seed is None:
@@ -1812,13 +1932,14 @@ def make_flash_lbias_sharded(
         return tuple(
             s for s in (
                 qkv_spec, qkv_spec, qkv_spec,
-                mask_spec(bias) if has_bias else None, lb_spec,
+                mask_spec(bias) if has_bias else None, rel_spec,
                 P() if has_dropout else None,
             ) if s is not None
         )
 
     def fwd_shard(*sargs):
-        sq, sk, sv, sbias, slb, sseed = split(sargs)
+        sq, sk, sv, sbias, srel, sseed = split(sargs)
+        slb = relative_bias_matrix(srel.astype(sq.dtype), sq.shape[2], sk.shape[2])
         o, lse = _fwd(sq, sk, sv, sbias, slb, **kw, **drop_kw(sseed))
         return o, lse[..., :1]
 
@@ -1834,30 +1955,32 @@ def make_flash_lbias_sharded(
         return run_fwd(args, bias)[0]
 
     def f_fwd(*args):
-        q, k, v, bias, lbias, seed = split(args)
+        q, k, v, bias, rel, seed = split(args)
         o, lse1 = run_fwd(args, bias)
-        return o, (q, k, v, bias, lbias, seed, o, lse1)
+        return o, (q, k, v, bias, rel, seed, o, lse1)
 
     def f_bwd(res, do):
-        q, k, v, bias, lbias, seed, o, lse1 = res
+        q, k, v, bias, rel, seed, o, lse1 = res
 
         def bwd_shard(*sargs):
             sargs, sseed = (sargs[:-1], sargs[-1]) if has_dropout else (sargs, None)
             if has_bias:
-                sq, sk, sv, sbias, slb, so, slse1, sdo = sargs
+                sq, sk, sv, sbias, srel, so, slse1, sdo = sargs
             else:
-                (sq, sk, sv, slb, so, slse1, sdo), sbias = sargs, None
+                (sq, sk, sv, srel, so, slse1, sdo), sbias = sargs, None
             lse = jax.lax.broadcast_in_dim(
                 slse1[..., 0], (*slse1.shape[:-1], LANES), (0, 1, 2)
             )
-            dq, dk, dv, dlb = _bwd(
+            slb = relative_bias_matrix(srel.astype(sq.dtype), sq.shape[2], sk.shape[2])
+            dq, dk, dv, drel = _bwd(
                 sq, sk, sv, sbias, slb, so, lse, sdo, **kw, **drop_kw(sseed)
             )
-            # each batch shard computed dbias for ITS rows only: the
+            # each batch shard summed the diagonals of ITS rows only: the
             # explicit cross-shard reduction autodiff can't insert here
+            # (a (H, Q + K - 1) vector, where it was a (1, H, Q, K) matrix)
             if batch_axes:
-                dlb = jax.lax.psum(dlb, batch_axes)
-            return dq, dk, dv, dlb
+                drel = jax.lax.psum(drel, batch_axes)
+            return dq, dk, dv, drel
 
         base = fwd_in_specs(bias)
         if has_dropout:
@@ -1866,16 +1989,16 @@ def make_flash_lbias_sharded(
             (P(),) if has_dropout else ()
         )
         args = tuple(
-            x for x in (q, k, v, bias, lbias, o, lse1, do) if x is not None
+            x for x in (q, k, v, bias, rel, o, lse1, do) if x is not None
         ) + ((seed,) if has_dropout else ())
-        dq, dk, dv, dlb = jax.shard_map(
+        dq, dk, dv, drel = jax.shard_map(
             bwd_shard, mesh=mesh, in_specs=in_specs,
-            out_specs=(qkv_spec, qkv_spec, qkv_spec, lb_spec), check_vma=False,
+            out_specs=(qkv_spec, qkv_spec, qkv_spec, rel_spec), check_vma=False,
         )(*args)
         out = (dq, dk, dv)
         if has_bias:
             out = (*out, jnp.zeros_like(bias))
-        out = (*out, dlb)
+        out = (*out, drel)
         if has_dropout:
             out = (*out, None)  # seed: int, no cotangent
         return out
@@ -1885,17 +2008,17 @@ def make_flash_lbias_sharded(
 
 
 def flash_attention_lbias_sharded(
-    q, k, v, bias, learned_bias, *, mesh,
+    q, k, v, bias, relative_bias, *, mesh,
     batch_axes: tuple[str, ...], head_axis: str | None,
     causal: bool = False, scale: float | None = None,
     block_q: int | None = None, block_k: int | None = None,
     interpret: bool | None = None, dtype=None,
     dropout_rate: float = 0.0, dropout_seed=None, hw_rng: bool | None = None,
 ):
-    """Front door for the multi-device learned-bias path (see
+    """Front door for the multi-device relative-bias path (see
     ``make_flash_lbias_sharded``).  Same shape/validation contract as
     ``flash_attention``; block sizes are the per-shard auto defaults
-    (q and the learned bias's Q dim are full-length per shard — only batch
+    (q and the bias's diagonals are full-length per shard — only batch
     and heads split).  The mask additionally must not carry a HEAD dim
     (the per-shard BlockSpec would index the wrong heads on non-first
     tensor shards); a full query dim — a (B, 1, Q, K) mask — is fine, since
@@ -1926,11 +2049,12 @@ def flash_attention_lbias_sharded(
             if bd not in (1, full):
                 raise ValueError(
                     f"bias dim {i} is {bd}, must be 1 or {full} (the head dim "
-                    "must be 1 on the sharded learned-bias path)"
+                    "must be 1 on the sharded relative-bias path)"
                 )
-    want = (1, q.shape[1], q.shape[2], k.shape[2])
-    if tuple(learned_bias.shape) != want:
-        raise ValueError(f"learned_bias shape {tuple(learned_bias.shape)} != {want}")
+    want = (q.shape[1], q.shape[2] + k.shape[2] - 1)
+    if tuple(relative_bias.shape) != want:
+        raise ValueError(f"relative_bias shape {tuple(relative_bias.shape)} != {want}")
+    relative_bias = relative_bias.astype(jnp.float32)  # its cotangent comes back in fp32
     if interpret is None:
         interpret = _default_interpret()
     if hw_rng is None:
@@ -1948,7 +2072,7 @@ def flash_attention_lbias_sharded(
         out_dtype=dtype or q.dtype,
         dropout_rate=dropout_rate, hw_rng=bool(hw_rng),
     )
-    args = (q, k, v, bias, learned_bias) if bias is not None else (q, k, v, learned_bias)
+    args = (q, k, v, bias, relative_bias) if bias is not None else (q, k, v, relative_bias)
     if dropout_rate > 0.0:
         args = (*args, jnp.asarray(dropout_seed, jnp.int32).reshape(()))
     return f(*args)

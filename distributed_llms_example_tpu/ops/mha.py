@@ -46,13 +46,14 @@ from distributed_llms_example_tpu.utils.jsonlog import log_json
 _IMPL_LOGGED: set[tuple] = set()
 
 
-def _log_impl_once(impl: str, reason: str) -> None:
+def _log_impl_once(impl: str, reason: str, **counts: int) -> None:
     """One-time JSON line saying which attention path a module selected —
-    so "flash is wired in" claims are verifiable from any run log."""
+    so "flash is wired in" claims are verifiable from any run log.
+    ``counts``: trace-time tallies the line carries as numbers."""
     key = (impl, reason)
     if key not in _IMPL_LOGGED:
         _IMPL_LOGGED.add(key)
-        log_json({"event": "attention_impl", "impl": impl, "reason": reason})
+        log_json({"event": "attention_impl", "impl": impl, "reason": reason, **counts})
 
 
 def _mesh_batch_shards(mesh: Mesh) -> int:

@@ -118,7 +118,7 @@ def _qkv(q_len, kv_len, heads=H, d=D):
 
 
 FLASH_CASES = {
-    # name: (q_len, kv_len, causal, kv-mask bias, learned bias, probs dropout)
+    # name: (q_len, kv_len, causal, kv-mask bias, relative bias, probs dropout)
     "encoder-noncausal-mask": (SRC, SRC, False, True, False, 0.0),
     "decoder-causal": (TGT, TGT, True, False, False, 0.0),
     "cross": (TGT, SRC, False, True, False, 0.0),
@@ -137,7 +137,7 @@ def test_flash_fwd_bwd_compiles(case, one_chip, compiled_kernels):
     if masked:
         shapes.append(((B, 1, 1, kv_len), F32))
     if learned:
-        shapes.append(((1, H, q_len, kv_len), F32))
+        shapes.append(((H, q_len + kv_len - 1), F32))
     if rate:
         shapes.append(((), jnp.int32))
 
@@ -147,7 +147,7 @@ def test_flash_fwd_bwd_compiles(case, one_chip, compiled_kernels):
         lbias = rest.pop() if learned else None
         bias = rest.pop() if masked else None
         out = flash_attention(
-            q, k, v, bias, learned_bias=lbias, causal=causal,
+            q, k, v, bias, relative_bias=lbias, causal=causal,
             dropout_rate=rate, dropout_seed=seed,
         )
         return out.astype(F32).sum()
@@ -156,6 +156,57 @@ def test_flash_fwd_bwd_compiles(case, one_chip, compiled_kernels):
     text = _compile(jax.grad(loss, argnums=argnums), one_chip, *shapes)
     # forward + dq + dkv kernels, plus the dbias kernel of the learned flavor
     assert text.count("tpu_custom_call") >= (4 if learned else 3)
+    if learned:
+        # the dbias kernel hands back diagonal sums (heads, q tiles, kv tiles, 8 sublanes, 2 x block_k lanes),
+        # and no kernel writes a (1, H, Q, K) gradient: the one matrix of that shape is the bias XLA lays out
+        calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
+        assert any(re.search(r"= f32\[16,2,1,8,2048\]", ln) for ln in calls), [ln[:120] for ln in calls]
+        assert not any(re.search(r"= \w+\[1,16,1024,1024\]", ln) for ln in calls)
+
+
+def test_t5_cell_microbatch_gradient_sums_its_bias_gradient_along_diagonals(topo, one_chip, compiled_kernels, monkeypatch):
+    """``t5-large.train``'s microbatch (one row, source 1,024, target 128, dropout
+    on, bfloat16 compute) lowered for the described chip, forward and backward:
+    every one of its 48 self-attention sites hands its relative bias to the flash
+    kernel as a per-diagonal vector (the trace-time tally the run's log carries),
+    no kernel writes a (1, 16, 1024, 1024) bias gradient, and the two
+    (32 buckets, 16 heads) tables get theirs from 2,047 and 255 per-diagonal
+    values, not from 1,048,576 x 16 scattered ones (145.8 ms of a 904 ms step
+    before PR 40).  Lowered, not compiled: 60 s against several minutes."""
+    from benchmarks.harness import program, spec as spec_mod
+    from distributed_llms_example_tpu.core.config import MeshConfig
+    from distributed_llms_example_tpu.core.mesh import build_mesh
+    from distributed_llms_example_tpu.models import registry, t5
+    from distributed_llms_example_tpu.parallel.activation import activation_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels' auto rules ask it
+    tallies = []
+    flush = t5.flush_relative_bias_sites
+    monkeypatch.setattr(t5, "flush_relative_bias_sites", lambda: tallies.append(flush()))
+    cfg = spec_mod.load_json(os.path.join(spec_mod.BENCH_DIR, "configs", "t5-large.json"))
+    monkeypatch.setattr(registry, "T5_CONFIGS", dict(registry.T5_CONFIGS))  # the cell's entry leaves with the test
+    lm = registry.load_model(
+        program.register_bench_model(cfg, spec_mod.load_module("adapters", cfg["family"])), dtype=BF16, load_weights=False)
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+                          jax.eval_shape(lambda: lm.init_params(0)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+
+    def loss(p, key, src, mask, dec):
+        out = lm.module.apply({"params": p}, src, mask, dec, deterministic=False, rngs={"dropout": key})
+        return out.astype(F32).sum()
+
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    with activation_mesh(build_mesh(MeshConfig(data=-1), devices=topo.devices[:1])):  # as the trainer traces its step
+        text = jax.jit(jax.grad(loss)).lower(params, key, i32(1, SRC), i32(1, SRC), i32(1, TGT)).as_text()
+    assert tallies[-1] == {"diagonal": 48, "matrix": 0}, tallies  # (the tallies before it: init's toy shapes)
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    results = [ln.rsplit(" -> ", 1)[1] for ln in kernels]
+    assert sum("tensor<16x2x1x8x2048xf32>" in r for r in results) == 24  # the encoder's sites: 2 q tiles of 512 x 1,024
+    assert sum("tensor<16x1x1x8x256xf32>" in r for r in results) == 24  # the decoder's: one tile of 128 x 128
+    assert not any("1x16x1024x1024x" in r or "1x16x128x128xbf16" in r for r in results), results
+    table_scatters = re.findall(
+        r"\}\) : \(tensor<32x16xf32>, tensor<([\dx]+)xi32>, tensor<([\dx]+)xf32>\) -> tensor<32x16xf32>", text)
+    assert sorted(set(table_scatters)) == [("2047x1", "2047x16"), ("255x1", "255x16")], table_scatters
 
 
 def test_flash_llama7b_shape_compiles(one_chip, compiled_kernels):

@@ -18,6 +18,7 @@ from distributed_llms_example_tpu.ops.attention import (
 from distributed_llms_example_tpu.ops.flash_attention import (
     flash_attention,
     flash_supported,
+    relative_bias_matrix,
 )
 
 B, H, D = 2, 3, 32
@@ -150,8 +151,8 @@ def test_auto_block_selection():
 
 def test_noncausal_block_cap():
     """Non-causal attention without a learned bias tiles up to 1024 (measured
-    faster on v5e); causal stays at 512, and learned-bias caps block_q at
-    512 (dlbias VMEM) while its block_k may reach 1024."""
+    faster on v5e); causal stays at 512, and relative-bias caps block_q at
+    512 (dbias tile VMEM) while its block_k may reach 1024."""
     from distributed_llms_example_tpu.ops.flash_attention import (
         MAX_BLOCK,
         MAX_BLOCK_NONCAUSAL,
@@ -163,8 +164,8 @@ def test_noncausal_block_cap():
     assert auto_block(2048, MAX_BLOCK_NONCAUSAL) == 1024
     assert auto_block(512, MAX_BLOCK_NONCAUSAL) == 512
     # flash_supported mirrors the per-path caps: 592 = 16*37 tiles only
-    # above 512, so it is eligible non-causal but NOT causal; learned-bias
-    # caps block_q at 512 (dlbias VMEM) while block_k may reach 1024
+    # above 512, so it is eligible non-causal but NOT causal; relative-bias
+    # caps block_q at 512 (dbias tile VMEM) while block_k may reach 1024
     assert flash_supported(592, 592, 64)
     assert not flash_supported(592, 592, 64, causal=True)
     assert not flash_supported(592, 592, 64, has_learned_bias=True)
@@ -197,21 +198,21 @@ def test_noncausal_block_cap():
 
 
 def test_lbias_asymmetric_tiles_grad_parity():
-    """The learned-bias default tiling is now ASYMMETRIC (block_q capped at
-    512, block_k at 1024) — run its backward (dq/dkv/dlbias kernels) with
+    """The relative-bias default tiling is ASYMMETRIC (block_q capped at
+    512, block_k at 1024) — run its backward (dq/dkv/dbias kernels) with
     block_k > block_q and check gradients against plain attention."""
     rng = np.random.RandomState(11)
     q = jnp.asarray(rng.randn(1, 2, 64, 32), jnp.float32)
     k = jnp.asarray(rng.randn(1, 2, 128, 32), jnp.float32)
     v = jnp.asarray(rng.randn(1, 2, 128, 32), jnp.float32)
-    lb = jnp.asarray(rng.randn(1, 2, 64, 128).astype(np.float32) * 0.1)
+    lb = jnp.asarray(rng.randn(2, 64 + 128 - 1).astype(np.float32) * 0.1)
 
     def loss_flash(q, k, v, lb):
-        out = flash_attention(q, k, v, learned_bias=lb, block_q=64, block_k=128)
+        out = flash_attention(q, k, v, relative_bias=lb, block_q=64, block_k=128)
         return jnp.sum(out ** 2)
 
     def loss_ref(q, k, v, lb):
-        return jnp.sum(dot_product_attention(q, k, v, lb) ** 2)
+        return jnp.sum(dot_product_attention(q, k, v, relative_bias_matrix(lb, 64, 128)) ** 2)
 
     got = jax.grad(loss_flash, argnums=(0, 1, 2, 3))(q, k, v, lb)
     want = jax.grad(loss_ref, argnums=(0, 1, 2, 3))(q, k, v, lb)
@@ -236,19 +237,19 @@ def test_fully_masked_rows_give_finite_zero_grads():
     sentinel scale) or they contribute garbage — potentially inf/NaN once a
     learned bias shifts s — to the batch-summed learned-bias gradient.
     Dead-example grads must be exactly zero and the live example's grads
-    (and the summed dlbias) must equal a run without the dead example."""
+    (and the summed bias gradient) must equal a run without the dead example."""
     q_len = kv_len = 64
     q, k, v = _qkv(q_len, kv_len)
     mask = np.ones((B, kv_len), np.float32)
     mask[0, :] = 0  # example 0: every key masked
     bias = jnp.where(jnp.asarray(mask)[:, None, None, :] > 0, 0.0, -jnp.inf)
     rng = np.random.RandomState(2)
-    lbias = jnp.asarray(rng.randn(1, H, q_len, kv_len).astype(np.float32) * 0.1)
+    lbias = jnp.asarray(rng.randn(H, q_len + kv_len - 1).astype(np.float32) * 0.1)
 
     def loss(q, k, v, lbias, bias):
         return jnp.sum(
             flash_attention(
-                q, k, v, bias, learned_bias=lbias, causal=True, block_q=32, block_k=32
+                q, k, v, bias, relative_bias=lbias, causal=True, block_q=32, block_k=32
             )
             ** 2
         )
@@ -256,7 +257,7 @@ def test_fully_masked_rows_give_finite_zero_grads():
     # the dead example's FORWARD output must be exact zeros (not an
     # average of v over causally-forbidden positions)
     out = flash_attention(
-        q, k, v, bias, learned_bias=lbias, causal=True, block_q=32, block_k=32
+        q, k, v, bias, relative_bias=lbias, causal=True, block_q=32, block_k=32
     )
     np.testing.assert_array_equal(np.asarray(out[0]), 0.0)
     assert np.isfinite(np.asarray(out)).all()
@@ -320,8 +321,133 @@ def test_beam_grouped_attention_matches_replicated_kv():
     ref = dot_product_attention(q, k_rep, v_rep, bias_rep)
     got = beam_grouped_attention(q, k, v, bias_rep)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-6, rtol=1e-6)
-    # unscaled + learned-bias variant (the T5 cross path)
-    lb = jnp.asarray(rng.randn(1, H, Q, K).astype(np.float32) * 0.1)
-    ref2 = dot_product_attention(q, k_rep, v_rep, bias_rep + lb, scale=1.0)
-    got2 = beam_grouped_attention(q, k, v, bias_rep, scale=1.0, learned_bias=lb)
+    # unscaled variant (the T5 cross path)
+    ref2 = dot_product_attention(q, k_rep, v_rep, bias_rep, scale=1.0)
+    got2 = beam_grouped_attention(q, k, v, bias_rep, scale=1.0)
     np.testing.assert_allclose(np.asarray(got2), np.asarray(ref2), atol=1e-6, rtol=1e-6)
+
+
+# ------------------------------------------- the relative bias, by diagonals
+
+
+@pytest.mark.parametrize("q_len,kv_len", [(1, 1), (8, 8), (16, 24), (24, 16), (128, 128), (96, 40), (7, 13)])
+def test_relative_bias_matrix_is_the_toeplitz_matrix_of_its_vector(q_len, kv_len):
+    """``out[0, h, q, k] = rel[h, (k - q) + q_len - 1]``, at lengths whose
+    strip heights factor as 8 x 16 x n and at ones that do not."""
+    rel = np.random.RandomState(q_len).randn(3, q_len + kv_len - 1).astype(np.float32)
+    want = np.stack([[rel[h, np.arange(kv_len) - i + q_len - 1] for i in range(q_len)] for h in range(3)])[None]
+    np.testing.assert_array_equal(np.asarray(relative_bias_matrix(jnp.asarray(rel), q_len, kv_len)), want)
+
+
+# name: (batch, q_len, kv_len, block_q, block_k, causal, extra)
+RELATIVE_BIAS_CASES = {
+    "square_2x2_tiles": (2, 128, 128, 64, 64, False, None),
+    "causal_2x2_tiles": (2, 128, 128, 64, 64, True, None),
+    "one_tile": (1, 64, 64, 64, 64, False, None),
+    "wide_kv_lane_tiles": (1, 64, 256, 32, 128, False, None),  # block_k a vreg's lanes: the rotates
+    "lane_tiles_4x2": (1, 256, 256, 64, 128, False, None),
+    "causal_lane_tiles": (1, 256, 256, 128, 128, True, None),
+    "tall_q_tile": (1, 256, 64, 128, 32, False, None),  # block_q > block_k: five segments
+    "batch_3": (3, 64, 64, 32, 32, False, None),
+    "mask_bias": (2, 64, 128, 32, 64, False, "mask"),
+    "dropout_seed": (2, 64, 64, 32, 32, False, "dropout"),
+    "causal_dropout_seed": (1, 128, 128, 64, 128, True, "dropout"),
+    "fully_masked_row": (2, 64, 64, 32, 32, True, "dead"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RELATIVE_BIAS_CASES))
+def test_relative_bias_gradient_is_the_matrix_gradient_summed_along_diagonals(case):
+    """dq, dk, dv and the (H, Q + K - 1) vector's gradient from the kernels
+    (the dbias tile reduced along its diagonals in VMEM, the tiles' sums
+    overlap-added outside) against ``jax.grad`` through the materialised
+    matrix and ``dot_product_attention``."""
+    from distributed_llms_example_tpu.ops.fused_dropout import hash_keep_mask
+
+    batch, q_len, kv_len, block_q, block_k, causal, extra = RELATIVE_BIAS_CASES[case]
+    heads, d, rate, seed = 2, 16, 0.15, 1234
+    rng = np.random.RandomState(len(case))
+    mk = lambda *shape: jnp.asarray(rng.randn(*shape).astype(np.float32))  # noqa: E731
+    q, k, v, w = mk(batch, heads, q_len, d), mk(batch, heads, kv_len, d), mk(batch, heads, kv_len, d), mk(batch, heads, q_len, d)
+    rel = mk(heads, q_len + kv_len - 1) * 0.3
+    bias, live = None, slice(None)
+    if extra == "mask":
+        mask = np.ones((batch, kv_len), np.int32)
+        mask[0, kv_len - 38:] = 0
+        bias = mask_to_bias(jnp.asarray(mask))
+    if extra == "dead":  # every key of example 0 masked: its rows are dead, and XLA's softmax cannot follow
+        mask = np.ones((batch, kv_len), np.float32)
+        mask[0] = 0
+        bias, live = jnp.where(jnp.asarray(mask)[:, None, None, :] > 0, 0.0, -jnp.inf), slice(1, None)
+    drop = dict(dropout_rate=rate, dropout_seed=seed) if extra == "dropout" else {}
+
+    def loss_flash(q, k, v, rel):
+        out = flash_attention(q, k, v, bias, relative_bias=rel, causal=causal, scale=1.0,
+                              block_q=block_q, block_k=block_k, interpret=True, **drop)
+        return (out * w).sum()
+
+    def loss_ref(q, k, v, rel):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) + relative_bias_matrix(rel, q_len, kv_len)
+        if bias is not None:
+            s = s + bias[live]
+        if causal:
+            s = s + make_causal_bias(q_len, kv_len)
+        p = jax.nn.softmax(s, axis=-1)
+        if extra == "dropout":
+            keep = jnp.stack([jnp.stack([hash_keep_mask(seed, (q_len, kv_len), rate, tag_a=b, tag_b=h)
+                                         for h in range(heads)]) for b in range(batch)])
+            p = jnp.where(keep, p / (1 - rate), 0.0)
+        return (jnp.einsum("bhqk,bhkd->bhqd", p, v) * w[live]).sum()
+
+    got = jax.grad(loss_flash, argnums=(0, 1, 2, 3))(q, k, v, rel)
+    want = jax.grad(loss_ref, argnums=(0, 1, 2, 3))(q[live], k[live], v[live], rel)
+    assert got[3].shape == rel.shape and got[3].dtype == jnp.float32
+    for name, g, r in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(g[live]), np.asarray(r), atol=2e-5, rtol=1e-4, err_msg=name)
+        if extra == "dead":
+            np.testing.assert_array_equal(np.asarray(g[0]), 0.0, err_msg=name)
+    np.testing.assert_allclose(np.asarray(got[3]), np.asarray(want[3]), atol=5e-5, rtol=1e-4, err_msg="drel")
+
+
+@pytest.mark.parametrize("bidirectional", [True, False], ids=["encoder", "decoder"])
+def test_table_gradient_through_the_buckets_matches_the_embedding_path(bidirectional):
+    """The (buckets, heads) table's gradient through ``relative_bias_vector``
+    (a take over Q + K - 1 offsets) and the kernels, against the path that
+    gathers the table into the (1, H, Q, K) matrix with ``nn.Embed`` over
+    all Q x K pairs and attends in XLA."""
+    import flax.linen as nn
+
+    from distributed_llms_example_tpu.models.t5 import T5Config, relative_bias_vector, relative_position_bucket
+
+    cfg = T5Config(num_heads=2, relative_attention_num_buckets=8, relative_attention_max_distance=20)
+    q_len = kv_len = 64
+    rng = np.random.RandomState(5)
+    mk = lambda *shape: jnp.asarray(rng.randn(*shape).astype(np.float32))  # noqa: E731
+    q, k, v, w = (mk(2, 2, q_len, 16) for _ in range(4))
+    table = mk(8, 2)
+    embed = nn.Embed(8, 2)
+
+    def loss_flash(table):
+        rel = relative_bias_vector(table, cfg, q_len, kv_len, bidirectional=bidirectional)
+        out = flash_attention(q, k, v, relative_bias=rel, causal=not bidirectional, scale=1.0,
+                              block_q=32, block_k=32, interpret=True)
+        return (out * w).sum()
+
+    def loss_ref(table):
+        offsets = jnp.arange(kv_len)[None, :] - jnp.arange(q_len)[:, None]
+        buckets = relative_position_bucket(offsets, bidirectional=bidirectional, num_buckets=8, max_distance=20)
+        bias = embed.apply({"params": {"embedding": table}}, buckets).transpose(2, 0, 1)[None]
+        if not bidirectional:
+            bias = bias + make_causal_bias(q_len, kv_len)
+        return (dot_product_attention(q, k, v, bias, scale=1.0) * w).sum()
+
+    got, want = jax.grad(loss_flash)(table), jax.grad(loss_ref)(table)
+    # 64 positions reach every bucket a stack can use (the encoder's "ahead by zero" does not exist)
+    assert (np.abs(np.asarray(want)).max(axis=1) > 0).sum() >= 7
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-4)
+
+
+def test_relative_bias_shape_is_checked():
+    q, k, v = _qkv(64, 64)
+    with pytest.raises(ValueError, match="relative_bias shape"):
+        flash_attention(q, k, v, relative_bias=jnp.zeros((1, H, 64, 64)), interpret=True)

@@ -83,14 +83,27 @@ def test_bart_flash_cached_generation_falls_back():
     np.testing.assert_array_equal(toks["xla"], toks["flash"])
 
 
-def test_t5_flash_matches_xla_incl_bias_table_grad():
+def test_t5_flash_matches_xla_incl_bias_table_grad(monkeypatch):
     """T5 with attention_impl='flash': the learned relative-position bias
-    rides the kernel's differentiable learned_bias input — logits AND
-    gradients (including the bias tables) must match the XLA path, and the
-    table gradients must be nonzero (a silently-constant bias was exactly
-    the round-2 failure mode this guards against)."""
+    rides the kernel's differentiable relative_bias input as a per-diagonal
+    vector — logits AND gradients (including the two bias tables', which the
+    kernels hand back as diagonal sums) must match the XLA path's, which goes
+    through the (1, H, Q, K) matrix, and the table gradients must be nonzero
+    (a silently-constant bias was exactly the round-2 failure mode this
+    guards against).  The trace-time tally says which way each site went."""
+    from distributed_llms_example_tpu.core.config import MeshConfig
+    from distributed_llms_example_tpu.core.mesh import build_mesh
+    from distributed_llms_example_tpu.models import t5
     from distributed_llms_example_tpu.models.registry import T5_CONFIGS
     from distributed_llms_example_tpu.models.t5 import T5ForConditionalGeneration
+    from distributed_llms_example_tpu.parallel.activation import activation_mesh
+
+    tallies = []
+    flush = t5.flush_relative_bias_sites
+    monkeypatch.setattr(t5, "flush_relative_bias_sites", lambda: tallies.append(flush()))
+    # a one-device mesh, as a one-chip trainer traces its step: without a mesh
+    # the eight virtual CPU devices send a forced "flash" to XLA's path
+    mesh1 = build_mesh(MeshConfig(data=-1), devices=jax.devices()[:1])
 
     cfg = dataclasses.replace(T5_CONFIGS["t5-test"], dropout_rate=0.0)
     mods = _variants(cfg, T5ForConditionalGeneration)
@@ -105,10 +118,14 @@ def test_t5_flash_matches_xla_incl_bias_table_grad():
             logits = m.apply({"params": p}, src, src_mask, tgt)
             return jnp.mean(logits.astype(jnp.float32) ** 2)
 
-        return jax.value_and_grad(f)(params)
+        with activation_mesh(mesh1):
+            return jax.value_and_grad(f)(params)
 
     (l_x, g_x), (l_f, g_f) = loss(mods["xla"]), loss(mods["flash"])
+    sites = cfg.num_layers + cfg.decoder_layers  # one self-attention a block, both stacks
+    assert tallies[-2:] == [{"diagonal": 0, "matrix": sites}, {"diagonal": sites, "matrix": 0}], tallies
     np.testing.assert_allclose(float(l_x), float(l_f), rtol=1e-5)
+    tables = 0
     paths_x = jax.tree_util.tree_flatten_with_path(g_x)[0]
     paths_f = jax.tree.leaves(g_f)
     for (path, a), b in zip(paths_x, paths_f):
@@ -117,7 +134,10 @@ def test_t5_flash_matches_xla_incl_bias_table_grad():
             np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-3, err_msg=name
         )
         if "relative_attention_bias" in name:
+            tables += 1
             assert np.abs(np.asarray(b)).sum() > 0, f"{name}: zero bias-table grad"
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6, rtol=1e-5, err_msg=name)
+    assert tables == 2  # the encoder's and the decoder's
 
 
 @pytest.mark.slow  # ~20s sharded-lbias compile: slow tier (single-device

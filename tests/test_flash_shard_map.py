@@ -12,6 +12,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from distributed_llms_example_tpu.models.llama import LlamaForCausalLM
 from distributed_llms_example_tpu.models.registry import LLAMA_CONFIGS
@@ -147,7 +148,7 @@ def test_flan_t5_xl_hot_paths_select_flash():
     must select flash on its training hot paths on a single TPU chip — the
     config VERDICT r2 flagged as 'will train entirely on XLA attention'.
     The learned relative-position bias rides the kernel's differentiable
-    learned_bias input there (T5Attention._attend)."""
+    relative_bias input there (T5Attention._attend)."""
     single = dict(use_cache=False, mesh=None, backend="tpu", device_count=1)
     # encoder self-attention: 1024×1024 scores, learned bias present
     impl, _ = select_attention_impl(
@@ -175,18 +176,22 @@ def test_flan_t5_xl_hot_paths_select_flash():
     assert impl == "xla"
 
 
-def test_lbias_sharded_matches_xla_incl_dbias(mesh8):
-    """Multi-device learned-bias flash (hand-written vjp, dbias psummed
-    across batch shards) must reproduce XLA attention values AND all
-    gradients — including the learned bias's, whose reduction over batch
-    shards is the part generic shard_map autodiff can't provide under
-    check_vma=False."""
+@pytest.mark.parametrize("causal", [False, True], ids=["noncausal", "causal"])
+def test_lbias_sharded_matches_xla_incl_dbias(mesh8, causal):
+    """Multi-device relative-bias flash (hand-written vjp, the bias's
+    diagonal sums psummed across batch shards) must reproduce XLA attention
+    values AND all gradients — including the bias vector's, whose reduction
+    over batch shards is the part generic shard_map autodiff can't provide
+    under check_vma=False — and the single-device kernels' gradient of the
+    same vector, which it is the same sum of."""
     from distributed_llms_example_tpu.ops.attention import (
         dot_product_attention,
         make_causal_bias,
     )
     from distributed_llms_example_tpu.ops.flash_attention import (
+        flash_attention,
         flash_attention_lbias_sharded,
+        relative_bias_matrix,
     )
 
     rs = np.random.RandomState(3)
@@ -194,29 +199,35 @@ def test_lbias_sharded_matches_xla_incl_dbias(mesh8):
     q = jnp.asarray(rs.randn(B, H, S, D).astype(np.float32))
     k = jnp.asarray(rs.randn(B, H, S, D).astype(np.float32))
     v = jnp.asarray(rs.randn(B, H, S, D).astype(np.float32))
-    lb = jnp.asarray(rs.randn(1, H, S, S).astype(np.float32) * 0.5)
+    lb = jnp.asarray(rs.randn(H, 2 * S - 1).astype(np.float32) * 0.5)
     mask = np.zeros((B, 1, 1, S), np.float32)
     mask[:, :, :, -16:] = -1e9
     mask = jnp.asarray(mask)
 
-    for causal in (False, True):
-        def f_sharded(q, k, v, lb):
-            out = flash_attention_lbias_sharded(
-                q, k, v, mask, lb, mesh=mesh8,
-                batch_axes=("data", "fsdp"), head_axis="tensor",
-                causal=causal, scale=1.0,
-            )
-            return jnp.sum(out ** 2)
+    def f_sharded(q, k, v, lb):
+        out = flash_attention_lbias_sharded(
+            q, k, v, mask, lb, mesh=mesh8,
+            batch_axes=("data", "fsdp"), head_axis="tensor",
+            causal=causal, scale=1.0,
+        )
+        return jnp.sum(out ** 2)
 
-        def f_ref(q, k, v, lb):
-            bias = mask + lb + (make_causal_bias(S, S) if causal else 0.0)
-            return jnp.sum(dot_product_attention(q, k, v, bias, scale=1.0) ** 2)
+    def f_single(q, k, v, lb):
+        return jnp.sum(flash_attention(q, k, v, mask, relative_bias=lb, causal=causal, scale=1.0) ** 2)
 
-        va, ga = jax.value_and_grad(f_sharded, argnums=(0, 1, 2, 3))(q, k, v, lb)
-        vb, gb = jax.value_and_grad(f_ref, argnums=(0, 1, 2, 3))(q, k, v, lb)
-        np.testing.assert_allclose(float(va), float(vb), rtol=1e-4)
-        for name, a, b in zip("dq dk dv dlbias".split(), ga, gb):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), atol=2e-3, rtol=2e-3,
-                err_msg=f"causal={causal} {name}",
-            )
+    def f_ref(q, k, v, lb):
+        bias = mask + relative_bias_matrix(lb, S, S) + (make_causal_bias(S, S) if causal else 0.0)
+        return jnp.sum(dot_product_attention(q, k, v, bias, scale=1.0) ** 2)
+
+    va, ga = jax.value_and_grad(f_sharded, argnums=(0, 1, 2, 3))(q, k, v, lb)
+    vb, gb = jax.value_and_grad(f_ref, argnums=(0, 1, 2, 3))(q, k, v, lb)
+    np.testing.assert_allclose(float(va), float(vb), rtol=1e-4)
+    assert ga[3].shape == (H, 2 * S - 1)
+    for name, a, b in zip("dq dk dv drel".split(), ga, gb):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=2e-3, rtol=2e-3,
+            err_msg=f"causal={causal} {name}",
+        )
+    # eight rows on one device, or one row a (data x fsdp) shard psummed: the same diagonals
+    np.testing.assert_allclose(np.asarray(ga[3]), np.asarray(jax.grad(f_single, argnums=3)(q, k, v, lb)),
+                               atol=1e-4, rtol=1e-4)

@@ -398,20 +398,23 @@ def test_flash_probs_dropout_cross_attention():
 
 
 def test_flash_probs_dropout_learned_bias_grad():
-    """T5's differentiable relative-position bias: the dlbias kernel also
-    recomputes the mask (batch-innermost grid)."""
+    """T5's differentiable relative-position bias: the dbias kernel also
+    recomputes the mask (batch-innermost grid) before it sums the tile
+    along its diagonals."""
+    from distributed_llms_example_tpu.ops.flash_attention import relative_bias_matrix
+
     q, k, v = _qkv()
     B, H, S, _ = q.shape
-    lb = jax.random.normal(jax.random.PRNGKey(4), (1, H, S, S)) * 0.1
+    lb = jax.random.normal(jax.random.PRNGKey(4), (H, 2 * S - 1)) * 0.1
     w = jax.random.normal(jax.random.PRNGKey(9), q.shape)
 
     def f(lb):
         return (flash_attention(
-            q, k, v, learned_bias=lb, scale=1.0, dropout_rate=0.15,
+            q, k, v, relative_bias=lb, scale=1.0, dropout_rate=0.15,
             dropout_seed=SEED, interpret=True) * w).sum()
 
     def f_ref(lb):
-        return (_ref_attn(q, k, v, 0.15, scale=1.0, lbias=lb) * w).sum()
+        return (_ref_attn(q, k, v, 0.15, scale=1.0, lbias=relative_bias_matrix(lb, S, S)) * w).sum()
 
     np.testing.assert_allclose(
         np.asarray(jax.grad(f)(lb)), np.asarray(jax.grad(f_ref)(lb)), atol=2e-4
@@ -449,7 +452,7 @@ def test_flash_probs_keep_rate():
 
 @pytest.mark.slow  # ~80s: grads through the sharded lbias kernel's
 #                  hand-written vjp (8 interpret shards × 4 kernels); the
-#                  dlbias+dropout math itself is covered fast by
+#                  dbias+dropout math itself is covered fast by
 #                  test_flash_probs_dropout_learned_bias_grad
 def test_t5_attn_dropout_routes_through_kernel(dp_mesh):
     """A T5 config with attn_dropout_rate > 0 under a mesh (forced flash →
@@ -480,7 +483,7 @@ def test_t5_attn_dropout_routes_through_kernel(dp_mesh):
     assert (a == b).all()
     c = run(jax.random.PRNGKey(2))
     assert (a != c).any()
-    # gradients flow through the in-kernel mask (incl. the dlbias kernel
+    # gradients flow through the in-kernel mask (incl. the dbias kernel
     # and its cross-shard psum)
     g = jax.grad(lambda p: run(jax.random.PRNGKey(1), p).sum())(params)
     assert all(bool(jnp.isfinite(x).all()) for x in jax.tree.leaves(g))

@@ -528,7 +528,10 @@ def test_grad_accum_carry_sharded_and_optimizer_outside_scan(setup):
 # prints the one it got).
 UNCACHED_FORWARD_STABLEHLO = {
     "bart-test": "5e92d8ee0010ac50",
-    "t5-test": "e8058bedc3fd5580",
+    # re-read in PR 40: T5's uncached forward lays its relative bias out from a
+    # (heads, 2 q - 1) per-diagonal vector (e8058bedc3fd5580 before); the other
+    # three families' programs are the parent's
+    "t5-test": "4a1feb71a05810f0",
     "llama-test": "eb36f48211af733e",
     "lfm2-moe-test": "af0a81de6fffcc8e",
 }
@@ -554,3 +557,55 @@ def test_uncached_forward_lowers_to_the_same_program(name):
         text = jax.jit(jax.grad(loss)).lower(a_params, key, *args).as_text()
     got = hashlib.sha256(re.sub(r"loc\(.*?\)", "", text).encode()).hexdigest()[:16]
     assert got == UNCACHED_FORWARD_STABLEHLO[name], f"{name}: {got} ({len(text)} chars)"
+
+
+# sha256 (16 hex) of the StableHLO of each serving family's one-row prefill wave
+# and decode step at toy sizes (4 slots, prompt 16, 8 new tokens), matmul
+# precision pinned, locations stripped — read at c3364de, the parent of PR 40,
+# which changed the flash backward and ``models/t5.py`` and promised the serve
+# cells the parent's programs (a refused PR 38 had been judged on a serve
+# cell's ``setup_s``).  A change that means to move a serve program replaces
+# its pair with its own reading (`python -m pytest -k serve_programs` prints it).
+SERVE_PROGRAM_STABLEHLO = {
+    "bart-test": ("338704be9d80f7bf", "11e45487d2fb1744"),
+    "lfm2-moe-test": ("528fb781646eb336", "bb94970b379ada29"),
+    "brumby-test": ("749984b88dc6fc07", "8ce8b68afc9ea8f6"),
+    "mellum-test": ("7f4fd4c1ec83912a", "6c0bec6e7e781b29"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SERVE_PROGRAM_STABLEHLO))
+def test_serve_programs_lower_to_the_same_programs(name):
+    import hashlib
+    import re
+
+    from distributed_llms_example_tpu.core.config import MeshConfig
+    from distributed_llms_example_tpu.core.mesh import build_mesh
+    from distributed_llms_example_tpu.evaluation.generation import _init_cache
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine
+
+    slots_n, prompt, new = 4, 16, 8
+    lm = load_model(name, load_weights=False)
+    serve = ServeConfig(max_slots=slots_n, prefill_batch=2, max_new_tokens=new, max_source_length=prompt)
+    eng = ServingEngine(lm.module, lm.config, build_mesh(MeshConfig(data=-1), devices=jax.devices()[:1]), serve,
+                        is_seq2seq=lm.is_seq2seq)
+    params = jax.eval_shape(lambda: lm.init_params(0))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    active = jax.ShapeDtypeStruct((slots_n,), jnp.bool_)
+    ids = i32(1, prompt)
+    with jax.default_matmul_precision("highest"):
+        prefill = eng._prefill.lower(params, ids, ids).as_text()
+        if lm.is_seq2seq:
+            enc, mask, ckv = jax.tree.map(  # a wave's rows -> the slots
+                lambda x: jax.ShapeDtypeStruct((slots_n, *x.shape[1:]), x.dtype), eng._prefill.eval_shape(params, ids, ids))
+            cache = jax.eval_shape(lambda p: _init_cache(
+                eng.model, p, slots_n, new, jnp.zeros(enc.shape, enc.dtype), jnp.zeros(mask.shape, mask.dtype)), params)
+            state = {"cache": cache, "enc": enc, "enc_mask": mask, "ckv": ckv, "last": i32(slots_n, 1)}
+            step = eng._step.lower(params, state, i32(slots_n), active).as_text()
+        else:
+            zeros = jnp.zeros((slots_n, prompt), jnp.int32)
+            cache, mask, _, _ = jax.eval_shape(lambda p: eng._prefill_core(p, zeros, zeros), params)
+            state = {"cache": cache, "mask": mask, "last": i32(slots_n)}
+            step = eng._step.lower(params, state, i32(slots_n), i32(slots_n), active).as_text()
+    got = tuple(hashlib.sha256(re.sub(r"loc\(.*?\)", "", t).encode()).hexdigest()[:16] for t in (prefill, step))
+    assert got == SERVE_PROGRAM_STABLEHLO[name], f"{name}: (prefill, decode step) = {got}"

@@ -22,6 +22,7 @@ recorder is built without a factory).
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
 from typing import Callable, Sequence
 
@@ -30,6 +31,17 @@ from typing import Callable, Sequence
 # max means some host stalled (GC, page cache, a slow storage read) — the
 # local precursor of the cross-host skew the heartbeat watches for.
 STRAGGLER_FACTOR = 2.0
+
+
+_thread = threading.local()  # .recorders: those with a span open on this thread, outermost first
+
+
+def open_spans() -> list[str]:
+    """This thread's open spans as ``<scope>/<name>``, outermost first, of
+    every recorder: what a listener that fires inside one is charged to
+    (``obs/setup.py``: a compilation's phase, a late compilation's span).
+    Put together when asked (a compilation asks); a span pays nothing for it."""
+    return [f"{r.scope}/{name}" for r in getattr(_thread, "recorders", ()) for name in r._open]
 
 
 def percentiles(values: Sequence[float], qs: Sequence[float]) -> list[float]:
@@ -82,6 +94,7 @@ class SpanRecorder:
         straggler_factor: float = STRAGGLER_FACTOR,
         scope: str = "train",
         annotate: Callable | None = None,
+        totals: bool = False,
     ):
         self.ring_size = int(ring_size)
         self.clock = clock
@@ -92,7 +105,12 @@ class SpanRecorder:
         self._annotate = annotate
         self.straggler_factor = float(straggler_factor)
         self._ring: list[float] = []  # per-step wall seconds, newest last
-        self._depth = 0
+        self._open: list[str] = []  # this recorder's open spans, outermost first
+        # path ("outer/inner") -> seconds of every span closed since the
+        # recorder was built: never reset, needs no step_complete.  Kept
+        # where asked for (the set-up account's recorder): a round's or a
+        # step's spans pay nothing for it
+        self._totals: dict[str, float] | None = {} if totals else None
         self._window_spans: dict[str, list[float]] = {}  # name → [total_s, count, max_s]
         self._window_steps = 0
         self._window_t0 = clock()
@@ -109,13 +127,25 @@ class SpanRecorder:
     @contextlib.contextmanager
     def span(self, name: str):
         with self._annotate(f"{self.scope}/{name}") as annotation:
-            self._depth += 1
+            outermost = not self._open
+            if outermost:  # open_spans() finds this recorder's open spans through the thread's list
+                try:
+                    recorders = _thread.recorders
+                except AttributeError:
+                    recorders = _thread.recorders = []
+                recorders.append(self)
+            self._open.append(name)
             sp = Span(self.clock(), annotation)
             try:
                 yield sp
             finally:
                 dt = sp.dur = self.clock() - sp.t0
-                self._depth -= 1
+                if self._totals is not None:
+                    path = "/".join(self._open)
+                    self._totals[path] = self._totals.get(path, 0.0) + dt
+                self._open.pop()
+                if outermost:
+                    recorders.pop()
                 agg = self._window_spans.get(name)
                 if agg is None:
                     self._window_spans[name] = [dt, 1, dt]
@@ -124,7 +154,7 @@ class SpanRecorder:
                     agg[1] += 1
                     if dt > agg[2]:
                         agg[2] = dt
-                if self._depth == 0:
+                if outermost:
                     self._step_spans[name] = self._step_spans.get(name, 0.0) + dt
 
     def step_complete(self) -> None:
@@ -156,6 +186,12 @@ class SpanRecorder:
         self._step_spans = {}
 
     # -- reporting -------------------------------------------------------
+
+    def totals(self) -> dict[str, float]:
+        """Seconds by path (``outer/inner``: a span under the spans of this
+        recorder that were open around it) of every span closed so far, of
+        a recorder built with ``totals=True``."""
+        return dict(self._totals or {})
 
     def window_step_times(self) -> list[float]:
         if self._window_steps == 0:

@@ -75,6 +75,7 @@ from distributed_llms_example_tpu.parallel.activation import (
     constrain_cache,
     kv_cache_context,
 )
+from distributed_llms_example_tpu.obs import setup
 from distributed_llms_example_tpu.obs.spans import SpanRecorder, percentiles
 from distributed_llms_example_tpu.parallel.sharding import (
     CACHE_LENGTH_AXIS,
@@ -326,6 +327,7 @@ class ServingEngine:
     adapter: encoder+cross-KV slots (BART/T5) or prompt-cache slots
     (LLaMA-family)."""
 
+    @setup.phase("engine_init", awaits="serve")
     def __init__(self, model: Any, config: Any, mesh: Any,
                  serve: ServeConfig | None = None, *, is_seq2seq: bool = True):
         self.model, self.config, self.mesh = model, config, mesh
@@ -495,37 +497,40 @@ class ServingEngine:
         # wave holds one request, and a wave of prefill_batch rows would
         # compute rows nobody sent while every live slot waits for it
         self.wave_sizes = tuple(sorted({batch_shards, self.prefill_batch}))
-        # per-program Python trace counts: a retrace IS a recompile, so the
-        # zero-recompile contract (AOT-warmed buckets, fixed-shape churn)
-        # is pinnable by comparing these before/after serving traffic
+        # per-program Python trace counts, a test's contract: equal before and
+        # after traffic means no program was traced again (AOT-warmed buckets,
+        # fixed-shape churn).  What a trace, its lowering and its compile or
+        # cache load COST, and whether the cache held, is the set-up account's
+        # (obs/setup.py: seconds by program; ``late_compile`` after ready)
         self.trace_counts: dict[str, int] = {}
         self._warmed = False
         self._slot_cache = None  # _slot_cache_shapes' memo
-        if self.spec and self.serve.spec_draft_model:
-            from distributed_llms_example_tpu.models.registry import load_model
+        with setup.span("engine_build"):  # the drafter if any, and the programs (jit wrappers: nothing traces yet)
+            if self.spec and self.serve.spec_draft_model:
+                from distributed_llms_example_tpu.models.registry import load_model
 
-            dm = load_model(self.serve.spec_draft_model)
-            if dm.is_seq2seq:
-                raise ValueError(
-                    f"spec_draft_model={self.serve.spec_draft_model!r} is "
-                    "seq2seq — the draft model proposes causal decode "
-                    "tokens, so it must be a causal family"
+                dm = load_model(self.serve.spec_draft_model)
+                if dm.is_seq2seq:
+                    raise ValueError(
+                        f"spec_draft_model={self.serve.spec_draft_model!r} is "
+                        "seq2seq — the draft model proposes causal decode "
+                        "tokens, so it must be a causal family"
+                    )
+                if dm.config.vocab_size != config.vocab_size:
+                    raise ValueError(
+                        f"spec_draft_model={self.serve.spec_draft_model!r} "
+                        f"vocab {dm.config.vocab_size} != target vocab "
+                        f"{config.vocab_size} — draft proposals are token ids "
+                        "compared against the target argmax, so the vocabs "
+                        "must be the same id space"
+                    )
+                self.drafter = spec_decode.DraftRunner(
+                    dm, slots=self.S, src_width=self.W, max_new=self.L,
+                    buckets=self.buckets, wave_sizes=self.wave_sizes,
+                    k=self.spec, pad=self.pad,
+                    kv_cache_dtype=self.serve.kv_cache_dtype, wrap=self._wrap,
                 )
-            if dm.config.vocab_size != config.vocab_size:
-                raise ValueError(
-                    f"spec_draft_model={self.serve.spec_draft_model!r} "
-                    f"vocab {dm.config.vocab_size} != target vocab "
-                    f"{config.vocab_size} — draft proposals are token ids "
-                    "compared against the target argmax, so the vocabs "
-                    "must be the same id space"
-                )
-            self.drafter = spec_decode.DraftRunner(
-                dm, slots=self.S, src_width=self.W, max_new=self.L,
-                buckets=self.buckets, wave_sizes=self.wave_sizes,
-                k=self.spec, pad=self.pad,
-                kv_cache_dtype=self.serve.kv_cache_dtype, wrap=self._wrap,
-            )
-        self._build_programs()
+            self._build_programs()
         self.last_stats: ServeStats | None = None
 
     # ------------------------------------------------------------ programs
@@ -907,36 +912,53 @@ class ServingEngine:
         resident = sum(cache_pool.tree_bytes(state[k]) for k in keys if k in state)
         return resident, 0
 
+    @setup.phase("warm")
     def warm(self, params, state) -> Any:
         """AOT-warm every compiled program before the first real request:
         one prefill+admit trace per bucket and wave size (zeros, all writes
         dropped via out-of-range slot indices) and one all-slots-idle decode
         step — so no request ever pays a compile, and the trace counts are
         pinned BEFORE traffic (``trace_counts``).  Returns the (possibly
-        donated-and-rebound) state."""
+        donated-and-rebound) state.
+
+        Each program call is a child span of ``setup/warm`` (obs/setup.py):
+        ``warm_prefill`` and ``warm_admit`` (counters ``rows``, ``bucket``; the
+        prefix path's warm admission is a ``warm_admit`` too), ``warm_decode_step``
+        and, with speculative decode, ``warm_verify``; the session opens
+        ``warm_draft`` for the draft model.  A call returns once its program is
+        traced, lowered and compiled or loaded: no span waits on the device."""
         if self._warmed:
             return state
+
+        def call(span, program, *args, **counters):
+            with setup.span(span) as sp:
+                if counters:
+                    sp.set(**counters)
+                return program(*args)
+
         S = self.S
         width_full = self.W + self.L
         for rows, bucket in itertools.product(self.wave_sizes, self.buckets):
+            at = {"rows": rows, "bucket": bucket}
             park = jnp.full((rows,), S, jnp.int32)  # out of range: every write drops
             ids = jnp.zeros((rows, bucket), jnp.int32)
-            pre = self._prefill(params, ids, ids)
+            pre = call("warm_prefill", self._prefill, params, ids, ids, **at)
             if self.is_seq2seq:
                 enc, pmask, ckv = pre
-                state = self._admit(state, enc, pmask, ckv, park)
+                state = call("warm_admit", self._admit, state, enc, pmask, ckv, park, **at)
             elif self.paged:
                 cache, full_mask, _, first = pre
                 ntc = (bucket + self.L) // self.block_size
                 sentinel = jnp.full((rows * ntc,), self.pool.num_blocks, jnp.int32)
-                state = self._admit(state, cache, full_mask, first, park, sentinel)
+                state = call("warm_admit", self._admit, state, cache, full_mask, first, park, sentinel, **at)
             else:
                 cache, full_mask, _, first = pre
-                state = self._admit(state, cache, full_mask, first, park)
+                state = call("warm_admit", self._admit, state, cache, full_mask, first, park, **at)
             if self.paged and self.prefix:
                 # the warm admission of a tail of this width, all writes dropped
                 # (park slots, sentinel block tables, out-of-range starts)
-                _, state = self._warm_admit(
+                _, state = call(
+                    "warm_admit", self._warm_admit,
                     params, state,
                     ids,
                     jnp.zeros((rows, width_full), jnp.int32),
@@ -945,16 +967,17 @@ class ServingEngine:
                     park,
                     jnp.full((rows, self.n_tiles), self.pool.num_blocks, jnp.int32),
                     jnp.full((rows * self.n_tiles,), self.pool.num_blocks, jnp.int32),
+                    **at,
                 )
         idle = jnp.zeros((S,), bool)
         pos = jnp.zeros((S,), jnp.int32)
         if self.is_seq2seq:
-            _, state = self._step(params, state, pos, idle)
+            _, state = call("warm_decode_step", self._step, params, state, pos, idle)
         elif self.paged:
             bt = jnp.full((S, self.n_tiles), self.pool.num_blocks, jnp.int32)
-            _, state = self._step(params, state, bt, pos, pos, idle)
+            _, state = call("warm_decode_step", self._step, params, state, bt, pos, pos, idle)
         else:
-            _, state = self._step(params, state, pos, pos, idle)
+            _, state = call("warm_decode_step", self._step, params, state, pos, pos, idle)
         if self.spec:
             # one all-idle verify round: the spec program joins the
             # zero-recompile contract alongside the plain step
@@ -964,17 +987,14 @@ class ServingEngine:
                 sbt = jnp.full(
                     (S, self.n_tiles), self.pool.num_blocks, jnp.int32
                 )
-                _, _, state = self._verify(
-                    params, state, x0, sbt, pos, pos, idle, room0
-                )
+                _, _, state = call("warm_verify", self._verify, params, state, x0, sbt, pos, pos, idle, room0)
             else:
-                _, _, state = self._verify(
-                    params, state, x0, pos, pos, idle, room0
-                )
+                _, _, state = call("warm_verify", self._verify, params, state, x0, pos, pos, idle, room0)
         self._warmed = True
         return state
 
     # ---------------------------------------------------------------- loop
+    @setup.phase("session_open", ready="serve")
     def open(self, params: Any, *, replica: int | None = None,
              spans: SpanRecorder | None = None) -> "ServeSession":
         """Open a stepwise serving session over this engine: ``submit``
@@ -994,9 +1014,10 @@ class ServingEngine:
         stated = getattr(self.config, "param_dtype", None)
         if stated is not None:
             dtype = jnp.dtype(stated)
-            params = jax.jit(lambda p: jax.tree.map(
-                lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x, p
-            ))(params)
+            with setup.span("weights_resident"):
+                params = jax.jit(lambda p: jax.tree.map(
+                    lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x, p
+                ))(params)
         return ServeSession(self, params, replica=replica, spans=spans)
 
     def generate(
@@ -1112,7 +1133,8 @@ class ServeSession:
             if eng.paged
             else None
         )
-        self.state = eng._init_state(params)
+        with setup.span("init_cache"):  # abstract traces of model.init, zeros, their placement
+            self.state = eng._init_state(params)
         self.state = eng.warm(params, self.state)
         self.t_open = self.spans.clock()
         self.stats.cache_bytes_resident, self._per_block = (
@@ -1173,8 +1195,9 @@ class ServeSession:
         self._spec_fed: list[list[int] | None] = [None] * S
         self.draft_state = None
         if eng.drafter is not None:
-            self.draft_state = eng.drafter.init_state()
-            self.draft_state = eng.drafter.warm(self.draft_state)
+            with setup.span("warm_draft"):
+                self.draft_state = eng.drafter.init_state()
+                self.draft_state = eng.drafter.warm(self.draft_state)
         self._win_spec_steps, self._win_spec_emitted = 0, 0
         # prefill waves by the rows their programs computed (the summary's)
         self._waves_by_rows: dict[int, int] = {}
@@ -2170,6 +2193,9 @@ class ServeSession:
             )
         if self.replica is not None:
             summary["replica"] = int(self.replica)
+        # programs traced, lowered, compiled or loaded after set-up was over,
+        # process-wide (obs/setup.py: each a ``late_compile`` event with its name)
+        summary["late_compiles"] = setup.late_compiles()
         # the shared bucketed account (params + kv_cache over the one
         # scheme) with its fit verdict — the capacity gauges' bytes,
         # re-pointed through obs/memprof.py

@@ -28,6 +28,7 @@ accumulation everywhere, deterministic multi-host data sharding.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import time
@@ -58,6 +59,7 @@ from distributed_llms_example_tpu.io.checkpoint import (
 )
 from distributed_llms_example_tpu.io.valohai_meta import save_valohai_metadata
 from distributed_llms_example_tpu.models.registry import load_model
+from distributed_llms_example_tpu.obs import setup
 from distributed_llms_example_tpu.parallel.sharding import shard_params
 from distributed_llms_example_tpu.train.optim import make_optimizer_bundle
 from distributed_llms_example_tpu.train.step import (
@@ -70,7 +72,11 @@ from distributed_llms_example_tpu.utils.backoff import sleep_backoff
 from distributed_llms_example_tpu.utils.jsonlog import MetricLogger, log_json
 
 
+_NOT_FIRST = contextlib.nullcontext()  # what wraps every call of the step program but the first
+
+
 class Trainer:
+    @setup.phase("trainer_init", awaits="train")
     def __init__(
         self,
         cfg: TrainConfig,
@@ -88,7 +94,6 @@ class Trainer:
         self.mesh = mesh if mesh is not None else build_mesh(cfg.mesh)
         log_json({"event": "device_report", **device_report()})
 
-        self.tokenizer = get_tokenizer(cfg.tokenizer, cfg.model_ckpt)
         compute_dtype = parse_dtype(cfg.compute_dtype)
         self.loaded = load_model(
             cfg.model_ckpt, dtype=compute_dtype, remat=cfg.remat, remat_policy=cfg.remat_policy,
@@ -98,49 +103,51 @@ class Trainer:
         )
         self.model, self.config = self.loaded.module, self.loaded.config
 
-        if self.loaded.is_seq2seq:
-            mk_ds = lambda recs: SummarizationDataset(  # noqa: E731
-                recs,
-                self.tokenizer,
-                max_source_length=cfg.max_source_length,
-                max_target_length=cfg.max_target_length,
-                source_column=cfg.source_column,
-                target_column=cfg.target_column,
-            )
-        else:
-            # decoder-only: prompt+target concatenated, loss masked on prompt
-            mk_ds = lambda recs: CausalLMDataset(  # noqa: E731
-                recs,
-                self.tokenizer,
-                max_length=cfg.max_source_length,
-                max_target_length=cfg.max_target_length,
-                source_column=cfg.source_column,
-                target_column=cfg.target_column,
-            )
-        self.train_ds = mk_ds(train_records)
-        self.val_ds = mk_ds(val_records) if val_records else None
+        with setup.span("data_open"):  # dataset, tokenizer, the batch plan
+            self.tokenizer = get_tokenizer(cfg.tokenizer, cfg.model_ckpt)
+            if self.loaded.is_seq2seq:
+                mk_ds = lambda recs: SummarizationDataset(  # noqa: E731
+                    recs,
+                    self.tokenizer,
+                    max_source_length=cfg.max_source_length,
+                    max_target_length=cfg.max_target_length,
+                    source_column=cfg.source_column,
+                    target_column=cfg.target_column,
+                )
+            else:
+                # decoder-only: prompt+target concatenated, loss masked on prompt
+                mk_ds = lambda recs: CausalLMDataset(  # noqa: E731
+                    recs,
+                    self.tokenizer,
+                    max_length=cfg.max_source_length,
+                    max_target_length=cfg.max_target_length,
+                    source_column=cfg.source_column,
+                    target_column=cfg.target_column,
+                )
+            self.train_ds = mk_ds(train_records)
+            self.val_ds = mk_ds(val_records) if val_records else None
 
-        # For causal LM, input and labels share one width: cap both at
-        # max_source_length so the bucket widths agree.
-        tgt_cap = cfg.max_target_length if self.loaded.is_seq2seq else cfg.max_source_length
-        self._tgt_cap = tgt_cap  # the topology-change rebuild re-derives the plan
-        self.batches = BatchIterator(
-            self.train_ds,
-            global_batch=cfg.batch_size,
-            process_count=jax.process_count(),
-            process_index=jax.process_index(),
-            seed=cfg.shuffle_seed,
-            bucket_multiple=cfg.pad_to_multiple,
-            max_source_length=cfg.max_source_length,
-            max_target_length=tgt_cap,
-        )
-        steps_per_epoch = self.batches.steps_per_epoch()
-        if steps_per_epoch == 0:
-            raise ValueError(
-                f"dataset of {len(self.train_ds)} examples is smaller than one "
-                f"global batch ({cfg.batch_size})"
+            # For causal LM, input and labels share one width: cap both at
+            # max_source_length so the bucket widths agree.
+            tgt_cap = cfg.max_target_length if self.loaded.is_seq2seq else cfg.max_source_length
+            self._tgt_cap = tgt_cap  # the topology-change rebuild re-derives the plan
+            self.batches = BatchIterator(
+                self.train_ds,
+                global_batch=cfg.batch_size,
+                process_count=jax.process_count(),
+                process_index=jax.process_index(),
+                seed=cfg.shuffle_seed,
+                bucket_multiple=cfg.pad_to_multiple,
+                max_source_length=cfg.max_source_length,
+                max_target_length=tgt_cap,
             )
-        self.total_steps = steps_per_epoch * cfg.num_epochs
+            steps_per_epoch = self.batches.steps_per_epoch()
+            if steps_per_epoch == 0:
+                raise ValueError(
+                    f"dataset of {len(self.train_ds)} examples is smaller than one "
+                    f"global batch ({cfg.batch_size})"
+                )
+            self.total_steps = steps_per_epoch * cfg.num_epochs
 
         self.tx, self.schedule, self.optim_spec = make_optimizer_bundle(
             learning_rate=cfg.learning_rate,
@@ -152,7 +159,10 @@ class Trainer:
 
         params = self.loaded.params
         if params is None:
-            params = jax.device_get(self.loaded.init_params(cfg.shuffle_seed))
+            with setup.span("model_init"):  # flax's init: its trace, its compile or load, its run
+                params = self.loaded.init_params(cfg.shuffle_seed)
+            with setup.span("params_to_host"):
+                params = jax.device_get(params)
 
         # Pipeline parallelism: stage>1 swaps in the family's GPipe adapter
         # — blocks stacked (leading layer dim sharded over ``stage``),
@@ -221,7 +231,8 @@ class Trainer:
                 "schedule": getattr(self.model, "pipeline_schedule", "gpipe"),
             })
 
-        params = shard_params(params, self.mesh, self._rules)
+        with setup.span("shard_params"):
+            params = shard_params(params, self.mesh, self._rules)
         # gradient-collective compression (--grad-compression int8,
         # ops/quant_collectives.py): per-worker partial grads tiled over
         # the replica axes, s8 wire, error-feedback tree in TrainState —
@@ -264,8 +275,9 @@ class Trainer:
                 "workers": self._grad_workers,
                 "worker_axes": list(GRAD_WORKER_AXES),
             })
-        self.state = create_train_state(params, self.tx)
-        self.state_sh = state_shardings(self.state, self.mesh, self._rules)
+        with setup.span("optimizer_init"):  # the moments beside the parameters, as laid out
+            self.state = create_train_state(params, self.tx)
+            self.state_sh = state_shardings(self.state, self.mesh, self._rules)
         if cfg.grad_compression == "int8":
             # EF allocated DIRECTLY into the tiled layout (sharded at
             # birth): a default-device zeros tree before the device_put
@@ -277,7 +289,8 @@ class Trainer:
             self.state, self.state_sh = attach_error_feedback(
                 self.state, self.state_sh, self.mesh, self._grad_workers,
             )
-        self.state = jax.tree.map(lambda x, s: jax.device_put(x, s), self.state, self.state_sh)
+        with setup.span("state_to_device"):
+            self.state = jax.tree.map(lambda x, s: jax.device_put(x, s), self.state, self.state_sh)
 
         # Sequence (context) parallelism needs every bucket width divisible
         # by the axis: widths are multiples of pad_to_multiple capped at the
@@ -402,7 +415,8 @@ class Trainer:
         from distributed_llms_example_tpu.obs.health import health_enabled
 
         self.health_on = health_enabled(cfg)
-        self._build_train_step()
+        with setup.span("build_step"):
+            self._build_train_step()
         # deterministic fault injection (obs/chaos.py --chaos): the ONE
         # injection point for faulted numerics, checkpoint corruption,
         # transient data errors and signals; the legacy
@@ -527,9 +541,10 @@ class Trainer:
             # shape requires, so the newest verified step always wins.
             t0 = time.perf_counter()
             self._reshard_plan = {}
-            restored = self.checkpointer.restore_latest(
-                None, target_for=self._restore_target_for
-            )
+            with setup.span("restore"):
+                restored = self.checkpointer.restore_latest(
+                    None, target_for=self._restore_target_for
+                )
             if restored is None:
                 # checkpoints EXIST but none passed verification:
                 # training silently from step 0 would let this run's
@@ -637,9 +652,11 @@ class Trainer:
         # shard_map programs yet (ROADMAP open item).
         from distributed_llms_example_tpu.obs import TrainerObs
 
-        self.obs = TrainerObs(cfg, start_step=self.start_step, manage_sink=False)
-        if not self.pipelined:
-            self.obs.startup_gauges(self.mesh, tgt_cap=tgt_cap)
+        with setup.span("obs_open"):  # holds the existing train/obs_gauge_compile where gauges are on
+            self.obs = TrainerObs(cfg, start_step=self.start_step, manage_sink=False)
+            if not self.pipelined:
+                self.obs.startup_gauges(self.mesh, tgt_cap=tgt_cap)
+        self._stepped = False  # the step program has been called (set-up's first_step is over)
 
     # ------------------------------------------------------------------
 
@@ -1938,11 +1955,17 @@ class Trainer:
                         )
                     with obs.step_span():
                         gb = put_batch(batch, self.mesh, sequence_sharded=self.sequence_sharded)
-                        if self.use_dropout:
-                            self._rng, sub = jax.random.split(self._rng)
-                            self.state, metrics = self.train_step(self.state, gb, sub)
-                        else:
-                            self.state, metrics = self.train_step(self.state, gb)
+                        # set-up's last span: trace, lowering and compile or load are
+                        # synchronous inside the first call; nothing waits on the device
+                        with _NOT_FIRST if self._stepped else setup.span("first_step"):
+                            if self.use_dropout:
+                                self._rng, sub = jax.random.split(self._rng)
+                                self.state, metrics = self.train_step(self.state, gb, sub)
+                            else:
+                                self.state, metrics = self.train_step(self.state, gb)
+                    if not self._stepped:
+                        self._stepped = True
+                        setup.ready("train")
                     step += 1
                     self._last_step = step
                     last_metrics = metrics
@@ -2132,7 +2155,8 @@ class Trainer:
         self.checkpointer.wait()
         self.save_final()
         wall = time.perf_counter() - t0
-        log_json({"event": "done", "steps": step, "wall_seconds": wall})
+        log_json({"event": "done", "steps": step, "wall_seconds": wall,
+                  "late_compiles": setup.late_compiles()})
         return {"steps": step, "wall_seconds": wall, "final_eval": last_eval}
 
     def save_final(self) -> None:

@@ -27,9 +27,12 @@ data×fsdp×expert, heads over tensor — ``CACHE_RULES``):
   (``cache_positions`` per-row cache writes), idle slots parked at an
   out-of-range offset so their writes drop.
 
-Host loop per step: admit into free slots (if any), run the step, read the
-(slots,) token vector back, append/evict.  Greedy only — beam search keeps
-the static split path (the per-step beam reorder has no per-slot form).
+Host loop per round: admit into free slots (if any), dispatch the next
+decode step, THEN read back the (slots,) token vector of the step dispatched
+the round before, append/evict: the device always has one program queued
+behind the one it runs (``ServeSession``: the round's order).  Greedy only —
+beam search keeps the static split path (the per-step beam reorder has no
+per-slot form).
 Single-controller: multi-process serving is a queueing layer above this,
 not a collective program.
 
@@ -48,9 +51,11 @@ queue" vs "prefill is slow".
 
 Host spans (obs/spans.py, scope ``serve``): each round is ``serve/round``,
 partitioned into ``admit_prep``, ``prefill_dispatch``, ``decode_dispatch``,
-``token_fetch``, ``emit`` and ``window_log``.  Under a profiler session they
-sit on the device trace's clock; the prefill/decode seconds of the events
-above are these spans' durations.
+``token_fetch``, ``emit`` and ``window_log``.  ``token_fetch`` and ``emit``
+name the round dispatched one call earlier, one behind the ``decode_dispatch``
+beside them; a causal wave's first tokens are a second ``token_fetch`` /
+``emit`` pair at the round's end.  Under a profiler session they sit on the
+device trace's clock.
 """
 
 from __future__ import annotations
@@ -177,8 +182,20 @@ class ServeStats:
     sequences: int = 0
     decode_steps: int = 0
     decode_tokens: int = 0
+    # the cadence a client sees: for each decode round, from the instant the
+    # host last held new tokens (the previous round's fetch, or a wave's first
+    # tokens) to the end of this round's fetch; from the round's own dispatch
+    # where nothing was in flight before it (a lockstep round, the first round
+    # after an idle spell)
     decode_seconds: float = 0.0
+    # a wave's dispatch until the host holds its first tokens (causal), or the
+    # dispatch alone (seq2seq: a wave has no token of its own to wait for)
     prefill_seconds: float = 0.0
+    # the round's order (ServeSession.step): rounds dispatched while the round
+    # before was still unfetched, and tokens computed for a slot that had
+    # already ended by EOS (found one round late, dropped at emit)
+    rounds_ahead: int = 0
+    tokens_discarded: int = 0
     slot_occupancy: float = 0.0
     # capacity gauges (static byte accounting — measured, not inferred):
     # resident = the serving state's fixed allocation; in_use = what live
@@ -1033,7 +1050,7 @@ class ServingEngine:
         emitted).  ``max_new`` optionally caps each request below the
         engine-wide ``max_new_tokens`` (the per-request ``max_tokens`` of a
         real serving API — and the lever continuous batching exists for:
-        a short request frees its slot the step it finishes).  Fills
+        a short request frees its slot when its last token is emitted).  Fills
         ``self.last_stats`` and emits serve_window / serve_summary obs
         events.  Thin wrapper over a ``ServeSession``: submit everything,
         step until drained, finalize."""
@@ -1068,6 +1085,20 @@ class ServeSession:
     bookkeeping, byte accounting, and obs events are exactly the
     engine's — the split moves control flow, not semantics, which is why
     the engine-vs-static determinism pins keep covering every driver.
+
+    **The round's order** (``step``): the host keeps one decode program
+    queued behind the one that runs.  A round admits, dispatches decode
+    program n+1, and only THEN fetches and emits the tokens of program n:
+    nothing the dispatch needs is in those tokens (the next input token is
+    on the device in ``state["last"]``, positions advance by one, and a slot
+    leaves the dispatch mask by budget, which the host knows).  A slot that
+    ends by EOS is found one round late: the token program n+1 computed for
+    it is dropped at emit (``tokens_discarded``), and whatever that step
+    wrote is overwritten whole by the slot's next admission, which the
+    device runs after it.  A causal wave's first tokens are fetched after
+    the decode dispatch behind it.  How far the host runs ahead follows from
+    what a round's input depends on (``_depth``): a speculative round is
+    built from the fetched tokens and stays in lockstep.
 
     The replica router (serving/router.py) opens one session per engine
     replica; ``progress`` (bumped on every admit chunk and decode step)
@@ -1115,10 +1146,22 @@ class ServeSession:
         self.progress = 0
         # slot bookkeeping (the generate loop's former closure state)
         self.slot_req = np.full(S, -1, np.int64)  # request index per slot
-        self.emitted = np.zeros(S, np.int64)
+        self.emitted = np.zeros(S, np.int64)  # tokens the host holds, per slot
+        # tokens the device computes or has computed for the slot's request
+        # that the host has not fetched yet: a causal wave's first token, the
+        # round in flight; a slot's next position is ``emitted + ahead``
+        self.ahead = np.zeros(S, np.int64)
+        self.slot_budget = np.zeros(S, np.int64)  # the request's budget, per slot
         self.lengths = np.zeros(S, np.int64)  # true prompt lengths
         self.base = np.full(S, eng.W, np.int64)  # causal: decode tail start
+        # a slot holds a request until its LAST token is emitted (not: computed)
         self.active = np.zeros(S, bool)
+        # decode rounds dispatched and not fetched (at most ``_depth`` between
+        # two calls of ``step``), and the waves whose first tokens are unfetched
+        # (always fetched before the round that dispatched them returns)
+        self._inflight: "collections.deque[dict]" = collections.deque()
+        self._firsts: list[dict] = []
+        self._tokens_t = float("-inf")  # when the host last held new tokens
         # paged bookkeeping: block ownership per slot + the block table
         # the step program reads (sentinel = num_blocks → reads fill
         # zeros, writes drop)
@@ -1263,7 +1306,21 @@ class ServeSession:
         return int(self.active.sum())
 
     def has_work(self) -> bool:
-        return bool(self.pending) or bool(self.active.any())
+        """Something is queued, a slot's last token is unemitted, or a round
+        is in flight (its tokens, if only dropped ones, are still to fetch)."""
+        return bool(self.pending) or bool(self.active.any()) or bool(self._inflight)
+
+    @property
+    def _depth(self) -> int:
+        """Decode rounds the host may leave unfetched when ``step`` returns:
+        decided by what the next round's input is built from, not by a flag.
+        A plain greedy round (flat or paged) needs nothing of the fetched
+        tokens: 1.  A speculative round drafts from ``outputs[rid][-1]`` and
+        the request's n-gram history, i.e. from the fetched tokens: 0.
+        Paged + prefix runs ahead too: a dropped token's write lands at
+        ``base + emitted - 1 >= bucket``, in the slot's own decode-tail blocks,
+        and the prefix index keeps prompt blocks alone (positions < prompt)."""
+        return 0 if self.eng.spec else 1
 
     def output(self, rid: int) -> list[int]:
         return self.outputs[rid]
@@ -1331,14 +1388,18 @@ class ServeSession:
         log_json(record)
 
     def _evict_slot(self, slot: int) -> None:
-        """Free the slot NOW — and, paged, drop one reference per block it
-        held (the evict-returns-all-blocks contract; under prefix_cache a
-        shared block survives until its LAST holder evicts).  The
+        """Free the slot: called when its request's last token is EMITTED
+        (one round after the device computed it; a round still in flight for
+        the slot finds another request there, or none, and drops its token)
+        — and, paged, drop one reference per block it held (the
+        evict-returns-all-blocks contract; under prefix_cache a shared
+        block survives until its LAST holder evicts).  The
         registered chain releases tail-first so warm retention ages the
         DEEP end of a prefix out before its root — a partially-evicted
         chain still matches at shorter prefixes."""
         self.active[slot] = False
         self.slot_req[slot] = -1
+        self.ahead[slot] = 0
         self._spec_fed[slot] = None
         self._win_done += 1
         if self.eng.paged and self.slot_blocks[slot]:
@@ -1381,10 +1442,80 @@ class ServeSession:
         dispatch.set(rows=admitted, rows_computed=rows)
         self._waves_by_rows[rows] = self._waves_by_rows.get(rows, 0) + 1
 
-    def _admit_now(self, finished: list) -> None:
+    def _seat(self, rid: int, slot: int, length: int, base: int) -> None:
+        """The slot's tables for the request an admission puts there.  A causal
+        wave computes the request's first token: one token ahead of the host."""
+        self.slot_req[slot] = rid
+        self.emitted[slot] = 0
+        self.ahead[slot] = 0 if self.eng.is_seq2seq else 1
+        self.slot_budget[slot] = self.budgets[rid]
+        self.lengths[slot] = length
+        self.base[slot] = base
+        self.active[slot] = True
+
+    def _wave_dispatched(self, sp, first, rows: list[tuple[int, int, int]]) -> None:
+        """Books of a wave whose programs are dispatched (``sp``, its closed
+        ``prefill_dispatch`` span): ``rows`` are its (row, slot, rid).  Nothing
+        waits for the device here: a causal wave's first tokens (``first``, on
+        the device; already in ``state["last"]``) are fetched by
+        ``_emit_firsts``; a seq2seq wave has none and its prefill share is the
+        dispatch."""
+        self.progress += 1
+        for _, _, rid in rows:
+            self.admit_t[rid] = sp.t0
+        if first is None:
+            self.stats.prefill_seconds += sp.dur
+            self._win_prefill += sp.dur
+            for _, _, rid in rows:
+                self.prefill_dt[rid] = sp.dur
+        else:
+            self._firsts.append({"first": first, "rows": rows, "t0": sp.t0})
+        self.stats.peak_cache_bytes_in_use = max(
+            self.stats.peak_cache_bytes_in_use, self._bytes_in_use()
+        )
+
+    def _emit_firsts(self, finished: list) -> None:
+        """Fetch and emit the first tokens of the waves dispatched this round:
+        a request's first token is stamped when its wave ends.  A first token
+        that is EOS, or a budget of one, ends the request here; the decode
+        round dispatched behind the wave left a budget-of-one slot out, and
+        drops the token it computed for one that ended by EOS."""
+        for wave in self._firsts:
+            with self.spans.span("token_fetch") as fetch:  # the host waits for the wave here
+                first_h = np.asarray(jax.device_get(wave["first"]))
+            now = self._tokens_t = fetch.end
+            dt = now - wave["t0"]
+            self.stats.prefill_seconds += dt
+            self._win_prefill += dt
+            with self.spans.span("emit"):
+                for r, slot, rid in wave["rows"]:
+                    self.prefill_dt[rid] = dt
+                    self._emit_token(slot, rid, int(first_h[r]), now, finished)
+        self._firsts.clear()
+
+    def _emit_token(self, slot: int, rid: int, tok: int, now: float, finished: list) -> None:
+        """One token the host now holds, appended to its request; the slot is
+        free when that was the request's last (EOS, or its budget)."""
+        self.outputs[rid].append(tok)
+        if self.ttft[rid] is None:
+            self.ttft[rid] = now - self.submit_t[rid]
+            self.first_tok_wall[rid] = now
+        self.emitted[slot] += 1
+        self.ahead[slot] -= 1
+        if tok == self.eng.eos or self.emitted[slot] >= self.budgets[rid]:
+            self._evict_slot(slot)  # its last token is emitted: slot (and its blocks) free
+            self._finish_request(rid, slot, now)
+            finished.append(rid)
+
+    def _admit_now(self) -> None:
+        """Admit queued requests into free slots: one prefill wave, its
+        programs dispatched and NOT waited for.  Prompt lengths are the sums of
+        the mask the host built; a causal wave's first token stays on the
+        device (``state["last"]``) for the decode round dispatched behind it
+        and is fetched after that dispatch (``_emit_firsts``)."""
         eng = self.eng
         if eng.paged and eng.prefix:
-            return self._admit_now_prefix(finished)
+            return self._admit_now_prefix()
         S, W, C = eng.S, eng.W, eng.prefill_batch
         with self.spans.span("admit_prep") as prep:
             free = [i for i in range(S) if not self.active[i]]
@@ -1445,16 +1576,21 @@ class ServeSession:
                     )
                     self.slot_bt[slot, :] = row
                     admit_rows[r, :] = row[:ntc]
+            # a causal prompt's length is what the prefill program sums: the mask's ones
+            lengths = mask.sum(axis=1)
+            for r, rid in enumerate(reqs):
+                self._seat(rid, free[r], plen(rid) if eng.is_seq2seq else int(lengths[r]), bucket)
         with self.spans.span("prefill_dispatch") as sp:
             self._count_wave(sp, n, rows)
             pre = eng._prefill(self.params, jnp.asarray(ids), jnp.asarray(mask))
+            first = None
             if eng.is_seq2seq:
                 enc, pmask, ckv = pre
                 self.state = eng._admit(
                     self.state, enc, pmask, ckv, jnp.asarray(slot_idx)
                 )
             else:
-                cache, full_mask, plens, first = pre
+                cache, full_mask, _, first = pre
                 if eng.paged:
                     self.state = eng._admit(
                         self.state, cache, full_mask, first, jnp.asarray(slot_idx),
@@ -1464,41 +1600,9 @@ class ServeSession:
                     self.state = eng._admit(
                         self.state, cache, full_mask, first, jnp.asarray(slot_idx)
                     )
-                plens_h = np.asarray(jax.device_get(plens))
-                first_h = np.asarray(jax.device_get(first))
-        t0, dt, now = sp.t0, sp.dur, sp.end
-        self.stats.prefill_seconds += dt
-        self._win_prefill += dt
-        self.progress += 1
-        with self.spans.span("emit"):
-            for r, rid in enumerate(reqs):
-                slot = free[r]
-                self.slot_req[slot] = rid
-                self.emitted[slot] = 0
-                self.lengths[slot] = plen(rid)
-                self.base[slot] = bucket
-                self.active[slot] = True
-                self.admit_t[rid] = t0
-                self.prefill_dt[rid] = dt
-                if not eng.is_seq2seq:
-                    self.lengths[slot] = int(plens_h[r])
-                    # the causal prefill already produced token #1
-                    self.outputs[rid].append(int(first_h[r]))
-                    self.emitted[slot] = 1
-                    self.ttft[rid] = now - self.submit_t[rid]
-                    self.first_tok_wall[rid] = now
-                    if (
-                        int(first_h[r]) == eng.eos
-                        or self.emitted[slot] >= self.budgets[rid]
-                    ):
-                        self._evict_slot(slot)
-                        self._finish_request(rid, slot, now)
-                        finished.append(rid)
-            self.stats.peak_cache_bytes_in_use = max(
-                self.stats.peak_cache_bytes_in_use, self._bytes_in_use()
-            )
+        self._wave_dispatched(sp, first, [(r, free[r], rid) for r, rid in enumerate(reqs)])
 
-    def _admit_now_prefix(self, finished: list) -> None:
+    def _admit_now_prefix(self) -> None:
         """Prefix-cache admission: per-row transactional packing (match
         the longest cached chain → acquire it → alloc only the tail,
         rolling the acquire back when the pool comes up short), then at
@@ -1511,7 +1615,9 @@ class ServeSession:
         scatter's pool state); a warm row must NOT match another warm
         row's fresh tail blocks — those land in the same program call it
         would gather from — so matches truncate before any block first
-        written by this wave's warm chunk."""
+        written by this wave's warm chunk.  Neither dispatch is waited for
+        (``_admit_now``): both chunks' first tokens are fetched after the
+        decode round dispatched behind them."""
         eng = self.eng
         S, W, C = eng.S, eng.W, eng.prefill_batch
         bs, N = eng.block_size, eng.pool.num_blocks
@@ -1610,25 +1716,17 @@ class ServeSession:
                     admit_rows[r, :] = row[:ntc]
             with self.spans.span("prefill_dispatch") as sp:
                 self._count_wave(sp, len(cold), rows)
-                cache, full_mask, plens, first = eng._prefill(
+                cache, full_mask, _, first = eng._prefill(
                     self.params, jnp.asarray(ids), jnp.asarray(mask)
                 )
                 self.state = eng._admit(
                     self.state, cache, full_mask, first, jnp.asarray(slot_idx),
                     jnp.asarray(admit_rows.reshape(-1)),
                 )
-                plens_h = np.asarray(jax.device_get(plens))
-                first_h = np.asarray(jax.device_get(first))
-            t0, dt, now = sp.t0, sp.dur, sp.end
-            self.stats.prefill_seconds += dt
-            self._win_prefill += dt
-            self.progress += 1
-            with self.spans.span("emit"):
-                for r, (rid, slot, p, _h) in enumerate(cold):
-                    self._admit_bookkeep(
-                        rid, slot, int(plens_h[r]), bucket, int(first_h[r]),
-                        t0, dt, now, finished,
-                    )
+            lengths = mask.sum(axis=1)  # what the prefill program sums
+            for r, (rid, slot, _p, _h) in enumerate(cold):
+                self._seat(rid, slot, int(lengths[r]), bucket)
+            self._wave_dispatched(sp, first, [(r, slot, rid) for r, (rid, slot, _p, _h) in enumerate(cold)])
         # ---- warm chunk: gather matched chains, prefill only the tails
         if warm:
             with self.spans.span("admit_prep"):
@@ -1674,49 +1772,22 @@ class ServeSession:
                     jnp.asarray(tail_last), jnp.asarray(slot_idx),
                     jnp.asarray(bt), jnp.asarray(admit_rows.reshape(-1)),
                 )
-                first_wh = np.asarray(jax.device_get(first_w))
-            t0, dt, now = sp.t0, sp.dur, sp.end
-            self.stats.prefill_seconds += dt
-            self._win_prefill += dt
-            self.progress += 1
-            with self.spans.span("emit"):
-                for r, wr in enumerate(warm):
-                    self._admit_bookkeep(
-                        wr["rid"], wr["slot"], wr["p"], wr["bucket"],
-                        int(first_wh[r]), t0, dt, now, finished,
-                    )
-        self.stats.peak_cache_bytes_in_use = max(
-            self.stats.peak_cache_bytes_in_use, self._bytes_in_use()
-        )
-
-    def _admit_bookkeep(
-        self, rid: int, slot: int, length: int, base: int, first: int,
-        t0: float, dt: float, now: float, finished: list,
-    ) -> None:
-        """Per-row post-admit bookkeeping shared by the prefix path's
-        cold and warm chunks — byte-for-byte the causal branch of
-        ``_admit_now``'s trailing loop."""
-        eng = self.eng
-        self.slot_req[slot] = rid
-        self.lengths[slot] = length
-        self.base[slot] = base
-        self.active[slot] = True
-        self.admit_t[rid] = t0
-        self.prefill_dt[rid] = dt
-        self.outputs[rid].append(first)
-        self.emitted[slot] = 1
-        self.ttft[rid] = now - self.submit_t[rid]
-        self.first_tok_wall[rid] = now
-        if first == eng.eos or self.emitted[slot] >= self.budgets[rid]:
-            self._evict_slot(slot)
-            self._finish_request(rid, slot, now)
-            finished.append(rid)
+            for wr in warm:
+                self._seat(wr["rid"], wr["slot"], wr["p"], wr["bucket"])
+            self._wave_dispatched(sp, first_w, [(r, wr["slot"], wr["rid"]) for r, wr in enumerate(warm)])
 
     def step(self) -> list[int]:
-        """One scheduler round: admit into free slots, then — if any slot
-        is live — one decode step.  Returns the session-local rids of
-        requests that finished during this call (finish-at-prefill
-        included).  The batch ``generate`` loop is
+        """One scheduler round: admit into free slots (a slot is free when
+        its request's last token has been EMITTED), dispatch the next decode
+        program for every slot that still needs a token computed, then fetch
+        and emit the tokens of the program dispatched the round BEFORE (and
+        the first tokens of this round's causal wave).  So the first round
+        after an idle spell emits no decode token and the last one dispatches
+        nothing; a speculative session dispatches and fetches the same round
+        (``_depth``).  Returns the session-local rids of requests that
+        finished during this call: whose last token this call emitted
+        (finish-at-prefill included).  ``outputs[rid]`` grows only with tokens
+        the host holds and the request keeps.  The batch ``generate`` loop is
         ``while has_work(): step()``.  A RESOURCE_EXHAUSTED escaping the
         round trips the OOM forensics (obs/memprof.py): the postmortem
         bundle lands atomically, then the error re-raises — the session
@@ -1756,8 +1827,9 @@ class ServeSession:
             account=self._memory_account(),
         )
 
-    def _spec_dispatch(self, offsets):
-        """Assemble one draft-then-verify round.  Drafts come from the
+    def _spec_dispatch(self, offsets, rope):
+        """Assemble one draft-then-verify round (in lockstep: nothing is ahead,
+        every live slot's last token is in ``outputs``).  Drafts come from the
         n-gram self-drafter or the shrunk draft model; serving/spec.py
         owns BOTH drafters and all acceptance/rollback math (repo_lint
         rule 17) — this method only packs inputs and runs the compiled
@@ -1803,20 +1875,17 @@ class ServeSession:
                 for s in range(S)
             ]
             x[:, 1:] = spec_decode.ngram_drafts(hist, K, eng.pad)
-        rope = self.lengths + self.emitted - 1
         if eng.paged:
             target, n_emit, self.state = eng._verify(
                 self.params, self.state, jnp.asarray(x),
                 jnp.asarray(self.slot_bt),
-                jnp.asarray(offsets.astype(np.int32)),
-                jnp.asarray(rope.astype(np.int32)),
+                jnp.asarray(offsets), jnp.asarray(rope),
                 jnp.asarray(self.active), jnp.asarray(room),
             )
         else:
             target, n_emit, self.state = eng._verify(
                 self.params, self.state, jnp.asarray(x),
-                jnp.asarray(offsets.astype(np.int32)),
-                jnp.asarray(rope.astype(np.int32)),
+                jnp.asarray(offsets), jnp.asarray(rope),
                 jnp.asarray(self.active), jnp.asarray(room),
             )
         return target, n_emit
@@ -1888,6 +1957,7 @@ class ServeSession:
                     finished.append(rid)
                     evicted = True
                     break
+            self.ahead[slot] = 0  # the round's tokens are all emitted
             if not evicted:
                 self._spec_fed[slot] = fed
         stats.spec_steps += 1
@@ -1906,70 +1976,103 @@ class ServeSession:
         return finished
 
     def _round_stages(self) -> list[int]:
-        eng = self.eng
+        """Admit, dispatch round n+1, then fetch and emit round n: one loop at
+        the depth ``_depth`` gives.  At depth 0 (speculative) a wave's first
+        tokens are fetched before the dispatch that is built from them, and
+        the round dispatched is fetched before this returns.  A round that
+        dispatches nothing (every live slot's last token is in flight) fetches
+        what is in flight."""
         finished: list[int] = []
-        self._admit_now(finished)
-        if not self.active.any():
-            return finished  # every admitted sequence finished at prefill
-        offsets = (
-            self.emitted if eng.is_seq2seq else (self.base + self.emitted - 1)
-        )
+        depth = self._depth
+        self._admit_now()
+        if depth == 0:
+            self._emit_firsts(finished)
+        dispatched = self._dispatch_decode()
+        while len(self._inflight) > (depth if dispatched else 0):
+            self._fetch_and_emit(self._inflight.popleft(), finished)
+        self._emit_firsts(finished)
+        return finished
+
+    def _dispatch_decode(self) -> bool:
+        """Dispatch one decode program for the slots that still need a token
+        computed: live, and not already computing their last by budget (exact:
+        the host knows every budget).  Its inputs are what the host knows now:
+        a slot's position is its emitted count plus its tokens ahead, its input
+        token is on the device.  False when there is no such slot."""
+        eng = self.eng
+        held = self.emitted + self.ahead  # tokens of the request before this round's
+        todo = self.active & (held < self.slot_budget)
+        if not todo.any():
+            return False
+        offsets = (held if eng.is_seq2seq else self.base + held - 1).astype(np.int32)
+        rope = (self.lengths + held - 1).astype(np.int32)
         with self.spans.span("decode_dispatch") as dispatch:
             if eng.spec:
-                target, n_emit = self._spec_dispatch(offsets)
+                tokens = self._spec_dispatch(offsets, rope)
             elif eng.is_seq2seq:
                 tokens, self.state = eng._step(
-                    self.params, self.state,
-                    jnp.asarray(offsets.astype(np.int32)),
-                    jnp.asarray(self.active),
+                    self.params, self.state, jnp.asarray(offsets), jnp.asarray(todo),
                 )
             elif eng.paged:
-                rope = self.lengths + self.emitted - 1
                 tokens, self.state = eng._step(
                     self.params, self.state,
                     jnp.asarray(self.slot_bt),
-                    jnp.asarray(offsets.astype(np.int32)),
-                    jnp.asarray(rope.astype(np.int32)),
-                    jnp.asarray(self.active),
+                    jnp.asarray(offsets), jnp.asarray(rope), jnp.asarray(todo),
                 )
             else:
-                rope = self.lengths + self.emitted - 1
                 tokens, self.state = eng._step(
                     self.params, self.state,
-                    jnp.asarray(offsets.astype(np.int32)),
-                    jnp.asarray(rope.astype(np.int32)),
-                    jnp.asarray(self.active),
+                    jnp.asarray(offsets), jnp.asarray(rope), jnp.asarray(todo),
                 )
             # slots whose state the round moves: every one, live or not, except
             # where the decode program's steps walk the live slots alone
-            n_live = int(self.active.sum())
-            counters = {"slots_live": n_live, "slots_streamed": n_live if eng.streams_live_slots else eng.S}
+            n_live = int(todo.sum())
+            ahead = len(self._inflight)  # the round before is unfetched
+            counters = {"slots_live": n_live, "slots_streamed": n_live if eng.streams_live_slots else eng.S,
+                        "ahead": ahead}
             if len(self._kv_lengths) and not eng.spec:
                 # K/V positions the round's attention needs (a live slot's own, the
                 # step's included; on a window leaf at most the window) against those
                 # its program reads (every slot's whole leaf), over the attention layers
-                held = (self.lengths + self.emitted)[self.active].astype(np.int64)
-                counters["kv_positions_live"] = int(np.minimum(held[:, None], self._kv_lengths[None, :]).sum())
+                needed = (self.lengths + held)[todo].astype(np.int64)
+                counters["kv_positions_live"] = int(np.minimum(needed[:, None], self._kv_lengths[None, :]).sum())
                 counters["kv_positions_streamed"] = int(eng.S * self._kv_lengths.sum())
             dispatch.set(**counters)
-        with self.spans.span("token_fetch") as fetch:  # the host waits for the device here
+        slots = np.flatnonzero(todo)
+        self.ahead[slots] += 1
+        self.stats.rounds_ahead += ahead
+        self.progress += 1
+        # the round in flight: its tokens on the device, the slot -> rid it was
+        # dispatched for (a slot freed since holds another request, or none),
+        # its dispatch instant
+        self._inflight.append({"tokens": tokens, "slots": slots, "rids": self.slot_req[slots],
+                               "t0": dispatch.t0})
+        return True
+
+    def _fetch_and_emit(self, rnd: dict, finished: list) -> None:
+        """Fetch a dispatched round's tokens (the host waits for the device
+        here, and nowhere else in a plain round) and emit them.  A token whose
+        slot no longer holds the request it was computed for is dropped: the
+        request ended by EOS a round before (``tokens_discarded``)."""
+        eng, stats = self.eng, self.stats
+        with self.spans.span("token_fetch") as fetch:
             if eng.spec:
-                spec_toks = np.asarray(jax.device_get(target))
-                spec_emit = np.asarray(jax.device_get(n_emit))
+                spec_toks, spec_emit = (np.asarray(jax.device_get(x)) for x in rnd["tokens"])
             else:
-                toks = np.asarray(jax.device_get(tokens))
+                toks = np.asarray(jax.device_get(rnd["tokens"]))
                 if len(toks) > eng.S:  # a flat round of a model with experts
                     fetch.set(**{k: int(v) for k, v in zip(MOE_COUNTERS, toks[eng.S:])})
-        dt = fetch.end - dispatch.t0
+        dt = fetch.end - max(rnd["t0"], self._tokens_t)
+        self._tokens_t = fetch.end
         with self.spans.span("emit") as emit:
             now = emit.t0
-            self.stats.decode_seconds += dt
-            self.stats.decode_steps += 1
+            stats.decode_seconds += dt
+            stats.decode_steps += 1
             self.progress += 1
             self._win_decode += dt
-            n_active = self.active_count
-            self.stats.slot_occupancy += n_active / eng.S
-            self._win_occ += n_active / eng.S
+            n_computed = len(rnd["slots"])
+            stats.slot_occupancy += n_computed / eng.S
+            self._win_occ += n_computed / eng.S
             self._bpt_samples.append(
                 self._bytes_in_use() / max(self._live_tokens(), 1)
             )
@@ -1979,90 +2082,87 @@ class ServeSession:
                 # an honest cross-mode comparison
                 appended = self._spec_append(spec_toks, spec_emit, now, finished)
             else:
-                appended = n_active
-                for slot in np.nonzero(self.active)[0]:
-                    rid = int(self.slot_req[slot])
-                    tok = int(toks[slot])
-                    self.outputs[rid].append(tok)
-                    if self.ttft[rid] is None:
-                        self.ttft[rid] = now - self.submit_t[rid]
-                        self.first_tok_wall[rid] = now
-                    self.emitted[slot] += 1
-                    if tok == eng.eos or self.emitted[slot] >= self.budgets[rid]:
-                        self._evict_slot(slot)  # slot (and its blocks) free NOW
-                        self._finish_request(rid, slot, now)
-                        finished.append(rid)
-            self.stats.decode_tokens += appended
+                appended = 0
+                for slot, rid in zip(rnd["slots"].tolist(), rnd["rids"].tolist()):
+                    if self.slot_req[slot] != rid:
+                        stats.tokens_discarded += 1
+                        continue
+                    self._emit_token(slot, rid, int(toks[slot]), now, finished)
+                    appended += 1
+            stats.decode_tokens += appended
             self._win_tokens += appended
         every = eng.serve.log_every_steps
-        if every and self.stats.decode_steps % every == 0:
+        if every and stats.decode_steps % every == 0:
             with self.spans.span("window_log"):
-                w_dt = max(now - self._win_t0, 1e-9)
-                window = {
-                    "event": "serve_window",
-                    "step": self.stats.decode_steps,
-                    "decode_tokens_per_sec": round(self._win_tokens / w_dt, 1),
-                    "decode_tokens_per_sec_chip": round(
-                        self._win_tokens / w_dt / self.n_chips, 1
-                    ),
-                    "slot_occupancy": round(self._win_occ / every, 4),
-                    "queue_depth": len(self.pending),
-                    # queueing telemetry: the window's offered vs served rate
-                    # and their imbalance — a sustained positive queue_growth
-                    # is the open-loop collapse signal (arrivals outpacing
-                    # service), visible live instead of post-hoc
-                    "arrival_rate_per_sec": round(self._win_arrivals / w_dt, 2),
-                    "service_rate_per_sec": round(self._win_done / w_dt, 2),
-                    "queue_growth": int(self._win_arrivals - self._win_done),
-                    # the window's wall split: admission prefill vs decode
-                    # steps — a window whose prefill share balloons is paying
-                    # admission on the decode critical path
-                    "prefill_ms": round(self._win_prefill * 1e3, 1),
-                    "decode_ms": round(self._win_decode * 1e3, 1),
-                    # capacity gauges: what the cache state holds RIGHT NOW
-                    # per live token — the number the paged pool shrinks
-                    "cache_bytes_in_use": self._bytes_in_use(),
-                    "cache_bytes_per_token": round(
-                        self._bytes_in_use() / max(self._live_tokens(), 1), 1
-                    ),
-                }
-                if eng.paged:
-                    window["pool_blocks_in_use"] = eng.pool.blocks_in_use
-                    window["pool_blocks_free"] = eng.pool.blocks_free
-                    if eng.prefix:
-                        # cumulative-to-date prefix-cache gauges: hit rate over
-                        # eligible admissions, prefill tokens served from the
-                        # pool instead of recomputed, and the warm set's bytes
-                        window["prefix_hit_rate"] = round(
-                            self.stats.prefix_hits
-                            / max(self.stats.prefix_lookups, 1), 4
-                        )
-                        window["prefill_tokens_saved_frac"] = round(
-                            self.stats.prefill_tokens_saved
-                            / max(self.stats.prefill_tokens_total, 1), 4
-                        )
-                        window["pool_blocks_warm"] = eng.pool.blocks_warm
-                        window["warm_bytes"] = (
-                            eng.pool.blocks_warm * self._per_block
-                        )
-                if eng.spec:
-                    # the speculative ledger live: window-local multi-token
-                    # yield + the cumulative draft acceptance rate
-                    window["accepted_tokens_per_step"] = round(
-                        self._win_spec_emitted / max(self._win_spec_steps, 1), 4
-                    )
-                    window["acceptance_rate"] = round(
-                        self.stats.spec_accepted
-                        / max(self.stats.spec_drafted, 1), 4
-                    )
-                if self.replica is not None:
-                    window["replica"] = int(self.replica)
-                log_json(window)
-                self._win_tokens, self._win_t0, self._win_occ = 0, now, 0.0
-                self._win_prefill, self._win_decode = 0.0, 0.0
-                self._win_arrivals, self._win_done = 0, 0
-                self._win_spec_steps, self._win_spec_emitted = 0, 0
-        return finished
+                self._log_window(now)
+
+    def _log_window(self, now: float) -> None:
+        eng, every = self.eng, self.eng.serve.log_every_steps
+        w_dt = max(now - self._win_t0, 1e-9)
+        window = {
+            "event": "serve_window",
+            "step": self.stats.decode_steps,
+            "decode_tokens_per_sec": round(self._win_tokens / w_dt, 1),
+            "decode_tokens_per_sec_chip": round(
+                self._win_tokens / w_dt / self.n_chips, 1
+            ),
+            "slot_occupancy": round(self._win_occ / every, 4),
+            "queue_depth": len(self.pending),
+            # queueing telemetry: the window's offered vs served rate
+            # and their imbalance — a sustained positive queue_growth
+            # is the open-loop collapse signal (arrivals outpacing
+            # service), visible live instead of post-hoc
+            "arrival_rate_per_sec": round(self._win_arrivals / w_dt, 2),
+            "service_rate_per_sec": round(self._win_done / w_dt, 2),
+            "queue_growth": int(self._win_arrivals - self._win_done),
+            # the window's wall split: admission prefill vs decode
+            # steps — a window whose prefill share balloons is paying
+            # admission on the decode critical path
+            "prefill_ms": round(self._win_prefill * 1e3, 1),
+            "decode_ms": round(self._win_decode * 1e3, 1),
+            # capacity gauges: what the cache state holds RIGHT NOW
+            # per live token — the number the paged pool shrinks
+            "cache_bytes_in_use": self._bytes_in_use(),
+            "cache_bytes_per_token": round(
+                self._bytes_in_use() / max(self._live_tokens(), 1), 1
+            ),
+        }
+        if eng.paged:
+            window["pool_blocks_in_use"] = eng.pool.blocks_in_use
+            window["pool_blocks_free"] = eng.pool.blocks_free
+            if eng.prefix:
+                # cumulative-to-date prefix-cache gauges: hit rate over
+                # eligible admissions, prefill tokens served from the
+                # pool instead of recomputed, and the warm set's bytes
+                window["prefix_hit_rate"] = round(
+                    self.stats.prefix_hits
+                    / max(self.stats.prefix_lookups, 1), 4
+                )
+                window["prefill_tokens_saved_frac"] = round(
+                    self.stats.prefill_tokens_saved
+                    / max(self.stats.prefill_tokens_total, 1), 4
+                )
+                window["pool_blocks_warm"] = eng.pool.blocks_warm
+                window["warm_bytes"] = (
+                    eng.pool.blocks_warm * self._per_block
+                )
+        if eng.spec:
+            # the speculative ledger live: window-local multi-token
+            # yield + the cumulative draft acceptance rate
+            window["accepted_tokens_per_step"] = round(
+                self._win_spec_emitted / max(self._win_spec_steps, 1), 4
+            )
+            window["acceptance_rate"] = round(
+                self.stats.spec_accepted
+                / max(self.stats.spec_drafted, 1), 4
+            )
+        if self.replica is not None:
+            window["replica"] = int(self.replica)
+        log_json(window)
+        self._win_tokens, self._win_t0, self._win_occ = 0, now, 0.0
+        self._win_prefill, self._win_decode = 0.0, 0.0
+        self._win_arrivals, self._win_done = 0, 0
+        self._win_spec_steps, self._win_spec_emitted = 0, 0
 
     # ------------------------------------------------------------ closing
     def finalize(self) -> ServeStats:
@@ -2072,6 +2172,8 @@ class ServeSession:
         unfinished and count against goodput, never silently vanish."""
         if self._finalized:
             return self.stats
+        while self._inflight:  # a round in flight: its tokens are served before the books close
+            self._fetch_and_emit(self._inflight.popleft(), [])
         self._finalized = True
         eng, stats = self.eng, self.stats
         stats.ttft_s = [t for t in self.ttft if t is not None]
@@ -2126,6 +2228,10 @@ class ServeSession:
             **stats.goodput,
             "slot_occupancy": round(stats.slot_occupancy, 4),
             "prefill_seconds": round(stats.prefill_seconds, 3),
+            # the round's order: rounds dispatched with the round before
+            # unfetched, and tokens computed for a slot that had ended by EOS
+            "rounds_ahead": stats.rounds_ahead,
+            "tokens_discarded": stats.tokens_discarded,
             # waves by the row count of the programs that ran them
             "prefill_waves_by_rows": {
                 str(r): n for r, n in sorted(self._waves_by_rows.items())

@@ -607,6 +607,39 @@ def test_router_statelessness_drain_leaves_nothing(llama_pool, tmp_path):
     assert outs2 == outs1
 
 
+@pytest.mark.parametrize("ticks_before_drain", [1, 2, 4])
+def test_drain_loses_no_in_flight_token(llama_pool, ticks_before_drain):
+    """PR 42: a replica's session keeps one decode round queued behind the one
+    that runs.  A drain that begins with a round in flight serves that round's
+    tokens too: the draining replica steps while ``has_work()`` (true while a
+    round is unfetched), parks as drained with nothing in flight, and every
+    request's output is the single-engine oracle's, token for token."""
+    lm, params, reqs, engines, oracle_outs = llama_pool
+    router = ReplicaRouter(engines[:2], params, RouterConfig(log_every_ticks=0))
+    for r in reqs:
+        router.submit(r)
+    for _ in range(ticks_before_drain):
+        router.tick()
+    victim = router.replicas[0]
+    assert victim.session._inflight and victim.session.active_count > 0  # a round is in flight as the drain begins
+    held = {q.rid for q in router.requests if q.replica == 0 and not q.done}
+    router.drain_replica(0)
+    router.run_until_drained()
+    router.tick()  # the tick that finds the draining replica's slots empty
+    assert victim.state == "drained"
+    assert not victim.session._inflight and not victim.session.has_work()
+    # live slots finished in place, on the replica that was draining
+    assert any(router.requests[rid].done and router.requests[rid].replica == 0 for rid in held)
+    stats = victim.session.stats
+    router.finalize()
+    assert [list(router.requests[i].out) for i in range(len(reqs))] == oracle_outs
+    # what the replica computed and did not serve is what it computed for slots that had ended by EOS
+    eos = lm.config.eos_token_id
+    ended_by_eos = sum(1 for q in router.requests if q.replica == 0 and q.out[-1] == eos and len(q.out) < 8)
+    assert stats.tokens_discarded == ended_by_eos
+    assert router.retries_total == 0
+
+
 def test_serve_session_incremental_equals_batch(llama_pool):
     """The stepwise session API: submitting mid-flight (the router's
     arrival pattern) produces the same per-request tokens as the batch

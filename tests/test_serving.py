@@ -678,7 +678,10 @@ def _traced_session(rig, clock, **recorder):
 def test_round_spans_partition_the_round(serve_rig, capsys):
     """On the real clock: a plain round is admit_prep + decode_dispatch +
     token_fetch + emit (+ window_log at its cadence) with under 5 % of self
-    time; a round that admits also has prefill_dispatch and its own emit."""
+    time; a round that admits also has prefill_dispatch.  Since PR 42 the
+    round dispatches program n+1 and then fetches program n: the first round
+    fetches nothing, the last dispatches nothing, and ``token_fetch`` names
+    the round dispatched one call earlier."""
     import time
 
     sess, notes = _traced_session(serve_rig, time.perf_counter)
@@ -689,40 +692,46 @@ def test_round_spans_partition_the_round(serve_rig, capsys):
         sess.step()
     sess.finalize()
     rounds = [e for e in notes.events if e["name"] == "serve/round"]
-    assert len(rounds) == sess.stats.decode_steps and len(rounds) >= 8
+    # N decode programs take N + 1 rounds: one more dispatch than fetch in the first, one fewer in the last
+    assert len(rounds) == sess.stats.decode_steps + 1 and len(rounds) >= 9
+    assert sess.stats.rounds_ahead == sess.stats.decode_steps - 1 and sess.stats.tokens_discarded == 0
     plain_self, kinds = [], set()
-    for rd in rounds:
+    for i, rd in enumerate(rounds):
         kids = notes.children(rd)
         names = [k["name"] for k in kids]
         for a, b in zip(kids, kids[1:]):
             assert a["end"] <= b["start"]  # siblings, in order, none nested in another
         wave = "serve/prefill_dispatch" in names
         kinds.add(wave)
-        tail = ["serve/decode_dispatch", "serve/token_fetch", "serve/emit"]
+        first, last = i == 0, i == len(rounds) - 1
+        tail = ([] if last else ["serve/decode_dispatch"]) + ([] if first else ["serve/token_fetch", "serve/emit"])
         logs = ["serve/window_log"] if names[-1] == "serve/window_log" else []
         if wave:
-            assert names == ["serve/admit_prep", "serve/prefill_dispatch", "serve/emit"] + tail + logs
+            assert names == ["serve/admit_prep", "serve/prefill_dispatch"] + tail + logs
             assert set(kids[0]["stats"]) == {"n", "queue_wait_us_sum"}
             # the wave's dispatch says what it admitted and what its programs computed
             assert kids[1]["stats"] == {"rows": kids[0]["stats"]["n"], "rows_computed": 4}
         else:
             assert names == ["serve/admit_prep"] + tail + logs
             assert kids[0]["stats"] == {}
-            covered = sum(k["end"] - k["start"] for k in kids)
-            plain_self.append(1.0 - covered / (rd["end"] - rd["start"]))
+            if not last:
+                covered = sum(k["end"] - k["start"] for k in kids)
+                plain_self.append(1.0 - covered / (rd["end"] - rd["start"]))
         # the admitting admit_prep and its prefill_dispatch carry counters, and every round's
         # decode_dispatch says how many slots held a request and how many the cache streamed (PR 35):
-        # every one here, K/V caches having no step that walks the live slots alone (PR 36)
+        # every one here, K/V caches having no step that walks the live slots alone (PR 36);
+        # and whether the round before it was still unfetched (PR 42): every round but the first
         for e in [rd] + kids[2 if wave else 1:]:
             if e["name"] == "serve/decode_dispatch":
-                assert set(e["stats"]) == {"slots_live", "slots_streamed"}
+                assert set(e["stats"]) == {"slots_live", "slots_streamed", "ahead"}
                 assert 1 <= e["stats"]["slots_live"] <= e["stats"]["slots_streamed"] == sess.eng.S
+                assert e["stats"]["ahead"] == (0 if first else 1)
             else:
                 assert e["stats"] == {}
     assert kinds == {True, False} and not sess.eng.streams_live_slots
     assert sorted(plain_self)[len(plain_self) // 2] < 0.05
     logged = sum("serve/window_log" in [k["name"] for k in notes.children(rd)] for rd in rounds)
-    assert logged == len(rounds) // 3  # log_every_steps=3
+    assert logged == sess.stats.decode_steps // 3  # log_every_steps=3, counted in fetched rounds
     capsys.readouterr()
 
 
@@ -796,8 +805,10 @@ def test_round_syncs_and_event_fields_are_what_they_were(serve_rig, monkeypatch,
         before = calls["device_get"]
         sess.step()
         rounds += 1
-        assert calls["device_get"] - before == 1
-    assert calls == {"device_get": rounds, "block_until_ready": 0}
+        # the first round dispatches and fetches nothing; every later one fetches the round before it
+        assert calls["device_get"] - before == (0 if rounds == 1 else 1)
+    assert calls == {"device_get": rounds - 1, "block_until_ready": 0}
+    assert sess.stats.decode_steps == rounds - 1
     sess.finalize()
     events = [_json.loads(x) for x in capsys.readouterr().out.splitlines() if x.startswith("{")]
     window = next(e for e in events if e.get("event") == "serve_window")
@@ -816,18 +827,24 @@ def test_round_syncs_and_event_fields_are_what_they_were(serve_rig, monkeypatch,
         "peak_cache_bytes_in_use", "cache_bytes_per_token", "memory_account", "hbm_headroom_gib"}
     # PR 28 adds the static cache bytes by kind of leaf, beside cache_bytes_resident
     # PR 41: the count of compilations after set-up was over (obs/setup.py), process-wide
+    # PR 42: the round's order in two counts (rounds dispatched ahead of the last fetch, tokens dropped after an EOS)
     assert set(summary) - {"peak_hbm_bytes"} == was | {
-        "host_spans", "kv_bytes", "conv_state_bytes", "prefill_waves_by_rows", "late_compiles"}
+        "host_spans", "kv_bytes", "conv_state_bytes", "prefill_waves_by_rows", "late_compiles",
+        "rounds_ahead", "tokens_discarded"}
+    assert summary["rounds_ahead"] == summary["decode_steps"] - 1 and summary["tokens_discarded"] == 0
     assert summary["prefill_waves_by_rows"] == {"4": 3}  # 10 requests as 4 + 4 + 2; the mesh shards 4 rows
     assert summary["kv_bytes"] > 0 and summary["conv_state_bytes"] == 0
     host = summary["host_spans"]
     assert host["window_steps"] == rounds and host["spans"]["round"]["count"] == rounds
     assert set(host["spans"]) == {"round", "admit_prep", "prefill_dispatch", "decode_dispatch",
                                   "token_fetch", "emit", "window_log"}
-    # prefill_seconds and the decode seconds ARE the spans' durations
+    # a seq2seq wave's prefill seconds ARE its dispatch span's; the decode seconds run from fetch end to
+    # fetch end (the cadence a client sees), so they hold what lies between two fetches (dispatches,
+    # waves, emit) and, but for the first round's dispatch, add up to the span from the first dispatch
+    # to the last fetch
     assert summary["prefill_seconds"] == pytest.approx(host["spans"]["prefill_dispatch"]["total_ms"] / 1e3, abs=2e-3)
-    decode_ms = host["spans"]["decode_dispatch"]["total_ms"] + host["spans"]["token_fetch"]["total_ms"]
-    assert sess.stats.decode_seconds * 1e3 == pytest.approx(decode_ms, rel=0.02)
+    fetched = host["spans"]["decode_dispatch"]["total_ms"] + host["spans"]["token_fetch"]["total_ms"]
+    assert fetched * 0.98 <= sess.stats.decode_seconds * 1e3 <= host["spans"]["round"]["total_ms"] * 1.02
 
 
 def test_serving_programs_are_named(mesh8, caplog, capsys):
@@ -921,6 +938,237 @@ def test_engine_matches_static_batching_causal(mesh8):
     eos, pad = lm.config.eos_token_id, lm.config.pad_token_id
     for got, want in zip(outs, ref):
         assert trim_eos(got, eos, pad) == trim_eos(want, eos, pad)
+
+
+# ---------------------------- the round's order: dispatch ahead, fetch behind (PR 42)
+
+# one engine a kind of slot state, at toy sizes: more requests than slots, waves
+# smaller than the slot count, so admissions and evictions fall mid-stream
+RUN_AHEAD_KINDS = {
+    "flat-seq2seq": ("bart-test", True, {}),
+    "flat-causal": ("llama-test", False, {}),
+    "paged": ("llama-test", False, {"paged_kv": True, "kv_block_size": 8}),
+    "paged-prefix": ("llama-test", False, {"paged_kv": True, "kv_block_size": 8, "prefix_cache": True,
+                                           "prefix_cache_budget_gib": 0.01}),
+    "retention-state": ("brumby-test", False, {}),
+    "conv-state-and-experts": ("lfm2-moe-test", False, {}),
+}
+
+
+@pytest.fixture(scope="module")
+def ahead_rigs():
+    """``rig(kind)`` -> (engine, params, requests, budgets), built once a kind."""
+    import dataclasses
+
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine
+
+    built = {}
+
+    def rig(kind):
+        if kind not in built:
+            name, seq2seq, modes = RUN_AHEAD_KINDS[kind]
+            lm = load_model(name)
+            config = lm.config
+            if not seq2seq:
+                # the tests below choose the end-of-sequence id themselves (a host-side
+                # comparison: no program reads it)
+                config = dataclasses.replace(config, eos_token_id=None)
+            eng = ServingEngine(
+                lm.module, config, None,
+                ServeConfig(max_slots=3, prefill_batch=2, max_new_tokens=8, max_source_length=16,
+                            log_every_steps=0, request_spans=False, **modes),
+                is_seq2seq=seq2seq,
+            )
+            rng = np.random.RandomState(11)
+            reqs = _requests(rng, 8, lo=3, hi=14, vocab=120)
+            if "prefix_cache" in modes:
+                # two requests that share a whole block with an earlier one, so a warm admission runs
+                reqs[0], reqs[1] = (list(rng.randint(4, 120, 12)) for _ in range(2))
+                reqs[5] = reqs[0][:8] + reqs[5][:4]
+                reqs[6] = reqs[1][:8] + reqs[6][:3]
+            budgets = [int(b) for b in rng.randint(2, 9, len(reqs))]
+            built[kind] = (eng, lm.init_params(0), reqs, budgets)
+        return built[kind]
+
+    return rig
+
+
+def _serve(eng, params, reqs, budgets, *, lockstep=False, late=3, eos=None):
+    """Serve ``reqs`` through a session, the last ``late`` submitted mid-stream;
+    ``lockstep`` pins the round to depth 0 (dispatch, fetch, emit: the oracle)."""
+    from distributed_llms_example_tpu.serving.engine import ServeSession
+
+    old_eos = eng.eos
+    eng.eos = eos if eos is not None else old_eos
+    try:
+        sess = eng.open(params)
+        if lockstep:
+            assert isinstance(ServeSession._depth, property)  # what the oracle overrides
+            sess.__class__ = type("LockstepSession", (ServeSession,), {"_depth": property(lambda self: 0)})
+        rids = [sess.submit(r, max_new=b) for r, b in zip(reqs[:-late], budgets[:-late])]
+        finished = []
+        for _ in range(4):
+            finished += sess.step()
+        rids += [sess.submit(r, max_new=b) for r, b in zip(reqs[-late:], budgets[-late:])]
+        while sess.has_work():
+            finished += sess.step()
+        stats = sess.finalize()
+        assert sorted(finished) == rids  # every request is reported finished, once
+        return [list(sess.outputs[r]) for r in rids], stats
+    finally:
+        eng.eos = old_eos
+
+
+@pytest.mark.parametrize("kind", sorted(RUN_AHEAD_KINDS))
+def test_running_ahead_serves_the_lockstep_tokens(ahead_rigs, kind, capsys):
+    """(a) + (c): a session that dispatches round n+1 before it fetches round n
+    serves, token for token, what the lockstep round serves (and, for the flat
+    seq2seq engine, what static batching does), through admissions and evictions
+    mid-stream; requests that end by budget discard nothing, and every round but
+    one a dispatch streak's first is dispatched ahead."""
+    eng, params, reqs, budgets = ahead_rigs(kind)
+    want, lock = _serve(eng, params, reqs, budgets, lockstep=True)
+    got, stats = _serve(eng, params, reqs, budgets)
+    assert got == want and [len(g) for g in got] == budgets
+    assert lock.rounds_ahead == 0 and lock.tokens_discarded == 0
+    assert stats.tokens_discarded == 0 and stats.decode_tokens == lock.decode_tokens
+    # a slot is free when its request's last token is EMITTED, a round after it was computed: with
+    # three slots for eight requests an admission may wait a round longer, never a token more
+    assert lock.decode_steps <= stats.decode_steps <= lock.decode_steps + len(reqs)
+    # a steady run: the device is never without a queued program after the first dispatch
+    assert stats.rounds_ahead == stats.decode_steps - 1
+    if kind == "flat-seq2seq":
+        from distributed_llms_example_tpu.serving.engine import static_batch_generate, trim_eos
+
+        lm = load_model("bart-test")
+        ref = static_batch_generate(lm.module, lm.config, None, params, reqs, max_new_tokens=8, width=16, batch=4)
+        eos, pad = lm.config.eos_token_id, lm.config.pad_token_id
+        for g, w in zip(got, ref):
+            assert trim_eos(g, eos, pad) == trim_eos(w, eos, pad)[: len(g)]
+    if kind == "paged-prefix":
+        assert stats.prefix_hits >= 2
+    capsys.readouterr()
+
+
+@pytest.mark.parametrize("kind", sorted(RUN_AHEAD_KINDS))
+@pytest.mark.parametrize("where", ["mid-stream", "first-token"])
+def test_a_request_that_ends_by_eos_is_found_one_round_late(ahead_rigs, kind, where, capsys):
+    """(b): the token the round in flight computed for a slot whose request had
+    ended by EOS is in no output and is counted; the slot is admitted again and
+    the next request there is served right."""
+    eng, params, reqs, budgets = ahead_rigs(kind)
+    budgets = [max(b, 4) for b in budgets]
+    free, _ = _serve(eng, params, reqs, budgets, lockstep=True)
+    # an id that ends an early request where the case wants it: its first token (the wave's), or a later one
+    if where == "first-token":
+        eos = free[0][0]
+    else:
+        eos = next(o[t] for o in free[:3] for t in range(1, len(o) - 1) if o[t] not in o[:t])
+    cut = [o[: o.index(eos) + 1] if eos in o else o for o in free]
+    if where == "first-token":
+        assert len(cut[0]) == 1
+    else:
+        assert any(1 < len(c) < len(o) for c, o in zip(cut[:3], free))
+    want, lock = _serve(eng, params, reqs, budgets, lockstep=True, eos=eos)
+    got, stats = _serve(eng, params, reqs, budgets, eos=eos)
+    # the oracle with that id is the free run cut at it (greedy: a request's tokens do not depend on its neighbours)
+    assert want == cut and got == cut
+    ended_early = sum(1 for o, b in zip(cut, budgets) if o[-1] == eos and len(o) < b)
+    assert ended_early >= 1 and stats.tokens_discarded == ended_early and lock.tokens_discarded == 0
+    assert stats.decode_tokens == lock.decode_tokens
+    capsys.readouterr()
+
+
+def test_dispatch_opens_before_the_fetch_of_the_round_before_closes(serve_rig, capsys):
+    """(d): the recorder's order.  ``decode_dispatch`` of round n+1 opens before
+    ``token_fetch`` of round n closes; at every fetch one more round has been
+    dispatched than fetched, until the last round fetches with nothing behind it."""
+    clock = _Clock()
+    sess, notes = _traced_session(serve_rig, clock)
+    rng = np.random.RandomState(12)
+    for r in _requests(rng, 3):
+        sess.submit(r, max_new=5)
+    order = []
+    real_span = sess.spans.span
+
+    def ticking(name):
+        clock.t += 1.0  # every span opens at an instant of its own
+        return real_span(name)
+
+    sess.spans.span = ticking
+    while sess.has_work():
+        sess.step()
+        clock.t += 1.0
+    for e in notes.events:
+        if e["name"] in ("serve/decode_dispatch", "serve/token_fetch"):
+            order.append(e)
+    names = [e["name"].split("/")[1] for e in order]
+    assert names == ["decode_dispatch"] + ["decode_dispatch", "token_fetch"] * 4 + ["token_fetch"]
+    dispatches = [e for e in order if e["name"].endswith("decode_dispatch")]
+    fetches = [e for e in order if e["name"].endswith("token_fetch")]
+    for n, fetch in enumerate(fetches[:-1]):
+        # round n's fetch closes after round n+1's dispatch opened (and closed: one thread)
+        assert dispatches[n + 1]["start"] < fetch["end"] and dispatches[n]["end"] < dispatches[n + 1]["start"]
+    assert [d["stats"]["ahead"] for d in dispatches] == [0, 1, 1, 1, 1]
+    assert sess.finalize().decode_steps == 5
+    capsys.readouterr()
+
+
+@pytest.mark.parametrize("how", ["has_work", "finalize"])
+def test_no_in_flight_token_is_lost(ahead_rigs, how, capsys):
+    """(d): a round in flight keeps the session at work, and closing the books
+    with one in flight fetches and serves it first."""
+    eng, params, reqs, budgets = ahead_rigs("flat-causal")
+    want, _ = _serve(eng, params, reqs[:2], [5, 5], lockstep=True, late=1)
+    if how == "finalize":
+        sess = eng.open(params)
+        rids = [sess.submit(r, max_new=5) for r in reqs[:2]]
+        sess.step()  # the wave (token 1) and round 1 dispatched
+        sess.step()  # round 2 dispatched, round 1 fetched: two tokens held, a third in flight
+        assert [len(sess.outputs[r]) for r in rids] == [2, 2] and sess._inflight
+        sess.finalize()
+        assert [list(sess.outputs[r]) for r in rids] == [w[:3] for w in want] and not sess._inflight
+    else:
+        # an id that ends the request mid-stream: the round dispatched before it was seen is then in flight for nobody
+        at = next(j for j in range(1, 4) if want[0][j] not in want[0][:j])
+        old, eng.eos = eng.eos, want[0][at]
+        try:
+            sess = eng.open(params)
+            rid = sess.submit(reqs[0], max_new=5)
+            for _ in range(at):  # the wave (token 1) and round 1 dispatched; then a round dispatched and one fetched
+                assert sess.step() == []
+            assert sess.step() == [rid]  # round at + 1 dispatched, round at fetched: the EOS
+            assert not sess.active.any() and not sess.pending
+            assert sess.has_work()  # the round dispatched before the EOS was seen is still to fetch
+            assert sess.step() == [] and not sess.has_work()
+            stats = sess.finalize()
+            assert list(sess.outputs[rid]) == want[0][: at + 1] and stats.tokens_discarded == 1
+        finally:
+            eng.eos = old
+    capsys.readouterr()
+
+
+def test_a_speculative_session_stays_in_lockstep(capsys):
+    """(e): a verify round is built from the fetched tokens (``outputs[rid][-1]``,
+    the n-gram history), so it is dispatched and fetched in the same round."""
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine
+
+    lm = load_model("llama-test")
+    params = lm.init_params(0)
+    rng = np.random.RandomState(13)
+    reqs = _requests(rng, 5, lo=3, hi=14, vocab=120)
+    serve = dict(max_slots=2, prefill_batch=2, max_new_tokens=8, max_source_length=16, log_every_steps=0,
+                 request_spans=False)
+    plain = ServingEngine(lm.module, lm.config, None, ServeConfig(**serve), is_seq2seq=False)
+    spec = ServingEngine(lm.module, lm.config, None, ServeConfig(spec_tokens=2, **serve), is_seq2seq=False)
+    want = plain.generate(params, reqs)
+    # (two slots that fill and end together: a round with every last token in flight dispatches nothing)
+    assert 0 < plain.last_stats.rounds_ahead < plain.last_stats.decode_steps
+    got = spec.generate(params, reqs)
+    assert got == want
+    assert spec.last_stats.rounds_ahead == 0 and spec.last_stats.tokens_discarded == 0
+    assert spec.last_stats.spec_steps == spec.last_stats.decode_steps > 0
+    capsys.readouterr()
 
 
 def test_engine_validates_composition_and_shards():

@@ -90,6 +90,7 @@ def measure(session, run: Run, rate_rps: float, seconds: float) -> dict:
     close = out["t0"] + seconds
     tokens_in_window = sum(1 for r in rows for t in r["tokens_at"] if t <= close)
     waves = [dt for dt, admitted in out["rounds"] if admitted]
+    seen, admitted = [r["rounds_at"] for r in done], [n for _, n in out["rounds"]]
     busy = sum(dt for dt, _ in out["rounds"])
     return {
         "rows": rows, "prompts": prompts, "done": done, "ttft_s": ttft, "gaps_s": gaps, "late_s": late,
@@ -112,6 +113,10 @@ def measure(session, run: Run, rate_rps: float, seconds: float) -> dict:
             [(r["tokens_at"][0] - r["arrival"]) if r["tokens_at"] else None for r in rows],
             list(arrivals), out["wall_s"]),
         "share_of_rounds_with_a_wave": len(waves) / max(1, len(out["rounds"])),
+        # of the gaps ``gap_p95_ms`` is taken over (the completed requests'), those that waited out a wave
+        "share_of_gaps_with_a_wave": loadgen.share_of_gaps_with_a_wave(seen, admitted),
+        # ... and a wave of several requests, which runs the program of ``prefill_batch`` rows
+        "share_of_gaps_with_a_full_wave": loadgen.share_of_gaps_with_a_wave(seen, admitted, of_at_least=2),
         "wave_ms_p50": stats.percentile(waves, 0.5) * 1e3 if waves else None,
     }
 
@@ -164,11 +169,14 @@ def run(run: Run) -> dict:
               "gap_ms": {k: v * (1e3 if k != "n" else 1) for k, v in stats.summary(m["gaps_s"]).items()},
               "late_ms": {k: v * (1e3 if k != "n" else 1) for k, v in stats.summary(m["late_s"]).items()},
               "rounds": len(m["rounds"]), "share_of_rounds_with_a_wave": m["share_of_rounds_with_a_wave"],
+              "share_of_gaps_with_a_wave": m["share_of_gaps_with_a_wave"],
+              "share_of_gaps_with_a_full_wave": m["share_of_gaps_with_a_full_wave"], "wave_ms_p50": m["wave_ms_p50"],
               "setup_pieces_s": {"imports_and_device": t_driver - run.t_start,
                                  "engine_open_with_compile": t_open - t_driver, "warm_up_requests": t_warm - t_open}})
 
     layers = {"cell": cell, "config": cfg, "peaks": run.peaks, "step_times": m["rounds"],
-              "late_s": m["late_s"], "trace": None}
+              "late_s": m["late_s"], "share_of_gaps_with_a_wave": m["share_of_gaps_with_a_wave"],
+              "share_of_gaps_with_a_full_wave": m["share_of_gaps_with_a_full_wave"], "trace": None}
     if run.trace:
         with profiled(run, ANNOTATIONS, "serve_step", layers):
             measure(session, run, rate, float(cell.recipe("trace_seconds", 2.0)))

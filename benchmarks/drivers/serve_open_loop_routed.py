@@ -85,12 +85,14 @@ def run(run: Run) -> dict:
               "slots_live_peak": m["slots_live_peak"], "by_5s": m["by_5s"],
               "ttft_ms": ms(m["ttft_s"]), "gap_ms": ms(m["gaps_s"]), "late_ms": ms(m["late_s"]),
               "rounds": len(m["rounds"]), "share_of_rounds_with_a_wave": m["share_of_rounds_with_a_wave"],
-              "wave_ms_p50": m["wave_ms_p50"],
+              "share_of_gaps_with_a_wave": m["share_of_gaps_with_a_wave"],
+              "share_of_gaps_with_a_full_wave": m["share_of_gaps_with_a_full_wave"], "wave_ms_p50": m["wave_ms_p50"],
               "setup_pieces_s": {"imports_and_device": t_driver - run.t_start,
                                  "engine_open_with_compile": t_open - t_driver, "warm_up_requests": t_warm - t_open}})
 
     layers = {"cell": cell, "config": cfg, "peaks": run.peaks, "step_times": m["rounds"],
-              "late_s": m["late_s"], "trace": None}
+              "late_s": m["late_s"], "share_of_gaps_with_a_wave": m["share_of_gaps_with_a_wave"],
+              "share_of_gaps_with_a_full_wave": m["share_of_gaps_with_a_full_wave"], "trace": None}
     if run.trace:
         with profiled(run, ANNOTATIONS, "serve_step", layers):
             base.measure(session, run, rate, float(cell.recipe("trace_seconds", 2.0)))

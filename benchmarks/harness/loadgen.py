@@ -49,18 +49,20 @@ def drive(session: Any, prompts: list, budgets: list, arrivals: np.ndarray, *,
     """Submit request ``i`` when ``arrivals[i]`` has passed, never waiting on a
     completion; otherwise step the session.  Arrivals stop at ``seconds``; the
     loop then drains for at most ``drain_seconds``.  Returns per-request rows
-    (scheduled arrival, submit instant, the instant each token was seen),
-    per-round (duration, admitted?) pairs and, beside them, the slots that
+    (scheduled arrival, submit instant, the instant each token was seen and
+    the round that showed it),
+    per-round (duration, requests admitted: 0 in a plain round) pairs and, beside them, the slots that
     held a request in each round, and every ``PROBE_EVERY_S`` a probe
     (offset, rounds so far, this process's CPU seconds) for the log."""
     n = len(prompts)
     t0 = clock()
     due = [t0 + float(a) for a in arrivals]
-    rows = [{"index": i, "arrival": due[i], "submit": None, "tokens_at": [], "budget": budgets[i], "rid": None}
+    rows = [{"index": i, "arrival": due[i], "submit": None, "tokens_at": [], "rounds_at": [],
+             "budget": budgets[i], "rid": None}
             for i in range(n)]
     by_rid: dict[int, int] = {}
     live: set[int] = set()
-    rounds: list[tuple[float, bool]] = []
+    rounds: list[tuple[float, int]] = []
     slots_live: list[int] = []
     probes: list[tuple[float, int, float]] = []
     i = 0
@@ -85,12 +87,13 @@ def drive(session: Any, prompts: list, budgets: list, arrivals: np.ndarray, *,
             with jax.profiler.TraceAnnotation("serve_step"):
                 finished = session.step()
             te = clock()
-            rounds.append((te - ts, session.queue_depth < waiting))
+            rounds.append((te - ts, waiting - session.queue_depth))
             slots_live.append(len(live) - session.queue_depth)  # submitted, not queued: in a slot this round
             for rid in list(live):
                 row = rows[by_rid[rid]]
                 new = len(session.outputs[rid]) - len(row["tokens_at"])
                 row["tokens_at"].extend([te] * new)
+                row["rounds_at"].extend([len(rounds) - 1] * new)
             live.difference_update(finished)
         else:
             wait = due[i] - clock()
@@ -99,6 +102,32 @@ def drive(session: Any, prompts: list, budgets: list, arrivals: np.ndarray, *,
     probes.append((clock() - t0, len(rounds), time.process_time()))
     return {"t0": t0, "wall_s": clock() - t0, "rows": rows, "rounds": rounds, "slots_live": slots_live,
             "probes": probes}
+
+
+def share_of_gaps_with_a_wave(rounds_at: Sequence[Sequence[int]], admitted: Sequence[int], *,
+                              of_at_least: int = 1) -> float | None:
+    """Of the inter-token gaps of the given requests, the share that hold a
+    prefill wave of at least ``of_at_least`` requests.  ``rounds_at[r]`` is,
+    token by token, the round that showed request ``r`` the token;
+    ``admitted[k]`` is how many requests round ``k`` admitted.  The gap between
+    two tokens seen in rounds ``a`` and ``b`` holds a wave when one of the rounds
+    ``a + 1 .. b`` admitted: every live slot waits out every wave, so one wave
+    under ``n`` slots that are between two tokens is ``n`` such gaps.  This,
+    not the share of ROUNDS with a wave, is what places the 95th percentile of
+    the gaps: above 5 % it is a wave round's gap.  A wave of two requests or
+    more runs the program of ``prefill_batch`` rows: above 5 % of gaps under
+    THOSE, the 95th percentile is a full wave round's.  (A seq2seq wave's
+    device time lands in the round AFTER the one that admitted; that round's
+    gaps are as many, to a slot.)  None without a gap."""
+    waves_up_to = [0]
+    for n in admitted:
+        waves_up_to.append(waves_up_to[-1] + (n >= of_at_least))
+    gaps = held = 0
+    for seen in rounds_at:
+        for a, b in zip(seen, seen[1:]):
+            gaps += 1
+            held += waves_up_to[b + 1] > waves_up_to[a + 1]
+    return held / gaps if gaps else None
 
 
 def queue_growing(ttft_s: Sequence[float | None], arrivals_s: Sequence[float], wall_s: float, *,

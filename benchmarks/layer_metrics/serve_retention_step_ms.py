@@ -1,7 +1,7 @@
 """Device milliseconds of the power-retention steps in one decode round:
 summed durations of the ``retention_step`` custom calls (``ops/retention.py``:
-the Pallas kernel that scales, updates and reads every slot's state, one call
-a layer) inside one run of the program ``jit_serve_decode_step``, median over
+the Pallas kernel that scales, updates and reads each live slot's state and
+steps over an idle one's, one call a layer) inside one run of the program ``jit_serve_decode_step``, median over
 the traced window's runs.
 
 How the trace shows them: a custom call named after the jitted function that

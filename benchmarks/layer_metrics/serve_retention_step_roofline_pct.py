@@ -1,7 +1,8 @@
 """The retention steps' share of their bandwidth roofline in a decode round:
 the bytes the state of every slot STREAMED must move (``slots_streamed``, the
-counter on ``serve/decode_dispatch``: the flat cache streams all of them, live
-or not; each slot's state is read once and written once a layer,
+counter on ``serve/decode_dispatch``: the round's LIVE slots where the kernel
+walks its live list, as it has since PR 36, and every slot of the flat cache
+where it does not; each such slot's state is read once and written once a layer,
 ``flops/<family>.py`` ``retention_step_bytes``: the 8,256 rows a KV head the
 mathematics needs, not the rows a layout pads them to) over the chip's HBM
 bandwidth, over ``serve_retention_step_ms``.  Bandwidth-bound: a step does

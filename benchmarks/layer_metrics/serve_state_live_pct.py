@@ -1,8 +1,9 @@
 """Share of the state traffic of a decode round that serves a request:
 100 x ``slots_live`` / ``slots_streamed``, the counters the engine sets on
 each round's ``serve/decode_dispatch`` span, median over the traced window's
-rounds.  The flat cache streams every slot's state every round; what the idle
-ones cost, every live slot waits for."""
+rounds.  100 where the step walks the live slots alone (the retention kernel's
+live list, since PR 36); where a program streams every slot of the flat cache,
+the idle ones' share is what every live slot waits for."""
 
 import statistics
 

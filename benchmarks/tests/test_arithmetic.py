@@ -56,6 +56,112 @@ def test_knee_rule():
     assert loadgen.queue_growing([0.01, None, 0.01, 0.01], [], 10.0)
 
 
+@pytest.mark.parametrize("rounds_at, admitted, want, want_full", [
+    # no wave in the window: no gap holds one
+    ([[0, 1, 2, 3], [1, 2, 3]], [0] * 4, 0.0, 0.0),
+    # one wave (round 2) under n = 2 slots that are between two tokens, N = 8 gaps in all: n / N
+    ([[0, 1, 2, 3, 4], [1, 2, 3], [3, 4, 5]], [0, 0, 1, 0, 0, 0], 2 / 8, 0.0),
+    # ... and a wave of three requests (round 4) under 2 slots: 4 of 8 hold a wave, 2 of 8 a full one
+    ([[0, 1, 2, 3, 4], [1, 2, 3], [3, 4, 5]], [0, 0, 1, 0, 3, 0], 4 / 8, 2 / 8),
+    # the request the wave admits sees its FIRST token in round 2: a first token ends no gap
+    ([[0, 1, 2, 3], [2, 3]], [0, 0, 1, 0], 1 / 4, 0.0),
+    # a gap that spans rounds (a slot left out of round 2's mask) holds the wave of any round inside it
+    ([[0, 3]], [0, 0, 2, 0], 1.0, 1.0),
+    # two waves inside one gap are one gap; two tokens seen in one round are a gap that holds none
+    ([[0, 3, 3, 4]], [0, 1, 4, 1, 0], 1 / 3, 1 / 3),
+    ([[5]], [0] * 6, None, None), ([], [], None, None),
+])
+def test_share_of_gaps_with_a_wave_on_a_hand_made_window(rounds_at, admitted, want, want_full):
+    """What places ``gap_p95_ms``: of the gaps it is taken over, those between whose tokens a round admitted
+    (and, for the second flip, admitted two requests or more)."""
+    for got, expected in ((loadgen.share_of_gaps_with_a_wave(rounds_at, admitted), want),
+                          (loadgen.share_of_gaps_with_a_wave(rounds_at, admitted, of_at_least=2), want_full)):
+        assert got is None if expected is None else got == pytest.approx(expected)
+
+
+class _Session:
+    """The least session ``loadgen.drive`` can drive: a queued request is admitted by the next round (a wave
+    round, which gives it its first token), every request in a slot gets one token a round."""
+
+    def __init__(self):
+        self.outputs, self.left, self.queue = {}, {}, []
+
+    queue_depth = property(lambda self: len(self.queue))
+
+    def submit(self, prompt, max_new, arrival):
+        rid = len(self.outputs)
+        self.outputs[rid] = []
+        self.queue.append((rid, max_new))
+        return rid
+
+    def has_work(self):
+        return bool(self.queue or self.left)
+
+    def step(self):
+        self.left.update(self.queue)
+        self.queue = []
+        for rid in self.left:
+            self.outputs[rid].append(7)
+            self.left[rid] -= 1
+        finished = [rid for rid, n in self.left.items() if n == 0]
+        for rid in finished:
+            del self.left[rid]
+        return finished
+
+
+def test_the_drive_loop_says_which_round_showed_each_token():
+    """Three requests of four tokens arriving at 0, 2.5 and 100 ticks of a clock that moves one tick (a ms) a reading:
+    the second arrives while the first is between tokens (one of its gaps holds that wave), the third after
+    both have gone (its wave stalls nobody): 1 gap of 9, and no round admitted two."""
+    ticks = iter(range(10**6))
+    out = loadgen.drive(_Session(), [[1]] * 3, [4] * 3, np.array([0.0, 2.5e-3, 0.1]), seconds=0.2, drain_seconds=5e-3,
+                        clock=lambda: next(ticks) * 1e-3)
+    rows, admitted = out["rows"], [a for _, a in out["rounds"]]
+    assert [len(r["tokens_at"]) for r in rows] == [4, 4, 4] and sorted(admitted)[-4:] == [0, 1, 1, 1]
+    assert all(len(r["rounds_at"]) == len(r["tokens_at"]) and admitted[r["rounds_at"][0]] for r in rows)
+    assert all(out["rounds"][k][0] > 0 and t0 <= t1 for r in rows for k in r["rounds_at"]
+               for t0, t1 in zip(r["tokens_at"], r["tokens_at"][1:]))
+    assert loadgen.share_of_gaps_with_a_wave([r["rounds_at"] for r in rows], admitted) == pytest.approx(1 / 9)
+    assert loadgen.share_of_gaps_with_a_wave([r["rounds_at"] for r in rows], admitted, of_at_least=2) == 0.0
+
+
+def test_the_wave_gap_shares_are_read_from_the_windows_summary():
+    from benchmarks.harness import spec as spec_mod
+
+    reader = spec_mod.load_module("layer_metrics", "serve_wave_gap_share_pct")
+    full = spec_mod.load_module("layer_metrics", "serve_full_wave_gap_share_pct")
+    assert full.read({"share_of_gaps_with_a_full_wave": 0.0117}) == pytest.approx(1.17)
+    assert full.read({"share_of_gaps_with_a_full_wave": 0.0}) == 0.0 and full.read({}) is None
+    # which cells report it is BENCHMARK.json's to say: those whose traffic has a program of several rows
+    bench = spec_mod.load_benchmark()
+    listed = next(m["workloads"] for m in bench["per_layer"] if m["name"] == "serve_full_wave_gap_share_pct")
+    several = [w["name"] for w in bench["workloads"] if w["name"].endswith("serve-steady")
+               and spec_mod.Cell(bench, w["name"]).recipe("prefill_batch") > 1]
+    assert listed == several
+    # the summary a mellum run of PR 44 left (4.6 rps, the old rate): on the 5 % line
+    assert reader.read({"share_of_gaps_with_a_wave": 0.0503, "step_times": []}) == pytest.approx(5.03)
+    assert reader.read({"share_of_gaps_with_a_wave": 0.0}) == 0.0  # gaps and no wave among them: a reading
+    assert reader.read({"share_of_gaps_with_a_wave": None}) is None and reader.read({}) is None  # no gap, no run
+
+
+def test_the_replay_draws_the_harness_budgets_and_counts_its_shares():
+    import importlib.util
+
+    from benchmarks.harness import loadgen, spec as spec_mod
+
+    path = os.path.join(spec_mod.BENCH_DIR, "tools", "replay.py")
+    replay = importlib.util.module_from_spec(s := importlib.util.spec_from_file_location("replay_tool", path))
+    s.loader.exec_module(replay)
+    assert replay.budgets_for(7, 40, (32, 128)) == loadgen.requests(7, 40, 16, (32, 128))[1]
+    sizes = dict(output_tokens=(4, 8), prefill_batch=4, max_slots=8, wave_ms=(20.0, 80.0), jitter=0.0)
+    flat = replay.replay(3, 2.0, 20.0, plain_ms=(5.0, 0.0), **sizes)
+    # far under the first line every gap is a plain round's; a round that costs by its live slots is dearer
+    assert flat["gap_p95_ms"] == pytest.approx(5.0) and flat["unfinished"] == 0 and 0 < flat["gaps_pct"] < 5
+    assert replay.replay(3, 2.0, 20.0, plain_ms=(5.0, 1.0), **sizes)["gap_p95_ms"] > 6.0
+    # one request a wave at most: no gap holds a full wave
+    assert replay.replay(3, 2.0, 20.0, plain_ms=(5.0, 0.0), **{**sizes, "prefill_batch": 1})["full_pct"] == 0.0
+
+
 def test_comparison_helpers():
     ref = {("a", None): 1.0, ("b", 0): 2.0, ("b", 1): 1e-9, ("c", None): 4.0}
     prog = {("a", None): 1.1, ("b", 0): 2.0, ("b", 1): 0.1, ("c", None): 4.0}

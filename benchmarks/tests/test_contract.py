@@ -65,6 +65,19 @@ def test_every_named_file_is_there(bench):
         assert os.path.exists(os.path.join(bdir, "layer_metrics", m["name"] + ".py")), m["name"]
 
 
+def test_a_serve_cells_rate_is_its_share_of_its_swept_knee(bench):
+    """One rule for every cell that offers load at a fixed rate: ``rate_rps`` = ``knee_share`` x ``knee_rps`` to
+    one decimal, the share at most four fifths, and ``knee_from`` says which sweep the knee is from."""
+    bdir = os.path.join(ROOT, "benchmarks", "traffic")
+    files = sorted({w["traffic"] for w in bench["workloads"]})
+    paced = {t: x for t in files for x in [json.load(open(os.path.join(bdir, t + ".json")))] if "rate_rps" in x}
+    assert len(paced) >= 4
+    for name, traffic in paced.items():
+        assert 0 < traffic["knee_share"] <= 0.8, name
+        assert traffic["rate_rps"] == round(traffic["knee_share"] * traffic["knee_rps"], 1), name
+        assert traffic["knee_from"], name
+
+
 def _check_line(bench, cell, line, trace):
     assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
     assert line["correct"] is False and line["rehearsal"] is True  # a rehearsal can never pass

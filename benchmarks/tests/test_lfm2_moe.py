@@ -81,7 +81,10 @@ def test_forward_returns_the_positions_that_predict_the_served_tokens(ref, toy, 
     moved = np.asarray(fwd(jnp.asarray(ids), jnp.asarray(other)))
     np.testing.assert_array_equal(moved[:, :3], out[:, :3])
     assert np.abs(moved[:, 3:] - out[:, 3:]).max() > 1e-3
-    said = [json.loads(x) for x in capsys.readouterr().out.splitlines() if x.startswith("{")]
+    # the reference's own lines: once an earlier test of the process has built an engine, the program's
+    # set-up account says on the same stream what compiles after it (``late_compile``)
+    said = [x for x in map(json.loads, (x for x in capsys.readouterr().out.splitlines() if x.startswith("{")))
+            if "near_tie_share" in x]
     assert said and all(x["positions"] == 10 and 0 <= x["near_tie_share"] <= 1 for x in said)  # every call says it
 
 

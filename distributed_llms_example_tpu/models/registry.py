@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from distributed_llms_example_tpu.models import t5 as t5_mod
 from distributed_llms_example_tpu.models.bart import BartConfig, BartForConditionalGeneration
 from distributed_llms_example_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
+from distributed_llms_example_tpu.models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
 from distributed_llms_example_tpu.models.convert import convert_state_dict
 from distributed_llms_example_tpu.models.lfm2 import Lfm2Config, Lfm2ForCausalLM
 from distributed_llms_example_tpu.models.mellum import MellumConfig, MellumForCausalLM
@@ -158,6 +159,23 @@ MELLUM_CONFIGS: dict[str, MellumConfig] = {
         param_dtype=None,
     ),
     "mellum2-12b-a2.5b": MellumConfig(),
+}
+
+
+# Falcon-H1 (models/falcon_h1.py): a Mamba-2 mixer and GQA side by side in every
+# block, so a layer's cache entry holds K/V, a matrix state and convolution taps.
+# Sizes and the µP multipliers from tiiuae/Falcon-H1-34B-Instruct's config.json;
+# pad/eos are the byte tokenizer's.
+FALCON_H1_CONFIGS: dict[str, FalconH1Config] = {
+    # 2 groups of 2 mixer heads, 5 query heads a KV head as published; every
+    # multiplier differs from 1 (the published ones are kept)
+    "falcon-h1-test": FalconH1Config(
+        vocab_size=256, hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+        num_attention_heads=10, num_key_value_heads=2, head_dim=16,
+        mamba_d_ssm=64, mamba_n_heads=4, mamba_d_head=16, mamba_d_state=32, mamba_n_groups=2,
+        mamba_chunk_size=16, max_position_embeddings=256, attention_in_multiplier=0.8, param_dtype=None,
+    ),
+    "falcon-h1-34b": FalconH1Config(),
 }
 
 
@@ -304,6 +322,9 @@ def _build(family: str, cfg: Any, dtype: jnp.dtype, remat: bool, params: Any = N
     if family == "mellum":
         module = MellumForCausalLM(cfg, dtype=dtype, remat=remat, remat_policy=remat_policy)
         return LoadedModel("mellum", cfg, module, params, is_seq2seq=False)
+    if family == "falcon_h1":
+        module = FalconH1ForCausalLM(cfg, dtype=dtype, remat=remat, remat_policy=remat_policy)
+        return LoadedModel("falcon_h1", cfg, module, params, is_seq2seq=False)
     raise ValueError(f"unsupported model family {family!r}")
 
 
@@ -405,8 +426,10 @@ def load_model(
         return _build("brumby", _apply_impl(BRUMBY_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
     if short in MELLUM_CONFIGS:
         return _build("mellum", _apply_impl(MELLUM_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
+    if short in FALCON_H1_CONFIGS:
+        return _build("falcon_h1", _apply_impl(FALCON_H1_CONFIGS[short]), dtype, remat, remat_policy=remat_policy)
     known = (sorted(T5_CONFIGS) + sorted(BART_CONFIGS) + sorted(LLAMA_CONFIGS) + sorted(LFM2_CONFIGS)
-             + sorted(BRUMBY_CONFIGS) + sorted(MELLUM_CONFIGS))
+             + sorted(BRUMBY_CONFIGS) + sorted(MELLUM_CONFIGS) + sorted(FALCON_H1_CONFIGS))
     raise ValueError(
         f"unknown model {name_or_path!r}: not a local checkpoint dir and not one of {known}"
     )
@@ -421,5 +444,6 @@ __all__ = [
     "LFM2_CONFIGS",
     "BRUMBY_CONFIGS",
     "MELLUM_CONFIGS",
+    "FALCON_H1_CONFIGS",
     "t5_mod",
 ]

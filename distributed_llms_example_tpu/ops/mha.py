@@ -528,6 +528,9 @@ class MultiHeadAttention(nn.Module):
     window: int | None = None
     # YaRN's scaling of the rotation (``rope_cos_sin``); None = the plain one
     rope_yarn: YarnRope | None = None
+    # a fixed factor on every key, ``k = key_multiplier x W_k s`` (Falcon-H1's
+    # µP ``key_multiplier``); 1.0 = the model has none, and nothing is traced
+    key_multiplier: float = 1.0
 
     @property
     def kv_heads(self) -> int:
@@ -557,10 +560,8 @@ class MultiHeadAttention(nn.Module):
         this, every decode step re-projects the full encoder output
         through k/v_proj — 2·S·d_model² FLOPs per layer per token, ~100×
         the rest of the step for src 1024 summarization."""
-        return (
-            self._split(self.k_proj(kv_hidden), self.kv_heads),
-            self._split(self.v_proj(kv_hidden), self.kv_heads),
-        )
+        k = self._split(self.k_proj(kv_hidden), self.kv_heads)
+        return (k * self.key_multiplier if self.key_multiplier != 1.0 else k), self._split(self.v_proj(kv_hidden), self.kv_heads)
 
     @nn.compact
     def _cache_kv(self, key: jnp.ndarray, value: jnp.ndarray,
@@ -720,6 +721,8 @@ class MultiHeadAttention(nn.Module):
             kv_src = hidden if kv_hidden is None else kv_hidden
             k = self._split(self.k_proj(kv_src), self.kv_heads)
             v = self._split(self.v_proj(kv_src), self.kv_heads)
+            if self.key_multiplier != 1.0:
+                k = k * self.key_multiplier
             if self.qk_norm_eps is not None:
                 q, k = self.q_norm(q), self.k_norm(k)
 

@@ -113,6 +113,10 @@ DEFAULT_RULES: list[tuple[str, P]] = [
     # row-parallel; the (d, taps) depthwise weight is small and replicated
     (r"conv/in_proj/kernel", P("fsdp", "tensor")),
     (r"conv/out_proj/kernel", P("tensor", "fsdp")),
+    # Mamba-2 mixer (Falcon-H1): the same splits; its per-head vectors and the
+    # (conv_dim, taps) depthwise weight are small and replicated
+    (r"mixer/in_proj/kernel", P("fsdp", "tensor")),
+    (r"mixer/out_proj/kernel", P("tensor", "fsdp")),
     # MoE: stacked expert weights — experts over the dedicated ``expert``
     # axis (GSPMD lowers the dispatch/combine einsums to the expert
     # all-to-all), megatron column/row splits over ``tensor`` WITHIN each
@@ -168,6 +172,12 @@ CACHE_RULES: list[tuple[str, P]] = [
     # the shard that holds the head's projections
     (r"retention_state$", P(("data", "fsdp", "expert"), "tensor", None, None, None)),
     (r"retention_norm$", P(("data", "fsdp", "expert"), "tensor", None, None)),
+    # a state-space mixer's state (models/falcon_h1.py): (batch, heads, state,
+    # head size), no length axis; a head's state never leaves the shard that
+    # holds the head.  Its ``conv_state`` is the rule above: the convolution is
+    # depthwise, so any split of its channels x | B | C computes it where they
+    # lie (B and C belong to groups, not heads: the split after it is GSPMD's)
+    (r"ssm_state$", P(("data", "fsdp", "expert"), "tensor", None, None)),
     (r"cache_index$", P()),
 ]
 
@@ -253,7 +263,7 @@ def cache_leaf_spec(name: str, shape: tuple, mesh_axes: Any, kv_heads: int, *, p
     step pays collectives inside a head.  THE single definition of the
     serving cache layout: ``activation.constrain_cache`` and the engine's
     host placement both derive from it."""
-    if name in ("retention_state", "retention_norm") and not pool:  # (batch, kv_heads, rotations, ...)
+    if name in ("retention_state", "retention_norm", "ssm_state") and not pool:  # (batch, heads, ...): no length axis
         return P(_batch_axes_if_even(shape[0], mesh_axes), _tensor_if_even(shape[1], mesh_axes),
                  *([None] * (len(shape) - 2)))
     if len(shape) != 3:

@@ -329,6 +329,7 @@ CACHE_BYTES_KIND = {
     "conv_state": "conv_state_bytes",
     "retention_state": "retention_state_bytes",
     "retention_norm": "retention_state_bytes",
+    "ssm_state": "ssm_state_bytes",
 }
 
 
@@ -376,7 +377,8 @@ class ServingEngine:
             sorted({int(b) for b in self.serve.prefill_buckets if 0 < int(b) < self.W})
         ) + (self.W,)
         # a model whose cache holds state other than K/V (LFM2's conv state,
-        # Brumby's retention state), or K/V of a sliding window (Mellum's
+        # Brumby's retention state, Falcon-H1's state-space state and taps
+        # beside its K/V), or K/V of a sliding window (Mellum's
         # ``window_key`` / ``window_value``: a ring of the last positions),
         # serves on the flat cache only: the block pool pages K/V by cache
         # position (a state leaf has none; a window layer's blocks would have to
@@ -386,9 +388,9 @@ class ServingEngine:
         flat_only = None
         if getattr(config, "has_recurrent_state", False):
             flat_only = (
-                "a recurrent state (a convolution state beside K/V, or a retention "
-                "state), which the block pool cannot page and a rejected draft cannot "
-                "roll back"
+                "a recurrent state (a convolution state or a state-space state beside "
+                "K/V, or a retention state), which the block pool cannot page and a "
+                "rejected draft cannot roll back"
             )
         elif getattr(config, "has_window_cache", False):
             flat_only = (

@@ -67,9 +67,9 @@ def compiled_kernels(monkeypatch):
     precision is put back to jax's default, which is what the program runs
     with: other test files raise it to "highest" as they are imported, and
     Mosaic refuses an fp32-precision matmul on bf16 operands."""
-    from distributed_llms_example_tpu.ops import flash_attention, fused_dropout, fused_optim, retention
+    from distributed_llms_example_tpu.ops import flash_attention, fused_dropout, fused_optim, retention, ssm
 
-    for mod in (flash_attention, fused_dropout, fused_optim, retention):
+    for mod in (flash_attention, fused_dropout, fused_optim, retention, ssm):
         monkeypatch.setattr(mod, "_default_interpret", lambda: False)
     with jax.default_matmul_precision(None):
         yield
@@ -655,3 +655,100 @@ def test_mellum_cell_serving_programs_compile_and_fit(topo, one_chip, compiled_k
     assert "%gmm" in text and wave.memory_analysis().temp_size_in_bytes < 2.5e9, wave.memory_analysis()
     admit = eng._admit.lower(state, abstract(wave_cache), abstract(wave_mask), abstract(wave_first), i32(1)).compile()
     assert admit.memory_analysis().temp_size_in_bytes < 0.1e9 and admit.memory_analysis().alias_size_in_bytes >= kv_bytes
+
+
+# ---- falcon-h1-34b.serve-steady: the state-space step's kernel, and the cell's three serving programs, whole ----
+
+FALCON_SLOTS, FALCON_WAVE, FALCON_PROMPT, FALCON_NEW = 64, 4, 256, 256
+_SSM_STEP = re.compile(r"%ssm_step(?:\.\d+)? = \(f32\[64,32,1,128\]\{[^}]*\}, f32\[64,32,256,128\]\{[^}]*\}\) custom-call\(")
+# the live-slot list and its length lead the call's operands (scalar prefetch), the decays go whole
+# (64 x 32), and the state (operand 6, counted from them) is the result's buffer
+_SSM_STEP_OPERANDS = re.compile(
+    r"%ssm_step(?:\.\d+)? = .*operand_layout_constraints=\{s32\[64\]\{0\}, s32\[1\]\{0\}, f32\[2048\]\{0\}, "
+    r".*output_to_operand_aliasing=\{\{1\}: \(6, \{\}\)\}")
+_FALCON_PROMPT_ATTN = re.compile(r"%prompt_attn(?:\.\d+)? = \(bf16\[(\d),20,256,128\]\{[^}]*\}, ")
+
+
+def test_ssm_step_kernel_compiles_and_updates_the_state_in_place(one_chip, compiled_kernels):
+    """The decode kernel at the cell's shapes (64 slots, 32 heads of 128 in 2
+    groups, state (64, 32, 256, 128) float32) with a live-slot mask: Mosaic
+    takes the tiling, the turn of B and C and the scalar-prefetched list, the
+    custom call carries the kernel's name (what ``serve_ssm_step_ms`` looks
+    for), and the state is aliased to the result, not copied."""
+    from distributed_llms_example_tpu.ops import ssm
+
+    s_shape = ssm.state_shape(FALCON_SLOTS, 32, 128, 256)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((FALCON_SLOTS, 32, 128), BF16), ((FALCON_SLOTS, 32), F32), ((32,), F32), ((FALCON_SLOTS, 2, 256), BF16),
+        ((FALCON_SLOTS, 2, 256), BF16), ((32,), F32), (s_shape, F32), ((FALCON_SLOTS,), jnp.bool_))]
+    step = jax.jit(lambda *a: ssm.ssm_step(*a[:7], live=a[7]), donate_argnums=(6,)).lower(*args).compile()
+    text, mem = step.as_text(), step.memory_analysis()
+    assert len(_SSM_STEP.findall(text)) == 1, [ln[:160] for ln in text.splitlines() if "custom-call(" in ln]
+    assert len(_SSM_STEP_OPERANDS.findall(text)) == 1
+    assert mem.alias_size_in_bytes >= math.prod(s_shape) * 4 and mem.temp_size_in_bytes < 0.01e9, mem
+    assert not _large_copies(text, math.prod(s_shape))
+
+
+def test_falcon_h1_cell_serving_programs_compile_and_fit(topo, one_chip, compiled_kernels, monkeypatch):
+    """Decode step, prefill wave (1 and 4 rows) and admit at 64 slots x 256 + 256
+    tokens, four layers at the published widths, bfloat16 weights: THREE kinds of
+    leaf a layer in one slot cache; one ``ssm_step`` call a layer on the state as
+    it rests (1.07 GB, aliased, no copy of a state or K/V leaf) beside one decode
+    attention a layer (5 query heads a KV head as q rows); a wave's attention is
+    four ``prompt_attn`` calls; and every result that ``serve_ssm_prefill_ms``
+    tells by its shape is the scan's (no attention or projection result has one)."""
+    from benchmarks.harness import program, spec as spec_mod
+    from distributed_llms_example_tpu.core.config import MeshConfig
+    from distributed_llms_example_tpu.core.mesh import build_mesh
+    from distributed_llms_example_tpu.models import registry
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels' auto rules and donation ask it
+    cfg = spec_mod.load_json(os.path.join(spec_mod.BENCH_DIR, "configs", "falcon-h1-34b.json"))
+    lm = registry.load_model(
+        program.register_bench_model(cfg, spec_mod.load_module("adapters", cfg["family"])), dtype=BF16)
+    assert lm.config.num_hidden_layers == 4 and lm.config.vocab_size == 32640 and lm.config.eos_token_id is None
+    assert lm.config.decode_streams_live_slots
+    serve = ServeConfig(max_slots=FALCON_SLOTS, prefill_batch=FALCON_WAVE, max_new_tokens=FALCON_NEW,
+                        max_source_length=FALCON_PROMPT)
+    eng = ServingEngine(lm.module, lm.config, build_mesh(MeshConfig(data=-1), devices=topo.devices[:1]),
+                        serve, is_seq2seq=False)
+
+    def abstract(tree, dtype=None):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype if dtype is not None and jnp.issubdtype(x.dtype, jnp.floating) else x.dtype,
+            sharding=one_chip), tree)
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    params = abstract(jax.eval_shape(lambda: lm.init_params(0)), BF16)
+    zeros = lambda n: (jnp.zeros((n, FALCON_PROMPT), jnp.int32),) * 2  # noqa: E731
+    slots_cache = eng._slot_cache_shapes(params)
+    shapes = sorted({x.shape for x in jax.tree.leaves(slots_cache)})
+    assert shapes == [(), (64, 32, 256, 128), (64, 512, 512), (64, 5120, 3)], shapes  # state, K/V, taps
+    state = {"cache": abstract(slots_cache), "mask": i32(FALCON_SLOTS, FALCON_PROMPT + FALCON_NEW), "last": i32(FALCON_SLOTS)}
+    active = jax.ShapeDtypeStruct((FALCON_SLOTS,), jnp.bool_, sharding=one_chip)
+    state_bytes, kv_bytes = 4 * 64 * 32 * 256 * 128 * 4, 2 * 4 * 64 * 512 * 512 * 2
+
+    step = eng._step.lower(params, state, i32(FALCON_SLOTS), i32(FALCON_SLOTS), active).compile()
+    text, mem = step.as_text(), step.memory_analysis()
+    assert len(_SSM_STEP.findall(text)) == 4, [ln[:160] for ln in text.splitlines() if "custom-call(" in ln]
+    assert len(_SSM_STEP_OPERANDS.findall(text)) == 4  # each walks the round's live slots
+    assert len(_decode_attn_calls(text, (64, 4, 5, 128))) == 4  # 5 query heads a KV head as q rows
+    assert mem.alias_size_in_bytes >= state_bytes + kv_bytes and mem.temp_size_in_bytes < 0.3e9, mem
+    assert not _large_copies(text, 64 * 512 * 512)  # neither a K/V leaf nor (16 x larger) a state leaf
+
+    assert eng.wave_sizes == (1, FALCON_WAVE)
+    is_ssm = spec_mod.load_module("layer_metrics", "serve_ssm_prefill_ms").ssm_shapes(32, 2, 128, 256, 128)
+    for rows in eng.wave_sizes:
+        wave_cache, wave_mask, _, wave_first = jax.eval_shape(lambda p: eng._prefill_core(p, *zeros(rows)), params)
+        wave = eng._prefill.lower(params, i32(rows, FALCON_PROMPT), i32(rows, FALCON_PROMPT)).compile()
+        text = wave.as_text()
+        assert [int(n) for n in _FALCON_PROMPT_ATTN.findall(text)] == [rows] * 4
+        assert wave.memory_analysis().temp_size_in_bytes < 1.0e9, wave.memory_analysis()
+        told = [ln for ln in text.splitlines() if " = " in ln and ln.startswith("  ")
+                and (m := _SHAPE.search(ln.split(" = ", 1)[1])) and is_ssm(tuple(int(x) for x in m.group(1).split(",") if x))]
+        assert told and all("ssm_prefill" in ln or "/mixer/" in ln for ln in told if "op_name=" in ln), [
+            ln[:200] for ln in told if "op_name=" in ln and "ssm_prefill" not in ln][:5]
+        admit = eng._admit.lower(state, abstract(wave_cache), abstract(wave_mask), abstract(wave_first), i32(rows)).compile()
+        assert admit.memory_analysis().temp_size_in_bytes < 0.1e9
+        assert admit.memory_analysis().alias_size_in_bytes >= state_bytes + kv_bytes

@@ -142,7 +142,13 @@ class BartDecoderLayer(nn.Module):
         use_cache: bool = False,
         cross_kv=None,
         cache_positions=None,
+        encoder_mask=None,
+        live=None,
     ):
+        """``encoder_mask`` (the 0/1 mask ``cross_bias`` was made from) and
+        ``live`` (which rows hold a request) are what a cross step over a
+        holder's (batch, length, heads x head_dim) ``cross_kv`` reads its
+        rows' lengths and its idle rows from (``MultiHeadAttention``)."""
         residual = hidden
         h = self.self_attn(
             hidden, bias=self_bias, use_cache=use_cache, deterministic=deterministic,
@@ -152,7 +158,7 @@ class BartDecoderLayer(nn.Module):
         residual = hidden
         h = self.cross_attn(
             hidden, kv_hidden=encoder_hidden, bias=cross_bias, cross_kv=cross_kv,
-            deterministic=deterministic,
+            deterministic=deterministic, mask=encoder_mask, live=live,
         )
         hidden = self.cross_attn_layer_norm(self.dropout(h, deterministic, residual=residual))
         residual = hidden
@@ -166,6 +172,7 @@ class BartForConditionalGeneration(nn.Module):
     dtype: jnp.dtype = jnp.float32
     remat: bool = False
     remat_policy: str = "full"  # "full" | "dots" (utils/remat.py)
+    cross_kv_rows = True  # ``cross_kv`` takes ``rows``: what the serving engine asks before it asks for them
 
     def setup(self) -> None:
         cfg = self.config
@@ -208,14 +215,17 @@ class BartForConditionalGeneration(nn.Module):
             hidden = constrain_hidden(blk(hidden, bias, deterministic))
         return hidden
 
-    def cross_kv(self, encoder_hidden):
+    def cross_kv(self, encoder_hidden, rows: bool = False):
         """Per-decoder-layer cross-attention K/V, projected ONCE from the
         encoder output.  The decode loop's per-step cross projections
         (2·S·d_model² FLOPs per layer) dwarf everything else it does at
         summarization shapes; generation precomputes this tuple after
-        ``encode`` and threads it through every decode step."""
+        ``encode`` and threads it through every decode step.  ``rows``: each
+        pair as a cache keeps K/V, (B, S, heads x head_dim), for a holder of
+        many one-token steps (``MultiHeadAttention.project_kv``): the serving
+        engine asks for it wherever a model's ``cross_kv`` takes the word."""
         return tuple(
-            blk.cross_attn.project_kv(encoder_hidden) for blk in self.decoder_blocks
+            blk.cross_attn.project_kv(encoder_hidden, rows) for blk in self.decoder_blocks
         )
 
     def decode(
@@ -258,12 +268,14 @@ class BartForConditionalGeneration(nn.Module):
                 else None
             )
         cross_bias = mask_to_bias(encoder_mask) if encoder_mask is not None else None
+        # a serving step parks an idle slot at the self cache's length: its cross K/V is not read either
+        live = None if cache_positions is None or max_kv_len is None else cache_positions < max_kv_len
         hidden = constrain_hidden(hidden)
         for i, blk in enumerate(self.decoder_blocks):
             hidden = constrain_hidden(blk(
                 hidden, self_bias, encoder_hidden, cross_bias, deterministic, use_cache,
                 cross_kv=None if cross_kv is None else cross_kv[i],
-                cache_positions=cache_positions,
+                cache_positions=cache_positions, encoder_mask=encoder_mask, live=live,
             ))
         logits = constrain_logits(hidden @ self.shared.embedding.astype(self.dtype).T)
         return logits + self.final_logits_bias.astype(logits.dtype)

@@ -1347,14 +1347,16 @@ def _decode_valid(offset, ki, rows: int, q_len: int, block_k: int, q_group: int 
     return q_pos >= k_pos
 
 
-def _decode_bias_spec(bias_shape, rows: int, block_k: int):
+def _decode_bias_spec(bias_shape, rows: int, block_k: int, walk=None):
     """BlockSpec of a decode step's additive bias (``_decode_bias_rows``),
     every dim 1 or full: the step's slot x head group x rows x kv tile where
     the bias has them.  The grid is (slot, head group, kv tile), whatever
-    scalar-prefetch refs follow."""
+    scalar-prefetch refs follow; ``walk`` turns a grid step (and those refs)
+    into the (slot, head group, kv tile) whose blocks it holds, None = its own."""
     b1, g1, r1, k1 = (n == 1 for n in bias_shape)
 
-    def index_map(b, g, ki, *_):
+    def index_map(*at):
+        b, g, ki = at[:3] if walk is None else walk(*at)
         return (0 if b1 else b, 0 if g1 else g, 0, 0 if k1 else ki)
 
     return pl.BlockSpec(
@@ -1365,16 +1367,21 @@ def _decode_bias_spec(bias_shape, rows: int, block_k: int):
 def _decode_update(group, ki, offset, allocated, refs, *, scale, block_k, nk, q_group):
     """What both decode kernels do with one grid step's blocks: start the
     statistics on the first kv tile, fold the tile in where it is live
-    (``allocated``: None, or the paged kernel's "this tile has a block"),
-    and on the last tile hand each head its own lanes of the accumulator.
+    (at or before the tile of the slot's last position; ``allocated``: None,
+    or the paged kernel's "this tile has a block"), and on the last tile hand
+    each head its own lanes of the accumulator.  A dead tile's step computes
+    nothing; whether it also fetches nothing is up to the caller's index maps.
     ``group`` is the step's head group, which only the int8 scales need: they
     come whole, and a group that is not all heads picks its own."""
     q_ref, k_ref, v_ref, ks_ref, vs_ref, bias_ref, o_ref, m_scr, l_scr, acc_scr = refs
     _, hb, q_len, d = o_ref.shape  # the step's output block: slot x heads x q rows x d
     first_head = 0 if ks_ref is None or ks_ref.shape[-1] == hb else group * hb
-    # every live position of this slot's tile is <= offset + q_len - 1:
-    # tiles past that contribute nothing — skip their DMA'd compute
-    live = ki * block_k <= offset + q_len - 1
+    # every live position of this slot is <= the last q row's, offset + (q_len
+    # - 1) // q_group: tiles past that contribute nothing, and their arithmetic
+    # is skipped here.  What such a step HOLDS is the index maps' business: the
+    # flat kernel's repeat the slot's last live tile, so nothing is fetched for
+    # it either (``_decode_walk``); the paged kernel's fetch the (clamped) block
+    live = ki * block_k <= offset + (q_len - 1) // q_group
     if allocated is not None:
         live = jnp.logical_and(live, allocated)
 
@@ -1412,16 +1419,23 @@ def _decode_kernel(
     has_scales: bool = False, q_group: int = 1,
 ):
     it = iter(refs)
-    off_ref = next(it)  # SMEM (batch,) int32: absolute position of q row 0
+    # scalar prefetch: (batch,) absolute position of q row 0; the slot indices,
+    # live ones first; (1,) how many are live
+    off_ref, order_ref, n_ref = next(it), next(it), next(it)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
     ks_ref = next(it) if has_scales else None
     vs_ref = next(it) if has_scales else None
     bias_ref = next(it) if has_bias else None
-    _decode_update(
-        pl.program_id(1), pl.program_id(2), off_ref[pl.program_id(0)], None,
-        (q_ref, k_ref, v_ref, ks_ref, vs_ref, bias_ref, *it),
-        scale=scale, block_k=block_k, nk=nk, q_group=q_group,
-    )
+    rest = tuple(it)
+    b, g, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)  # read outside the branch: interpret mode needs it
+
+    @pl.when(b < n_ref[0])  # past the last live slot a step holds that slot's last blocks and does nothing
+    def _():
+        _decode_update(
+            g, ki, off_ref[order_ref[b]], None,
+            (q_ref, k_ref, v_ref, ks_ref, vs_ref, bias_ref, *rest),
+            scale=scale, block_k=block_k, nk=nk, q_group=q_group,
+        )
 
 
 def _check_decode_bias(bias, batch, heads, q_len, kv_len):
@@ -1445,6 +1459,7 @@ def flash_decode(
     bias: jnp.ndarray | None = None,
     *,
     offsets: jnp.ndarray,
+    live: jnp.ndarray | None = None,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     scale: float | None = None,
@@ -1493,6 +1508,20 @@ def flash_decode(
     per-row mask, the dead-tile skip and the fp32 accumulation are what
     they were.
 
+    **What is fetched** (PR 46): only what a row needs.  ``live`` ((B,) bool;
+    None = every row, which is every caller but the serving engine's step)
+    says which rows hold a sequence.  The grid's slot axis walks a list of
+    the slots, live ones first, as far as their number (both by scalar
+    prefetch beside ``offsets``, as ``retention_step``'s); a step past the
+    last live slot holds the blocks of the last live step, so the pipeline
+    copies nothing in or out for an idle row, whose output is ZERO and whose
+    leaf may hold anything.  Inside a live row the kv tile index stops at the
+    row's last live tile, that of its last q row's position: the steps after
+    it hold that tile again (no copy) and compute nothing, so a tile past a
+    row's last position is never read either.  The kernel moves whole tiles,
+    never fewer bytes than the row's positions; a skipped step costs what an
+    empty grid step costs (~0.35 us on v5e).
+
     ``ring``: ``k``/``v`` are a window layer's leaves, (B, window, H x d),
     written at ``position mod window`` (``ops/mha.py`` ``cache_window_kv``),
     and ``offsets`` are the rows' absolute POSITIONS: an entry is valid by the
@@ -1500,6 +1529,8 @@ def flash_decode(
     congruent to r, there from position r on), so the step reads entries ``<=
     min(position, window - 1)``; the order of a ring's entries does not
     matter to a softmax over keys cached after RoPE.  One q position a row.
+    A ring takes the live list (an idle row's ring is not read) and no tile
+    stop: a live row's ring is fetched whole.
     The same kernel under its own name in a device trace, ``window_decode``
     (``_window_decode_call``: not inlined), whatever its call site.
     """
@@ -1536,8 +1567,9 @@ def flash_decode(
     hb = decode_step_heads(
         heads, block_k, d, k.dtype.itemsize, q_len=q_len, int8_scales=has_scales
     )
+    live = jnp.ones((batch,), bool) if live is None else jnp.asarray(live, bool).reshape(batch)
     return (_window_decode_call if ring else _decode_call)(
-        jnp.asarray(offsets, jnp.int32).reshape(batch), q, k, v, k_scale, v_scale, bias,
+        jnp.asarray(offsets, jnp.int32).reshape(batch), live, q, k, v, k_scale, v_scale, bias,
         scale=float(scale), block_k=block_k, step_heads=hb, q_group=q_group,
         interpret=bool(interpret), dtype=dtype,
     )
@@ -1548,64 +1580,103 @@ WINDOW_DECODE = "window_decode"  # a ring step's custom call in a device trace
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=_DECODE_STATICS)
-def _decode_call(offsets, q, k, v, k_scale, v_scale, bias, **statics):
+def _decode_call(offsets, live, q, k, v, k_scale, v_scale, bias, **statics):
     """``flash_decode``'s program on checked operands, every choice made from
     the shapes passed in as a static.  Jitted so that a decode program's
     call sites of one shape (twelve layers) trace the kernel once, and
     inlined so that each stays an operation of its own call site (the
     custom call keeps the site's name)."""
-    return _decode_program(offsets, q, k, v, k_scale, v_scale, bias, **statics)
+    return _decode_program(offsets, live, q, k, v, k_scale, v_scale, bias, **statics)
 
 
 @functools.partial(jax.jit, static_argnames=_DECODE_STATICS)
-def _window_decode_call(offsets, q, k, v, k_scale, v_scale, bias, **statics):
+def _window_decode_call(offsets, live, q, k, v, k_scale, v_scale, bias, **statics):
     """The same program for a ring leaf, NOT inlined and the kernel named, so
     that the custom call is ``window_decode`` in a device trace and no metric
     has to tell a window layer's step from a full layer's by its shape."""
-    return _decode_program(offsets, q, k, v, k_scale, v_scale, bias, name=WINDOW_DECODE, **statics)
+    return _decode_program(offsets, live, q, k, v, k_scale, v_scale, bias, ring=True, **statics)
 
 
-def _decode_program(offsets, q, k, v, k_scale, v_scale, bias, *, scale: float, block_k: int,
-                    step_heads: int, q_group: int, interpret: bool, dtype, name: str | None = None):
+def _decode_walk(b, g, ki, offsets, order, n_live, *, nk: int, block_k: int, last_pos: int,
+                 last_group: int, ring: bool):
+    """Whose blocks grid step (b, g, ki) of the decode kernel holds: (slot, head
+    group, kv tile).  ``offsets`` (slots,), ``order`` (the slot indices, live
+    ones first) and ``n_live`` (1,) are the call's scalar-prefetch refs (any
+    indexable does: the tests walk a grid with numpy arrays).  While ``b <
+    n_live``: slot ``order[b]``, its own group, and tile ``min(ki, the slot's
+    last live tile)``, that of position ``offsets[slot] + last_pos`` (the last
+    q row's); a ``ring`` has no tile stop (its entries are valid by position).
+    Past the last live slot: the last live step's blocks, whatever (g, ki)."""
+    on = b < n_live[0]
+    slot = order[jnp.where(on, b, jnp.maximum(n_live[0] - 1, 0))]
+    last = nk - 1 if ring else jnp.clip((offsets[slot] + last_pos) // block_k, 0, nk - 1)
+    return slot, jnp.where(on, g, last_group), jnp.where(on, jnp.minimum(ki, last), last)
+
+
+def _decode_program(offsets, live, q, k, v, k_scale, v_scale, bias, *, scale: float, block_k: int,
+                    step_heads: int, q_group: int, interpret: bool, dtype, ring: bool = False):
+    """The pallas call.  Its grid is (slot, head group, kv tile) at full size,
+    but what a step HOLDS follows the live list (``_decode_walk``), so
+    consecutive steps that need nothing new name the block already resident
+    and the pipeline moves nothing.  Every operand and the output go by the
+    same walk.  A slot the grid never visits has no output written: it is
+    set to zero after the call.  That also makes every grid axis sequential,
+    as ``retention._step_call``'s."""
     batch, heads, q_len, d = q.shape
     hb, nk = step_heads, k.shape[1] // block_k
     has_scales = k_scale is not None
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    n_live = jnp.sum(live, dtype=jnp.int32).reshape(1)
+    walk = functools.partial(
+        _decode_walk, nk=nk, block_k=block_k, last_pos=(q_len - 1) // q_group,
+        last_group=heads // hb - 1, ring=ring,
+    )
 
-    def q_map(b, g, ki):
-        return (b, g, 0, 0)
+    def q_map(*at):
+        slot, g, _ = walk(*at)
+        return (slot, g, 0, 0)
 
-    def kv_map(b, g, ki):
-        return (b, ki, g)
+    def kv_map(*at):
+        slot, g, ki = walk(*at)
+        return (slot, ki, g)
+
+    def scale_map(*at):
+        slot, _, ki = walk(*at)
+        return (slot, ki, 0)
 
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),  # offsets, whole array
         pl.BlockSpec((1, 1, hb * q_len, hb * d), q_map),
         pl.BlockSpec((1, block_k, hb * d), kv_map),
         pl.BlockSpec((1, block_k, hb * d), kv_map),
     ]
     if has_scales:  # all heads' scales a step: a part of their lanes is no block
-        in_specs += [pl.BlockSpec((1, block_k, heads), lambda b, g, ki: (b, ki, 0))] * 2
+        in_specs += [pl.BlockSpec((1, block_k, heads), scale_map)] * 2
     if bias is not None:
         bias = _decode_bias_rows(bias, heads, q_len, hb)
-        in_specs.append(_decode_bias_spec(bias.shape, hb * q_len, block_k))
+        in_specs.append(_decode_bias_spec(bias.shape, hb * q_len, block_k, walk))
     out = pl.pallas_call(
         functools.partial(
             _decode_kernel, scale=scale, block_k=block_k, nk=nk,
             has_bias=bias is not None, has_scales=has_scales, q_group=q_group,
         ),
-        grid=(batch, heads // hb, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, hb, q_len, d), q_map),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # offsets, order, n_live
+            grid=(batch, heads // hb, nk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, hb, q_len, d), q_map),
+            scratch_shapes=_decode_scratch(hb, q_len, d),
+        ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=_decode_scratch(hb, q_len, d),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-        name=name,
-    )(offsets, *[
+        name=WINDOW_DECODE if ring else None,  # a full leaf's call keeps its call site's name
+    )(offsets, order, n_live, *[
         x for x in (decode_q_rows(q, hb), k, v, k_scale, v_scale, bias) if x is not None
     ])
+    # a slot the grid did not visit has no output written: zero, not what the buffer held
+    out = jnp.where(live[:, None, None, None], out, jnp.zeros((), out.dtype))
     return out if dtype is None else out.astype(dtype)
 
 
@@ -1788,6 +1859,7 @@ def flash_decode_run(
     *,
     offsets: jnp.ndarray,
     mesh,
+    live: jnp.ndarray | None = None,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     scale: float | None = None,
@@ -1800,7 +1872,8 @@ def flash_decode_run(
     ``shard_map`` on a mesh (batch over data×fsdp×expert, heads over
     ``tensor``, mirroring ``ops.mha.flash_run``: q's head axis, and the
     merged last axis of the (B, L, H x d) buffers, in which the heads are
-    contiguous).  ``offsets`` shard with the batch rows; the int8 KV scales
+    contiguous).  ``offsets`` and ``live`` shard with the batch rows (each
+    batch shard walks its own live list); the int8 KV scales
     (``k_scale``/``v_scale``, (B, L, H)) shard exactly like the buffers
     they dequantize; the kernel body needs no collectives (decode never
     mixes rows or heads).  A bias carrying a HEAD dim must be full-size (it
@@ -1813,7 +1886,7 @@ def flash_decode_run(
 
     if mesh is None or _math.prod(mesh.devices.shape) == 1:
         return flash_decode(
-            q, k, v, bias, offsets=offsets, k_scale=k_scale, v_scale=v_scale,
+            q, k, v, bias, offsets=offsets, live=live, k_scale=k_scale, v_scale=v_scale,
             scale=scale, dtype=dtype, interpret=interpret, q_group=q_group, ring=ring,
         )
     batch_axes = tuple(a for a in BATCH_AXES if a in mesh.shape)
@@ -1823,19 +1896,21 @@ def flash_decode_run(
     off_spec = P(batch_axes or None)
     has_scales = k_scale is not None
 
-    def run(q, k, v, off, *rest):
+    def run(q, k, v, off, on, *rest):
         rest = list(rest)
         ks = vs = None
         if has_scales:
             ks, vs = rest.pop(0), rest.pop(0)
         return flash_decode(
-            q, k, v, rest[0] if rest else None, offsets=off,
+            q, k, v, rest[0] if rest else None, offsets=off, live=on,
             k_scale=ks, v_scale=vs, scale=scale,
             dtype=dtype, interpret=interpret, q_group=q_group, ring=ring,
         )
 
-    args = (q, k, v, jnp.asarray(offsets, jnp.int32).reshape(q.shape[0]))
-    in_specs = (q_spec, kv_spec, kv_spec, off_spec)
+    rows = q.shape[0]
+    args = (q, k, v, jnp.asarray(offsets, jnp.int32).reshape(rows),
+            jnp.ones((rows,), bool) if live is None else jnp.asarray(live, bool).reshape(rows))
+    in_specs = (q_spec, kv_spec, kv_spec, off_spec, off_spec)
     if has_scales:
         args = (*args, k_scale, v_scale)
         in_specs = (*in_specs, kv_spec, kv_spec)

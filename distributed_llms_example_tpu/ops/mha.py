@@ -247,6 +247,55 @@ def select_decode_impl(
     )
 
 
+def select_cached_step(
+    attention_impl: str,
+    *,
+    batch: int,
+    heads: int,
+    kv_heads: int,
+    head_dim: int,
+    q_len: int,
+    kv_len: int,
+    mesh: Mesh | None,
+    kv_dtype: jnp.dtype = jnp.bfloat16,
+    bias_kv_only: bool = True,
+    dropout: bool = False,
+) -> tuple[str, str, bool]:
+    """(impl, reason, fold) of ``MultiHeadAttention._cached_attend``: which
+    way a cached step of ``heads`` query heads over (batch, kv_len, kv_heads x
+    head_dim) leaves goes (``select_decode_impl`` on this process's backend and
+    devices), and whether grouped-query attention is FOLDED into the decode
+    kernel's q rows (each KV head read once for the query heads that share
+    it; any other route repeats K and V to the query heads).  ``bias_kv_only``:
+    no bias, or one with no head or row axis (a wider one cannot fold);
+    ``dropout``: probs dropout is wanted, which the kernel does not draw.
+    The serving engine asks the same question of the same function to count
+    what a round's attention reads: ``flash_decode`` fetches the live slots'
+    tiles alone, XLA's path every slot's whole leaf."""
+    backend, devices = jax.default_backend(), jax.device_count()
+    ask = lambda **kw: select_decode_impl(  # noqa: E731
+        attention_impl, batch=batch, head_dim=head_dim, kv_len=kv_len, mesh=mesh,
+        backend=backend, device_count=devices, kv_dtype=kv_dtype, **kw,
+    )
+    rep = heads // kv_heads
+    if (
+        rep > 1
+        and q_len * rep <= MAX_DECODE_Q_ROWS
+        and bias_kv_only
+        and (mesh is None or kv_heads % mesh.shape.get("tensor", 1) == 0)
+        and not dropout
+        and ask(heads=kv_heads, q_len=q_len * rep)[0] == "flash_decode"
+    ):
+        return "flash_decode", f"grouped: {rep} query heads a KV head as q rows", True
+    impl, reason = ask(heads=heads, q_len=q_len)
+    if dropout and impl == "flash_decode":
+        # the decode kernel has no in-kernel mask stream; a decode
+        # pass that WANTS probs dropout (MC-dropout eval) keeps the
+        # old XLA semantics instead of silently going deterministic
+        impl, reason = "xla", "probs dropout requested on cached decode"
+    return impl, reason, False
+
+
 def decode_step_bias(offsets: jnp.ndarray, q_len: int, kv_len: int) -> jnp.ndarray:
     """(B, 1, q_len, kv_len) additive validity+causality mask for a cached
     decode step: q row r (absolute position ``offsets[b] + r``) attends
@@ -552,16 +601,28 @@ class MultiHeadAttention(nn.Module):
         b, s, _ = x.shape
         return x.reshape(b, s, heads, self.head_dim).transpose(0, 2, 1, 3)
 
-    def project_kv(self, kv_hidden: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    def project_kv(self, kv_hidden: jnp.ndarray, rows: bool = False) -> tuple[jnp.ndarray, jnp.ndarray]:
         """K/V projections alone, as ``__call__`` would compute them —
         (B, kv_heads, S, head_dim) each.  Generation precomputes these ONCE
         per sequence for cross-attention (the encoder output is fixed for
         the whole decode) and feeds them back via ``cross_kv``; without
         this, every decode step re-projects the full encoder output
         through k/v_proj — 2·S·d_model² FLOPs per layer per token, ~100×
-        the rest of the step for src 1024 summarization."""
-        k = self._split(self.k_proj(kv_hidden), self.kv_heads)
-        return (k * self.key_multiplier if self.key_multiplier != 1.0 else k), self._split(self.v_proj(kv_hidden), self.kv_heads)
+        the rest of the step for src 1024 summarization.
+
+        ``rows``: the pair as a CACHE keeps K/V, (B, S, kv_heads x head_dim)
+        each — what ``k_proj`` returns before ``_split``, the self cache's
+        layout (``cache_kv``) and the decode kernel's — for a holder that
+        keeps it across many one-token steps (the serving engine's slots):
+        ``__call__`` tells the two by rank and sends a 3-D pair through
+        ``_cached_attend``.  A grouped-query cross attention keeps the 4-D
+        pair and XLA's path."""
+        k, v = self.k_proj(kv_hidden), self.v_proj(kv_hidden)
+        if self.key_multiplier != 1.0:
+            k = k * self.key_multiplier
+        if rows and self.kv_heads == self.num_heads:
+            return k, v
+        return self._split(k, self.kv_heads), self._split(v, self.kv_heads)
 
     @nn.compact
     def _cache_kv(self, key: jnp.ndarray, value: jnp.ndarray,
@@ -584,6 +645,7 @@ class MultiHeadAttention(nn.Module):
         offsets: jnp.ndarray,
         deterministic: bool,
         ring: bool = False,
+        live: jnp.ndarray | None = None,
     ) -> jnp.ndarray:
         """A cached decode step's attention: ``q`` (B, heads, T, d) against
         the cache leaves ``k``/``v`` (B, L, kv_heads x d) (int8 scales (B,
@@ -595,47 +657,38 @@ class MultiHeadAttention(nn.Module):
         L, d) inside the program.  ``ring``: the leaves are a window layer's
         (``cache_window_kv``) and ``offsets`` the rows' absolute positions;
         an entry is valid by the row's position, the first ``min(position,
-        window - 1) + 1`` of them, on either path."""
+        window - 1) + 1`` of them, on either path.
+
+        ``live`` (B,) bool: the rows that hold a sequence, which is all the
+        decode kernel fetches (an idle row's output is zero there; XLA's path
+        reads every row and its idle rows hold whatever their leaves give,
+        which no caller reads).  None = what the offsets say: a row at or past
+        the leaf's length is PARKED (its write was dropped, ``cache_kv``),
+        which is how the serving engine's step programs mark an idle slot; an
+        evaluation decode parks nothing, so every row is live."""
         b, _, t, d = q.shape
         kv_len = k.shape[1]
+        if live is None:
+            live = offsets < kv_len
         mesh = current_mesh()
-        backend, devices = jax.default_backend(), jax.device_count()
         dropout = float(self.probs_dropout_rate) if not deterministic else 0.0
         # grouped-query attention: the decode kernel reads each KV head once
         # for the ``rep`` query heads that share it, folded into its q rows;
         # every other path gets K and V repeated to the q heads
         rep = self.num_heads // self.kv_heads
-        fold_gqa = (
-            rep > 1
-            and t * rep <= MAX_DECODE_Q_ROWS
-            and (bias is None or bias.shape[1] == bias.shape[2] == 1)
-            and (mesh is None or self.kv_heads % mesh.shape.get("tensor", 1) == 0)
-            and not dropout
-            and select_decode_impl(
-                self.attention_impl, batch=b, heads=self.kv_heads,
-                head_dim=d, q_len=t * rep, kv_len=kv_len,
-                mesh=mesh, backend=backend, device_count=devices, kv_dtype=k.dtype,
-            )[0] == "flash_decode"
+        impl, reason, fold_gqa = select_cached_step(
+            self.attention_impl, batch=b, heads=self.num_heads, kv_heads=self.kv_heads, head_dim=d,
+            q_len=t, kv_len=kv_len, mesh=mesh, kv_dtype=k.dtype,
+            bias_kv_only=bias is None or bias.shape[1] == bias.shape[2] == 1, dropout=dropout > 0.0,
         )
+        _log_impl_once(impl, reason)
         if fold_gqa:
-            _log_impl_once("flash_decode", f"grouped: {rep} query heads a KV head as q rows")
             rows = q.reshape(b, self.kv_heads, rep, t, d).swapaxes(2, 3).reshape(b, self.kv_heads, t * rep, d)
             out = flash_decode_run(
-                rows, k, v, bias, offsets=offsets, mesh=mesh,
+                rows, k, v, bias, offsets=offsets, live=live, mesh=mesh,
                 k_scale=k_scale, v_scale=v_scale, dtype=self.dtype, q_group=rep, ring=ring,
             )
             return out.reshape(b, self.kv_heads, t, rep, d).swapaxes(2, 3).reshape(b, self.num_heads, t, d)
-        impl, reason = select_decode_impl(
-            self.attention_impl, batch=b, heads=self.num_heads, head_dim=d,
-            q_len=t, kv_len=kv_len, mesh=mesh, backend=backend, device_count=devices,
-            kv_dtype=k.dtype,
-        )
-        if dropout > 0.0 and impl == "flash_decode":
-            # the decode kernel has no in-kernel mask stream; a decode
-            # pass that WANTS probs dropout (MC-dropout eval) keeps the
-            # old XLA semantics instead of silently going deterministic
-            impl, reason = "xla", "probs dropout requested on cached decode"
-        _log_impl_once(impl, reason)
         if impl == "flash_decode":
             if rep > 1:
                 # the leaf's merged axis apart, each KV head repeated in place
@@ -647,7 +700,7 @@ class MultiHeadAttention(nn.Module):
                     k_scale, v_scale = (jnp.repeat(x, rep, axis=2) for x in (k_scale, v_scale))
             # int8 KV scales dequantize per kv tile inside the kernel
             return flash_decode_run(
-                q, k, v, bias, offsets=offsets, mesh=mesh,
+                q, k, v, bias, offsets=offsets, live=live, mesh=mesh,
                 k_scale=k_scale, v_scale=v_scale, dtype=self.dtype, ring=ring,
             )
         k, v = cache_heads_view(k, v, k_scale, v_scale, self.kv_heads)
@@ -673,6 +726,7 @@ class MultiHeadAttention(nn.Module):
         deterministic: bool = True,
         cache_positions: jnp.ndarray | None = None,
         mask: jnp.ndarray | None = None,
+        live: jnp.ndarray | None = None,
     ) -> jnp.ndarray:
         """``positions``: optional (batch, q_len) absolute positions for RoPE
         — needed when cache slots don't equal sequence positions (right-
@@ -691,6 +745,16 @@ class MultiHeadAttention(nn.Module):
         of the new tokens are real (its ring keeps a prompt's last real
         positions; an idle slot's step writes nothing); None = all are.
 
+        A ``cross_kv`` pair of rank 3 is a holder's, (batch, source length,
+        heads x head_dim) as ``project_kv(rows=True)`` returns it: the step
+        goes the way a cached self-attention step goes (``_cached_attend``:
+        the decode kernel where ``select_decode_impl`` says so, which then
+        fetches a row's tiles up to the source's last real position and
+        nothing of an idle row).  ``mask`` is then the source's 0/1 padding
+        mask (batch, source length), from which that position is read, and
+        ``live`` (batch,) bool says which rows hold a request (a serving
+        slot's; None = all).
+
         A cached call of several tokens without ``cache_positions`` is a
         PROMPT and starts its sequence: where
         ``select_attention_impl`` sends it to the flash kernel (or the layer
@@ -701,6 +765,15 @@ class MultiHeadAttention(nn.Module):
         q = self._split(self.q_proj(hidden), self.num_heads)
         if cross_kv is not None:
             k, v = cross_kv
+            if k.ndim == 3:
+                b, src_len = k.shape[:2]
+                # the kernel's per-row length: the source's last real position (the padding
+                # mask rides along as the bias, so a mask with holes stays right)
+                last = jnp.full((b,), src_len - 1, jnp.int32) if mask is None else jnp.max(
+                    jnp.where(mask > 0, jnp.arange(src_len, dtype=jnp.int32), 0), axis=1)
+                out = self._cached_attend(q, k, v, None, None, bias, last, deterministic, live=live)
+                b_, h_, s_, d_ = out.shape
+                return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b_, s_, h_ * d_))
             if k.shape[0] != hidden.shape[0]:
                 if self.kv_heads != self.num_heads:
                     # GQA cross-attention cannot fold beams next to heads
@@ -957,6 +1030,7 @@ class MultiHeadAttention(nn.Module):
             return self._cached_attend(
                 q, ring_k, ring_v, None, None, None,
                 jnp.broadcast_to(positions[:, 0], (b,)).astype(jnp.int32), deterministic, ring=True,
+                live=real[:, 0],  # an idle slot's step wrote nothing, and its ring is not read
             )
         if self._prompt_impl(q, bias) == "flash":
             return self._prompt_attend(q, k, v, bias)

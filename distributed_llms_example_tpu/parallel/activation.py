@@ -181,7 +181,8 @@ def constrain_cache(tree, kv_heads: int | None = None):
     (``sharding.cache_leaf_spec``, CACHE_RULES' one definition): K/V
     buffers (batch, len, heads x head_dim), the int8 cache's (batch, len,
     heads) scales, a ``conv_state``; the unnamed 4-D leaves of a cross-KV
-    tuple via ``constrain_kv``; scalars (the ``cache_index`` counters)
+    tuple via ``constrain_kv`` (its 3-D leaves, (batch, len, heads x
+    head_dim), as a K/V buffer); scalars (the ``cache_index`` counters)
     replicated by GSPMD default.  ``kv_heads`` (``sharding.cache_kv_heads``
     of the model's config) is what a K/V buffer's merged axis is told apart
     by, so a tree that holds one needs it; a cross-KV tree does not.  No-op
@@ -198,9 +199,12 @@ def constrain_cache(tree, kv_heads: int | None = None):
         name = str(path[-1].key) if path and hasattr(path[-1], "key") else ""
         if name in KV_LEAVES and kv_heads is None:
             raise ValueError(f"constrain_cache: the K/V leaf {name} needs the model's kv_heads")
+        nd = getattr(x, "ndim", 0)
+        if not name and nd == 3 and kv_heads is not None:
+            name = "cached_key"  # a cross-KV pair kept as a cache keeps K/V: that leaf's spec
         spec = cache_leaf_spec(name, getattr(x, "shape", ()), dict(mesh.shape), kv_heads)
         if spec is not None:
             return constrain(x, spec)
-        return constrain_kv(x) if getattr(x, "ndim", 0) == 4 else x
+        return constrain_kv(x) if nd == 4 else x
 
     return jtu.tree_map_with_path(pin, tree)

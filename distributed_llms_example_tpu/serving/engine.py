@@ -60,6 +60,7 @@ device trace's clock.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 import math
@@ -413,6 +414,9 @@ class ServingEngine:
         # what a decode round streams (the ``slots_streamed`` counter): the
         # model's own predicate, the one that chooses its decode step
         self.streams_live_slots = bool(getattr(config, "decode_streams_live_slots", False))
+        # a seq2seq model that can lay a slot's cross K/V as a cache keeps K/V
+        # (its ``cross_kv(..., rows=True)``): the cross step then fetches live slots alone
+        self.cross_kv_rows = is_seq2seq and bool(getattr(model, "cross_kv_rows", False))
         self.paged = bool(self.serve.paged_kv)
         self.pool: cache_pool.CachePool | None = None
         if self.paged:
@@ -596,13 +600,51 @@ class ServingEngine:
         mask-invisible to the others, so the size changes no served token."""
         return self.wave_sizes[0] if n <= self.wave_sizes[0] else self.prefill_batch
 
+    def step_reads(self, leaf, ring: bool = False) -> tuple[int, int]:
+        """How a decode round's attention reads the K/V leaf ``leaf`` (slots,
+        length, kv_heads x head_dim): (length, tile).  ``tile`` > 0: the decode
+        kernel runs the step (``ops/mha.py`` ``select_cached_step``, the
+        function that routes it, asked what the layer asks) and fetches of a
+        live slot the tiles of that many positions up to its last one (a
+        ``ring`` leaf: tile = length, the live slot's ring whole) and of an
+        idle slot nothing; 0: XLA's path, every slot's whole leaf."""
+        from distributed_llms_example_tpu.ops.flash_attention import decode_block
+        from distributed_llms_example_tpu.ops.mha import select_cached_step
+
+        length, lanes = int(leaf.shape[1]), int(leaf.shape[2])
+        cfg = self.config
+        heads = next(int(n) for n in (getattr(cfg, a, None) for a in (
+            "num_attention_heads", "decoder_attention_heads", "num_heads")) if n)
+        kernel = select_cached_step(
+            getattr(cfg, "attention_impl", "auto"), batch=self.S, heads=heads, kv_heads=self.kv_heads,
+            head_dim=lanes // self.kv_heads, q_len=1, kv_len=length, mesh=self.mesh, kv_dtype=leaf.dtype,
+        )[0] == "flash_decode"
+        return length, (length if ring else decode_block(length)) if kernel else 0
+
+    def positions_read(self, reads, last: np.ndarray) -> int:
+        """K/V positions a decode round's attention fetches, over the leaves
+        ``reads`` ((length, tile) -> how many, ``step_reads``), the live slots'
+        last positions being ``last``: whole tiles of the live slots up to the
+        last live one where the kernel runs (at most the leaf: a ring is read
+        whole from wherever the slot's position stands), every slot's leaf elsewhere."""
+        return int(sum(
+            n * (int(np.minimum((last // tile + 1) * tile, length).sum()) if tile else self.S * length)
+            for (length, tile), n in reads
+        ))
+
     def _build_programs(self) -> None:
         model, L, S = self.model, self.L, self.S
 
         if self.is_seq2seq:
+            # the cross K/V a slot keeps for its decode steps: (row, length, heads x
+            # head_dim) like the self cache where the model's ``cross_kv`` lays it
+            # so (``cross_kv_rows``: its cross step then goes the self step's way,
+            # the decode kernel over live slots), else (row, heads, length, head_dim)
+            rows_kw = {"rows": True} if self.cross_kv_rows else {}
+
             def prefill(params, ids, mask):
                 enc = model.apply({"params": params}, ids, mask, method="encode")
-                ckv = constrain_cache(model.apply({"params": params}, enc, method="cross_kv"))
+                ckv = constrain_cache(model.apply({"params": params}, enc, method="cross_kv", **rows_kw), self.kv_heads)
                 return enc, mask, ckv
 
             def admit(state, enc, mask, ckv, slot_idx):
@@ -611,9 +653,8 @@ class ServingEngine:
                 # the (per-bucket-compiled) admit program
                 enc = cache_pool.pad_axis(enc, 1, self.W)
                 mask = cache_pool.pad_axis(mask, 1, self.W)
-                ckv = jax.tree.map(
-                    lambda x: cache_pool.pad_axis(x, 2, self.W) if x.ndim == 4 else x,
-                    ckv,
+                ckv = jax.tree.map(  # the length axis by the leaf's rank
+                    lambda x: cache_pool.pad_axis(x, {4: 2, 3: 1}[x.ndim], self.W), ckv,
                 )
                 return {
                     **state,
@@ -853,6 +894,8 @@ class ServingEngine:
                 return spec
         if nd == 4:  # precomputed cross K/V, (slots, heads, len, head_dim)
             return kv_leaf_spec(x.shape, mesh_axes)
+        if nd == 3 and path.startswith("ckv"):  # (slots, len, heads x head_dim): the self cache's layout and spec
+            return cache_leaf_spec("cached_key", x.shape, mesh_axes, self.kv_heads)
         batch = BATCH_AXES if x.shape[0] % max(batch_shards, 1) == 0 else None
         return P(batch, *([None] * (nd - 1)))
 
@@ -1102,6 +1145,23 @@ class ServeSession:
     what a round's input depends on (``_depth``): a speculative round is
     built from the fetched tokens and stays in lockstep.
 
+    **What a round says it read** (counters on ``serve/decode_dispatch``,
+    all host arithmetic over what the dispatch already knows): ``slots_live``
+    / ``slots_streamed`` (the slots that get a token, and those whose
+    recurrent state the program moves: the live ones where the model's step
+    kernel walks a live list, every one otherwise); for a flat causal cache
+    ``kv_positions_live`` (the K/V positions the round's attention needs: a
+    live slot's own, on a window leaf at most the window) and
+    ``kv_positions_streamed`` (those its program FETCHES, by leaf:
+    ``ServingEngine.step_reads`` asks the function that routes the step;
+    where the decode kernel runs it, whole kv tiles of the live slots up to
+    each one's write position and a window leaf's ring whole, else every
+    slot's whole leaf); for a seq2seq model ``cross_positions_live`` /
+    ``cross_positions_streamed``, the same two for the cross K/V (a live
+    slot's source positions; their whole tiles where the slots keep the pair
+    as a cache keeps K/V and the kernel runs the cross step, else slots x
+    source width).  Streamed is never under live.
+
     The replica router (serving/router.py) opens one session per engine
     replica; ``progress`` (bumped on every admit chunk and decode step)
     is its per-replica heartbeat, ``take_pending`` is its drain path,
@@ -1193,6 +1253,8 @@ class ServeSession:
         # the lengths of the K/V leaves, a (K, V) pair an attention layer: what a
         # decode round's ``kv_positions_*`` counters sum over (flat causal cache)
         kv_lengths = []
+        # the same leaves by how a round's step reads them (``step_reads``) -> how many
+        kv_reads: collections.Counter = collections.Counter()
         for path, x in jax.tree_util.tree_leaves_with_path(self.state.get("cache", self.state.get("pool", {}))):
             leaf = cache_leaf_name(path)
             if leaf != "cache_index":  # a counter, not state
@@ -1202,12 +1264,19 @@ class ServeSession:
                 window_bytes += int(np.prod(x.shape)) * x.dtype.itemsize
             if leaf in ("cached_key", "window_key") and not (eng.paged or eng.is_seq2seq):
                 kv_lengths.append(int(x.shape[1]))
+                kv_reads[eng.step_reads(x, ring=leaf in WINDOW_LEAVES)] += 1
         if window_bytes:
             # a model with window layers: ``kv_bytes`` split by kind of leaf
             by_kind["kv_window_bytes"] = window_bytes
             by_kind["kv_full_bytes"] = by_kind["kv_bytes"] - window_bytes
         self._cache_bytes_by_kind = by_kind
         self._kv_lengths = np.asarray(kv_lengths, np.int64)
+        self._kv_reads = sorted(kv_reads.items())
+        # a seq2seq slot's cross K/V, a (K, V) pair a decoder layer, the same way
+        # (a (slots, heads, length, head_dim) pair is XLA's to read, whole)
+        self._cross_reads = sorted(collections.Counter(
+            eng.step_reads(k) if k.ndim == 3 else (int(k.shape[2]), 0) for k, _ in self.state.get("ckv", ())
+        ).items())
         if eng.paged and eng.prefix:
             # the device pool tensor was just re-zeroed (_init_state), so
             # any warm chains a PREVIOUS session retained now index
@@ -2035,10 +2104,16 @@ class ServeSession:
             if len(self._kv_lengths) and not eng.spec:
                 # K/V positions the round's attention needs (a live slot's own, the
                 # step's included; on a window leaf at most the window) against those
-                # its program reads (every slot's whole leaf), over the attention layers
+                # its program reads (``positions_read``), over the attention layers
                 needed = (self.lengths + held)[todo].astype(np.int64)
                 counters["kv_positions_live"] = int(np.minimum(needed[:, None], self._kv_lengths[None, :]).sum())
-                counters["kv_positions_streamed"] = int(eng.S * self._kv_lengths.sum())
+                counters["kv_positions_streamed"] = eng.positions_read(self._kv_reads, offsets[todo])
+            if self._cross_reads:
+                # the same for a seq2seq round's cross attention: a live slot's source
+                # positions against what the cross step reads, over the decoder layers
+                source = self.lengths[todo]
+                counters["cross_positions_live"] = int(source.sum()) * len(self.state["ckv"])
+                counters["cross_positions_streamed"] = eng.positions_read(self._cross_reads, source - 1)
             dispatch.set(**counters)
         slots = np.flatnonzero(todo)
         self.ahead[slots] += 1

@@ -455,6 +455,17 @@ def test_bart_cell_decode_step_copies_no_cache_leaf(topo, one_chip, compiled_ker
     step = eng._step.lower(params, state, i32(BART_SLOTS), active).compile()
     text = step.as_text()
     assert len(_decode_attn_calls(text, (64, 16, 1, 64))) == 12  # flash_decode, one a decoder layer
+    # the cross step on the same kernel (PR 46), as ``serve_cross_attn_ms`` finds it; the slots keep the cross K/V
+    # as a cache keeps K/V, 24 leaves that enter in the descending layout and are copied nowhere; XLA's two cross
+    # fusions are gone; and the 24 call sites' live lists are ONE sort (one expression, merged), not 24
+    assert len(re.findall(r"%cross_attn\.\d+ = bf16\[64,16,1,64\]\{[^}]*\} custom-call\(", text)) == 12
+    assert {x.shape for x in jax.tree.leaves(ckv)} == {(64, 1024, 1024)}
+    cross = [p for p in _entry_layouts(text) if p.startswith("bf16[64,1024,1024]")]
+    # 24 cross K/V leaves and the slots' encoder states, the same shape
+    assert len(cross) == 24 + 1 and all(p.startswith("bf16[64,1024,1024]{2,1,0:") for p in cross), cross
+    assert not _large_copies(text, 64 * 1024 * 1024)
+    assert not re.search(r"f32\[64,16,1024\]|bf16\[64,16,1024,64\]", text)
+    assert len(re.findall(r" sort\(", text)) == 1
     _assert_cache_rests_where_it_is_read(text, step.memory_analysis(), (64, 128, 1024), leaves=24)
 
 

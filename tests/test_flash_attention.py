@@ -451,3 +451,151 @@ def test_relative_bias_shape_is_checked():
     q, k, v = _qkv(64, 64)
     with pytest.raises(ValueError, match="relative_bias shape"):
         flash_attention(q, k, v, relative_bias=jnp.zeros((1, H, 64, 64)), interpret=True)
+
+
+# ------------------------------------------------ flash_decode's live list (PR 46)
+#
+# The decode kernel fetches what is live: the slots ``live`` names, and of each
+# the kv tiles up to its last position.  (The kernel against the dense reference
+# at every shape, with every row live: tests/test_serving.py.)
+
+
+def _leaf(x):
+    """(B, H, L, d) as the cache keeps it: (B, L, H x d), heads side by side."""
+    b, h, length, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, length, h * d)
+
+
+# name: (B, H, L, d, q rows, q rows a position, bias, int8 K/V, block_k)
+LIVE_CASES = {
+    "plain": (6, 4, 128, 16, 1, 1, None, False, None),
+    "grouped": (6, 2, 256, 64, 4, 4, None, False, None),
+    "int8": (6, 4, 128, 64, 1, 1, None, True, None),
+    "pad-bias": (6, 4, 128, 16, 1, 1, "pad", False, None),
+    "full-bias-q4": (6, 4, 128, 16, 4, 1, "full", False, None),
+    "tiles": (6, 4, 256, 16, 1, 1, None, False, 64),
+    "tiles-grouped-int8-pad-bias": (6, 2, 256, 64, 4, 4, "pad", True, 64),
+}
+
+
+def _live_case(case):
+    from distributed_llms_example_tpu.ops import flash_attention as fa
+
+    B, H, L, d, q_len, q_group, bias_kind, int8, block_k = LIVE_CASES[case]
+    rng = np.random.RandomState(7)
+    q = jnp.asarray(rng.randn(B, H, q_len, d).astype(np.float32))
+    k = jnp.asarray(rng.randn(B, H, L, d).astype(np.float32))
+    v = jnp.asarray(rng.randn(B, H, L, d).astype(np.float32))
+    live = np.array([True, False, True, True, False, False])
+    # live rows in the first tile, a middle one and the last; idle rows parked at L, as the engine parks them
+    offsets = np.where(live, [3, 0, L // 2 + 5, L - q_len, 0, 0], L).astype(np.int32)
+    bias = None
+    if bias_kind == "pad":
+        bias = np.where(rng.rand(B, 1, 1, L) > 0.2, 0.0, -1e9).astype(np.float32)
+        bias[..., 0] = 0.0  # key 0 stays live: it is all a fresh row sees
+    elif bias_kind == "full":
+        bias = rng.randn(B, H, q_len, L).astype(np.float32)
+    scales = {}
+    leaves = [_leaf(k), _leaf(v)]
+    dense_k, dense_v = k, v
+    if int8:
+        (qk, ks), (qv, vs) = fa.quantize_kv(k), fa.quantize_kv(v)
+        leaves = [_leaf(qk), _leaf(qv)]
+        scales = {"k_scale": ks.transpose(0, 2, 1), "v_scale": vs.transpose(0, 2, 1)}
+        dense_k, dense_v = fa.dequantize_kv(qk, ks), fa.dequantize_kv(qv, vs)
+    kw = dict(block_k=block_k, q_group=q_group)
+    return fa, q, leaves, scales, bias, offsets, live, (dense_k, dense_v), kw
+
+
+@pytest.mark.parametrize("case", sorted(LIVE_CASES))
+def test_flash_decode_live_rows_are_the_all_rows_kernels_and_idle_rows_zero(case):
+    """With ``live`` given, a live row is bit for bit what the kernel returns
+    for it when every row is live (``live=None``: today's call), and within
+    tolerance the masked dense attention; an idle row is zero."""
+    fa, q, (k, v), scales, bias, offsets, live, (dk, dv), kw = _live_case(case)
+    b = None if bias is None else jnp.asarray(bias)
+    every = fa.flash_decode(q, k, v, b, offsets=jnp.minimum(offsets, k.shape[1] - 1), **scales, **kw)
+    out = np.asarray(fa.flash_decode(q, k, v, b, offsets=offsets, live=live, **scales, **kw))
+    np.testing.assert_array_equal(out[live], np.asarray(every)[live])
+    assert (out[~live] == 0).all()
+    L, Q = dk.shape[2], q.shape[2]
+    q_pos = offsets[:, None, None, None] + (np.arange(Q) // kw["q_group"])[None, None, :, None]
+    step = jnp.where(jnp.arange(L)[None, None, None, :] <= q_pos, 0.0, -1e9)
+    ref = dot_product_attention(q, dk, dv, step if b is None else b + step)  # row r sits at position r // q_group
+    np.testing.assert_allclose(out[live], np.asarray(ref)[live], atol=2e-6)
+    # every row named live is the call without the word
+    ones = fa.flash_decode(q, k, v, b, offsets=jnp.minimum(offsets, L - 1), live=np.ones(len(live), bool), **scales, **kw)
+    np.testing.assert_array_equal(np.asarray(ones), np.asarray(every))
+
+
+@pytest.mark.parametrize("case", sorted(LIVE_CASES))
+def test_flash_decode_reads_nothing_of_idle_slots_and_dead_tiles(case):
+    """Idle slots' leaves (K, V, int8 scales, bias rows) and every whole tile
+    past a live slot's last position filled with NaN: the output is finite and
+    the same, so nothing of them is used (a NaN times a zero weight would be a
+    NaN: the tiles are not merely masked)."""
+    fa, q, (k, v), scales, bias, offsets, live, _, kw = _live_case(case)
+    L = k.shape[1]
+    block = kw["block_k"] or fa.decode_block(L)
+    last_tile = np.minimum(offsets + (q.shape[2] - 1) // kw["q_group"], L - 1) // block
+    dead = (np.arange(L)[None, :] // block > last_tile[:, None]) | ~live[:, None]  # (B, L)
+    assert dead[live].any() or block == L  # the tiled cases leave dead tiles behind live rows
+    poison = lambda x: jnp.where(jnp.asarray(dead)[:, :, None], jnp.nan, x.astype(jnp.float32)).astype(x.dtype)  # noqa: E731
+    if k.dtype == jnp.int8:  # an s8 cannot hold a NaN: its scales do
+        pk, pv, pscales = k, v, {n: poison(s) for n, s in scales.items()}
+    else:
+        pk, pv, pscales = poison(k), poison(v), scales
+    b = pb = None
+    if bias is not None:
+        b = jnp.asarray(bias)
+        pb = jnp.where(jnp.asarray(~live)[:, None, None, None], jnp.nan, b)  # idle rows' bias is not fetched either
+    out = np.asarray(fa.flash_decode(q, k, v, b, offsets=offsets, live=live, **scales, **kw))
+    poisoned = np.asarray(fa.flash_decode(q, pk, pv, pb, offsets=offsets, live=live, **pscales, **kw))
+    assert np.isfinite(poisoned).all()
+    np.testing.assert_array_equal(poisoned, out)
+
+
+@pytest.mark.parametrize("case", ["plain", "tiles-grouped-int8-pad-bias"])
+def test_flash_decode_with_no_live_row_returns_zeros(case):
+    fa, q, (k, v), scales, bias, offsets, live, _, kw = _live_case(case)
+    b = None if bias is None else jnp.asarray(bias)
+    nan = lambda x: x if x.dtype == jnp.int8 else jnp.full_like(x, jnp.nan)  # noqa: E731
+    out = fa.flash_decode(
+        q, nan(k), nan(v), b, offsets=np.full(len(live), k.shape[1], np.int32), live=np.zeros(len(live), bool),
+        **{n: nan(s) for n, s in scales.items()}, **kw,
+    )
+    assert out.shape == q.shape and (np.asarray(out) == 0).all()
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["leaf", "ring"])
+def test_flash_decode_walk_names_a_new_block_only_for_a_live_tile(ring):
+    """The grid's walk (``_decode_walk``, what every operand's index map is):
+    over the whole grid in the pipeline's order, the (slot, tile) a step holds
+    changes exactly once a live tile of a live slot (a ring: every tile of a
+    live slot) and never for an idle slot or a dead tile, whose steps hold the
+    block already there; so the K/V fetched are the live tiles' and no more."""
+    from distributed_llms_example_tpu.ops import flash_attention as fa
+
+    nk, block, groups = 4, 64, 2
+    live = np.array([False, True, False, True, True, False, False, False])
+    offsets = np.array([256, 70, 256, 0, 255, 256, 256, 256], np.int32)  # live rows end in tiles 1, 0 and 3
+    order = np.argsort(~live, kind="stable").astype(np.int32)
+    n_live = np.array([live.sum()], np.int32)
+    held, fetches = None, []
+    for b in range(len(live)):
+        for g in range(groups):
+            for ki in range(nk):
+                at = tuple(int(x) for x in fa._decode_walk(
+                    b, g, ki, offsets, order, n_live, nk=nk, block_k=block, last_pos=0, last_group=groups - 1, ring=ring))
+                if at != held:
+                    fetches.append(at)
+                    held = at
+    tiles = {1: 2, 3: 1, 4: 4}  # live slot -> its live tiles
+    want = [(s, g, t) for s in (1, 3, 4) for g in range(groups) for t in range(nk if ring else tiles[s])]
+    assert fetches == want
+    # nobody live: one block for the whole grid (slot order[0], its last tile), which the kernel never touches
+    idle = {tuple(int(x) for x in fa._decode_walk(
+        b, g, ki, offsets, np.arange(8, dtype=np.int32), np.array([0], np.int32),
+        nk=nk, block_k=block, last_pos=0, last_group=groups - 1, ring=ring))
+        for b in range(8) for g in range(groups) for ki in range(nk)}
+    assert idle == {(0, groups - 1, nk - 1)}

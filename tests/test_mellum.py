@@ -351,7 +351,10 @@ def test_modes_that_cannot_hold_a_window_leaf_are_refused_by_name(mode):
         _engine(lm, **mode)
 
 
-def test_a_decode_round_reports_the_kv_positions_it_needs_and_streams():
+@pytest.mark.parametrize("impl", ["xla", "flash"])
+def test_a_decode_round_reports_the_kv_positions_it_needs_and_streams(impl):
+    from distributed_llms_example_tpu.core.config import MeshConfig
+    from distributed_llms_example_tpu.core.mesh import build_mesh
     from distributed_llms_example_tpu.obs.spans import SpanRecorder
 
     seen = []
@@ -369,15 +372,23 @@ def test_a_decode_round_reports_the_kv_positions_it_needs_and_streams():
         def set_metadata(self, **kw):
             seen.append((self.name, kw))
 
-    lm, params, _ = seeded(8)
-    sess = _engine(lm, new=8).open(params, spans=SpanRecorder(scope="serve", annotate=Annotation))
+    lm, params, _ = seeded(8, attention_impl=impl)
+    # a forced kernel runs (interpreted) only under a mesh context: eight virtual devices without one select XLA
+    mesh = build_mesh(MeshConfig(data=-1), devices=jax.devices()[:1])
+    sess = _engine(lm, new=16, mesh=mesh).open(params, spans=SpanRecorder(scope="serve", annotate=Annotation))
     sess.submit(list(range(2, 42)), max_new=4)  # 40 tokens: past the window
     sess.submit(list(range(2, 7)), max_new=2)  # 5 tokens: inside it
     while sess.has_work():
         sess.step()
     sess.finalize()
     rounds = [kw for name, kw in seen if name == "serve/decode_dispatch"]
-    assert all(kw["kv_positions_streamed"] == 3 * (3 * W + 56) for kw in rounds)  # slots x (window leaves + the full leaf)
+    if impl == "xla":
+        assert sess._kv_reads == [((W, 0), 3), ((64, 0), 1)]
+        assert all(kw["kv_positions_streamed"] == 3 * (3 * W + 64) for kw in rounds)  # slots x (window leaves + the full leaf)
+    else:  # the decode kernel (PR 46): of a live slot its rings whole and the full leaf's one tile (64 positions: 56 do not tile, and XLA would read them), of an idle slot nothing
+        assert sess._kv_reads == [((W, W), 3), ((64, 64), 1)]
+        assert [kw["kv_positions_streamed"] for kw in rounds] == [kw["slots_live"] * (3 * W + 64) for kw in rounds]
+        assert {kw["slots_live"] for kw in rounds} == {1, 2}
     # the round that emits a request's 2nd token holds its prompt and first token: 41 and 6 positions
     assert rounds[0]["kv_positions_live"] == (41 + 3 * W) + (6 + 3 * 6)
     assert rounds[1]["kv_positions_live"] == 42 + 3 * W  # the short request is done

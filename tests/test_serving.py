@@ -224,6 +224,26 @@ def test_flash_decode_run_shards_the_leaf_like_its_heads(mesh8, int8):
     np.testing.assert_array_equal(np.asarray(sharded), np.asarray(one))
 
 
+def test_flash_decode_run_walks_each_batch_shards_own_live_list(mesh8):
+    """Under ``shard_map`` ``live`` shards with the batch rows and each shard
+    sorts its own: the result is the one-device call's, idle rows zero, whatever
+    the idle rows' leaves hold."""
+    from distributed_llms_example_tpu.ops import flash_attention as fa
+
+    rng = np.random.RandomState(6)
+    B, H, L, d = 8, 4, 64, 16
+    q = jnp.asarray(rng.randn(B, H, 1, d).astype(np.float32))
+    k, v = (_leaf(jnp.asarray(rng.randn(B, H, L, d).astype(np.float32))) for _ in range(2))
+    live = np.array([True, False, False, True, False, True, True, False])  # a shard with none, one with both
+    offsets = jnp.asarray(np.where(live, rng.randint(0, L, B), L), jnp.int32)
+    one = np.asarray(flash_decode(q, k, v, offsets=offsets, live=live))
+    nan = jnp.where(jnp.asarray(live)[:, None, None], 0.0, jnp.nan)
+    sharded = jax.jit(lambda q, k, v, off, on: fa.flash_decode_run(q, k, v, None, offsets=off, live=on, mesh=mesh8))(
+        q, k + nan, v + nan, offsets, jnp.asarray(live))
+    np.testing.assert_array_equal(np.asarray(sharded), one)
+    assert (one[~live] == 0).all() and np.abs(one[live]).min() > 0
+
+
 def test_flash_decode_supported_gating():
     assert flash_decode_supported(1, 128, 64)
     assert flash_decode_supported(8, 64, 16)
@@ -723,8 +743,14 @@ def test_round_spans_partition_the_round(serve_rig, capsys):
         # and whether the round before it was still unfetched (PR 42): every round but the first
         for e in [rd] + kids[2 if wave else 1:]:
             if e["name"] == "serve/decode_dispatch":
-                assert set(e["stats"]) == {"slots_live", "slots_streamed", "ahead"}
+                assert set(e["stats"]) == {"slots_live", "slots_streamed", "ahead",
+                                           "cross_positions_live", "cross_positions_streamed"}
                 assert 1 <= e["stats"]["slots_live"] <= e["stats"]["slots_streamed"] == sess.eng.S
+                # a seq2seq round's cross attention (PR 46): the live slots' source positions over the
+                # decoder layers, against every slot's whole source width where XLA runs the step (here)
+                layers, st = len(sess.state["ckv"]), e["stats"]
+                assert 0 < st["cross_positions_live"] <= st["slots_live"] * sess.eng.W * layers
+                assert st["cross_positions_streamed"] == sess.eng.S * sess.eng.W * layers
                 assert e["stats"]["ahead"] == (0 if first else 1)
             else:
                 assert e["stats"] == {}
@@ -938,6 +964,179 @@ def test_engine_matches_static_batching_causal(mesh8):
     eos, pad = lm.config.eos_token_id, lm.config.pad_token_id
     for got, want in zip(outs, ref):
         assert trim_eos(got, eos, pad) == trim_eos(want, eos, pad)
+
+
+# ------------------- the decode kernel over live slots alone; bart's cross step on it (PR 46)
+
+
+@pytest.fixture
+def one_device_mesh():
+    """A forced ``attention_impl="flash"`` runs the kernels (interpreted) only
+    under a mesh context: pytest's eight virtual devices without one select XLA."""
+    from distributed_llms_example_tpu.core.config import MeshConfig
+    from distributed_llms_example_tpu.core.mesh import build_mesh
+
+    return build_mesh(MeshConfig(data=-1), devices=jax.devices()[:1])
+
+
+def _serve_all(sess, reqs, budgets):
+    """Serve ``reqs`` through the session to the end; -> each request's tokens."""
+    rids = [sess.submit(r, max_new=b) for r, b in zip(reqs, budgets)]
+    while sess.has_work():
+        sess.step()
+    sess.finalize()
+    return [list(sess.outputs[r]) for r in rids]
+
+
+@pytest.mark.parametrize("impl", ["flash", "auto"], ids=["kernel", "xla"])
+def test_bart_cross_step_over_mostly_idle_slots_serves_the_unbatched_tokens(impl, one_device_mesh, monkeypatch, capsys):
+    """bart-test, eight slots of which at most three hold a request, sources of
+    unequal length (one tile of the cross leaf and two): greedy tokens equal the
+    unbatched reference's, with the cross step (and the self step) on the decode
+    kernel, interpreted, and on XLA's path.  The slots keep the cross K/V as a
+    cache keeps K/V, and ``serve/decode_dispatch`` says what the cross step
+    reads: whole tiles of the live slots where the kernel runs, every slot's
+    whole width elsewhere."""
+    import time
+
+    from distributed_llms_example_tpu.obs.spans import SpanRecorder
+    from distributed_llms_example_tpu.ops import flash_attention as fa
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine, static_batch_generate, trim_eos
+
+    monkeypatch.setattr(fa, "MAX_BLOCK", 128)  # so that a 256-wide source is two kv tiles
+    kernel_calls, flash_decode = [], fa.flash_decode
+    monkeypatch.setattr(fa, "flash_decode", lambda q, k, v, bias=None, **kw: (
+        kernel_calls.append((k.shape, kw.get("live") is not None)), flash_decode(q, k, v, bias, **kw))[1])
+    lm = load_model("bart-test")
+    module, config = _with_impl(lm, impl)
+    params = lm.init_params(0)
+    W, L, S = 256, 16, 8
+    rng = np.random.RandomState(13)
+    reqs = [list(rng.randint(4, 200, n)) for n in (5, 200, 90)]
+    budgets = [16, 9, 12]
+    eng = ServingEngine(module, config, one_device_mesh,
+                        ServeConfig(max_slots=S, prefill_batch=2, max_new_tokens=L, max_source_length=W,
+                                    log_every_steps=0, request_spans=False), is_seq2seq=True)
+    notes = _Annotations(time.perf_counter)
+    sess = eng.open(params, spans=SpanRecorder(clock=time.perf_counter, scope="serve", annotate=notes))
+    layers = len(sess.state["ckv"])
+    assert {x.shape for x in jax.tree.leaves(sess.state["ckv"])} == {(S, W, config.d_model)}  # (slots, length, heads x d)
+    outs = _serve_all(sess, reqs, budgets)
+    xla_module, xla_config = _with_impl(lm, "xla")
+    ref = static_batch_generate(xla_module, xla_config, one_device_mesh, params, reqs, max_new_tokens=L, width=W, batch=1)
+    eos, pad = config.eos_token_id, config.pad_token_id
+    for got, want, budget in zip(outs, ref, budgets):
+        g = trim_eos(got, eos, pad)
+        assert g == trim_eos(want, eos, pad)[: len(g)] and len(g) <= budget
+    rounds = [e["stats"] for e in notes.events if e["name"] == "serve/decode_dispatch"]
+    assert rounds and all("kv_positions_streamed" not in st for st in rounds)  # the causal cache's counters
+    kernel = impl == "flash"
+    # the step's traces: the kernel for the self leaves and the cross leaves, told who is live, or not at all
+    assert set(kernel_calls) == ({((S, L, config.d_model), True), ((S, W, config.d_model), True)} if kernel else set())
+    assert sess._cross_reads == [((W, 128 if kernel else 0), layers)]
+    tiles = {5: 128, 200: 256, 90: 128}  # a source's positions as whole tiles of 128
+    seen = set()
+    for st in rounds:
+        assert 0 < st["cross_positions_live"] <= st["cross_positions_streamed"]
+        assert st["slots_live"] <= 3 < S
+        if kernel:
+            assert st["cross_positions_streamed"] <= st["slots_live"] * W * layers < S * W * layers
+            seen.add((st["cross_positions_live"] // layers, st["cross_positions_streamed"] // layers))
+        else:
+            assert st["cross_positions_streamed"] == S * W * layers
+    if kernel:  # each mix of live sources reads its own whole tiles: all three, then as the budgets end
+        assert {(5 + 200 + 90, 128 + 256 + 128), (5 + 90, 128 + 128), (5, 128)} <= seen
+        for live, streamed in seen:
+            assert streamed == sum(tiles[n] for n in _subset(live, (5, 200, 90)))
+    capsys.readouterr()
+
+
+def _subset(total, parts):
+    """The subset of ``parts`` that sums to ``total`` (the live sources of a round)."""
+    import itertools
+
+    return next(c for r in range(len(parts) + 1) for c in itertools.combinations(parts, r) if sum(c) == total)
+
+
+def test_causal_round_counts_the_whole_tiles_of_its_live_slots(one_device_mesh, monkeypatch, capsys):
+    """``kv_positions_streamed`` on the kernel's path: of each live slot the
+    whole kv tiles up to its write position, of an idle slot nothing; on XLA's
+    path every slot's whole leaf."""
+    import time
+
+    from distributed_llms_example_tpu.obs.spans import SpanRecorder
+    from distributed_llms_example_tpu.ops import flash_attention as fa
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine
+
+    monkeypatch.setattr(fa, "MAX_BLOCK", 128)
+    lm = load_model("llama-test")
+    W, L, S = 240, 16, 4  # a K/V leaf of 256 positions: two kv tiles
+    rng = np.random.RandomState(5)
+    reqs = [list(rng.randint(4, 120, n)) for n in (7, 130)]
+    tokens = {}
+    for impl in ("flash", "xla"):
+        module, config = _with_impl(lm, impl)
+        config = dataclasses.replace(config, eos_token_id=None)
+        eng = ServingEngine(module, config, one_device_mesh,
+                            ServeConfig(max_slots=S, prefill_batch=2, max_new_tokens=L, max_source_length=W,
+                                        prefill_buckets=(W,), log_every_steps=0, request_spans=False), is_seq2seq=False)
+        notes = _Annotations(time.perf_counter)
+        sess = eng.open(lm.init_params(0), spans=SpanRecorder(clock=time.perf_counter, scope="serve", annotate=notes))
+        tokens[impl] = _serve_all(sess, reqs, [6, 6])
+        layers = len(sess._kv_lengths)
+        rounds = [e["stats"] for e in notes.events if e["name"] == "serve/decode_dispatch"]
+        assert rounds and layers == lm.config.num_hidden_layers
+        for st in rounds:
+            assert st["slots_live"] == 2 and st["kv_positions_live"] <= st["kv_positions_streamed"]
+            # every prompt sits in the one bucket of 240, so a slot writes at 240 + its tokens so far: the second tile
+            assert st["kv_positions_streamed"] == (2 * 256 if impl == "flash" else S * 256) * layers
+    assert tokens["flash"] == tokens["xla"]
+    capsys.readouterr()
+
+
+def test_t5_serving_and_beam_evaluation_keep_their_cross_layout(one_device_mesh, capsys):
+    """What PR 46 leaves alone: T5's engine slots hold the cross K/V as (slots,
+    heads, length, head_dim) (its own attention class reads it) and count it as
+    read whole; ``generation.py`` hands bart's decode the 4-D pair, whose beam
+    path folds beams beside heads; both serve and search the tokens they did."""
+    from distributed_llms_example_tpu.evaluation.generation import make_beam_search
+    from distributed_llms_example_tpu.serving.engine import ServeConfig, ServingEngine, static_batch_generate, trim_eos
+
+    t5 = load_model("t5-test")
+    params = t5.init_params(0)
+    rng = np.random.RandomState(3)
+    reqs = _requests(rng, 3)
+    eng = ServingEngine(t5.module, t5.config, one_device_mesh,
+                        ServeConfig(max_slots=4, prefill_batch=2, max_new_tokens=8, max_source_length=32,
+                                    log_every_steps=0, request_spans=False), is_seq2seq=True)
+    assert not eng.cross_kv_rows
+    sess = eng.open(params)
+    heads, d_kv = t5.config.num_heads, t5.config.d_kv
+    assert {x.shape for x in jax.tree.leaves(sess.state["ckv"])} == {(4, heads, 32, d_kv)}
+    assert sess._cross_reads == [((32, 0), len(sess.state["ckv"]))]
+    outs = _serve_all(sess, reqs, [8] * 3)
+    ref = static_batch_generate(t5.module, t5.config, one_device_mesh, params, reqs, max_new_tokens=8, width=32, batch=1)
+    eos, pad = t5.config.eos_token_id, t5.config.pad_token_id
+    assert [trim_eos(o, eos, pad) for o in outs] == [trim_eos(r, eos, pad) for r in ref]
+    # beam evaluation of bart: the rows layout is the engine's to ask for, never generation's
+    bart = load_model("bart-test")
+    bp = bart.init_params(0)
+    ids = rng.randint(4, 200, (2, 16)).astype(np.int32)
+    mask = np.ones((2, 16), np.int32)
+    mask[1, -6:] = 0
+    enc = bart.module.apply({"params": bp}, ids, mask, method="encode")
+    pairs = bart.module.apply({"params": bp}, enc, method="cross_kv")
+    assert {x.ndim for x in jax.tree.leaves(pairs)} == {4}
+    rows = bart.module.apply({"params": bp}, enc, True, method="cross_kv")
+    for (k4, v4), (k3, v3) in zip(pairs, rows):  # the same numbers, laid as a cache keeps them
+        np.testing.assert_array_equal(np.asarray(_leaf(k4)), np.asarray(k3))
+        np.testing.assert_array_equal(np.asarray(_leaf(v4)), np.asarray(v3))
+    beams = {}
+    for impl in ("xla", "flash"):
+        mod, cfg = _with_impl(bart, impl)
+        beams[impl] = np.asarray(make_beam_search(mod, cfg, 8, num_beams=2)(bp, ids, mask))
+    np.testing.assert_array_equal(beams["xla"], beams["flash"])
+    capsys.readouterr()
 
 
 # ---------------------------- the round's order: dispatch ahead, fetch behind (PR 42)
